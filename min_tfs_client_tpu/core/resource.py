@@ -29,25 +29,27 @@ from min_tfs_client_tpu.utils.status import ServingError
 
 
 def detect_hbm_pools() -> dict[int, int]:
-    """Per-device HBM from PJRT memory stats. Devices without stats (CPU
-    test meshes) get a generous virtual pool each — the id set must mirror
-    jax.local_devices() or bound per-chip allocations from
-    estimate_for_mesh could name devices the tracker doesn't know."""
-    try:
-        import jax
+    """Per-device HBM from PJRT memory stats. CPU devices (the test
+    meshes) report none and get a generous virtual pool each — the id set
+    must mirror jax.local_devices() or bound per-chip allocations from
+    estimate_for_mesh could name devices the tracker doesn't know. A TPU
+    that reports no `bytes_limit` is an error: gating loads against a
+    made-up pool would approve what the chip cannot hold."""
+    import jax
 
-        pools = {}
-        for d in jax.local_devices():
-            stats = getattr(d, "memory_stats", lambda: None)()
-            if stats and "bytes_limit" in stats:
-                pools[d.id] = int(stats["bytes_limit"])
-            else:
-                pools[d.id] = 1 << 40
-        if pools:
-            return pools
-    except Exception:  # pragma: no cover - device probing best-effort
-        pass
-    return {0: 1 << 40}  # no backend at all: single virtual pool
+    pools = {}
+    for d in jax.local_devices():
+        stats = d.memory_stats() or {}
+        if "bytes_limit" in stats:
+            pools[d.id] = int(stats["bytes_limit"])
+        elif d.platform == "cpu":
+            pools[d.id] = 1 << 40
+        else:
+            raise ServingError.internal(
+                f"{d.platform} device {d.id} ({d.device_kind}) reports no "
+                "bytes_limit in memory_stats(); refusing to gate loads "
+                "against an invented HBM pool")
+    return pools
 
 
 def estimate_for_mesh(total_bytes: int, mesh_axes: dict[str, int],

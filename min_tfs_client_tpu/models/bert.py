@@ -156,6 +156,50 @@ def logits_fn(params: dict, config: BertConfig, input_ids, attention_mask,
         jnp.float32)
 
 
+def reference_logits(params: dict, config: BertConfig, input_ids,
+                     attention_mask) -> jax.Array:
+    """The float32 reference `logits_fn` is compared against: the same
+    post-LN encoder written in plain jax.numpy — float32 throughout,
+    masked softmax attention, no Pallas, no bf16 — under "highest" matmul
+    precision so a TPU does not quietly run it in bf16 either. Dense-MLP
+    configs only."""
+    f32 = jnp.float32
+    tree = jax.tree_util.tree_map(lambda p: jnp.asarray(p, f32), params)
+
+    def dense(p, x):
+        return x @ p["kernel"] + p["bias"]
+
+    def norm(p, x):
+        mean = jnp.mean(x, axis=-1, keepdims=True)
+        var = jnp.mean(jnp.square(x - mean), axis=-1, keepdims=True)
+        return ((x - mean) * jax.lax.rsqrt(var + config.layer_norm_eps)
+                * p["scale"] + p["bias"])
+
+    with jax.default_matmul_precision("highest"):
+        b, s = input_ids.shape
+        h, d = config.num_heads, config.hidden_size // config.num_heads
+        emb = tree["embeddings"]
+        x = (emb["word"]["embedding"][input_ids]
+             + emb["position"]["embedding"][jnp.arange(s)][None]
+             + emb["token_type"]["embedding"][jnp.zeros_like(input_ids)])
+        x = norm(emb["norm"], x)
+        key_mask = jnp.asarray(attention_mask, bool)[:, None, None, :]
+        for layer in tree["layers"]:
+            att = layer["attention"]
+            q, k, v = (dense(att[n], x).reshape(b, s, h, d)
+                       for n in ("query", "key", "value"))
+            scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(d)
+            probs = jax.nn.softmax(
+                jnp.where(key_mask, scores, -jnp.inf), axis=-1)
+            ctx = jnp.einsum("bhqk,bkhd->bqhd", probs, v).reshape(b, s, h * d)
+            x = norm(layer["attention_norm"], x + dense(att["out"], ctx))
+            mlp = layer["mlp"]
+            ffn = dense(mlp["wo"], jax.nn.gelu(dense(mlp["wi"], x)))
+            x = norm(layer["mlp_norm"], x + ffn)
+        pooled_out = jnp.tanh(dense(tree["pooler"], x[:, 0]))
+        return dense(tree["head"], pooled_out)
+
+
 # -- pipeline-parallel serving (SURVEY.md §2.11 PP row) ----------------------
 
 

@@ -1,22 +1,19 @@
-"""servetrend: the gated bench-regression sentry over the BENCH ledger.
+"""servetrend: the gated bench-regression sentry over the bench ledger.
 
-The repo's BENCH_*.json trajectory records what the bench harness
-measured each round, but nothing READS it: a chip-measured regression
-lands in a JSON file and stays invisible until a human diffs numbers by
-hand — and a stale cpu replay can masquerade as a chip number (the
-exact failure TPU_TIER documents). This tool makes the trajectory a
-gate:
+A trajectory of bench captures that nothing READS hides regressions: a
+chip-measured regression lands in a JSON file and stays invisible until
+a human diffs numbers by hand — and a replayed or cpu number can
+masquerade as a chip number. This tool makes the trajectory a gate:
 
  * every bench run appends schema-versioned trend records — one per
    measured leg, stamped with the knob context AND the measurement
    provenance `{platform, device_kind, probe_outcome}` captured at
    measurement time (bench.py stamps them; `ingest` backfills from
-   the checked-in driver files);
+   driver capture files);
  * `servetrend gate` compares the newest non-stale record per
    (metric, platform, device_kind) group against the median of its
    own history inside a noise band, and EXITS NONZERO on a regression
-   beyond the band — a recorded regression fails like a test (it is
-   wired into tier-1 against the repo's checked-in history);
+   beyond the band — a recorded regression fails like a test;
  * cross-provenance comparisons are REFUSED, never silently made: a
    cpu record can never gate against a tpu record, a v4 record never
    against a v5e record. A metric whose only history lives on another

@@ -21,27 +21,16 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-import inspect
-
-try:
-    from jax import shard_map as _shard_map
-except ImportError:  # older jax
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-# The fori_loop carry mixes axis-varying (rotating K/V) and invariant
-# arrays; disable the varying-manual-axes check under whichever name this
-# jax version spells it.
-_CHECK_KW = ("check_vma" if "check_vma" in
-             inspect.signature(_shard_map).parameters else "check_rep")
-
-
-def shard_map(fn, **kw):
-    kw[_CHECK_KW] = False
-    return _shard_map(fn, **kw)
 from jax.sharding import Mesh, PartitionSpec as P
 
 from min_tfs_client_tpu.ops.attention import NEG_INF
 from min_tfs_client_tpu.parallel.mesh import SEQ_AXIS
+
+
+def shard_map(fn, **kw):
+    """jax.shard_map with the varying-manual-axes check off: the fori_loop
+    carry mixes axis-varying (rotating K/V) and invariant arrays."""
+    return jax.shard_map(fn, check_vma=False, **kw)
 
 
 def _block_update(q, k_blk, v_blk, o, m, l, q_pos, k_pos, *, scale,

@@ -288,9 +288,16 @@ class Signature:
                 self.telemetry_label or "unlabeled", self.jitted(),
                 # the arrays dict is always the LAST positional arg
                 bucket_fn=lambda args: runtime.shape_bucket(args[-1]))
-        if self.params is not None:
-            return fn(self.params, arrays)
-        return fn(arrays)
+        args = (arrays,) if self.params is None else (self.params, arrays)
+        if self.mesh is None:
+            return fn(*args)
+        import jax
+
+        # The ambient mesh is how ops/attention learns, at trace time,
+        # which axes to split its Pallas kernel over (XLA partitions the
+        # rest of the program by itself, a Mosaic kernel it cannot).
+        with jax.set_mesh(self.mesh):
+            return fn(*args)
 
     def _data_axis_size(self) -> int:
         from min_tfs_client_tpu.parallel.mesh import data_axis_size
@@ -479,9 +486,8 @@ class Signature:
         # Fetch ONLY the requested outputs (the executable computes them
         # all, but unfetched ones never cross the device->host link), in a
         # single overlapped round: async-copy every output now, leave the
-        # materialization to result(). N sequential DMAs collapse to one
-        # round trip — on remote/tunneled PJRT transports each synchronous
-        # fetch costs a full RTT, and even locally the DMAs overlap.
+        # materialization to result(). N sequential blocking fetches
+        # collapse to one overlapped round of DMAs.
         pending = {k: outputs[k] for k in keys}
         # Issuing the copies is the dispatch half of the D2H stage (the
         # handle's result() records the blocking half under the same
@@ -599,22 +605,21 @@ class Signature:
         with tracing.span("device/execute"):
             return self._execute(arrays), batch
 
-    # Below this, the jit arg path transfers just as fast and the
-    # device_put plumbing (~0.2 ms of pure Python) dominates; the slow
-    # chunked per-arg conversion this guards against was measured on
-    # multi-MB conv inputs.
+    # Below this the device_put plumbing (~0.2 ms of pure Python)
+    # outweighs what an explicit transfer can save. The threshold predates
+    # the locally attached chip and has not been re-measured on it.
     _PLACE_MIN_BYTES = 256 * 1024
 
     @classmethod
     def _place(cls, arrays: dict[str, np.ndarray]) -> dict:
         """Explicit batched host->device transfer before dispatch. Passing
         LARGE ndarrays straight as jit args leaves the transfer to
-        per-argument conversion inside the call, which on remote PJRT
-        transports takes a slow chunked path (measured ~50x slower than
-        device_put for a 9.5MB conv input) and even locally serializes
-        with dispatch; one batched device_put of the whole input dict
-        overlaps the DMAs. Small inputs skip the explicit hop — for them
-        device_put's own Python overhead exceeds the transfer."""
+        per-argument conversion inside the call, serialized with
+        dispatch; one batched device_put of the whole input dict overlaps
+        the DMAs. Small inputs skip the explicit hop — for them
+        device_put's own Python overhead exceeds the transfer. Lands on
+        the default device: meshed signatures go through _shard_inputs
+        instead."""
         import jax
 
         dense = {k: v for k, v in arrays.items()

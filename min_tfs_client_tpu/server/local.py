@@ -18,6 +18,7 @@ from min_tfs_client_tpu.client.inprocess import (
 )
 from min_tfs_client_tpu.core.server_core import ServerCore, single_model_config
 from min_tfs_client_tpu.server.handlers import Handlers
+from min_tfs_client_tpu.utils import compile_cache
 from min_tfs_client_tpu.utils.status import error_from_exception, to_grpc_code
 
 
@@ -74,6 +75,7 @@ def boot_local_server(base_path: str) -> LocalServer:
             if (child / "servable.py").is_file():
                 platform = "jax"
             break
+    compile_cache.configure()
     core = ServerCore(
         single_model_config(name, str(path), platform=platform),
         file_system_poll_wait_seconds=0,  # poll once; in-process is static

@@ -79,26 +79,12 @@ def _load_lib() -> Optional[ctypes.CDLL]:
     ]
     lib.tpuhttp_stop.restype = None
     lib.tpuhttp_stop.argtypes = [ctypes.c_void_p]
-    try:
-        # Added after the first libtpunethttp.so shipped: a stale cached
-        # .so (mtime newer than the source it was built from, e.g. a
-        # copied artifact) may predate the symbol — degrade to the old
-        # no-headers behavior instead of failing the whole front-end.
-        lib.tpuhttp_request_header.restype = ctypes.c_char_p
-        lib.tpuhttp_request_header.argtypes = [
-            ctypes.c_void_p, ctypes.c_char_p,
-        ]
-    except AttributeError:  # pragma: no cover - stale prebuilt library
-        pass
+    lib.tpuhttp_request_header.restype = ctypes.c_char_p
+    lib.tpuhttp_request_header.argtypes = [
+        ctypes.c_void_p, ctypes.c_char_p,
+    ]
     _lib = lib
     return _lib
-
-
-def native_headers_available() -> bool:
-    """Whether the loaded library exports tpuhttp_request_header (False
-    only with a stale prebuilt .so; a fresh build always has it)."""
-    lib = _load_lib()
-    return lib is not None and hasattr(lib, "tpuhttp_request_header")
 
 
 def native_http_available() -> bool:
@@ -138,10 +124,7 @@ class NativeRestServer:
         C side's header table while the Request is still alive (the
         returned pointer is only valid during the synchronous callback;
         ctypes' c_char_p restype copies it to Python bytes here)."""
-        header_fn = getattr(self._lib, "tpuhttp_request_header", None)
-        if header_fn is None:  # pragma: no cover - stale prebuilt library
-            return ""
-        value = header_fn(req, TRACE_HEADER.encode())
+        value = self._lib.tpuhttp_request_header(req, TRACE_HEADER.encode())
         if not value:
             return ""
         try:

@@ -442,6 +442,7 @@ class Server:
 
         if opts.rest_api_port or opts.monitoring_config_file:
             from min_tfs_client_tpu.server.native_http import (
+                NativeRestServer,
                 start_best_rest_server,
             )
 
@@ -454,6 +455,8 @@ class Server:
                 num_threads=opts.rest_api_num_threads,
                 timeout_ms=opts.rest_api_timeout_in_ms,
                 impl=opts.rest_api_impl)
+            self.rest_backend = ("native" if isinstance(
+                self._rest_server, NativeRestServer) else "python")
 
         if opts.profiler_port:
             from min_tfs_client_tpu.server.profiler import (

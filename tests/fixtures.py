@@ -917,8 +917,8 @@ def _sleep_until(deadline: float) -> None:
 
 def simulate_device_latency(signature, latency_s: float):
     """Shim `signature.dispatch` so every launched execution completes
-    `latency_s` of wall-clock after dispatch (tunneled-PJRT-link model,
-    PERF.md transport profile). Install BEFORE wrapping with
+    `latency_s` of wall-clock after dispatch (a stand-in for a
+    high-latency host-device link). Install BEFORE wrapping with
     BatchedSignatureRunner — the runner captures the dispatch seam at
     construction. sync `run` routes through the same seam, so the
     window=1 path pays the full latency serially."""

@@ -29,8 +29,6 @@ os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
 sys.path.insert(0, {repo!r})
 
 import jax
-jax.config.update("jax_platforms", "cpu")
-
 import numpy as np
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P

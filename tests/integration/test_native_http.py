@@ -28,7 +28,7 @@ pytestmark = pytest.mark.skipif(
     not native_http_available(), reason="native HTTP library not buildable")
 
 
-def echo_route(handlers, prom, method, path, body):
+def echo_route(handlers, prom, method, path, body, trace_id=""):
     payload = json.dumps({
         "method": method, "path": path, "len": len(body),
         "body": body.decode("latin1"),
@@ -155,7 +155,7 @@ def test_corrupt_gzip_request_is_400(server):
 
 
 def test_large_response_gzipped_when_accepted():
-    def big_route(handlers, prom, method, path, body):
+    def big_route(handlers, prom, method, path, body, trace_id=""):
         return 200, "text/plain", b"A" * 50000
 
     srv = NativeRestServer(None, 0, route_fn=big_route)
@@ -239,7 +239,7 @@ def test_http_1_0_closes_by_default(server):
 
 
 def test_handler_exception_becomes_500():
-    def bad_route(handlers, prom, method, path, body):
+    def bad_route(handlers, prom, method, path, body, trace_id=""):
         raise RuntimeError("boom inside the router")
 
     srv = NativeRestServer(None, 0, route_fn=bad_route)

@@ -245,7 +245,8 @@ def test_partitioned_interior_serves_dp_sharded_on_the_mesh(exported):
             # devices in the lowered interior HLO.
             ids = np.stack([f["ids"] for f in FEATURES] * 3)[:8]
             hlo = part.interior_hlo_text([ids])
-            assert "devices=[8,1]<=[8]" in hlo
+            assert 'sdy.mesh @mesh = <["data"=8]>' in hlo
+            assert '#sdy.sharding<@mesh, [{"data"}, {}]>' in hlo
     finally:
         core.stop()
 

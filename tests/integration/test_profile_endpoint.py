@@ -208,13 +208,6 @@ class TestNativeTraceAdoption:
         ring must carry the caller's id — on the python backend via the
         handler's header dict, on the NATIVE backend via the
         tpuhttp_request_header bridge (new with this plane)."""
-        if rest_server.options.rest_api_impl == "native":
-            from min_tfs_client_tpu.server.native_http import (
-                native_headers_available,
-            )
-
-            if not native_headers_available():
-                pytest.skip("stale prebuilt .so without header export")
         trace_id = f"adopt-{rest_server.options.rest_api_impl}-0042"
         # Columnar format: the servable signature is rank-1, and the
         # row format would prepend a batch dimension.

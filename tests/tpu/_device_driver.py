@@ -3,17 +3,20 @@
 Run by tests/tpu/test_on_device.py in a subprocess (the pytest process
 itself is pinned to a CPU mesh by tests/conftest.py, and jax cannot switch
 backends mid-process). Each check prints one JSON line
-{"check": name, "ok": bool, ...}; the wrapper asserts on them.
+{"check": name, "ok": bool, ...}; the wrapper asserts on them, and the
+exit code is non-zero when any check failed.
 """
 
 import json
+import pathlib
 import sys
 import time
 
-sys.path.insert(0, "/root/repo")
+sys.path.insert(0, str(pathlib.Path(__file__).resolve().parents[2]))
 
 
 _LAST_EMIT = time.monotonic()
+_FAILED: list[str] = []
 
 
 def emit(check: str, ok: bool, **extra) -> None:
@@ -21,17 +24,24 @@ def emit(check: str, ok: bool, **extra) -> None:
     now = time.monotonic()
     extra.setdefault("ms", round((now - _LAST_EMIT) * 1e3, 1))
     _LAST_EMIT = now
+    if not ok:
+        _FAILED.append(check)
     print(json.dumps({"check": check, "ok": ok, **extra}), flush=True)
 
 
 def main() -> int:
+    from min_tfs_client_tpu.utils import compile_cache
+
+    compile_cache.configure()
     import jax
     import jax.numpy as jnp
     import numpy as np
 
     dev = jax.devices()[0]
-    emit("backend", dev.platform != "cpu", platform=str(dev.platform),
-         kind=getattr(dev, "device_kind", ""))
+    emit("backend", dev.platform == "tpu", platform=str(dev.platform),
+         kind=dev.device_kind)
+    if dev.platform != "tpu":
+        return 1  # nothing below means anything on another backend
 
     # -- 1. flash attention on the MXU vs the jnp oracle -------------------
     from min_tfs_client_tpu.ops.attention import (
@@ -70,6 +80,40 @@ def main() -> int:
         emit(f"flash_attention/{name}", err < 0.06, max_err=err,
              ms=round(dt, 2))
 
+    # -- 1b. ragged paged attention, with bias, past one page ---------------
+    # T5's only path (models/t5.py always passes bias=), at a page size
+    # below 128 and a table wider than one page: the shape Mosaic refused
+    # until the bias tile spanned its array's last two dims. Sq=1 is a
+    # decode tick, Sq=5 a verify block / prefill chunk.
+    from min_tfs_client_tpu.ops.attention import (
+        paged_attention,
+        paged_attention_reference,
+    )
+
+    pb, ph, pd, page, width = 4, 8, 64, 16, 3
+    n_pages = pb * width + 1
+    k_pages = jnp.asarray(
+        rng.standard_normal((n_pages, ph, page, pd)), jnp.bfloat16)
+    v_pages = jnp.asarray(
+        rng.standard_normal((n_pages, ph, page, pd)), jnp.bfloat16)
+    tables = jnp.asarray(
+        rng.permutation(n_pages - 1).reshape(pb, width), jnp.int32)
+    for sq in (1, 5):
+        pq = jnp.asarray(rng.standard_normal((pb, ph, sq, pd)), jnp.bfloat16)
+        bias = jnp.asarray(
+            rng.standard_normal((pb, ph, sq, width * page)), jnp.float32)
+        plens = jnp.asarray([width * page, page + 3, page, sq], jnp.int32)
+        lowered = jax.jit(paged_attention).lower(
+            pq, k_pages, v_pages, tables, plens, bias=bias).as_text()
+        got = np.asarray(paged_attention(
+            pq, k_pages, v_pages, tables, plens, bias=bias), np.float32)
+        want = np.asarray(paged_attention_reference(
+            pq, k_pages, v_pages, tables, plens, bias=bias), np.float32)
+        err = float(np.max(np.abs(got - want)))
+        emit(f"paged_bias_multipage/sq{sq}",
+             "tpu_custom_call" in lowered and err < 0.06, max_err=err,
+             dispatched="tpu_custom_call" in lowered)
+
     # -- 2. bucketed Predict through the serving stack on device -----------
     import pathlib
     import tempfile
@@ -106,8 +150,8 @@ def main() -> int:
          bool(np.allclose(probs, probs2, atol=1e-5)))
 
     # -- 4. int8 quantized serving on device vs full precision -------------
-    # Each trailing check fails in isolation (emit ok=False) — an
-    # exception here must not turn already-passed checks into failures.
+    # Each trailing check fails in isolation (emit ok=False, and a
+    # non-zero exit at the end) so one failure does not hide the rest.
     try:
         import dataclasses
 
@@ -184,7 +228,9 @@ def main() -> int:
         emit("continuous_batching_decode", toks == list(want), tokens=toks)
     except Exception as exc:  # noqa: BLE001 - per-check isolation
         emit("continuous_batching_decode", False, error=repr(exc)[:500])
-    return 0
+    if _FAILED:
+        print(f"failed checks: {_FAILED}", file=sys.stderr)
+    return 1 if _FAILED else 0
 
 
 if __name__ == "__main__":

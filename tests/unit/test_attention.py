@@ -7,6 +7,8 @@ import numpy as np
 import pytest
 
 from min_tfs_client_tpu.ops.attention import (
+    _flash_kernel_applies,
+    _paged_kernel_applies,
     attention,
     attention_reference,
     flash_attention,
@@ -288,3 +290,88 @@ def test_fully_masked_rows_are_zero_in_both_paths():
     np.testing.assert_array_equal(ref[0], 0.0)
     np.testing.assert_array_equal(fl[0], 0.0)
     np.testing.assert_allclose(fl[1], ref[1], atol=2e-5, rtol=2e-5)
+
+
+# -- the kernels as the TPU compiler sees them --------------------------------
+#
+# Interpret mode never meets Mosaic's block-shape rules, and the dispatcher
+# serves the jnp reference on this backend, so a kernel the chip refuses
+# passes every test above. Cross-lowering for the TPU platform applies
+# those rules here, at the shapes the server really sends.
+
+
+def _lower_for_tpu(fn, *args):
+    return jax.jit(fn).trace(*args).lower(lowering_platforms=("tpu",))
+
+
+def test_flash_lowers_for_tpu_at_bert_base_shapes():
+    b, h, s, d = 32, 12, 128, 64
+    qkv = jax.ShapeDtypeStruct((b, h, s, d), jnp.bfloat16)
+    lengths = jax.ShapeDtypeStruct((b,), jnp.int32)
+    assert _flash_kernel_applies(qkv, qkv)
+    text = _lower_for_tpu(
+        lambda q, k, v, n: flash_attention(q, k, v, lengths=n),
+        qkv, qkv, qkv, lengths).as_text()
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("sq", [1, 5])
+@pytest.mark.parametrize("width", [1, 2, 4])
+@pytest.mark.parametrize("block_size", [8, 16, 32, 128])
+def test_paged_with_bias_lowers_for_tpu(block_size, width, sq):
+    """T5's only path: bias, page sizes the gate admits, table wider than
+    one page (the case Mosaic refused while the bias rode as one
+    (Sq, P*bs) row per head)."""
+    b, h, d, pages = 4, 8, 64, 4 * width + 1
+    q = jax.ShapeDtypeStruct((b, h, sq, d), jnp.bfloat16)
+    arena = jax.ShapeDtypeStruct((pages, h, block_size, d), jnp.bfloat16)
+    tables = jax.ShapeDtypeStruct((b, width), jnp.int32)
+    lengths = jax.ShapeDtypeStruct((b,), jnp.int32)
+    bias = jax.ShapeDtypeStruct((b, h, sq, width * block_size), jnp.float32)
+    assert _paged_kernel_applies(q, arena, tables)
+    text = _lower_for_tpu(
+        lambda q, k, v, t, n, bias: paged_flash_attention(
+            q, k, v, t, n, bias=bias),
+        q, arena, arena, tables, lengths, bias).as_text()
+    assert "tpu_custom_call" in text
+
+
+def test_gates_refuse_what_the_kernels_cannot_hold():
+    # 16k keys x 64 dims in bf16, double-buffered K and V: past VMEM.
+    long_kv = jax.ShapeDtypeStruct((1, 2, 16384, 64), jnp.bfloat16)
+    assert not _flash_kernel_applies(long_kv, long_kv)
+    # 8192 sessions x 30 pages of table: past the 1 MiB of SMEM.
+    q = jax.ShapeDtypeStruct((8192, 8, 1, 64), jnp.bfloat16)
+    arena = jax.ShapeDtypeStruct((64, 8, 16, 64), jnp.bfloat16)
+    tables = jax.ShapeDtypeStruct((8192, 30), jnp.int32)
+    assert not _paged_kernel_applies(q, arena, tables)
+
+
+def test_flash_splits_over_the_ambient_serving_mesh():
+    """Under `jax.set_mesh` the kernel runs per shard — batch over the
+    data axis, heads over the model axis — inside a sharded jit, which
+    XLA cannot arrange for a Mosaic kernel by itself."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    mesh = Mesh(np.asarray(jax.devices()).reshape(4, 2), ("data", "model"))
+    b, h, s, d = 8, 4, 32, 16
+    q, k, v = (jax.device_put(
+        _rand((b, h, s, d), i),
+        NamedSharding(mesh, P("data", "model"))) for i in range(3))
+    lengths = jnp.asarray([s, 5, 17, 1, 32, 9, 0, 20], jnp.int32)
+    fn = jax.jit(lambda q, k, v, n: flash_attention(
+        q, k, v, lengths=n, interpret=True))
+    with jax.set_mesh(mesh):
+        got = fn(q, k, v, lengths)
+        jaxpr = str(jax.make_jaxpr(fn)(q, k, v, lengths))
+    assert "shard_map" in jaxpr
+    want = attention_reference(q, k, v, lengths=lengths)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=2e-5, rtol=2e-5)
+    # The paged kernel has no such wrapper: under a mesh its gate says no.
+    paged_args = (jax.ShapeDtypeStruct((4, 8, 1, 64), jnp.bfloat16),
+                  jax.ShapeDtypeStruct((9, 8, 16, 64), jnp.bfloat16),
+                  jax.ShapeDtypeStruct((4, 2), jnp.int32))
+    assert _paged_kernel_applies(*paged_args)
+    with jax.set_mesh(mesh):
+        assert not _paged_kernel_applies(*paged_args)

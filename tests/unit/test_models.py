@@ -35,6 +35,23 @@ def test_bert_padding_invariance():
                                atol=1e-5, rtol=1e-5)
 
 
+def test_bert_serving_forward_agrees_with_the_float32_reference():
+    """logits_fn (bf16, the served path) against reference_logits (plain
+    jax.numpy float32): logits-level agreement at bf16 resolution, with
+    ragged masks. chip_smoke.py makes the same comparison at BERT-base
+    width on the chip."""
+    config = bert.BertConfig.tiny(num_labels=3)
+    params = bert.init_params(jax.random.PRNGKey(2), config)
+    rng = np.random.default_rng(0)
+    ids = rng.integers(0, config.vocab_size, (4, 16)).astype(np.int32)
+    lengths = np.array([16, 9, 3, 1])
+    mask = (np.arange(16)[None] < lengths[:, None]).astype(np.int32)
+    want = np.asarray(bert.reference_logits(params, config, ids, mask))
+    assert want.dtype == np.float32
+    got = np.asarray(bert.logits_fn(params, config, ids, mask))
+    np.testing.assert_allclose(got, want, atol=0.03)
+
+
 def test_t5_greedy_decode_shapes_and_determinism():
     config = t5.T5Config.tiny()
     params = t5.init_params(jax.random.PRNGKey(0), config)

@@ -834,9 +834,11 @@ def test_attach_mesh_dp_shards_interior_and_matches_host():
     assert np.asarray(outs[0]).shape == (5, 4)  # sliced back
     np.testing.assert_allclose(outs[0], want[0], rtol=1e-5)
     np.testing.assert_array_equal(np.asarray(outs[1], object), want[1])
-    # The DP sharding really reaches XLA: batch dim split over 8 devices.
+    # The DP sharding really reaches XLA: batch dim split over 8 devices
+    # (Shardy's spelling — jax 0.9 no longer emits GSPMD's devices=[8,1]).
     hlo = part.interior_hlo_text([np.ones((8, 3), np.float32)])
-    assert 'devices=[8,1]<=[8]' in hlo, hlo[:500]
+    assert 'sdy.mesh @mesh = <["data"=8]>' in hlo, hlo[:500]
+    assert '#sdy.sharding<@mesh, [{"data"}, {}]>' in hlo, hlo[:500]
     # Detach restores the single-device path.
     part.attach_mesh(None)
     assert part.mesh is None

@@ -106,3 +106,38 @@ class TestMeshEstimate:
     def test_unresolvable_mesh_falls_back_to_unbound(self):
         alloc = estimate_for_mesh(8 * GB, {"data": 64, "model": 16})
         assert alloc == 8 * GB
+
+
+class TestDetectPools:
+    """The virtual pool is for CPU devices only; a TPU without a real
+    bytes_limit must not be gated against an invented one."""
+
+    class _Device:
+        def __init__(self, id, platform, stats):
+            self.id, self.platform, self._stats = id, platform, stats
+            self.device_kind = f"fake {platform}"
+
+        def memory_stats(self):
+            return self._stats
+
+    def _detect(self, monkeypatch, devices):
+        import jax
+
+        from min_tfs_client_tpu.core.resource import detect_hbm_pools
+
+        monkeypatch.setattr(jax, "local_devices", lambda: devices)
+        return detect_hbm_pools()
+
+    def test_cpu_devices_get_the_virtual_pool(self, monkeypatch):
+        pools = self._detect(monkeypatch, [self._Device(0, "cpu", None),
+                                           self._Device(1, "cpu", None)])
+        assert pools == {0: 1 << 40, 1: 1 << 40}
+
+    def test_tpu_reports_its_real_limit(self, monkeypatch):
+        pools = self._detect(monkeypatch, [self._Device(
+            0, "tpu", {"bytes_limit": 16 * GB, "bytes_in_use": 0})])
+        assert pools == {0: 16 * GB}
+
+    def test_tpu_without_bytes_limit_is_an_error(self, monkeypatch):
+        with pytest.raises(ServingError, match="bytes_limit"):
+            self._detect(monkeypatch, [self._Device(0, "tpu", None)])

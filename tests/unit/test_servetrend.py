@@ -1,13 +1,9 @@
 """servetrend unit suite (observability/servetrend.py): record
-extraction from bench emit lines and checked-in driver captures
-(provenance + staleness as per-record stamps), the schema-versioned
-ledger, the provenance-refusing regression gate — and the tier-1 run of
-`servetrend gate` against the repo's own BENCH_*.json history."""
+extraction from bench emit lines and driver captures (provenance +
+staleness as per-record stamps), the schema-versioned ledger, and the
+provenance-refusing regression gate."""
 
-import glob
 import json
-import os
-import pathlib
 
 import pytest
 
@@ -20,9 +16,6 @@ from min_tfs_client_tpu.observability.servetrend import (
     records_from_bench_line,
     records_from_driver_file,
 )
-
-REPO = pathlib.Path(__file__).resolve().parents[2]
-
 
 def _rec(metric, value, *, platform="cpu", device_kind=None, stale=False,
          unit="ms", seq=0, higher=None):
@@ -68,7 +61,7 @@ def test_bench_line_primary_and_config_legs():
 
 
 def test_leg_staleness_never_inherits_the_parent_marker():
-    # The real BENCH_r04 shape: a stale tpu replay primary riding next
+    # A stale tpu replay primary riding next
     # to freshly-measured live cpu legs in one emit line.
     configs = {
         "replayed@cpu": {"value": 7.0, "unit": "ms", "stale": True,
@@ -105,12 +98,6 @@ def test_driver_file_parsed_tail_and_unusable(tmp_path):
          "tail": 'runcated {"metric": "lat_p50", "va'}))
     assert records_from_driver_file(str(broken)) == []
     assert records_from_driver_file(str(tmp_path / "missing.json")) == []
-
-
-def test_repo_bench_r05_truncated_tail_is_skipped_gracefully():
-    # The checked-in r05 capture's tail is cut mid-line: it must shrink
-    # the history, not break the gate.
-    assert records_from_driver_file(str(REPO / "BENCH_r05.json")) == []
 
 
 # ---------------------------------------------------------------------------
@@ -220,22 +207,6 @@ def test_gate_min_history_knob():
     report = gate(recs, min_history=5)
     assert report["gated"] == 0
     assert report["results"][0]["status"] == "insufficient_history"
-
-
-# ---------------------------------------------------------------------------
-# Tier-1 acceptance: the repo's own checked-in history must gate clean.
-
-
-def test_repo_bench_history_gates_clean():
-    captures = sorted(glob.glob(str(REPO / "BENCH_r*.json")))
-    assert len(captures) >= 4
-    rc = servetrend.main(["gate", *captures])
-    assert rc == 0, "checked-in BENCH history flagged a regression"
-    # And the same stream, parsed directly: the newest real round gated
-    # against real same-provenance history — not vacuously green.
-    report = gate(gather(captures))
-    assert report["gated"] >= 2
-    assert report["regressions"] == 0
 
 
 def test_cli_gate_with_no_usable_records_fails_loudly(tmp_path):
