@@ -1586,6 +1586,7 @@ def _build_pooled_session_signatures(params: dict, config: T5Config, *,
     dedup = StepDeduper(max_entries=max(2 * max_slots, 64),
                         is_live=store.__contains__)
     from min_tfs_client_tpu.observability import runtime as rt
+    from min_tfs_client_tpu.observability import tracing
 
     prefill_jit = rt.instrument_jit(
         "t5:pooled:prefill", jax.jit(prefill_fn))
@@ -1612,14 +1613,17 @@ def _build_pooled_session_signatures(params: dict, config: T5Config, *,
         args = (params, jax.device_put(ids))
         if read_sampling is not None:
             args += read_sampling(inputs, 1)
-        state = prefill_jit(*args)
-        slot = pool.acquire_slot()
-        try:
-            pool.write(state, slot, session_key=sid)
-            store.put(sid, (slot, 0))
-        except Exception:
-            pool.release_slot(slot)
-            raise
+        # The prefill takes the device, and the pool write the pool's
+        # lock, against the ticks of the sessions that are stepping.
+        with tracing.span("decode/init", tokens=int(ids.shape[1])):
+            state = prefill_jit(*args)
+            slot = pool.acquire_slot()
+            try:
+                pool.write(state, slot, session_key=sid)
+                store.put(sid, (slot, 0))
+            except Exception:
+                pool.release_slot(slot)
+                raise
         return {"session_id": np.asarray(sid, object),
                 "batch": np.asarray(1, np.int32)}
 
@@ -1644,21 +1648,23 @@ def _build_pooled_session_signatures(params: dict, config: T5Config, *,
                 "step contract; this pool runs the dense-gather fallback")
         slot = pool.acquire_slot()
         try:
-            if paged:
-                # Step-contract pool: encoder-only prefill; the forced
-                # prefix streams through the ragged kernel in chunks,
-                # interleaved with other sessions' decode ticks.
-                state = prefill_jit(*args)
-                tokens = pre[0][:plen]
-                prefix_inputs = np.concatenate(
-                    [np.asarray([config.decoder_start_id], np.int32),
-                     tokens[:-1].astype(np.int32)])
-                pool.write(state, slot, prefill_inputs=prefix_inputs,
-                           prefill_next=int(tokens[-1]), session_key=sid)
-            else:
-                # Dense slot pool: one monolithic prefill.
-                state = prefill_jit(*args, jax.device_put(pre))
-                pool.write(state, slot, session_key=sid)
+            with tracing.span("decode/init", tokens=int(ids.shape[1])):
+                if paged:
+                    # Step-contract pool: encoder-only prefill; the
+                    # forced prefix streams through the ragged kernel in
+                    # chunks, interleaved with other sessions' ticks.
+                    state = prefill_jit(*args)
+                    tokens = pre[0][:plen]
+                    prefix_inputs = np.concatenate(
+                        [np.asarray([config.decoder_start_id], np.int32),
+                         tokens[:-1].astype(np.int32)])
+                    pool.write(state, slot, prefill_inputs=prefix_inputs,
+                               prefill_next=int(tokens[-1]),
+                               session_key=sid)
+                else:
+                    # Dense slot pool: one monolithic prefill.
+                    state = prefill_jit(*args, jax.device_put(pre))
+                    pool.write(state, slot, session_key=sid)
             store.put(sid, (slot, plen))
         except Exception:
             pool.release_slot(slot)

@@ -34,7 +34,9 @@ Served at `/monitoring/profile` on both REST backends and the router
                      risers first (the "what changed just now" view);
  * ?device=1&seconds=N — programmatic `jax.profiler.trace` capture to
                      --profile_dir (the XPlane dump the chip-truth
-                     campaign replays). jax is imported inside that
+                     campaign replays), with the stage spans mirrored
+                     into it and `host_clock.json` beside it
+                     (`traced_capture`). jax is imported inside that
                      function only — this module stays stdlib+tracing so
                      the jax-free router imports it.
 
@@ -48,6 +50,8 @@ a core. Treat the numbers as shares, not absolute CPU seconds.
 from __future__ import annotations
 
 import collections
+import contextlib
+import json
 import os
 import sys
 import threading
@@ -654,20 +658,61 @@ def diff_payload(seconds: float, hz: float | None = None) -> dict:
     return get().diff(seconds, hz)
 
 
+HOST_CLOCK_FILE = "host_clock.json"
+
+
+@contextlib.contextmanager
+def traced_capture(log_dir: str):
+    """One in-process `jax.profiler.trace` into `log_dir`, as both
+    captures of this server take it (`device_capture` below and
+    server/profiler.py `ProfilerServiceImpl.Profile`). For its length
+    the tracing spine mirrors its spans into TraceAnnotations, so the
+    capture shows the stage names on the host threads; at its end it
+    writes `host_clock.json` beside the capture, which joins the spans'
+    clock to the capture's.
+
+    The profiler's events count nanoseconds from the start of its
+    session (measured on a v5e, PERF.md section 7: not the Unix epoch),
+    which begins inside `start_trace`. So `tracing.clock_pair()` is read
+    just before `start_trace` (`zero`: the capture's zero on the spans'
+    clock and on the wall clock, early by what `start_trace` does before
+    it opens its session: 17-135 us measured), when it has returned
+    (`start`) and just before `stop_trace` (`stop`). A span's `ts` lies
+    at (ts - zero.span_us) * 1000 ns on the capture's host planes. The
+    pairs' `epoch_unix_ns` differ by what the wall clock drifted against
+    `perf_counter` meanwhile. jax is imported here only (the router
+    imports this module and stays jax-free)."""
+    import jax
+
+    os.makedirs(log_dir, exist_ok=True)
+    zero = tracing.clock_pair()
+    with jax.profiler.trace(log_dir):
+        start = tracing.clock_pair()
+        try:
+            with tracing.profiler_annotations():
+                yield
+        finally:
+            stop = tracing.clock_pair()
+            with open(os.path.join(log_dir, HOST_CLOCK_FILE), "w") as out:
+                json.dump({
+                    "schema": "host_clock/1",
+                    "epoch_unix_ns": zero["epoch_unix_ns"],
+                    "drift_ns": stop["epoch_unix_ns"]
+                    - zero["epoch_unix_ns"],
+                    "zero": zero, "start": start, "stop": stop},
+                    out, indent=1)
+
+
 def device_capture(seconds: float, log_dir: str = "") -> dict:
-    """Programmatic jax.profiler.trace window -> --profile_dir. The jax
-    import lives HERE so the module stays importable on the jax-free
-    router (the endpoint maps the ImportError to a 501)."""
+    """Programmatic device capture window -> --profile_dir (the
+    endpoint maps the jax ImportError of a jax-free router to a 501)."""
     root = log_dir or profile_dir()
     if not root:
         raise ValueError(
             "device capture needs --profile_dir (no directory configured)")
-    import jax  # deliberate function-scope import (router stays jax-free)
-
     seconds = min(max(float(seconds), 0.1), CAPTURE_MAX_SECONDS)
     run_dir = os.path.join(root, f"servespy-{int(time.time() * 1000):x}")
-    os.makedirs(run_dir, exist_ok=True)
-    with jax.profiler.trace(run_dir):
+    with traced_capture(run_dir):
         time.sleep(seconds)
     files = []
     for dirpath, _, filenames in os.walk(run_dir):

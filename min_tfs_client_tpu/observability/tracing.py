@@ -31,18 +31,22 @@ Sinks, fed when a trace finishes:
     by the existing Prometheus text exporter);
  2. a bounded ring of recent traces, rendered as Chrome-trace/Perfetto
     JSON by the `/monitoring/traces` debug endpoint (server/rest.py);
- 3. optional `jax.profiler.TraceAnnotation` bridging (`bridge_profiler`),
-    so on-demand XProf captures show the same stage names. Off by
-    default: a TraceAnnotation object per span costs ~1us of pure Python
-    even with no capture active, which is real money at toy-model
-    latencies.
+ 3. `jax.profiler.TraceAnnotation` bridging for the length of an
+    in-process device capture (`profiler_annotations`, entered by
+    observability/profiling.py `traced_capture`), so the capture shows
+    the same stage names on the host threads. Off outside a capture: a
+    TraceAnnotation object per span costs ~1us of pure Python even with
+    no capture active, which is real money at toy-model latencies.
 
 Clocks: spans record `time.perf_counter()` (CLOCK_MONOTONIC — comparable
 across threads); Chrome-trace `ts` values are microseconds relative to one
 process-wide epoch so concurrent requests align on a single timeline.
 Every trace also captures `time.time()` at open, so cross-process
 stitching (the router's fleet view, docs/OBSERVABILITY.md "Fleet
-tracing") can render all processes on the shared wall clock.
+tracing") can render all processes on the shared wall clock. A device
+capture writes `clock_pair()` at its start and stop into the capture's
+`host_clock.json`, which puts any `ts` of `/monitoring/traces` on the
+profiler's clock by one addition.
 
 Fleet scope: a trace carries a globally-unique `trace_id`. The router
 mints one per routed request and propagates it as the
@@ -106,7 +110,7 @@ def valid_trace_id(value) -> str | None:
     return None
 
 _enabled = True
-_bridge = os.environ.get("TPU_SERVING_TRACE_XPROF", "") not in ("", "0")
+_bridge = False  # on only inside profiler_annotations()
 _ann_cls = None  # lazily resolved jax.profiler.TraceAnnotation; False = n/a
 
 # The canonical stage names, in pipeline order. Anything recording a new
@@ -148,12 +152,22 @@ STAGES = (
     "pipeline/host",
     "pipeline/dispatch",
     "pipeline/materialize",
-    # Pooled decode tick (servables/decode_sessions.py), recorded on the
-    # tick leader's trace: one chunked-prefill round, the decode device
-    # program itself, and the overlapped per-slot output fetch.
+    # Pooled decode sessions (servables/decode_sessions.py). On the
+    # request that opens a session: its prefill and pool write. On every
+    # stepping request: the time until a tick's round took the step. On
+    # the round leader's trace, the loop's consecutive phases: from the
+    # previous round's delivery to this round's snapshot, the host work
+    # before the first transfer (one chunked-prefill round nested in
+    # it), the transfers and the ENQUEUE of the device program (not its
+    # run), the wait for its outputs, and handing each rider its row.
+    "decode/init",
+    "decode/wait",
+    "decode/handoff",
+    "decode/prepare",
     "decode/prefill_chunk",
     "decode/tick",
     "decode/fetch",
+    "decode/deliver",
     "serving/serialize",
 )
 
@@ -169,12 +183,32 @@ def enabled() -> bool:
     return _enabled
 
 
-def bridge_profiler(on: bool) -> None:
-    """Mirror every span into a jax.profiler.TraceAnnotation so XProf /
-    TensorBoard captures show the serving stage names alongside the XLA
-    timeline. Optional — costs ~1us/span even with no capture running."""
+@contextlib.contextmanager
+def profiler_annotations():
+    """For the block, mirror every `span` and request envelope into a
+    jax.profiler.TraceAnnotation, so the capture that is running shows
+    the serving stage names on the host threads over the XLA ops. The
+    in-process captures enter this for their own length (the profiler
+    takes one capture at a time, so blocks do not nest); a span that is
+    open when it ends closes the annotation it opened."""
     global _bridge
-    _bridge = bool(on)
+    _bridge = True
+    try:
+        yield
+    finally:
+        _bridge = False
+
+
+def clock_pair() -> dict:
+    """This instant on the three clocks a capture has to join, read back
+    to back: `perf_counter` (what spans record), the Unix-epoch
+    nanosecond (the profiler's clock), and `span_us`, the `ts` that
+    `/monitoring/traces` would give it. `epoch_unix_ns` is the Unix
+    nanosecond of `ts` 0: a span's `ts` maps to the profiler's clock as
+    epoch_unix_ns + ts * 1000."""
+    pc, ns = time.perf_counter(), time.time_ns()
+    return {"perf_counter_s": pc, "unix_ns": ns, "span_us": _us(pc),
+            "epoch_unix_ns": ns - round((pc - _EPOCH) * 1e9)}
 
 
 def _annotation(name: str):
@@ -337,6 +371,16 @@ def annotate(**kv) -> None:
         tr.annotate(**kv)
 
 
+def add_span(name: str, t0: float, t1: float, **args) -> None:
+    """Record on the current trace a stage whose ends were stamped by
+    hand (`time.perf_counter()`): one that begins on one side of a lock
+    or of a thread hand-off and ends on the other, where no `with` can
+    hold it. No-op without a trace, as `span` is."""
+    tr = _current.get()
+    if tr is not None:
+        tr.add_span(name, t0, t1, args or None)
+
+
 def add_cost(**kv) -> None:
     """Accumulate cost events onto the current trace (no-op without
     one). A batch fanout splits the value across its riders."""
@@ -465,10 +509,10 @@ class span:
     """Context manager recording one named stage on the current trace.
 
     Deliberately slim — this sits on the hot path of every request. The
-    profiler bridge (TraceAnnotation) only engages when bridge_profiler
-    turned it on, and the active-stage registry (the sampling profiler's
-    sample→stage join) only when track_stages armed it — the common OFF
-    path pays one module-bool check per side.
+    profiler bridge (TraceAnnotation) only engages inside
+    profiler_annotations(), and the active-stage registry (the sampling
+    profiler's sample→stage join) only when track_stages armed it — the
+    common OFF path pays one module-bool check per side.
     """
 
     __slots__ = ("name", "args", "_t0", "_ann")

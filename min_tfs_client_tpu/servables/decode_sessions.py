@@ -656,29 +656,40 @@ class SlotPool:
             self._pool = self._write_jit(self._pool, state,
                                          self._jax.numpy.int32(slot))
 
-    def tick(self, slots: list[int]) -> dict[int, dict]:
+    def tick(self, slots: list[int],
+             of_round: Optional[TickRound] = None) -> dict[int, dict]:
         """Advance the given slots in ONE device call; other slots'
         state is untouched (masked merge). Returns per-slot host outputs
-        after a single overlapped fetch."""
+        after a single overlapped fetch. `of_round` is the TickBatcher's
+        (None for a direct call): the phase spans carry its ordinal."""
         import numpy as np
 
         from min_tfs_client_tpu.robustness import faults
         from min_tfs_client_tpu.servables.servable import fetch_outputs
 
+        of_round = of_round or TickRound(0, 0.0)
+        ordinal, t_entry = of_round.ordinal, time.perf_counter()
         # Pre-tick faultpoint: a delay stretches every tick-mate's step
         # (the TickBatcher propagates one leader's fate to all riders),
         # a typed error fails the whole tick loudly.
         faults.point("backend.tick.pre", slots=len(slots))
         t0 = time.perf_counter()
         with self._lock:
+            lock_wait_us = int((time.perf_counter() - t0) * 1e6)
             active = np.zeros((self.max_slots,), bool)
             active[list(slots)] = True
-            with tracing.span("decode/tick", slots=len(slots)):
+            tracing.add_span(
+                "decode/prepare", t_entry, time.perf_counter(),
+                round=ordinal, slots=len(slots), live=len(slots),
+                lock_wait_us=lock_wait_us, prefills=0)
+            with tracing.span("decode/tick", slots=len(slots),
+                              round=ordinal):
                 self._pool, outputs = self._tick_jit(
                     self._params, self._pool,
                     self._jax.numpy.asarray(active))
-        with tracing.span("decode/fetch"):
+        with tracing.span("decode/fetch", round=ordinal):
             fetched = fetch_outputs(outputs)
+        of_round.fetched = time.perf_counter()
         round_s = time.perf_counter() - t0
         round_ms = round(round_s * 1e3, 3)
         self.timeline.events_many(
@@ -1474,7 +1485,8 @@ class PagedSlotPool:
 
     # -- decode phase ---------------------------------------------------------
 
-    def tick(self, slots: list[int]) -> dict[int, object]:
+    def tick(self, slots: list[int],
+             of_round: Optional[TickRound] = None) -> dict[int, object]:
         """Advance the given slots in ONE device call (plus, on the
         contract path, at most one chunked-prefill round for sessions
         still streaming a forced prefix). Returns per-slot host outputs;
@@ -1482,12 +1494,16 @@ class PagedSlotPool:
         (per-slot failure isolation — a capacity refusal for one session
         must not poison its tick-mates), and slots still mid-prefix carry
         the PREFILL_PENDING sentinel (the caller re-enters the batcher so
-        tick-mates' decodes interleave with the remaining chunks)."""
+        tick-mates' decodes interleave with the remaining chunks).
+        `of_round` is the TickBatcher's (None for a direct call): the
+        phase spans carry its ordinal."""
         import numpy as np
 
         from min_tfs_client_tpu.robustness import faults
         from min_tfs_client_tpu.servables.servable import fetch_outputs
 
+        of_round = of_round or TickRound(0, 0.0)
+        ordinal, t_entry = of_round.ordinal, time.perf_counter()
         slots = list(slots)
         # Pre-tick faultpoint, OUTSIDE the pool lock: a delay models a
         # slow device round; a typed error fails the whole tick (the
@@ -1499,8 +1515,9 @@ class PagedSlotPool:
         tick_events: list[tuple] = []
         t0 = time.perf_counter()
         with self._lock:
-            self._flush_prefills_locked(limit=self._max_prefills,
-                                        urgent=tuple(slots))
+            lock_wait_us = int((time.perf_counter() - t0) * 1e6)
+            prefills = self._flush_prefills_locked(
+                limit=self._max_prefills, urgent=tuple(slots))
             chunk_errors: dict[int, ServingError] = {}
             if self._prefix:
                 with tracing.span("decode/prefill_chunk"):
@@ -1540,32 +1557,35 @@ class PagedSlotPool:
                     tables[s, :len(pages)] = pages
                 active = np.zeros((self.max_slots,), bool)
                 active[live] = True
-                with tracing.span("decode/tick", slots=len(live)):
-                    if self._paged_step is not None:
-                        lengths = np.zeros((self.max_slots,), np.int32)
-                        for s, t in self._tokens.items():
-                            lengths[s] = t
-                        dense, arenas, outputs = self._tick_jit(
-                            self._params, self._dense_pool, self._arenas,
-                            self._jnp.asarray(tables),
-                            self._jnp.asarray(active),
-                            self._jnp.asarray(lengths))
-                        # What the ragged kernel actually reads: the pages
-                        # live sessions own — not slots × table width.
-                        gather_bytes = self.page_bytes * sum(
-                            len(self._pages[s]) for s in live)
-                    else:
-                        cur_pages = np.zeros((self.max_slots,), np.int32)
-                        for s in live:
-                            cur_pages[s] = self._tokens[s] // self.block_size
-                        dense, arenas, outputs = self._tick_jit(
-                            self._params, self._dense_pool, self._arenas,
-                            self._jnp.asarray(tables),
-                            self._jnp.asarray(active),
-                            self._jnp.asarray(cur_pages))
-                        # The fallback materializes the full gathered view.
-                        gather_bytes = self.page_bytes * self.max_slots \
-                            * width
+                # Where each slot's step stands: its token count on the
+                # contract path, its current page on the fallback.
+                cursor = np.zeros((self.max_slots,), np.int32)
+                if self._paged_step is not None:
+                    for s, t in self._tokens.items():
+                        cursor[s] = t
+                    # What the ragged kernel actually reads: the pages
+                    # live sessions own — not slots × table width.
+                    gather_bytes = self.page_bytes * sum(
+                        len(self._pages[s]) for s in live)
+                else:
+                    for s in live:
+                        cursor[s] = self._tokens[s] // self.block_size
+                    # The fallback materializes the full gathered view.
+                    gather_bytes = self.page_bytes * self.max_slots * width
+            tracing.add_span(
+                "decode/prepare", t_entry, time.perf_counter(),
+                round=ordinal, slots=len(slots), live=len(live),
+                lock_wait_us=lock_wait_us, prefills=prefills)
+            if live:
+                # Times the three sends and the ENQUEUE of the program:
+                # its run on the device ends under `decode/fetch`.
+                with tracing.span("decode/tick", slots=len(live),
+                                  round=ordinal, width=width):
+                    dense, arenas, outputs = self._tick_jit(
+                        self._params, self._dense_pool, self._arenas,
+                        self._jnp.asarray(tables),
+                        self._jnp.asarray(active),
+                        self._jnp.asarray(cursor))
                 self._dense_pool = tuple(dense)
                 self._arenas = tuple(arenas)
                 now = time.monotonic()
@@ -1580,8 +1600,9 @@ class PagedSlotPool:
                 self._report_gather_bytes(gather_bytes)
             self._publish_stats_locked()
         if live:
-            with tracing.span("decode/fetch"):
+            with tracing.span("decode/fetch", round=ordinal):
                 fetched = fetch_outputs(outputs)
+            of_round.fetched = time.perf_counter()
             round_ms = round((time.perf_counter() - t0) * 1e3, 3)
             for _, _, fields in tick_events:
                 fields["tick_ms"] = round_ms
@@ -1735,13 +1756,31 @@ class PagedSlotPool:
             self._width = min(self.pages_per_session, grown)
 
 
+class TickRound:
+    """One round of the TickBatcher, as the pool's `tick` sees it: the
+    per-batcher ordinal that every phase span of the round carries (and
+    each rider's `decode/wait`, which ties a rider's trace to the
+    leader's; 0 for a direct call of `tick`), when the round snapshotted
+    its riders, and, back from the tick, when its fetch ended: where
+    `decode/deliver` starts."""
+
+    __slots__ = ("ordinal", "taken", "fetched")
+
+    def __init__(self, ordinal: int, taken: float):
+        self.ordinal = ordinal
+        self.taken = taken
+        self.fetched: Optional[float] = None
+
+
 class _TickEntry:
-    __slots__ = ("done", "result", "error")
+    __slots__ = ("done", "result", "error", "arrived", "round")
 
     def __init__(self):
         self.done = False
         self.result = None
         self.error = None
+        self.arrived = time.perf_counter()
+        self.round: Optional[TickRound] = None  # the one that took it
 
 
 class TickBatcher:
@@ -1754,11 +1793,21 @@ class TickBatcher:
     leader role hands off safely: a waiter that wakes to find no leader
     takes over. Same-slot serialization is the session store's job (take/
     put), not this class's.
+
+    Spans (docs/OBSERVABILITY.md "Decode loop phases"): every step
+    records `decode/wait` on its own trace, entry to the snapshot of the
+    round that took it; the round's leader records on its trace
+    `decode/handoff` (the previous round's delivery, or the round's
+    first arrival if later, to the snapshot) and `decode/deliver` (end
+    of the tick's fetch to the riders' wake-up). With the pool's
+    prepare, tick and fetch between them they cover the leader thread
+    from one snapshot to the next.
     """
 
     def __init__(self, tick_fn, *, join_window_s: float = 0.0005,
                  cost_fn=None):
-        self._tick_fn = tick_fn  # (sorted list[slot]) -> {slot: result}
+        # (sorted list[slot], TickRound) -> {slot: result}
+        self._tick_fn = tick_fn
         self._join_window_s = join_window_s
         # Optional per-slot cost hook (pool.step_cost): charged onto
         # the CALLER's trace after its round delivers — leader and
@@ -1769,6 +1818,8 @@ class TickBatcher:
         self._pending: dict[int, _TickEntry] = {}
         self._inflight: set[int] = set()
         self._leader = False
+        self._rounds = 0        # ordinal of the newest round
+        self._delivered = 0.0   # perf_counter of its notify_all
 
     def _note_cost(self, slot: int) -> None:
         if self._cost_fn is None:
@@ -1779,6 +1830,12 @@ class TickBatcher:
             return  # telemetry; a broken cost_fn must not break steps
         if cost:
             tracing.add_cost(**cost)
+
+    def _note_wait(self, entry: _TickEntry, led: bool) -> None:
+        took = entry.round
+        if took is not None:
+            tracing.add_span("decode/wait", entry.arrived, took.taken,
+                             round=took.ordinal, led=led)
 
     def step(self, slot: int):
         entry = _TickEntry()
@@ -1801,6 +1858,7 @@ class TickBatcher:
                     # leader died between notify rounds.
                     self._cv.wait(timeout=0.1)
                 if entry.done:
+                    self._note_wait(entry, led=False)
                     if entry.error is not None:
                         raise entry.error
                     self._note_cost(slot)
@@ -1808,11 +1866,15 @@ class TickBatcher:
                 # fell through: we are the new leader
             else:
                 self._leader = True
-        result = self._lead(entry)
+        try:
+            result = self._lead(entry)
+        finally:
+            self._note_wait(entry, led=True)
         self._note_cost(slot)
         return result
 
     def _lead(self, own: _TickEntry):
+        new_leader = True
         try:
             if self._join_window_s:
                 time.sleep(self._join_window_s)
@@ -1821,14 +1883,31 @@ class TickBatcher:
                     batch = self._pending
                     self._pending = {}
                     self._inflight = set(batch)
+                    of_round = TickRound(self._rounds + 1,
+                                         time.perf_counter())
+                    since = self._delivered
+                    if batch:
+                        self._rounds = of_round.ordinal
                 if not batch:
                     break
+                for e in batch.values():
+                    e.round = of_round
+                # Time in which nothing was pending is no hand-off: the
+                # span starts no earlier than the round's first arrival
+                # (which may lie before this leader's own request began).
+                tracing.add_span(
+                    "decode/handoff",
+                    max(since, min(e.arrived for e in batch.values())),
+                    of_round.taken, round=of_round.ordinal,
+                    riders=len(batch), new_leader=new_leader)
+                new_leader = False
                 err = None
                 results: dict = {}
                 try:
-                    results = self._tick_fn(sorted(batch))
+                    results = self._tick_fn(sorted(batch), of_round)
                 except Exception as exc:  # noqa: BLE001 - delivered to waiters
                     err = exc
+                back = time.perf_counter()
                 with self._cv:
                     for s, e in batch.items():
                         e.done = True
@@ -1836,12 +1915,16 @@ class TickBatcher:
                         e.result = results.get(s)
                     self._inflight = set()
                     self._cv.notify_all()
-                    # Return as soon as our own round ran — pending
-                    # arrivals elect a new leader via the handoff path in
-                    # step() (a leader that kept draining would give its
-                    # own caller unbounded latency under sustained load).
-                    if own.done:
-                        break
+                    delivered = self._delivered = time.perf_counter()
+                tracing.add_span(
+                    "decode/deliver", of_round.fetched or back, delivered,
+                    round=of_round.ordinal)
+                # Return as soon as our own round ran — pending arrivals
+                # elect a new leader via the handoff path in step() (a
+                # leader that kept draining would give its own caller
+                # unbounded latency under sustained load).
+                if own.done:
+                    break
         finally:
             with self._cv:
                 self._leader = False

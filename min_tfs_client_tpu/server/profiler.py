@@ -118,7 +118,9 @@ class ProfilerServiceImpl:
     The reference registers this service on the main gRPC server
     (server.cc:324,339 -> profiler/rpc/profiler_service_impl.cc) so
     production tooling pulls traces without a side port. Profile() captures
-    `duration_ms` of XPlane trace into a repository dir and returns every
+    `duration_ms` of XPlane trace into a repository dir, with the stage
+    spans mirrored into it and `host_clock.json` beside it
+    (observability/profiling.py `traced_capture`), and returns every
     produced file as ProfileToolData; Monitor() returns a text snapshot of
     the serving metrics registry."""
 
@@ -139,9 +141,11 @@ class ProfilerServiceImpl:
         preexisting = ({f for f in root_path.rglob("*") if f.is_file()}
                        if root_path.exists() else set())
         try:
-            import jax
+            from min_tfs_client_tpu.observability.profiling import (
+                traced_capture,
+            )
 
-            with jax.profiler.trace(root):
+            with traced_capture(root):
                 time_mod.sleep(duration_s)
         except Exception as exc:  # profiler unavailable: empty trace
             response.empty_trace = True

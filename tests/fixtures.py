@@ -976,3 +976,33 @@ def simulate_host_latency(partition, per_row_s: float):
         seg.host_fn = slow(seg.host_fn)
     partition.post = slow(partition.post)
     return partition
+
+
+def harness_threads() -> frozenset:
+    """The sampling profiler's labels for the threads that are here
+    already and were started without `threading` (pytest-xdist's execnet
+    starts its own with `_thread.start_new_thread`): they are in
+    `sys._current_frames()` and not in `threading.enumerate()`, so the
+    sampler cannot name them. Call it before the process under test
+    starts anything. They are the test runner's, not the serving
+    process's: the profiler suites take their attribution bars over the
+    threads the code under test answers for (`own_attribution`)."""
+    import sys
+    import threading
+
+    registered = {t.ident for t in threading.enumerate()}
+    return frozenset(f"unnamed-{ident}" for ident in sys._current_frames()
+                     if ident not in registered)
+
+
+def own_attribution(threads: dict, harness: frozenset) -> tuple:
+    """(samples, of them on a named thread, of them in no known
+    subsystem) over a profile payload's `threads` but the runner's own."""
+    own = {label: info for label, info in threads.items()
+           if label not in harness}
+    samples = sum(info["samples"] for info in own.values())
+    named = sum(info["samples"] for label, info in own.items()
+                if not label.startswith("unnamed-"))
+    other = sum(info["samples"] for info in own.values()
+                if info["subsystem"] == "other")
+    return samples, named, other

@@ -89,21 +89,63 @@ class TestProfilerOverheadSmoke:
             ts.sort()
             return ts[n // 2] * 1e6
 
-        profiling.configure(hz=profiling.DEFAULT_HZ)
-        on, off = [], []
-        gc.collect()
-        gc.disable()
-        try:
+        def measure() -> tuple[float, float]:
+            """Best chunk of seven with the sampler on, and off."""
+            on, off = [], []
             for _ in range(7):  # interleave so both see the same load
                 profiling.start()
                 on.append(chunk_p50())
                 profiling.stop()
                 off.append(chunk_p50())
+            return min(on), min(off)
+
+        profiling.configure(hz=profiling.DEFAULT_HZ)
+        gc.collect()
+        gc.disable()
+        try:
+            # What the sampler costs is a floor under the `on` arm;
+            # what the machine's other work costs (this suite runs six
+            # workers wide) lands on either arm, in either direction.
+            # A sampler over its budget is over it in every attempt;
+            # noise is not: the best of three attempts is held to the
+            # same budget as one was.
+            attempts = []
+            for _ in range(3):
+                sampling, quiet = measure()
+                budget = max(0.05 * quiet, 60.0)
+                attempts.append((sampling - quiet, budget, sampling, quiet))
+                if sampling - quiet < budget:
+                    break
         finally:
             gc.enable()
-        sampling, quiet = min(on), min(off)
-        overhead = sampling - quiet
-        budget = max(0.05 * quiet, 60.0)
+        overhead, budget, sampling, quiet = min(attempts)
         assert overhead < budget, (
             f"sampler overhead {overhead:.1f}us exceeds budget "
-            f"{budget:.1f}us (on {sampling:.1f}us, off {quiet:.1f}us)")
+            f"{budget:.1f}us (on {sampling:.1f}us, off {quiet:.1f}us) "
+            f"in all of {len(attempts)} attempts: {attempts}")
+
+    def test_a_tick_s_own_cpu_time_is_under_half_a_percent_of_a_core(
+            self, rest_server):
+        """What the sampler controls, measured where no neighbour can
+        reach it: the CPU seconds of this thread (not the wall) that one
+        walk of every interpreter thread takes, driven directly. At the
+        default rate that is the share of a core the module's header
+        promises (<0.5% with tens of threads)."""
+        import threading
+
+        fold = profiling._Fold()
+        exclude = frozenset((threading.get_ident(),))
+        for _ in range(20):
+            fold.sample_once(exclude)  # format every frame key once
+        threads = fold.samples // fold.ticks
+        assert threads >= 5, "a serving process has its planes' threads"
+        best = float("inf")
+        for _ in range(5):
+            t0 = time.thread_time()
+            for _ in range(50):
+                fold.sample_once(exclude)
+            best = min(best, (time.thread_time() - t0) / 50)
+        share = best * profiling.DEFAULT_HZ
+        assert share < 0.005, (
+            f"one tick over {threads} threads takes {best * 1e6:.0f}us of "
+            f"CPU: {100 * share:.2f}% of a core at {profiling.DEFAULT_HZ} Hz")

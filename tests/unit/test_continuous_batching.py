@@ -214,6 +214,42 @@ def test_synthesize_warmup_primes_session_executables():
         sigs["decode_close"].run({"session_id": sid})
 
 
+class TestDensePoolPhases:
+    def test_dense_pool_records_the_paged_pool_s_phase_names(self, pooled):
+        """One session, each step its own round: the leader's trace has
+        the wait, the hand-off and the four phases in order, all of one
+        round, and `decode/init` sits on the opening request."""
+        from min_tfs_client_tpu.observability import tracing
+
+        config, _, sigs = pooled
+        sid = np.asarray(b"phases", object)
+        with tracing.request_trace("decode_init") as opened:
+            sigs["decode_init"].run(
+                {"session_id": sid,
+                 "input_ids": _prompt(config, np.random.default_rng(9))})
+        assert [(name, args) for name, _, _, args in opened.spans
+                if name.startswith("decode/")] \
+            == [("decode/init", {"tokens": SEQ})]
+        rounds = []
+        for _ in range(3):
+            with tracing.request_trace("decode_step") as trace:
+                sigs["decode_step"].run({"session_id": sid})
+            spans = [s for s in trace.spans if s[0].startswith("decode/")]
+            # Alone, a step's wait and its round's hand-off coincide.
+            spans.sort(key=lambda s: (s[1], s[0] != "decode/wait"))
+            assert [s[0] for s in spans] == [
+                "decode/wait", "decode/handoff", "decode/prepare",
+                "decode/tick", "decode/fetch", "decode/deliver"]
+            assert len({s[3]["round"] for s in spans}) == 1
+            assert "width" not in spans[3][3]  # no block table here
+            assert spans[0][3]["led"] and spans[3][3]["slots"] == 1
+            for a, b in zip(spans[1:], spans[2:]):
+                assert a[2] <= b[1]
+            rounds.append(spans[0][3]["round"])
+        assert rounds == [rounds[0], rounds[0] + 1, rounds[0] + 2]
+        sigs["decode_close"].run({"session_id": sid})
+
+
 class TestPooledAtMostOnce:
     """step_ordinal on the POOLED surface: a duplicate resend must not
     burn a shared tick (tick-mates' streams advance by real steps only)
@@ -269,7 +305,7 @@ class TestTickBatcher:
         batch_sizes = []
         release = threading.Event()
 
-        def tick(slots):
+        def tick(slots, of_round):
             if not release.is_set():
                 release.wait(5)
             batch_sizes.append(len(slots))
@@ -300,7 +336,7 @@ class TestTickBatcher:
     def test_sequential_steps_each_get_a_tick(self):
         calls = []
 
-        def tick(slots):
+        def tick(slots, of_round):
             calls.append(list(slots))
             return {s: "ok" for s in slots}
 
@@ -310,7 +346,7 @@ class TestTickBatcher:
         assert calls == [[3], [3]]
 
     def test_tick_error_propagates_to_every_waiter(self):
-        def tick(slots):
+        def tick(slots, of_round):
             raise RuntimeError("device fell over")
 
         batcher = TickBatcher(tick, join_window_s=0.02)
@@ -335,7 +371,7 @@ class TestTickBatcher:
         first_tick_started = threading.Event()
         let_first_finish = threading.Event()
 
-        def tick(slots):
+        def tick(slots, of_round):
             rounds.append(list(slots))
             if len(rounds) == 1:
                 first_tick_started.set()

@@ -1014,3 +1014,222 @@ class TestSessionTimelinesThroughPool:
         assert chunks, "counter missing from the Prometheus export"
         assert float(chunks[0].rsplit(" ", 1)[1]) >= 2  # 4 positions / 2
         sigs["decode_close"].run({"session_id": _sid("prom-kv")})
+
+
+class TestDecodeLoopPhases:
+    """The spans that name the time between two ticks
+    (docs/OBSERVABILITY.md "Decode loop phases"): the round leader's
+    consecutive phases and every rider's wait, tied by `round`."""
+
+    PHASES = ("decode/prepare", "decode/tick", "decode/fetch",
+              "decode/deliver")
+
+    def _step_sessions(self, sigs, config, n, steps, seed=31):
+        """n sessions stepping side by side, each step inside its own
+        request trace as a handler would open it; returns the traces."""
+        from min_tfs_client_tpu.observability import tracing
+
+        rng = np.random.default_rng(seed)
+        for i in range(n):
+            sigs["decode_init"].run({"session_id": _sid(f"ph-{seed}-{i}"),
+                                     "input_ids": _prompt(config, rng)})
+        traces, errors = [], []
+        start = threading.Barrier(n)
+
+        def worker(i):
+            try:
+                start.wait(10)
+                for _ in range(steps):
+                    with tracing.request_trace("decode_step") as trace:
+                        sigs["decode_step"].run(
+                            {"session_id": _sid(f"ph-{seed}-{i}")})
+                    traces.append(trace)
+            except Exception as exc:  # noqa: BLE001
+                errors.append(exc)
+
+        threads = [threading.Thread(target=worker, args=(i,))
+                   for i in range(n)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join()
+        assert not errors, errors
+        return traces
+
+    def _close(self, sigs, n, seed=31):
+        for i in range(n):
+            sigs["decode_close"].run({"session_id": _sid(f"ph-{seed}-{i}")})
+
+    @staticmethod
+    def _by_round(traces, names):
+        rounds: dict = {}
+        for tr in traces:
+            if tr is None:
+                continue
+            for name, t0, t1, args in tr.spans:
+                if name in names:
+                    rounds.setdefault(args["round"], []).append(
+                        (name, t0, t1, args))
+        return rounds
+
+    def test_each_round_has_its_phases_once_in_order(self, model):
+        config, _ = model
+        n, steps = 3, 5
+        sigs = _sigs(model, kv_block_size=2)
+        pool = sigs["decode_init"]._kv_pool
+        traces = self._step_sessions(sigs, config, n, steps)
+        width_at_end = pool.stats()["table_width"]
+        self._close(sigs, n)
+        rounds = self._by_round(traces,
+                                self.PHASES + ("decode/handoff",))
+        assert rounds and 0 not in rounds
+        assert sorted(rounds) == list(range(min(rounds), max(rounds) + 1))
+        stepped = 0
+        for r, spans in sorted(rounds.items()):
+            by_name = {name: (t0, t1, args) for name, t0, t1, args in spans}
+            assert sorted(name for name, *_ in spans) == sorted(
+                self.PHASES + ("decode/handoff",)), (r, spans)
+            order = [by_name[name] for name in
+                     ("decode/handoff",) + self.PHASES]
+            for (_, end, _), (begin, _, _) in zip(order, order[1:]):
+                assert end <= begin, (r, spans)
+            prepare, tick = by_name["decode/prepare"], by_name["decode/tick"]
+            assert tick[2]["slots"] == prepare[2]["live"] \
+                == by_name["decode/handoff"][2]["riders"]
+            assert prepare[2]["lock_wait_us"] >= 0
+            assert tick[2]["width"] in (1, 2, 4)
+            stepped += tick[2]["slots"]
+        assert stepped == n * steps
+        last = max(rounds)
+        assert {s[0]: s[3] for s in rounds[last]}["decode/tick"]["width"] \
+            == width_at_end == 4  # 5 tokens, 2 a page: 3 pages, bucket 4
+
+    def test_every_step_waited_for_a_round_the_leader_recorded(self, model):
+        config, _ = model
+        n, steps = 3, 4
+        sigs = _sigs(model, kv_block_size=2)
+        traces = self._step_sessions(sigs, config, n, steps, seed=32)
+        self._close(sigs, n, seed=32)
+        led_rounds = set(self._by_round(traces, ("decode/tick",)))
+        leaders = 0
+        for tr in traces:
+            waits = [(t0, t1, args) for name, t0, t1, args in tr.spans
+                     if name == "decode/wait"]
+            assert len(waits) == 1, tr.spans
+            t0, t1, args = waits[0]
+            assert tr.start <= t0 <= t1 <= tr.end
+            assert args["round"] in led_rounds
+            mine = [a for name, _, _, a in tr.spans if name == "decode/tick"]
+            assert args["led"] == bool(mine)
+            if mine:
+                leaders += 1
+                assert mine[0]["round"] == args["round"]
+        assert leaders == len(led_rounds)
+
+    def test_phases_and_handoff_cover_a_busy_batcher(self, model):
+        """Six sessions against a tick slowed to 5 ms (the pre-tick
+        faultpoint, which lies in `decode/prepare`), so that steps
+        arrive while a tick runs. The batcher is busy while a round is
+        in progress or a step waits in it (`decode/wait`); while it is
+        empty there is no work, and that is nobody's hand-off. From the
+        first round to the last, the leader threads' phases name all of
+        its busy time but the slivers between two phases."""
+        from min_tfs_client_tpu.robustness import faults
+
+        config, _ = model
+        n, steps = 6, 6
+        sigs = _sigs(model, kv_block_size=2)
+        faults.arm({"rules": [{"point": "backend.tick.pre",
+                               "action": "delay", "delay_ms": 5}]})
+        try:
+            traces = self._step_sessions(sigs, config, n, steps, seed=33)
+        finally:
+            faults.disarm()
+        self._close(sigs, n, seed=33)
+        rounds = self._by_round(traces, self.PHASES + ("decode/handoff",))
+        first, last = min(rounds), max(rounds)
+        assert last - first >= steps - 1
+        # From the first round's snapshot to the last round's delivery.
+        begin = max(t1 for name, _, t1, _ in rounds[first]
+                    if name == "decode/handoff")
+        end = max(t1 for _, _, t1, _ in rounds[last])
+
+        def seconds(intervals):
+            total, at = 0.0, begin
+            for t0, t1 in sorted(intervals):
+                t0, t1 = max(t0, at), min(t1, end)
+                if t1 > t0:
+                    total, at = total + t1 - t0, t1
+            return total
+
+        named = [(t0, t1) for spans in rounds.values()
+                 for _, t0, t1, _ in spans]
+        waits = [(t0, t1) for tr in traces for name, t0, t1, _ in tr.spans
+                 if name == "decode/wait"]
+        assert len(waits) == n * steps
+        busy = seconds(named + waits)
+        # Every round after the first is busy for its 5 ms delay at least.
+        assert busy >= 0.005 * (last - first)
+        assert seconds(named) >= 0.95 * busy, (seconds(named), busy)
+
+    def test_a_step_that_arrives_during_a_tick_waits_out_its_rest(self):
+        """A rider that enters while round 1 runs is taken by round 2's
+        snapshot, which cannot come before round 1 has delivered."""
+        from min_tfs_client_tpu.observability import tracing
+        from min_tfs_client_tpu.servables.decode_sessions import TickBatcher
+
+        running, finish = threading.Event(), threading.Event()
+        ticks = []
+
+        def tick(slots, of_round):
+            ticks.append((of_round.ordinal, list(slots)))
+            if of_round.ordinal == 1:
+                running.set()
+                finish.wait(5)
+            return {s: of_round.ordinal for s in slots}
+
+        batcher = TickBatcher(tick, join_window_s=0)
+        traces = {}
+
+        def rider(slot):
+            with tracing.request_trace("decode_step") as trace:
+                batcher.step(slot)
+            traces[slot] = trace
+
+        first = threading.Thread(target=rider, args=(1,))
+        first.start()
+        assert running.wait(5)
+        late = threading.Thread(target=rider, args=(2,))
+        late.start()
+        import time
+        time.sleep(0.05)  # the late rider is parked on round 1
+        released = time.perf_counter()
+        finish.set()
+        first.join()
+        late.join()
+        assert ticks == [(1, [1]), (2, [2])]
+        (wait,) = [s for s in traces[2].spans if s[0] == "decode/wait"]
+        assert wait[3] == {"round": 2, "led": True}
+        assert wait[2] - wait[1] >= 0.05 and wait[2] >= released
+        deliver = [s for s in traces[1].spans if s[0] == "decode/deliver"]
+        assert len(deliver) == 1 and deliver[0][2] <= wait[2]
+        (handoff,) = [s for s in traces[2].spans if s[0] == "decode/handoff"]
+        # Round 1 delivered before round 2's snapshot: the hand-off
+        # starts at that delivery, not at the late rider's arrival.
+        assert handoff[1] == pytest.approx(deliver[0][2], abs=1e-3)
+        assert handoff[3] == {"round": 2, "riders": 1, "new_leader": True}
+
+    def test_kill_switch_records_none_of_them(self, model):
+        from min_tfs_client_tpu.observability import tracing
+
+        config, _ = model
+        sigs = _sigs(model, kv_block_size=2)
+        recorded = len(tracing.ring_snapshot())
+        tracing.enable(False)
+        try:
+            traces = self._step_sessions(sigs, config, 2, 2, seed=34)
+        finally:
+            tracing.enable(True)
+        self._close(sigs, 2, seed=34)
+        assert traces == [None] * 4
+        assert len(tracing.ring_snapshot()) == recorded

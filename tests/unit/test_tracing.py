@@ -96,6 +96,84 @@ class TestSpanRecording:
         assert tr.meta["batch_size"] == 4.0
 
 
+class TestExplicitSpansAndCaptureClock:
+    def test_add_span_records_hand_stamped_times_on_the_current_trace(self):
+        with tracing.request_trace("decode_step") as tr:
+            tracing.add_span("decode/wait", 10.0, 10.5, round=3, led=False)
+            tracing.add_span("decode/deliver", 10.5, 10.6)
+        assert tr.spans == [
+            ("decode/wait", 10.0, 10.5, {"round": 3, "led": False}),
+            ("decode/deliver", 10.5, 10.6, None)]
+        tracing.add_span("decode/wait", 1.0, 2.0)  # no trace: silent
+
+    def test_kill_switch_stops_hand_stamped_spans_too(self):
+        tracing.enable(False)
+        try:
+            with tracing.request_trace("decode_step") as tr:
+                tracing.add_span("decode/wait", 1.0, 2.0, round=1)
+            assert tr is None and tracing.current_trace() is None
+        finally:
+            tracing.enable(True)
+
+    def test_the_decode_loop_s_stages_are_canonical_and_documented(self):
+        import pathlib
+
+        new = {"decode/init", "decode/wait", "decode/handoff",
+               "decode/prepare", "decode/deliver"}
+        assert new <= set(tracing.STAGES)
+        assert len(set(tracing.STAGES)) == len(tracing.STAGES)
+        doc = (pathlib.Path(__file__).resolve().parents[2]
+               / "docs" / "OBSERVABILITY.md").read_text()
+        for stage in tracing.STAGES:
+            if stage.startswith("decode/"):
+                assert f"| `{stage}` |" in doc, stage
+
+    def test_a_clock_pair_is_one_instant_on_three_clocks(self):
+        import time
+
+        before = time.time_ns()
+        pair = tracing.clock_pair()
+        after = time.time_ns()
+        assert before <= pair["unix_ns"] <= after
+        assert pair["span_us"] == pytest.approx(
+            (pair["perf_counter_s"] - tracing._EPOCH) * 1e6, abs=1e-2)
+        # ts 0 of /monitoring/traces on the Unix clock, whenever read.
+        again = tracing.clock_pair()
+        assert abs(again["epoch_unix_ns"] - pair["epoch_unix_ns"]) < 1e6
+        assert pair["epoch_unix_ns"] + pair["span_us"] * 1e3 \
+            == pytest.approx(pair["unix_ns"], abs=1e3)
+
+    def test_the_bridge_is_scoped_and_there_is_no_switch_to_leave_it_on(
+            self, monkeypatch):
+        entered = []
+
+        class Annotation:
+            def __init__(self, name):
+                self.name = name
+
+            def __enter__(self):
+                entered.append(self.name)
+
+            def __exit__(self, *exc):
+                entered.append("end " + self.name)
+
+        monkeypatch.setattr(tracing, "_ann_cls", Annotation)
+        assert not hasattr(tracing, "bridge_profiler")
+        with tracing.request_trace("predict"):
+            with tracing.span("device/execute"):
+                pass
+        assert entered == []
+        with pytest.raises(RuntimeError):
+            with tracing.profiler_annotations():
+                with tracing.request_trace("predict"):
+                    with tracing.span("device/execute"):
+                        pass
+                raise RuntimeError("the capture failed")
+        assert entered == ["serving/predict", "device/execute",
+                           "end device/execute", "end serving/predict"]
+        assert not tracing._bridge
+
+
 class TestBatchingHandoff:
     def test_traces_cross_the_queue_and_fan_out(self, scheduler):
         executed = []
