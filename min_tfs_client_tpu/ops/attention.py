@@ -268,10 +268,16 @@ def flash_attention(
 #    table's pages back into a contiguous (B, H, P*bs, D) view sized by the
 #    table width (true used tokens, NOT max length) and run masked dense
 #    attention. This is the CPU path and the token-exactness yardstick.
-#  * `paged_flash_attention` — Pallas kernel: the block table rides as a
-#    scalar-prefetch operand so the BlockSpec index_map DMAs exactly the
-#    pages each (batch, head) needs, one page per grid step, online-softmax
-#    accumulated in VMEM scratch. Pages never materialize contiguously.
+#  * `paged_flash_attention` — Pallas kernel over a (slot, head group,
+#    table entry) grid: the block table and the lengths ride as
+#    scalar-prefetch operands, and a step's BlockSpecs fetch ONE page of
+#    K and of V with every head of the group in it (a page holds its
+#    heads contiguously) plus that page's bias tile, online-softmax
+#    accumulated in VMEM scratch. A step past a slot's last page names
+#    the block already resident, so nothing is fetched for it, and its
+#    body does not run: a slot of length 0 (one that does not ride this
+#    tick) costs its steps' bare overhead. Pages never materialize
+#    contiguously.
 #
 # `paged_attention()` dispatches between them behind the same `_on_tpu()`
 # gate as the dense kernel (arXiv:2604.15464's ragged paged attention,
@@ -338,19 +344,20 @@ def paged_attention_reference(
 
 
 def _paged_kernel(tbl_ref, len_ref, qstart_ref, *rest,
-                  scale: float, block_size: int, num_heads: int, sq: int,
-                  has_bias: bool):
-    """One (batch*head, page) grid cell. The index_map already routed this
-    cell's K/V refs at the table's page; here we accumulate online softmax
-    across the page grid dim in VMEM scratch and emit on the last page."""
+                  scale: float, block_size: int, sq: int, has_bias: bool):
+    """One (slot, head group, table entry) grid cell. The index_maps
+    already routed this cell's K/V refs at the table's page, all the
+    group's heads in it; here we accumulate online softmax across the
+    table's entries in VMEM scratch and emit on the last one. Entries
+    past the slot's valid keys do nothing."""
     if has_bias:
         bias_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref = rest
     else:
         q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref = rest
         bias_ref = None
-    bh = pl.program_id(0)
-    page = pl.program_id(1)
-    sq_p, d = q_ref.shape
+    slot = pl.program_id(0)
+    page = pl.program_id(2)
+    valid_len = len_ref[slot]
 
     @pl.when(page == 0)
     def _init():
@@ -358,44 +365,78 @@ def _paged_kernel(tbl_ref, len_ref, qstart_ref, *rest,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    valid_len = len_ref[bh // num_heads]
-    q_start = qstart_ref[bh // num_heads]
-    q = q_ref[...].astype(jnp.float32) * scale
-    k = k_ref[...].astype(jnp.float32)  # (block_size, D)
-    v = v_ref[...].astype(jnp.float32)
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)
-    if bias_ref is not None:
-        s = s + bias_ref[...].astype(jnp.float32)
-    ki = (page * block_size
-          + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1))
-    # Query row r sits at absolute position q_start + r: it attends keys
-    # < min(valid_len, q_start + r + 1). Padded rows (r >= sq) mask
-    # everything and emit zeros.
-    qi = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-    row_limit = jnp.minimum(valid_len, q_start + qi + 1)
-    row_limit = jnp.where(qi < sq, row_limit, 0)
-    s = jnp.where(ki < row_limit, s, NEG_INF)
+    @pl.when(page * block_size < valid_len)
+    def _accumulate():
+        q = q_ref[...].astype(jnp.float32) * scale  # (heads, Sq_p, D)
+        k = k_ref[...].astype(jnp.float32)          # (heads, block_size, D)
+        v = v_ref[...].astype(jnp.float32)
+        s = jax.lax.dot_general(q, k, (((2,), (2,)), ((0,), (0,))),
+                                preferred_element_type=jnp.float32)
+        if bias_ref is not None:
+            s = s + bias_ref[...].astype(jnp.float32)
+        ki = (page * block_size
+              + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2))
+        # Query row r sits at absolute position q_start + r: it attends
+        # keys < min(valid_len, q_start + r + 1). Padded rows (r >= sq)
+        # mask everything and emit zeros.
+        qi = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        row_limit = jnp.minimum(valid_len, qstart_ref[slot] + qi + 1)
+        row_limit = jnp.where(qi < sq, row_limit, 0)
+        s = jnp.where(ki < row_limit, s, NEG_INF)
 
-    m_prev, l_prev, acc = m_ref[...], l_ref[...], acc_ref[...]
-    m_cur = jnp.max(s, axis=1, keepdims=True)
-    m_new = jnp.maximum(m_prev, m_cur)
-    p = jnp.exp(s - m_new)
-    correction = jnp.exp(m_prev - m_new)
-    l_new = correction * l_prev + jnp.sum(p, axis=1, keepdims=True)
-    acc_new = acc * correction + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    m_ref[...] = m_new
-    l_ref[...] = l_new
-    acc_ref[...] = acc_new
+        m_prev = m_ref[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
+        p = jnp.exp(s - m_new)
+        correction = jnp.exp(m_prev - m_new)
+        l_ref[...] = correction * l_ref[...] + jnp.sum(
+            p, axis=2, keepdims=True)
+        acc_ref[...] = acc_ref[...] * correction + jax.lax.dot_general(
+            p, v, (((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32)
+        m_ref[...] = m_new
 
-    @pl.when(page == pl.num_programs(1) - 1)
+    @pl.when(page == pl.num_programs(2) - 1)
     def _emit():
-        row_valid = m_new > NEG_INF * 0.5
-        denom = jnp.where(l_new == 0.0, 1.0, l_new)
-        o_ref[...] = jnp.where(row_valid, acc_new / denom,
+        # Rows that met no valid key (length 0, padded rows) never left
+        # NEG_INF: zeros, not a mean over masked V.
+        row_valid = m_ref[...] > NEG_INF * 0.5
+        l = l_ref[...]
+        denom = jnp.where(l == 0.0, 1.0, l)
+        o_ref[...] = jnp.where(row_valid, acc_ref[...] / denom,
                                0.0).astype(o_ref.dtype)
+
+
+def _query_rows(sq: int) -> int:
+    """Query rows as the kernel sees them: padded to a sublane tile."""
+    return max(8, 1 << (sq - 1).bit_length())
+
+
+def _paged_step_vmem_bytes(heads: int, sq: int, d: int, block_size: int,
+                           itemsize: int) -> int:
+    """VMEM one grid step of `_paged_kernel` needs with `heads` heads in
+    it: the K, V, bias, Q and O blocks double-buffered, the softmax
+    scratch, and the float32 temporaries of the body; lanes padded to
+    128, rows to the dtype's sublane tile."""
+    sq_p = _query_rows(sq)
+    lanes = lambda n: -(-n // 128) * 128
+    rows = lambda n: -(-n * itemsize // 32) * 32 // itemsize
+    kv = 2 * rows(block_size) * lanes(d) * itemsize
+    qo = 2 * rows(sq_p) * lanes(d) * itemsize
+    bias = sq_p * lanes(block_size) * 4
+    scratch = sq_p * (2 * 128 + lanes(d)) * 4
+    temps = ((sq_p + 2 * block_size) * lanes(d)
+             + 3 * sq_p * lanes(block_size)) * 4
+    return heads * (2 * (kv + qo + bias) + scratch + temps)
+
+
+def _paged_head_group(h: int, sq: int, d: int, block_size: int,
+                      itemsize: int) -> int:
+    """Heads a grid step carries: the largest divisor of `h` whose step
+    fits `_PAGED_STEP_VMEM_BYTES`; 0 when not even one head does."""
+    return next(
+        (g for g in range(h, 0, -1) if h % g == 0
+         and _paged_step_vmem_bytes(g, sq, d, block_size, itemsize)
+         <= _PAGED_STEP_VMEM_BYTES), 0)
 
 
 def paged_flash_attention(
@@ -414,71 +455,89 @@ def paged_flash_attention(
     paged_attention_reference; the block table, lengths, and q_start ride
     as scalar-prefetch operands so each grid step's BlockSpec index_map
     picks the right arena page — gathered pages never materialize in HBM.
-    `bias` (broadcastable to (B, H, Sq, P*block_size)) streams one
-    (Sq, block_size) tile per page alongside the K/V pages; its bytes are
-    ~Sq/(2·D) of the KV traffic, so the used-token byte scaling holds."""
+    The grid is (slot, head group, table entry): one step holds a page of
+    K and of V for all heads of its group (all of H wherever a step fits
+    VMEM, `_paged_head_group`). Table entries past a slot's
+    ceil(length / block_size) pages are never read: their steps name the
+    slot's last page again, which Pallas does not fetch twice, and skip
+    the body; a slot of length 0 yields zeros. `bias` (broadcastable to
+    (B, H, Sq, P*block_size)) streams one (heads, Sq, block_size) tile
+    per page alongside the K/V pages."""
     b, h, sq, d = q.shape
-    num_pages, _, block_size, _ = k_pages.shape
+    _, _, block_size, _ = k_pages.shape
     _, max_pages = block_tables.shape
     if scale is None:
         scale = 1.0 / float(np.sqrt(d))
     if q_start is None:
         q_start = lengths - sq
 
-    sq_p = max(8, 1 << (sq - 1).bit_length())  # MXU-friendly query rows
+    sq_p = _query_rows(sq)
     q_p = _pad_to(q, 2, sq_p)
-    q_f = q_p.reshape(b * h, sq_p, d)
+    # (The gate refuses a shape no head of which fits; interpret mode
+    # takes any.)
+    hg = max(1, _paged_head_group(h, sq, d, block_size,
+                                  k_pages.dtype.itemsize))
     # The table rides in SMEM flat: a 2-D SMEM array pads every row to 128
     # words, so a (b, P) table would cost b * 512 bytes however narrow.
     tbl = block_tables.astype(jnp.int32).reshape(-1)  # (b * P,)
 
-    def page_index(bh, p, tbl, lens, qs):
-        return (tbl[(bh // h) * max_pages + p], bh % h, 0, 0)
+    def entry(slot, p, lens):
+        """Table entry `p`, held at the slot's last page past its keys."""
+        used = (lens[slot] + block_size - 1) // block_size
+        return jnp.minimum(p, jnp.maximum(used - 1, 0))
+
+    def page_index(slot, g, p, tbl, lens, qs):
+        return (tbl[slot * max_pages + entry(slot, p, lens)], g, 0, 0)
+
+    def head_index(slot, g, p, tbl, lens, qs):
+        return (slot, g, 0, 0)
 
     in_specs = [
-        pl.BlockSpec((None, sq_p, d), lambda bh, p, tbl, lens, qs: (bh, 0, 0)),
-        pl.BlockSpec((None, None, block_size, d), page_index),
-        pl.BlockSpec((None, None, block_size, d), page_index),
+        pl.BlockSpec((None, hg, sq_p, d), head_index),
+        pl.BlockSpec((None, hg, block_size, d), page_index),
+        pl.BlockSpec((None, hg, block_size, d), page_index),
     ]
-    operands = [q_f, k_pages, v_pages]
+    operands = [q_p, k_pages, v_pages]
     if bias is not None:
-        # One (Sq_p, block_size) tile per page, laid out (b*h, P, Sq_p,
-        # bs) so the block's last two dims ARE the array's: Mosaic takes
-        # any page size that way, where a (Sq_p, bs) window into a
+        # One (heads, Sq_p, block_size) tile per page, laid out (b, P, h,
+        # Sq_p, bs) so the block's last two dims ARE the array's: Mosaic
+        # takes any page size that way, where a (Sq_p, bs) window into a
         # (Sq_p, P*bs) row needs bs % 128 == 0 as soon as P > 1.
         bias_f = jnp.broadcast_to(
             bias.astype(jnp.float32),
             (b, h, sq, max_pages * block_size))
         bias_f = _pad_to(bias_f, 2, sq_p).reshape(
-            b * h, sq_p, max_pages, block_size).transpose(0, 2, 1, 3)
+            b, h, sq_p, max_pages, block_size).transpose(0, 3, 1, 2, 4)
         in_specs.insert(0, pl.BlockSpec(
-            (None, None, sq_p, block_size),
-            lambda bh, p, tbl, lens, qs: (bh, p, 0, 0)))
+            (None, None, hg, sq_p, block_size),
+            lambda slot, g, p, tbl, lens, qs:
+            (slot, entry(slot, p, lens), g, 0, 0)))
         operands.insert(0, bias_f)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,  # block tables, lengths, q_start
-        grid=(b * h, max_pages),
+        grid=(b, h // hg, max_pages),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((None, sq_p, d),
-                               lambda bh, p, tbl, lens, qs: (bh, 0, 0)),
+        out_specs=pl.BlockSpec((None, hg, sq_p, d), head_index),
         scratch_shapes=[
-            pltpu.VMEM((sq_p, 1), jnp.float32),
-            pltpu.VMEM((sq_p, 1), jnp.float32),
-            pltpu.VMEM((sq_p, d), jnp.float32),
+            pltpu.VMEM((hg, sq_p, 1), jnp.float32),
+            pltpu.VMEM((hg, sq_p, 1), jnp.float32),
+            pltpu.VMEM((hg, sq_p, d), jnp.float32),
         ],
     )
     kernel = functools.partial(
-        _paged_kernel, scale=scale, block_size=block_size,
-        num_heads=h, sq=sq, has_bias=bias is not None)
+        _paged_kernel, scale=scale, block_size=block_size, sq=sq,
+        has_bias=bias is not None)
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b * h, sq_p, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, h, sq_p, d), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
         name="_paged_kernel",  # the device-trace reduction finds it by name
     )(tbl, lengths.astype(jnp.int32), q_start.astype(jnp.int32), *operands)
-    return out.reshape(b, h, sq_p, d)[:, :, :sq, :]
+    return out[:, :, :sq, :]
 
 
 def paged_attention(
@@ -613,6 +672,10 @@ class PagedKV:
             lengths = self.lengths + sq
         if q_start is None:
             q_start = self.lengths
+        if self.active is not None:
+            # A slot that does not ride reads nothing: its rows went to
+            # the trash page and its output is never used.
+            lengths = jnp.where(self.active, lengths, 0)
         return paged_attention(q, self.arenas[k_key], self.arenas[v_key],
                                self.tables, lengths, scale=scale, bias=bias,
                                q_start=q_start)
@@ -624,11 +687,12 @@ def _on_tpu() -> bool:
 
 # Scoped on-chip memory the kernels are written against (TPU v5e: 1 MiB
 # of SMEM less what the program itself uses; half of the 16 MiB scoped
-# VMEM for resident K/V, the rest for Q/O blocks and f32 temporaries).
-# Both kernels AOT-compile for "TPU v5 lite" at these bounds and are
-# refused by the compiler just past them.
+# VMEM for resident K/V or for one paged step, the rest for Q/O blocks,
+# f32 temporaries and what the compiler keeps). Both kernels AOT-compile
+# for "TPU v5 lite" at these bounds.
 _PAGED_SMEM_BYTES = (1 << 20) - (16 << 10)
 _FLASH_KV_VMEM_BYTES = 8 << 20
+_PAGED_STEP_VMEM_BYTES = 8 << 20
 
 
 def _paged_kernel_applies(q: jax.Array, k_pages: jax.Array,
@@ -636,13 +700,19 @@ def _paged_kernel_applies(q: jax.Array, k_pages: jax.Array,
     """The shapes `_paged_kernel` compiles for, with or without bias, at
     any table width: head dim and page rows on sublane multiples (any
     such page size — the K/V, Q and bias blocks all span their arrays'
-    last two dims), and the scalar-prefetched operands — the flat
-    (B * P) table plus lengths and q_start — inside SMEM. One device
-    only: decode pools carry no mesh, and a paged read under a serving
-    mesh (speculative verify in a sharded export) takes the reference."""
+    last two dims), a step of at least one head inside VMEM
+    (`_paged_head_group` folds as many heads into a step as fit), and the
+    scalar-prefetched operands — the flat (B * P) table plus lengths and
+    q_start — inside SMEM. One device only: decode pools carry no mesh,
+    and a paged read under a serving mesh (speculative verify in a
+    sharded export) takes the reference."""
     b, max_pages = block_tables.shape
-    return (q.shape[-1] % 8 == 0
-            and k_pages.shape[-2] % 8 == 0
+    _, h, sq, d = q.shape
+    block_size = k_pages.shape[-2]
+    return (d % 8 == 0
+            and block_size % 8 == 0
+            and _paged_head_group(h, sq, d, block_size,
+                                  k_pages.dtype.itemsize) > 0
             and (b * max_pages + 2 * b) * 4 <= _PAGED_SMEM_BYTES
             and not _auto_mesh_axes())
 

@@ -1565,13 +1565,13 @@ class PagedSlotPool:
                         cursor[s] = t
                     # What the ragged kernel actually reads: the pages
                     # live sessions own — not slots × table width.
-                    gather_bytes = self.page_bytes * sum(
-                        len(self._pages[s]) for s in live)
+                    gather_pages = sum(len(self._pages[s]) for s in live)
                 else:
                     for s in live:
                         cursor[s] = self._tokens[s] // self.block_size
                     # The fallback materializes the full gathered view.
-                    gather_bytes = self.page_bytes * self.max_slots * width
+                    gather_pages = self.max_slots * width
+                gather_bytes = self.page_bytes * gather_pages
             tracing.add_span(
                 "decode/prepare", t_entry, time.perf_counter(),
                 round=ordinal, slots=len(slots), live=len(live),
@@ -1580,7 +1580,8 @@ class PagedSlotPool:
                 # Times the three sends and the ENQUEUE of the program:
                 # its run on the device ends under `decode/fetch`.
                 with tracing.span("decode/tick", slots=len(live),
-                                  round=ordinal, width=width):
+                                  round=ordinal, width=width,
+                                  pages=gather_pages):
                     dense, arenas, outputs = self._tick_jit(
                         self._params, self._dense_pool, self._arenas,
                         self._jnp.asarray(tables),

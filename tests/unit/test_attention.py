@@ -1,13 +1,17 @@
 """Flash attention kernel vs jnp reference (interpret mode on CPU mesh),
 plus the ragged paged variants (block-table KV) vs the dense path."""
 
+import importlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from min_tfs_client_tpu.ops.attention import (
+    PagedKV,
     _flash_kernel_applies,
+    _paged_head_group,
     _paged_kernel_applies,
     attention,
     attention_reference,
@@ -17,6 +21,10 @@ from min_tfs_client_tpu.ops.attention import (
     paged_flash_attention,
     paged_prefill_attention,
 )
+
+
+# `ops.attention` the module: the package exports the function under that name.
+attention_module = importlib.import_module("min_tfs_client_tpu.ops.attention")
 
 
 def _rand(shape, seed=0, dtype=np.float32):
@@ -265,6 +273,92 @@ class TestPagedAttention:
                     np.asarray(got)[i, :, :lvn[i]],
                     np.asarray(want)[i, :, :lvn[i]], atol=2e-5, rtol=2e-5)
 
+    def _ragged_case(self, sq, with_bias, *, h=3, trash_fill=0.0):
+        """Live slots among slots that do not ride: lengths 0 with every
+        table entry the trash page, one live slot ending exactly on a
+        page boundary and one a token past it. The trash page (the
+        arena's last, where live rows' trailing entries point too) holds
+        `trash_fill`."""
+        bs, d = 8, 16
+        lengths = np.asarray([0, 16, 17, 0, 40, 0], np.int32)
+        q, _, _, k_pages, v_pages, tables, _ = _paged_case(
+            40 + sq, b=len(lengths), h=h, d=d, block_size=bs, max_len=48,
+            sq=sq)
+        trash = k_pages.shape[0]
+        fill = jnp.full((1, h, bs, d), trash_fill, jnp.float32)
+        k_pages = jnp.concatenate([k_pages, fill])
+        v_pages = jnp.concatenate([v_pages, fill])
+        used = -(-lengths // bs)
+        tables = jnp.where(
+            jnp.arange(tables.shape[1])[None, :] < used[:, None],
+            tables, trash)
+        bias = None
+        if with_bias:
+            bias = _rand((len(lengths), h, sq, tables.shape[1] * bs),
+                         seed=sq)
+        return q, k_pages, v_pages, tables, jnp.asarray(lengths), bias
+
+    @pytest.mark.parametrize("with_bias", [False, True])
+    @pytest.mark.parametrize("sq", [1, 5, 16])
+    def test_kernel_reads_no_entry_past_a_slots_keys(self, sq, with_bias):
+        """The kernel must agree with the oracle row for row and emit
+        zeros for the slots that do not ride — with the trash page full
+        of NaN: an entry past a slot's last page is neither fetched nor
+        computed on (the oracle, which gathers every entry, sees a clean
+        trash page)."""
+        q, kp, vp, tbl, lengths, bias = self._ragged_case(sq, with_bias)
+        want = np.asarray(paged_attention_reference(
+            q, kp, vp, tbl, lengths, bias=bias))
+        _, kp_nan, vp_nan, _, _, _ = self._ragged_case(
+            sq, with_bias, trash_fill=np.nan)
+        got = np.asarray(paged_flash_attention(
+            q, kp_nan, vp_nan, tbl, lengths, bias=bias, interpret=True))
+        np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
+        np.testing.assert_array_equal(got[np.asarray(lengths) == 0], 0.0)
+
+    @pytest.mark.parametrize("sq", [1, 5])
+    def test_head_groups_when_a_step_cannot_hold_every_head(
+            self, sq, monkeypatch):
+        """Where all heads of a page do not fit the step's VMEM bound the
+        grid keeps a head-group axis: same answers."""
+        h = 4
+        q, kp, vp, tbl, lengths, bias = self._ragged_case(sq, True, h=h)
+        want = paged_flash_attention(q, kp, vp, tbl, lengths, bias=bias,
+                                     interpret=True)
+        one_head = attention_module._paged_step_vmem_bytes(
+            1, sq, q.shape[-1], kp.shape[-2], kp.dtype.itemsize)
+        monkeypatch.setattr(attention_module, "_PAGED_STEP_VMEM_BYTES",
+                            2 * one_head)
+        assert _paged_head_group(h, sq, q.shape[-1], kp.shape[-2],
+                                 kp.dtype.itemsize) == 2
+        got = paged_flash_attention(q, kp, vp, tbl, lengths, bias=bias,
+                                    interpret=True)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        np.testing.assert_allclose(
+            np.asarray(got), np.asarray(paged_attention_reference(
+                q, kp, vp, tbl, lengths, bias=bias)), atol=2e-5, rtol=2e-5)
+
+    @pytest.mark.parametrize("explicit", [False, True])
+    def test_attend_gives_slots_that_do_not_ride_length_zero(self, explicit):
+        """A handle that carries `active` attends with length 0 for the
+        other slots, on the default lengths and on a caller's own (T5
+        passes its own): their rows are zeros, the riders' unchanged."""
+        q, kp, vp, tbl, lengths, bias = self._ragged_case(1, True)
+        written = jnp.maximum(lengths - 1, 0)  # tokens before this step
+        active = jnp.asarray([False, True, False, False, True, False])
+        kv = PagedKV({"k": kp, "v": vp}, tbl, written, block_size=8,
+                     trash=kp.shape[0] - 1, row_axes={"k": 2, "v": 2},
+                     active=active)
+        kwargs = {"lengths": written + 1, "q_start": written} \
+            if explicit else {}
+        got = np.asarray(kv.attend(q, "k", "v", bias=bias, **kwargs))
+        want = np.asarray(paged_attention_reference(
+            q, kp, vp, tbl, written + 1, bias=bias))
+        np.testing.assert_array_equal(got[np.asarray(active)],
+                                      want[np.asarray(active)])
+        np.testing.assert_array_equal(got[~np.asarray(active)], 0.0)
+        assert np.abs(want[2]).max() > 0  # slot 2 is live, but idle
+
     def test_zero_length_rows_are_zero(self):
         q, k, v, k_pages, v_pages, tables, lengths = _paged_case(
             7, b=2, h=2, d=8, block_size=4, max_len=16)
@@ -315,14 +409,25 @@ def test_flash_lowers_for_tpu_at_bert_base_shapes():
     assert "tpu_custom_call" in text
 
 
-@pytest.mark.parametrize("sq", [1, 5])
-@pytest.mark.parametrize("width", [1, 2, 4])
-@pytest.mark.parametrize("block_size", [8, 16, 32, 128])
-def test_paged_with_bias_lowers_for_tpu(block_size, width, sq):
+# (b, h, d, block_size, width, sq): the page sizes the gate admits at
+# table widths 1, 2 and 4; the served shape of t5-large.sessions (64 slots
+# of 16 heads, 16-token pages, width 16) for the decode tick (sq 1) and a
+# prefill chunk (sq 16); and a shape whose heads the VMEM bound splits
+# into groups of 16.
+_PAGED_LOWERING_CASES = [
+    (4, 8, 64, block_size, width, sq)
+    for block_size in (8, 16, 32, 128) for width in (1, 2, 4)
+    for sq in (1, 5)
+] + [(64, 16, 64, 16, 16, 1), (64, 16, 64, 16, 16, 16),
+     (4, 64, 128, 128, 4, 16)]
+
+
+@pytest.mark.parametrize("b,h,d,block_size,width,sq", _PAGED_LOWERING_CASES)
+def test_paged_with_bias_lowers_for_tpu(b, h, d, block_size, width, sq):
     """T5's only path: bias, page sizes the gate admits, table wider than
     one page (the case Mosaic refused while the bias rode as one
     (Sq, P*bs) row per head)."""
-    b, h, d, pages = 4, 8, 64, 4 * width + 1
+    pages = b * width + 1
     q = jax.ShapeDtypeStruct((b, h, sq, d), jnp.bfloat16)
     arena = jax.ShapeDtypeStruct((pages, h, block_size, d), jnp.bfloat16)
     tables = jax.ShapeDtypeStruct((b, width), jnp.int32)
@@ -344,6 +449,15 @@ def test_gates_refuse_what_the_kernels_cannot_hold():
     q = jax.ShapeDtypeStruct((8192, 8, 1, 64), jnp.bfloat16)
     arena = jax.ShapeDtypeStruct((64, 8, 16, 64), jnp.bfloat16)
     tables = jax.ShapeDtypeStruct((8192, 30), jnp.int32)
+    assert not _paged_kernel_applies(q, arena, tables)
+    # A step of `_paged_kernel` holds a page of K and of V for a group of
+    # heads: 64 heads of 128-token x 128-dim pages fit 16 at a time...
+    assert _paged_head_group(64, 16, 128, 128, 2) == 16
+    # ...and one head of a 4096-token page does not fit at all.
+    q = jax.ShapeDtypeStruct((4, 8, 1, 128), jnp.bfloat16)
+    arena = jax.ShapeDtypeStruct((17, 8, 4096, 128), jnp.bfloat16)
+    tables = jax.ShapeDtypeStruct((4, 4), jnp.int32)
+    assert _paged_head_group(8, 1, 128, 4096, 2) == 0
     assert not _paged_kernel_applies(q, arena, tables)
 
 

@@ -1098,6 +1098,10 @@ class TestDecodeLoopPhases:
                 == by_name["decode/handoff"][2]["riders"]
             assert prepare[2]["lock_wait_us"] >= 0
             assert tick[2]["width"] in (1, 2, 4)
+            # The pages the riders hold: at least one each, never more
+            # than their table rows have entries.
+            assert tick[2]["slots"] <= tick[2]["pages"] \
+                <= tick[2]["slots"] * tick[2]["width"]
             stepped += tick[2]["slots"]
         assert stepped == n * steps
         last = max(rounds)
