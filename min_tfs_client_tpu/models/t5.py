@@ -351,11 +351,11 @@ def paged_decoder_positions(params: dict, config: T5Config,
         h = nn.rms_norm(layer["self_norm"], x)
         p = layer["self_attention"]
         q = nn._heads(nn.dense(p["query"], h), config.num_heads)
-        k_new = nn._heads(nn.dense(p["key"], h), config.num_heads)
-        v_new = nn._heads(nn.dense(p["value"], h), config.num_heads)
+        # K and V go in as the projection leaves them, (B, L, H * D): one
+        # arena row a token, its heads side by side.
         kv = kv.append(
-            {_cache_key(i, "k"): k_new.transpose(0, 2, 1, 3),
-             _cache_key(i, "v"): v_new.transpose(0, 2, 1, 3)},
+            {_cache_key(i, "k"): nn.dense(p["key"], h),
+             _cache_key(i, "v"): nn.dense(p["value"], h)},
             row_valid=chunk_lens)
         out = kv.attend(q, _cache_key(i, "k"), _cache_key(i, "v"),
                         bias=bias, scale=1.0, lengths=lengths_in,
@@ -744,14 +744,13 @@ def speculative_decode(
         bs = int(kv_block_size)
         pages_per = -(-cache_len // bs)
         n_pages = b * pages_per
-        caches_t = {}
-        spec_row_axes = {}
-        for i in range(config.num_decoder_layers):
-            for name in ("k", "v"):
-                caches_t[_cache_key(i, name)] = jnp.zeros(
-                    (n_pages + 1, config.num_heads, bs, config.d_kv),
-                    nn.COMPUTE_DTYPE)
-                spec_row_axes[_cache_key(i, name)] = 2
+        from min_tfs_client_tpu.ops.attention import PagedKV
+
+        caches_t = {
+            _cache_key(i, name): PagedKV.arena(
+                n_pages, bs, (config.num_heads, config.d_kv),
+                nn.COMPUTE_DTYPE)
+            for i in range(config.num_decoder_layers) for name in ("k", "v")}
         spec_tables = jnp.asarray(
             np.arange(n_pages, dtype=np.int32).reshape(b, pages_per))
     else:
@@ -788,12 +787,9 @@ def speculative_decode(
         # Target: ONE pass over the k+1-position block [cur, d_1..d_k].
         block = jnp.concatenate([cur, d_tokens], axis=1)  # (B, k+1)
         if kv_block_size:
-            from min_tfs_client_tpu.ops.attention import PagedKV
-
             q_start = jnp.full((b,), step, jnp.int32)
             kv = PagedKV(caches_t, spec_tables, q_start,
-                         block_size=bs, trash=n_pages,
-                         row_axes=spec_row_axes)
+                         block_size=bs, trash=n_pages)
             logits, kv = paged_decoder_positions(
                 params, config, block, q_start, kv, encoded_t, lengths)
             caches_t = kv.arenas

@@ -260,9 +260,19 @@ def flash_attention(
 # -- ragged paged attention (block-table KV) ---------------------------------
 #
 # The decode KV store (servables/decode_sessions.PagedSlotPool) keeps each
-# session's cache as block_size-token pages scattered through a shared
-# (num_pages, H, block_size, D) HBM arena, addressed by a per-session block
-# table. Attention then has two equivalent forms:
+# session's cache as block_size-token pages scattered through a shared HBM
+# arena, addressed by a per-session block table. The arena's shape is ONE
+# decision, owned by `PagedKV.arena`: `(num_pages + 1, block_size, F)`,
+# token-major and lane-dense. A page is block_size token rows; a row is
+# every head of one token, contiguous (F = H * D for attention K/V); the
+# last page is the trash page that absorbs masked writes. The append's
+# scatter (its scattered dims major, the row minor), the layout the runtime
+# gives a donated argument (row-major once the minor dim fills its 128
+# lanes) and the Pallas operand (row-major) all agree on it, so a tick
+# writes its rows in place and the kernel reads the pages as they lie: no
+# program copies an arena. (The old (pages, H, block_size, D) unit cost
+# three relayouts of every arena a tick at D = 64: PERF.md, PR 28.)
+# Attention over it has two equivalent forms:
 #
 #  * `paged_attention_reference` — the jnp semantics oracle: gather the
 #    table's pages back into a contiguous (B, H, P*bs, D) view sized by the
@@ -271,28 +281,29 @@ def flash_attention(
 #  * `paged_flash_attention` — Pallas kernel over a (slot, head group,
 #    table entry) grid: the block table and the lengths ride as
 #    scalar-prefetch operands, and a step's BlockSpecs fetch ONE page of
-#    K and of V with every head of the group in it (a page holds its
-#    heads contiguously) plus that page's bias tile, online-softmax
-#    accumulated in VMEM scratch. A step past a slot's last page names
-#    the block already resident, so nothing is fetched for it, and its
-#    body does not run: a slot of length 0 (one that does not ride this
-#    tick) costs its steps' bare overhead. Pages never materialize
-#    contiguously.
+#    K and of V, the group's heads side by side on its lanes, plus that
+#    page's bias tile, online-softmax accumulated in VMEM scratch. A step
+#    past a slot's last page names the block already resident, so nothing
+#    is fetched for it, and its body does not run: a slot of length 0 (one
+#    that does not ride this tick) costs its steps' bare overhead. Pages
+#    never materialize contiguously.
 #
 # `paged_attention()` dispatches between them behind the same `_on_tpu()`
 # gate as the dense kernel (arXiv:2604.15464's ragged paged attention,
 # collapsed to the single-arena/one-table layout the pool uses).
 
 
-def gather_kv_pages(pages: jax.Array, block_tables: jax.Array) -> jax.Array:
-    """(num_pages, H, bs, D) arena + (B, P) int32 tables -> (B, H, P*bs, D).
+def gather_kv_pages(pages: jax.Array, block_tables: jax.Array,
+                    num_heads: int) -> jax.Array:
+    """(num_pages, bs, H*D) arena + (B, P) int32 tables -> (B, H, P*bs, D).
 
     Entries past a sequence's allocated pages may name ANY in-range page
     (the pool points them at its trash page); callers mask by length."""
-    g = pages[block_tables]  # (B, P, H, bs, D)
     b, p = block_tables.shape
-    _, h, bs, d = pages.shape
-    return g.transpose(0, 2, 1, 3, 4).reshape(b, h, p * bs, d)
+    _, bs, f = pages.shape
+    g = pages[block_tables]  # (B, P, bs, F): token rows already in order
+    return g.reshape(b, p * bs, num_heads, f // num_heads).transpose(
+        0, 2, 1, 3)
 
 
 def paged_attention_reference(
@@ -307,24 +318,25 @@ def paged_attention_reference(
     q_start: Optional[jax.Array] = None,
 ) -> jax.Array:
     """Oracle: gather pages per true sequence length, then masked dense
-    attention. q (B, H, Sq, D) holds Sq consecutive positions; lengths
-    (B,) counts valid keys INCLUDING the query rows' own (already-
-    written) K/V. `q_start` (B,) is query row 0's absolute position —
-    default lengths - Sq (right-aligned, the KV-cache decode/verify
-    convention); a chunked prefill passes its chunk's start explicitly so
-    a partial final chunk (valid rows < Sq) still masks per true row
-    position. Query row r attends keys < min(lengths, q_start + r + 1),
-    so Sq=1 reduces to pure lengths masking and Sq>1 is causal within the
-    block. `bias` broadcastable to (B, H, Sq, P*block_size) is added
-    after scaling (T5's relative position bias over the gathered key
-    positions). Returns (B, H, Sq, D)."""
+    attention. q (B, H, Sq, D) holds Sq consecutive positions; the arenas
+    are `PagedKV.arena`s of (H, D) tokens; lengths (B,) counts valid keys
+    INCLUDING the query rows' own (already-written) K/V. `q_start` (B,) is
+    query row 0's absolute position — default lengths - Sq
+    (right-aligned, the KV-cache decode/verify convention); a chunked
+    prefill passes its chunk's start explicitly so a partial final chunk
+    (valid rows < Sq) still masks per true row position. Query row r
+    attends keys < min(lengths, q_start + r + 1), so Sq=1 reduces to pure
+    lengths masking and Sq>1 is causal within the block. `bias`
+    broadcastable to (B, H, Sq, P*block_size) is added after scaling
+    (T5's relative position bias over the gathered key positions).
+    Returns (B, H, Sq, D)."""
     b, h, sq, d = q.shape
     if scale is None:
         scale = 1.0 / float(np.sqrt(d))
     if q_start is None:
         q_start = lengths - sq
-    k = gather_kv_pages(k_pages, block_tables)
-    v = gather_kv_pages(v_pages, block_tables)
+    k = gather_kv_pages(k_pages, block_tables, h)
+    v = gather_kv_pages(v_pages, block_tables, h)
     s = jnp.einsum("bhqd,bhkd->bhqk", q, k,
                    preferred_element_type=jnp.float32) * scale
     if bias is not None:
@@ -346,15 +358,16 @@ def paged_attention_reference(
 def _paged_kernel(tbl_ref, len_ref, qstart_ref, *rest,
                   scale: float, block_size: int, sq: int, has_bias: bool):
     """One (slot, head group, table entry) grid cell. The index_maps
-    already routed this cell's K/V refs at the table's page, all the
-    group's heads in it; here we accumulate online softmax across the
-    table's entries in VMEM scratch and emit on the last one. Entries
-    past the slot's valid keys do nothing."""
+    already routed this cell's K/V refs at the table's page, the group's
+    heads side by side on its lanes; here we accumulate online softmax
+    across the table's entries in VMEM scratch and emit on the last one.
+    Entries past the slot's valid keys do nothing."""
     if has_bias:
         bias_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref = rest
     else:
         q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref = rest
         bias_ref = None
+    heads, _, d = q_ref.shape
     slot = pl.program_id(0)
     page = pl.program_id(2)
     valid_len = len_ref[slot]
@@ -365,11 +378,18 @@ def _paged_kernel(tbl_ref, len_ref, qstart_ref, *rest,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
+    def heads_of(page_ref):
+        """The page's heads off its lanes: (block_size, heads * D) ->
+        (heads, block_size, D). (Of three bodies timed on the chip this
+        one, then the head-batched matmuls, was quickest: PERF.md, PR 28.)"""
+        return jnp.stack([page_ref[:, h * d:(h + 1) * d]
+                          for h in range(heads)]).astype(jnp.float32)
+
     @pl.when(page * block_size < valid_len)
     def _accumulate():
         q = q_ref[...].astype(jnp.float32) * scale  # (heads, Sq_p, D)
-        k = k_ref[...].astype(jnp.float32)          # (heads, block_size, D)
-        v = v_ref[...].astype(jnp.float32)
+        k = heads_of(k_ref)
+        v = heads_of(v_ref)
         s = jax.lax.dot_general(q, k, (((2,), (2,)), ((0,), (0,))),
                                 preferred_element_type=jnp.float32)
         if bias_ref is not None:
@@ -414,27 +434,30 @@ def _query_rows(sq: int) -> int:
 def _paged_step_vmem_bytes(heads: int, sq: int, d: int, block_size: int,
                            itemsize: int) -> int:
     """VMEM one grid step of `_paged_kernel` needs with `heads` heads in
-    it: the K, V, bias, Q and O blocks double-buffered, the softmax
-    scratch, and the float32 temporaries of the body; lanes padded to
-    128, rows to the dtype's sublane tile."""
+    it: the K and V pages (the heads side by side on the lanes), the bias,
+    Q and O blocks double-buffered, the softmax scratch, and the float32
+    temporaries of the body; lanes padded to 128, rows to the dtype's
+    sublane tile."""
     sq_p = _query_rows(sq)
     lanes = lambda n: -(-n // 128) * 128
     rows = lambda n: -(-n * itemsize // 32) * 32 // itemsize
-    kv = 2 * rows(block_size) * lanes(d) * itemsize
+    kv = 2 * rows(block_size) * lanes(heads * d) * itemsize
     qo = 2 * rows(sq_p) * lanes(d) * itemsize
     bias = sq_p * lanes(block_size) * 4
     scratch = sq_p * (2 * 128 + lanes(d)) * 4
     temps = ((sq_p + 2 * block_size) * lanes(d)
              + 3 * sq_p * lanes(block_size)) * 4
-    return heads * (2 * (kv + qo + bias) + scratch + temps)
+    return 2 * kv + heads * (2 * (qo + bias) + scratch + temps)
 
 
 def _paged_head_group(h: int, sq: int, d: int, block_size: int,
                       itemsize: int) -> int:
-    """Heads a grid step carries: the largest divisor of `h` whose step
-    fits `_PAGED_STEP_VMEM_BYTES`; 0 when not even one head does."""
+    """Heads a grid step carries: the largest divisor of `h` whose lanes
+    of a page are whole 128-lane tiles (or the whole row) and whose step
+    fits `_PAGED_STEP_VMEM_BYTES`; 0 when there is none."""
     return next(
         (g for g in range(h, 0, -1) if h % g == 0
+         and (g == h or g * d % 128 == 0)
          and _paged_step_vmem_bytes(g, sq, d, block_size, itemsize)
          <= _PAGED_STEP_VMEM_BYTES), 0)
 
@@ -456,15 +479,16 @@ def paged_flash_attention(
     as scalar-prefetch operands so each grid step's BlockSpec index_map
     picks the right arena page — gathered pages never materialize in HBM.
     The grid is (slot, head group, table entry): one step holds a page of
-    K and of V for all heads of its group (all of H wherever a step fits
-    VMEM, `_paged_head_group`). Table entries past a slot's
-    ceil(length / block_size) pages are never read: their steps name the
-    slot's last page again, which Pallas does not fetch twice, and skip
-    the body; a slot of length 0 yields zeros. `bias` (broadcastable to
-    (B, H, Sq, P*block_size)) streams one (heads, Sq, block_size) tile
-    per page alongside the K/V pages."""
+    K and of V as it lies in the arena, (block_size, heads * D), for all
+    heads of its group (all of H wherever a step fits VMEM,
+    `_paged_head_group`); the body takes each head off its lanes. Table
+    entries past a slot's ceil(length / block_size) pages are never read:
+    their steps name the slot's last page again, which Pallas does not
+    fetch twice, and skip the body; a slot of length 0 yields zeros.
+    `bias` (broadcastable to (B, H, Sq, P*block_size)) streams one
+    (heads, Sq, block_size) tile per page alongside the K/V pages."""
     b, h, sq, d = q.shape
-    _, _, block_size, _ = k_pages.shape
+    _, block_size, _ = k_pages.shape
     _, max_pages = block_tables.shape
     if scale is None:
         scale = 1.0 / float(np.sqrt(d))
@@ -473,10 +497,10 @@ def paged_flash_attention(
 
     sq_p = _query_rows(sq)
     q_p = _pad_to(q, 2, sq_p)
-    # (The gate refuses a shape no head of which fits; interpret mode
+    # (The gate refuses a shape with no group that fits; interpret mode
     # takes any.)
-    hg = max(1, _paged_head_group(h, sq, d, block_size,
-                                  k_pages.dtype.itemsize))
+    hg = _paged_head_group(h, sq, d, block_size,
+                           k_pages.dtype.itemsize) or h
     # The table rides in SMEM flat: a 2-D SMEM array pads every row to 128
     # words, so a (b, P) table would cost b * 512 bytes however narrow.
     tbl = block_tables.astype(jnp.int32).reshape(-1)  # (b * P,)
@@ -487,15 +511,15 @@ def paged_flash_attention(
         return jnp.minimum(p, jnp.maximum(used - 1, 0))
 
     def page_index(slot, g, p, tbl, lens, qs):
-        return (tbl[slot * max_pages + entry(slot, p, lens)], g, 0, 0)
+        return (tbl[slot * max_pages + entry(slot, p, lens)], 0, g)
 
     def head_index(slot, g, p, tbl, lens, qs):
         return (slot, g, 0, 0)
 
     in_specs = [
         pl.BlockSpec((None, hg, sq_p, d), head_index),
-        pl.BlockSpec((None, hg, block_size, d), page_index),
-        pl.BlockSpec((None, hg, block_size, d), page_index),
+        pl.BlockSpec((None, block_size, hg * d), page_index),
+        pl.BlockSpec((None, block_size, hg * d), page_index),
     ]
     operands = [q_p, k_pages, v_pages]
     if bias is not None:
@@ -595,16 +619,15 @@ class PagedKV:
 
     The value a PagedSlotPool (servables/decode_sessions.py) hands a
     model's paged step contract, and the layout paged speculative decode
-    builds internally: per KV leaf one page arena `(num_pages(+trash),
-    ..., block_size, ...)`, one shared `(B, W)` int32 block table, and
-    per-sequence token counts. Purely functional — `append` returns a new
-    handle with updated arenas; the model never sees a gathered dense
-    cache.
+    builds internally: per KV leaf one page arena (`PagedKV.arena`, the
+    only place that spells its shape), one shared `(B, W)` int32 block
+    table, and per-sequence token counts. Purely functional — `append`
+    returns a new handle with updated arenas; the model never sees a
+    gathered dense cache.
 
     Fields:
       arenas     {key: arena}; key is caller-chosen (the pool uses the
                  leaf's pytree path, e.g. ("caches", 0, "self", "k"))
-      row_axes   {key: arena axis holding the block_size rows}
       tables     (B, W) int32; entries past a sequence's pages may name
                  any in-range page (the pool points them at trash)
       lengths    (B,) int32 tokens written BEFORE this step/chunk
@@ -612,28 +635,44 @@ class PagedKV:
       block_size, trash  static ints
     """
 
-    __slots__ = ("arenas", "row_axes", "tables", "lengths", "active",
-                 "block_size", "trash")
+    __slots__ = ("arenas", "tables", "lengths", "active", "block_size",
+                 "trash")
 
     def __init__(self, arenas: dict, tables: jax.Array, lengths: jax.Array,
-                 *, block_size: int, trash: int, row_axes: dict,
+                 *, block_size: int, trash: int,
                  active: Optional[jax.Array] = None):
         self.arenas = dict(arenas)
-        self.row_axes = dict(row_axes)
         self.tables = tables
         self.lengths = lengths
         self.active = active
         self.block_size = int(block_size)
         self.trash = int(trash)
 
+    @staticmethod
+    def arena_shape(num_pages: int, block_size: int,
+                    token_shape: tuple) -> tuple:
+        """`(num_pages + 1, block_size, F)`: a page is `block_size` token
+        rows, a row the `token_shape` values of one token flattened in
+        their order (attention K/V: (H, D) -> H * D lanes, head-major);
+        page `num_pages`, the last, is the trash page."""
+        return (int(num_pages) + 1, int(block_size),
+                int(np.prod(token_shape, dtype=np.int64)))
+
+    @classmethod
+    def arena(cls, num_pages: int, block_size: int, token_shape: tuple,
+              dtype) -> jax.Array:
+        """A zeroed arena of `num_pages` pages and the trash page."""
+        return jnp.zeros(cls.arena_shape(num_pages, block_size, token_shape),
+                         dtype)
+
     def append(self, updates: dict, *,
                row_valid: Optional[jax.Array] = None) -> "PagedKV":
         """Scatter this step's new rows into the arenas at positions
         lengths .. lengths+Sq-1. updates: {key: rows} with rows
-        (B, Sq, *unit-minus-row-axis) — e.g. a (P, H, bs, D) arena takes
-        (B, Sq, H, D) rows. Rows of inactive sequences, and rows at or
-        past `row_valid` (B,) (a partial final prefill chunk), land on
-        the trash page. Returns the updated handle."""
+        (B, Sq, *token_shape) or already (B, Sq, F) — one arena row a
+        token. Rows of inactive sequences, and rows at or past
+        `row_valid` (B,) (a partial final prefill chunk), land on the
+        trash page. Returns the updated handle."""
         first = next(iter(updates.values()))
         b, sq = first.shape[:2]
         pos = self.lengths[:, None] + jnp.arange(sq)[None, :]     # (B, Sq)
@@ -650,13 +689,13 @@ class PagedKV:
         arenas = dict(self.arenas)
         for key, rows in updates.items():
             arena = arenas[key]
-            ua = self.row_axes[key] - 1  # row axis inside the page unit
-            idx = (page,) + (slice(None),) * ua + (off,)
-            flat = rows.reshape((b * sq,) + rows.shape[2:])
-            arenas[key] = arena.at[idx].set(flat.astype(arena.dtype))
+            # Scattered dims (page, row) major, the written window minor:
+            # XLA scatters into the donated arena in place.
+            arenas[key] = arena.at[page, off].set(
+                rows.reshape(b * sq, arena.shape[-1]).astype(arena.dtype))
         return PagedKV(arenas, self.tables, self.lengths,
                        block_size=self.block_size, trash=self.trash,
-                       row_axes=self.row_axes, active=self.active)
+                       active=self.active)
 
     def attend(self, q: jax.Array, k_key, v_key, *,
                scale: Optional[float] = None,
@@ -698,18 +737,21 @@ _PAGED_STEP_VMEM_BYTES = 8 << 20
 def _paged_kernel_applies(q: jax.Array, k_pages: jax.Array,
                           block_tables: jax.Array) -> bool:
     """The shapes `_paged_kernel` compiles for, with or without bias, at
-    any table width: head dim and page rows on sublane multiples (any
-    such page size — the K/V, Q and bias blocks all span their arrays'
-    last two dims), a step of at least one head inside VMEM
-    (`_paged_head_group` folds as many heads into a step as fit), and the
-    scalar-prefetched operands — the flat (B * P) table plus lengths and
-    q_start — inside SMEM. One device only: decode pools carry no mesh,
-    and a paged read under a serving mesh (speculative verify in a
-    sharded export) takes the reference."""
+    any table width: an arena whose rows are the query's H * D lanes,
+    head dim and page rows on sublane multiples (any such page size — the
+    Q and bias blocks span their arrays' last two dims, the K/V block a
+    whole page's rows), a head group whose lanes are whole 128-lane tiles
+    or the whole row with its step inside VMEM (`_paged_head_group` folds
+    as many heads into a step as fit), and the scalar-prefetched operands
+    — the flat (B * P) table plus lengths and q_start — inside SMEM. One
+    device only: decode pools carry no mesh, and a paged read under a
+    serving mesh (speculative verify in a sharded export) takes the
+    reference."""
     b, max_pages = block_tables.shape
     _, h, sq, d = q.shape
-    block_size = k_pages.shape[-2]
-    return (d % 8 == 0
+    _, block_size, f = k_pages.shape
+    return (f == h * d
+            and d % 8 == 0
             and block_size % 8 == 0
             and _paged_head_group(h, sq, d, block_size,
                                   k_pages.dtype.itemsize) > 0

@@ -22,6 +22,7 @@ from __future__ import annotations
 
 import collections
 import contextlib
+import math
 import threading
 import time
 import weakref
@@ -820,9 +821,10 @@ class PagedSlotPool:
     ONE vmapped jitted call per token — but KV-cache leaves live in shared
     page arenas instead of per-slot max-length blocks:
 
-      * per cache leaf, ONE HBM arena `(num_blocks + 1, ..., block_size,
-        ...)` (the paged axis split into block_size-token pages; the last
-        page is the trash page absorbing masked writes);
+      * per cache leaf, ONE HBM arena laid out by ops/attention's
+        `PagedKV.arena`: `(num_blocks + 1, block_size, F)`, a page
+        block_size token rows, a row everything the leaf holds of one
+        token; the last page is the trash page absorbing masked writes;
       * per session, a block table of int32 page indices grown ON DEMAND —
         a session holds ceil(used_tokens / block_size) pages, so
         concurrent-session capacity scales with tokens actually written,
@@ -955,27 +957,27 @@ class PagedSlotPool:
         self.allocator = PageAllocator(self.num_blocks,
                                        metric_label=metric_label)
 
-        # Page-unit shape per paged leaf: drop the singleton session batch
-        # dim, paged axis -> block_size.  (1, H, S, D) axis 2 => (H, bs, D).
-        self._units: dict[int, tuple] = {}
+        # What one token holds of each paged leaf: the leaf's dims less
+        # the singleton session batch and the paged axis, in their order.
+        # (1, H, S, D) axis 2 => (H, D). PagedKV.arena lays an arena out
+        # from it; nothing here spells an arena's shape.
+        from min_tfs_client_tpu.ops.attention import PagedKV
+
+        self._token_shapes: dict[int, tuple] = {}
         arena_bytes = 0
         dense_equiv = 0
         page_bytes_total = 0  # bytes one page holds across ALL paged leaves
         for i, axis in paged_axes.items():
             shape = self._leaves[i].shape
-            unit = tuple(shape[1:axis]) + (self.block_size,) \
+            self._token_shapes[i] = tuple(shape[1:axis]) \
                 + tuple(shape[axis + 1:])
-            self._units[i] = unit
             itemsize = jnp.dtype(self._leaves[i].dtype).itemsize
-            per_page = itemsize
-            for d in unit:
-                per_page *= int(d)
-            arena_bytes += (self.num_blocks + 1) * per_page
+            pages, *page = PagedKV.arena_shape(
+                self.num_blocks, self.block_size, self._token_shapes[i])
+            per_page = itemsize * math.prod(page)
+            arena_bytes += pages * per_page
             page_bytes_total += per_page
-            per_leaf = itemsize
-            for d in shape:
-                per_leaf *= int(d)
-            dense_equiv += self.max_slots * per_leaf
+            dense_equiv += self.max_slots * itemsize * math.prod(shape)
         self.arena_bytes = arena_bytes
         self.dense_equivalent_bytes = dense_equiv
         self.page_bytes = page_bytes_total
@@ -985,8 +987,8 @@ class PagedSlotPool:
         # the lock (jit donation invalidates the old buffers), never
         # mutated in place.
         self._arenas = tuple(
-            jnp.zeros((self.num_blocks + 1,) + self._units[i],
-                      self._leaves[i].dtype)
+            PagedKV.arena(self.num_blocks, self.block_size,
+                          self._token_shapes[i], self._leaves[i].dtype)
             for i in sorted(paged_axes))          # guarded_by: self._lock
         self._arena_pos = {i: k for k, i in enumerate(sorted(paged_axes))}
         self._dense_pool = tuple(
@@ -1058,14 +1060,11 @@ class PagedSlotPool:
                 if axis is None:
                     full.append(dense_list[i])
                     continue
-                arena = arenas[self._arena_pos[i]]
-                ua = axis - 1  # paged axis inside the page unit
-                g = arena[tables]                  # (slots, W, *unit)
-                g = jnp.moveaxis(g, 1, ua + 1)     # W beside the page rows
-                unit = self._units[i]
-                merged = (self.max_slots,) + unit[:ua] \
-                    + (width * self.block_size,) + unit[ua + 1:]
-                full.append(g.reshape(merged)[:, None])
+                # (slots, W, bs, F): a session's token rows, in order.
+                g = arenas[self._arena_pos[i]][tables]
+                g = g.reshape((self.max_slots, width * self.block_size)
+                              + self._token_shapes[i])
+                full.append(jnp.moveaxis(g, 1, axis)[:, None])
             tree = jax.tree_util.tree_unflatten(treedef, full)
             if params is None:
                 new_tree, outputs = jax.vmap(step_fn)(tree)
@@ -1087,19 +1086,13 @@ class PagedSlotPool:
                     out_dense[i] = jnp.where(mask, new_leaves[i],
                                              dense_list[i])
                     continue
-                ua = axis - 1
-                unit = self._units[i]
-                n = new_leaves[i][:, 0]            # (slots, ..., W*bs, ...)
-                split = (self.max_slots,) + unit[:ua] \
-                    + (width, self.block_size) + unit[ua + 1:]
-                n = n.reshape(split)
-                n = jnp.moveaxis(n, ua + 1, 1)     # (slots, W, *unit)
+                arena = arenas[self._arena_pos[i]]
+                n = jnp.moveaxis(new_leaves[i][:, 0], axis, 1)
+                n = n.reshape((self.max_slots, width) + arena.shape[1:])
                 page = jnp.take_along_axis(
-                    n, cur_pages.reshape((-1,) + (1,) * (n.ndim - 1)),
-                    axis=1)[:, 0]                  # (slots, *unit)
-                out_arenas[self._arena_pos[i]] = \
-                    arenas[self._arena_pos[i]].at[scatter_idx].set(
-                        page.astype(arenas[self._arena_pos[i]].dtype))
+                    n, cur_pages[:, None, None, None], axis=1)[:, 0]
+                out_arenas[self._arena_pos[i]] = arena.at[scatter_idx].set(
+                    page.astype(arena.dtype))
             return out_dense, out_arenas, outputs
 
         def _contract_tree(dense_list):
@@ -1111,15 +1104,11 @@ class PagedSlotPool:
             return jax.tree_util.tree_unflatten(treedef, leaves)
 
         def _contract_kv(arenas, tables, lengths, active):
-            from min_tfs_client_tpu.ops.attention import PagedKV
-
             return PagedKV(
                 {self._paths[i]: arenas[self._arena_pos[i]]
                  for i in paged_axes},
                 tables, lengths,
                 block_size=self.block_size, trash=self._trash,
-                row_axes={self._paths[i]: paged_axes[i]
-                          for i in paged_axes},
                 active=active)
 
         def _merge_dense(dense_list, new_tree, active):

@@ -86,18 +86,18 @@ def main() -> int:
     # until the bias tile spanned its array's last two dims. Sq=1 is a
     # decode tick, Sq=5 a verify block / prefill chunk.
     from min_tfs_client_tpu.ops.attention import (
+        PagedKV,
         paged_attention,
         paged_attention_reference,
     )
 
     pb, ph, pd, page, width = 4, 8, 64, 16, 3
-    n_pages = pb * width + 1
-    k_pages = jnp.asarray(
-        rng.standard_normal((n_pages, ph, page, pd)), jnp.bfloat16)
-    v_pages = jnp.asarray(
-        rng.standard_normal((n_pages, ph, page, pd)), jnp.bfloat16)
+    n_pages = pb * width
+    shape = PagedKV.arena_shape(n_pages, page, (ph, pd))
+    k_pages = jnp.asarray(rng.standard_normal(shape), jnp.bfloat16)
+    v_pages = jnp.asarray(rng.standard_normal(shape), jnp.bfloat16)
     tables = jnp.asarray(
-        rng.permutation(n_pages - 1).reshape(pb, width), jnp.int32)
+        rng.permutation(n_pages).reshape(pb, width), jnp.int32)
     for sq in (1, 5):
         pq = jnp.asarray(rng.standard_normal((pb, ph, sq, pd)), jnp.bfloat16)
         bias = jnp.asarray(

@@ -84,31 +84,35 @@ def test_attention_dispatch_with_bias_uses_reference():
     np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=1e-6)
 
 
-def _paged_case(seed, *, b, h, d, block_size, max_len, sq=1):
-    """Random ragged case: contiguous K/V, the same values scattered into
-    a shuffled page arena + block tables, and per-example lengths."""
+def _token_rows(x):
+    """Dense (B, H, S, D) -> (B, S, H * D): one arena row a token."""
+    b, h, s, d = x.shape
+    return jnp.asarray(x).transpose(0, 2, 1, 3).reshape(b, s, h * d)
+
+
+def _paged_case(seed, *, b, h, d, block_size, max_len, sq=1,
+                dtype=np.float32):
+    """Random ragged case: contiguous K/V, the same values written through
+    `PagedKV.append` into arenas from `PagedKV.arena` behind shuffled block
+    tables (the arenas' last page is the trash page, all zeros), and
+    per-example lengths."""
     rng = np.random.default_rng(seed)
     pages_per_seq = -(-max_len // block_size)
     padded = pages_per_seq * block_size
-    k = rng.standard_normal((b, h, padded, d)).astype(np.float32)
-    v = rng.standard_normal((b, h, padded, d)).astype(np.float32)
+    k = jnp.asarray(rng.standard_normal((b, h, padded, d)), dtype)
+    v = jnp.asarray(rng.standard_normal((b, h, padded, d)), dtype)
     lengths = rng.integers(sq, max_len + 1, (b,)).astype(np.int32)
     n_pages = b * pages_per_seq
-    perm = rng.permutation(n_pages)
-    k_pages = np.empty((n_pages, h, block_size, d), np.float32)
-    v_pages = np.empty((n_pages, h, block_size, d), np.float32)
-    tables = np.empty((b, pages_per_seq), np.int32)
-    for i in range(b):
-        for p in range(pages_per_seq):
-            page = int(perm[i * pages_per_seq + p])
-            tables[i, p] = page
-            sl = slice(p * block_size, (p + 1) * block_size)
-            k_pages[page] = k[i, :, sl]
-            v_pages[page] = v[i, :, sl]
-    q = rng.standard_normal((b, h, sq, d)).astype(np.float32)
-    return (jnp.asarray(q), jnp.asarray(k), jnp.asarray(v),
-            jnp.asarray(k_pages), jnp.asarray(v_pages),
-            jnp.asarray(tables), jnp.asarray(lengths))
+    tables = jnp.asarray(
+        rng.permutation(n_pages).reshape(b, pages_per_seq), jnp.int32)
+    kv = PagedKV(
+        {name: PagedKV.arena(n_pages, block_size, (h, d), dtype)
+         for name in ("k", "v")},
+        tables, jnp.zeros((b,), jnp.int32), block_size=block_size,
+        trash=n_pages).append({"k": _token_rows(k), "v": _token_rows(v)})
+    q = jnp.asarray(rng.standard_normal((b, h, sq, d)), dtype)
+    return (q, k, v, kv.arenas["k"], kv.arenas["v"], tables,
+            jnp.asarray(lengths))
 
 
 class TestPagedAttention:
@@ -116,9 +120,9 @@ class TestPagedAttention:
         q, k, v, k_pages, v_pages, tables, _ = _paged_case(
             0, b=2, h=2, d=8, block_size=4, max_len=16)
         np.testing.assert_array_equal(
-            np.asarray(gather_kv_pages(k_pages, tables)), np.asarray(k))
+            np.asarray(gather_kv_pages(k_pages, tables, 2)), np.asarray(k))
         np.testing.assert_array_equal(
-            np.asarray(gather_kv_pages(v_pages, tables)), np.asarray(v))
+            np.asarray(gather_kv_pages(v_pages, tables, 2)), np.asarray(v))
 
     @pytest.mark.parametrize("block_size", [1, 8, 64])
     def test_oracle_token_exact_vs_dense(self, block_size):
@@ -273,21 +277,20 @@ class TestPagedAttention:
                     np.asarray(got)[i, :, :lvn[i]],
                     np.asarray(want)[i, :, :lvn[i]], atol=2e-5, rtol=2e-5)
 
-    def _ragged_case(self, sq, with_bias, *, h=3, trash_fill=0.0):
+    def _ragged_case(self, sq, with_bias, *, h=3, d=16, trash_fill=0.0):
         """Live slots among slots that do not ride: lengths 0 with every
         table entry the trash page, one live slot ending exactly on a
         page boundary and one a token past it. The trash page (the
         arena's last, where live rows' trailing entries point too) holds
         `trash_fill`."""
-        bs, d = 8, 16
+        bs = 8
         lengths = np.asarray([0, 16, 17, 0, 40, 0], np.int32)
         q, _, _, k_pages, v_pages, tables, _ = _paged_case(
             40 + sq, b=len(lengths), h=h, d=d, block_size=bs, max_len=48,
             sq=sq)
-        trash = k_pages.shape[0]
-        fill = jnp.full((1, h, bs, d), trash_fill, jnp.float32)
-        k_pages = jnp.concatenate([k_pages, fill])
-        v_pages = jnp.concatenate([v_pages, fill])
+        trash = k_pages.shape[0] - 1
+        k_pages = k_pages.at[trash].set(trash_fill)
+        v_pages = v_pages.at[trash].set(trash_fill)
         used = -(-lengths // bs)
         tables = jnp.where(
             jnp.arange(tables.shape[1])[None, :] < used[:, None],
@@ -322,14 +325,17 @@ class TestPagedAttention:
         """Where all heads of a page do not fit the step's VMEM bound the
         grid keeps a head-group axis: same answers."""
         h = 4
-        q, kp, vp, tbl, lengths, bias = self._ragged_case(sq, True, h=h)
+        q, kp, vp, tbl, lengths, bias = self._ragged_case(sq, True, h=h,
+                                                          d=64)
         want = paged_flash_attention(q, kp, vp, tbl, lengths, bias=bias,
                                      interpret=True)
-        one_head = attention_module._paged_step_vmem_bytes(
-            1, sq, q.shape[-1], kp.shape[-2], kp.dtype.itemsize)
+        two_heads = attention_module._paged_step_vmem_bytes(
+            2, sq, q.shape[-1], kp.shape[1], kp.dtype.itemsize)
         monkeypatch.setattr(attention_module, "_PAGED_STEP_VMEM_BYTES",
-                            2 * one_head)
-        assert _paged_head_group(h, sq, q.shape[-1], kp.shape[-2],
+                            two_heads)
+        # Two heads of 64 lanes are one 128-lane tile of a page's rows; one
+        # head alone would be half a tile, which the group never is.
+        assert _paged_head_group(h, sq, q.shape[-1], kp.shape[1],
                                  kp.dtype.itemsize) == 2
         got = paged_flash_attention(q, kp, vp, tbl, lengths, bias=bias,
                                     interpret=True)
@@ -347,8 +353,7 @@ class TestPagedAttention:
         written = jnp.maximum(lengths - 1, 0)  # tokens before this step
         active = jnp.asarray([False, True, False, False, True, False])
         kv = PagedKV({"k": kp, "v": vp}, tbl, written, block_size=8,
-                     trash=kp.shape[0] - 1, row_axes={"k": 2, "v": 2},
-                     active=active)
+                     trash=kp.shape[0] - 1, active=active)
         kwargs = {"lengths": written + 1, "q_start": written} \
             if explicit else {}
         got = np.asarray(kv.attend(q, "k", "v", bias=bias, **kwargs))
@@ -372,6 +377,97 @@ class TestPagedAttention:
         np.testing.assert_array_equal(kern[0], 0.0)
 
 
+class TestArenaLayout:
+    """`PagedKV.arena`: (pages + 1, block_size, H * D), one row a token."""
+
+    def test_arena_is_token_major_and_lane_dense(self):
+        assert PagedKV.arena_shape(1024, 16, (16, 64)) == (1025, 16, 1024)
+        arena = PagedKV.arena(6, 4, (2, 8), jnp.bfloat16)
+        assert arena.shape == (7, 4, 16) and arena.dtype == jnp.bfloat16
+        assert not np.asarray(arena, np.float32).any()
+
+    # (h, d, block_size, width, sq): rows of 128, 512 and 1024 lanes (T5-
+    # small's and T5-large's), and the 64 heads x 128 shape whose step the
+    # VMEM bound splits into groups of 16.
+    @pytest.mark.parametrize("h,d,block_size,width,sq", [
+        (2, 64, 16, 3, 1), (8, 64, 16, 3, 5), (16, 64, 16, 2, 1),
+        (16, 64, 16, 2, 16), (64, 128, 128, 2, 16)])
+    @pytest.mark.parametrize("with_bias", [False, True])
+    def test_kernel_matches_oracle_on_the_served_rows(
+            self, h, d, block_size, width, sq, with_bias):
+        b = 2
+        q, _, _, kp, vp, tables, lengths = _paged_case(
+            h + sq, b=b, h=h, d=d, block_size=block_size,
+            max_len=width * block_size, sq=sq, dtype=jnp.bfloat16)
+        assert kp.shape[-1] == h * d
+        group = _paged_head_group(h, sq, d, block_size, 2)
+        assert group == (16 if h == 64 else h)
+        bias = _rand((b, h, sq, width * block_size), seed=3) \
+            if with_bias else None
+        want = np.asarray(paged_attention_reference(
+            q, kp, vp, tables, lengths, bias=bias), np.float32)
+        got = np.asarray(paged_flash_attention(
+            q, kp, vp, tables, lengths, bias=bias, interpret=True),
+            np.float32)
+        # bfloat16 operands: the oracle rounds its probabilities to
+        # bfloat16 before the second matmul, the kernel keeps float32.
+        np.testing.assert_allclose(got, want, atol=0.03, rtol=0.03)
+
+    def _old_append_and_gather(self, rows, tables, lengths, keep, *, h, d,
+                               block_size, n_pages):
+        """The parent's unit, (pages, H, block_size, D), written the
+        parent's way (`arena.at[page, :, off]`) and gathered its way."""
+        b, sq, _ = rows.shape
+        arena = np.zeros((n_pages + 1, h, block_size, d), np.float32)
+        for i in range(b):
+            for r in range(sq):
+                pos = int(lengths[i]) + r
+                page = int(tables[i, pos // block_size]) \
+                    if keep[i, r] else n_pages
+                arena[page, :, pos % block_size] = \
+                    np.asarray(rows[i, r]).reshape(h, d)
+        g = arena[np.asarray(tables)]              # (B, W, H, bs, D)
+        return arena, g.transpose(0, 2, 1, 3, 4).reshape(
+            b, h, tables.shape[1] * block_size, d)
+
+    def test_append_writes_what_the_old_unit_held(self):
+        """A prefill chunk whose rows cross a page boundary, one slot's
+        tail invalid, one slot not riding: the gathered (B, H, W*bs, D)
+        view is bitwise what the parent's layout gave, the invalid rows
+        are on the trash page and no other page was touched."""
+        b, h, d, bs, width, sq = 3, 4, 16, 4, 3, 6
+        n_pages = b * width
+        rng = np.random.default_rng(5)
+        tables = jnp.asarray(rng.permutation(n_pages).reshape(b, width),
+                             jnp.int32)
+        lengths = jnp.asarray([2, 3, 5], jnp.int32)  # rows cross pages
+        row_valid = jnp.asarray([6, 4, 6], jnp.int32)
+        active = jnp.asarray([True, True, False])
+        rows = _rand((b, sq, h * d), seed=8)
+        keep = (np.arange(sq)[None, :] < np.asarray(row_valid)[:, None]) \
+            & np.asarray(active)[:, None]
+        old_arena, want = self._old_append_and_gather(
+            rows, np.asarray(tables), np.asarray(lengths), keep, h=h, d=d,
+            block_size=bs, n_pages=n_pages)
+
+        kv = PagedKV({"k": PagedKV.arena(n_pages, bs, (h, d), jnp.float32)},
+                     tables, lengths, block_size=bs, trash=n_pages,
+                     active=active)
+        # (B, Sq, H, D) rows and (B, Sq, H * D) rows are the same rows.
+        for given in (rows, rows.reshape(b, sq, h, d)):
+            arena = kv.append({"k": given}, row_valid=row_valid).arenas["k"]
+            np.testing.assert_array_equal(
+                np.asarray(gather_kv_pages(arena, tables, h)), want)
+            np.testing.assert_array_equal(
+                np.asarray(arena).reshape(n_pages + 1, bs, h, d),
+                old_arena.transpose(0, 2, 1, 3))
+        # Slot 2 does not ride and slot 1's last two rows are invalid:
+        # their pages hold nothing, the trash page took the rows.
+        untouched = np.asarray(arena)[np.asarray(tables[2])]
+        assert not untouched.any()
+        assert np.asarray(arena)[n_pages].any()
+
+
 def test_fully_masked_rows_are_zero_in_both_paths():
     # lengths[b]=0 (e.g. cross-attention over an empty input) must yield
     # zeros — not NaN, not a mean over masked V — identically on both paths.
@@ -392,6 +488,11 @@ def test_fully_masked_rows_are_zero_in_both_paths():
 # serves the jnp reference on this backend, so a kernel the chip refuses
 # passes every test above. Cross-lowering for the TPU platform applies
 # those rules here, at the shapes the server really sends.
+
+
+def _arena_struct(pages, block_size, h, d, dtype=jnp.bfloat16):
+    return jax.ShapeDtypeStruct(
+        PagedKV.arena_shape(pages, block_size, (h, d)), dtype)
 
 
 def _lower_for_tpu(fn, *args):
@@ -427,9 +528,8 @@ def test_paged_with_bias_lowers_for_tpu(b, h, d, block_size, width, sq):
     """T5's only path: bias, page sizes the gate admits, table wider than
     one page (the case Mosaic refused while the bias rode as one
     (Sq, P*bs) row per head)."""
-    pages = b * width + 1
     q = jax.ShapeDtypeStruct((b, h, sq, d), jnp.bfloat16)
-    arena = jax.ShapeDtypeStruct((pages, h, block_size, d), jnp.bfloat16)
+    arena = _arena_struct(b * width, block_size, h, d)
     tables = jax.ShapeDtypeStruct((b, width), jnp.int32)
     lengths = jax.ShapeDtypeStruct((b,), jnp.int32)
     bias = jax.ShapeDtypeStruct((b, h, sq, width * block_size), jnp.float32)
@@ -447,7 +547,7 @@ def test_gates_refuse_what_the_kernels_cannot_hold():
     assert not _flash_kernel_applies(long_kv, long_kv)
     # 8192 sessions x 30 pages of table: past the 1 MiB of SMEM.
     q = jax.ShapeDtypeStruct((8192, 8, 1, 64), jnp.bfloat16)
-    arena = jax.ShapeDtypeStruct((64, 8, 16, 64), jnp.bfloat16)
+    arena = _arena_struct(63, 16, 8, 64)
     tables = jax.ShapeDtypeStruct((8192, 30), jnp.int32)
     assert not _paged_kernel_applies(q, arena, tables)
     # A step of `_paged_kernel` holds a page of K and of V for a group of
@@ -455,7 +555,7 @@ def test_gates_refuse_what_the_kernels_cannot_hold():
     assert _paged_head_group(64, 16, 128, 128, 2) == 16
     # ...and one head of a 4096-token page does not fit at all.
     q = jax.ShapeDtypeStruct((4, 8, 1, 128), jnp.bfloat16)
-    arena = jax.ShapeDtypeStruct((17, 8, 4096, 128), jnp.bfloat16)
+    arena = _arena_struct(16, 4096, 8, 128)
     tables = jax.ShapeDtypeStruct((4, 4), jnp.int32)
     assert _paged_head_group(8, 1, 128, 4096, 2) == 0
     assert not _paged_kernel_applies(q, arena, tables)
@@ -484,7 +584,7 @@ def test_flash_splits_over_the_ambient_serving_mesh():
                                atol=2e-5, rtol=2e-5)
     # The paged kernel has no such wrapper: under a mesh its gate says no.
     paged_args = (jax.ShapeDtypeStruct((4, 8, 1, 64), jnp.bfloat16),
-                  jax.ShapeDtypeStruct((9, 8, 16, 64), jnp.bfloat16),
+                  _arena_struct(8, 16, 8, 64),
                   jax.ShapeDtypeStruct((4, 2), jnp.int32))
     assert _paged_kernel_applies(*paged_args)
     with jax.set_mesh(mesh):

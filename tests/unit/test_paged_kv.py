@@ -116,6 +116,22 @@ class TestPagedTokenExactness:
         got = _run(paged, _sid("p"), ids)
         assert got == want
 
+    @pytest.mark.parametrize("contract", [True, False])
+    def test_streams_match_dense_pool_at_lane_dense_rows(self, contract):
+        """H * d_kv = 128, a whole lane tile a token row (T5's own head
+        size, 64): the paged stream, through the step contract and
+        through the pool's generic gather/scatter tick, is the dense
+        pool's token for token."""
+        config = t5.T5Config.tiny(d_kv=64, num_heads=2, d_model=32)
+        lane_model = (config, t5.init_params(jax.random.PRNGKey(3), config))
+        ids = _prompt(config, np.random.default_rng(4))
+        want = _run(_sigs(lane_model), _sid("ld"), ids)
+        paged = _sigs(lane_model, kv_block_size=3,
+                      kv_use_step_contract=contract)
+        pool = paged["decode_init"]._kv_pool
+        assert pool._arenas[0].shape == (pool.num_blocks + 1, 3, 128)
+        assert _run(paged, _sid("lp"), ids) == want
+
     def test_interleaved_sessions_do_not_disturb_each_other(self, model):
         config, _ = model
         rng = np.random.default_rng(2)
@@ -337,6 +353,42 @@ class TestEviction:
         # The satellite bar: this mid-stream swap/restore exactness ran
         # THROUGH the paged step contract, not the dense-gather fallback.
         assert stats["step_contract"] is True
+
+    def test_swap_out_and_restore_carry_the_pages_bitwise(self, model):
+        """The arena's unit through `gather_fn` / `restore_fn` /
+        `_SwappedSession`, whatever its shape: a victim's pages come back
+        bit for bit, on whichever pages the allocator hands out."""
+        config, _ = model
+        rng = np.random.default_rng(11)
+        sigs = _sigs(model, kv_block_size=2, kv_num_blocks=5)
+        pool = sigs["decode_init"]._kv_pool
+        sa, sb = _sid("rt-a"), _sid("rt-b")
+        sigs["decode_init"].run(
+            {"session_id": sa, "input_ids": _prompt(config, rng)})
+        for _ in range(5):                         # three pages of rows
+            sigs["decode_step"].run({"session_id": sa})
+        slot = next(iter(pool._pages))
+        held = list(pool._pages[slot])
+        before = [np.asarray(a)[held] for a in pool._arenas]
+        assert all(b.any() for b in before)
+        assert before[0].shape == (3, 2, config.num_heads * config.d_kv)
+
+        # A second session that needs the pool's other pages evicts it...
+        sigs["decode_init"].run(
+            {"session_id": sb, "input_ids": _prompt(config, rng)})
+        for _ in range(5):
+            sigs["decode_step"].run({"session_id": sb})
+        assert slot in pool._swapped and slot not in pool._pages
+        for host, want in zip(pool._swapped[slot].pages_host, before):
+            np.testing.assert_array_equal(host[:len(held)], want)
+        # ...and its own next step restores it, onto other pages or not.
+        sigs["decode_close"].run({"session_id": sb})
+        with pool._lock:
+            pool._restore_locked(slot, ())
+        after = [np.asarray(a)[pool._pages[slot]] for a in pool._arenas]
+        for got, want in zip(after, before):
+            np.testing.assert_array_equal(got, want)
+        sigs["decode_close"].run({"session_id": sa})
 
     def test_close_policy_kills_oldest_idle_with_typed_error(self, model):
         config, _ = model

@@ -1,0 +1,112 @@
+"""The decode tick as the TPU compiler lays it out: no copy of an arena.
+
+PR 28's finding, guarded: with a page unit of (H, block_size, 64) XLA gave
+the donated arenas one layout, the append's scatter wanted a second and the
+Pallas operand a third, and every tick re-laid every arena three times (24
+of 50 ms at T5-large). `PagedKV.arena` is token-major and lane-dense so
+that all three agree. These tests compile the pool's programs for the v5e
+ahead of time (nothing runs: no chip is needed, only libtpu) and read the
+optimized module."""
+
+import importlib
+import os
+import re
+
+import jax
+import numpy as np
+import pytest
+
+from min_tfs_client_tpu.models import t5
+
+attention_module = importlib.import_module("min_tfs_client_tpu.ops.attention")
+
+BLOCK, SLOTS, MAXDEC = 16, 8, 64
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """One device of an ahead-of-time v5e topology, or a skip that says
+    why there is none."""
+    from jax.experimental import topologies
+
+    env = {"TPU_ACCELERATOR_TYPE": "v5litepod-4",
+           "TPU_WORKER_HOSTNAMES": "localhost"}
+    old = {k: os.environ.get(k) for k in env}
+    os.environ.update(env)
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as exc:  # no libtpu, or one that knows no v5e
+        pytest.skip(f"no ahead-of-time TPU topology here: {exc!r:.200}")
+    finally:
+        for k, v in old.items():
+            os.environ.pop(k) if v is None else os.environ.update({k: v})
+    return jax.sharding.SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def pool():
+    """A tiny T5 at T5-large's head size: d_kv 64, the width at which the
+    old unit filled half a lane tile and showed the fault."""
+    config = t5.T5Config.tiny(d_kv=64, num_heads=4, d_model=64)
+    params = t5.init_params(jax.random.PRNGKey(0), config)
+    sigs = t5.build_session_signatures(
+        params, config, seq_len=16, max_decode_len=MAXDEC,
+        max_sessions=SLOTS, continuous_batching=True, kv_block_size=BLOCK,
+        kv_prefill_chunk=BLOCK)
+    return sigs["decode_init"]._kv_pool
+
+
+def _compiled(pool, v5e, jitted, extra, monkeypatch):
+    """`jitted` of (params, dense pool, arenas, tables, *extra) compiled
+    for the v5e at the pool's widest table, with the kernel's gate open
+    as it is on the chip."""
+    monkeypatch.setattr(attention_module, "_on_tpu", lambda: True)
+
+    def struct(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=v5e)
+
+    slots, width = pool.max_slots, pool.pages_per_session
+    args = jax.tree_util.tree_map(
+        struct, (pool._params, pool._dense_pool, pool._arenas))
+    more = [jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+            for shape, dtype in [((slots, width), np.int32)] + extra]
+    return jitted.__wrapped__.lower(*args, *more).compile()
+
+
+def _arena_faults(pool, compiled):
+    """What the finding looked like, read off a compiled module: `copy`
+    instructions that produce an array of an arena's dims (in any order),
+    and parameters of the argument `arenas` that are not aliased input to
+    output."""
+    text = compiled.as_text()
+    assert "_paged_kernel" in text  # the kernel, not the reference
+    dims = sorted(pool._arenas[0].shape)
+    copies = [
+        line.strip()[:160] for line in text.splitlines()
+        if (shape := re.search(r"= \w+\[([\d,]+)\]\S* copy\(", line))
+        and sorted(int(n) for n in shape.group(1).split(",")) == dims]
+    aliased = {int(n) for n in re.findall(
+        r"\{\d+\}: \((\d+), \{\}, may-alias\)", text[:text.index("\n")])}
+    arenas = {int(n) for n in re.findall(
+        r"%arenas_\d+_\S* = \S+ parameter\((\d+)\)", text)}
+    assert len(arenas) == len(pool._arenas)
+    return copies, arenas - aliased
+
+
+def _program(pool, which):
+    """The pool's jitted program and what it takes after the tables."""
+    slots = pool.max_slots
+    if which == "tick":
+        return pool._tick_jit, [((slots,), np.bool_), ((slots,), np.int32)]
+    return pool._chunk_jit, [
+        ((slots, pool.prefill_chunk), np.int32), ((slots,), np.int32),
+        ((slots, 1), np.int32), ((slots,), np.int32)]
+
+
+@pytest.mark.parametrize("which", ["tick", "prefill_chunk"])
+def test_program_copies_no_arena(which, pool, v5e, monkeypatch):
+    compiled = _compiled(pool, v5e, *_program(pool, which), monkeypatch)
+    copies, unaliased = _arena_faults(pool, compiled)
+    assert not copies, copies
+    assert not unaliased, unaliased
