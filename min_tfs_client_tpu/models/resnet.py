@@ -121,34 +121,6 @@ def forward(params: dict, config: ResNetConfig, images: jax.Array
     return nn.dense(params["head"], x).astype(jnp.float32)
 
 
-def fwd_flops(config: ResNetConfig) -> int:
-    """Analytic forward FLOPs per image: 2*MACs over every conv (tracking
-    the v1.5 stride placement) plus the classifier head. Used by bench.py
-    for the MFU estimate — convs dominate, pooling/bias/relu ignored."""
-    def conv(h, w, kh, kw, cin, cout, stride=1):
-        ho, wo = -(-h // stride), -(-w // stride)
-        return ho, wo, 2 * ho * wo * kh * kw * cin * cout
-
-    h = w = config.image_size
-    h, w, total = conv(h, w, 7, 7, 3, config.width, 2)
-    h, w = -(-h // 2), -(-w // 2)  # max-pool stride 2
-    c_in = config.width
-    for i, size in enumerate(config.stage_sizes):
-        c_mid = config.width * (2 ** i)
-        c_out = c_mid * 4
-        for j in range(size):
-            stride = 2 if (j == 0 and i > 0) else 1
-            _, _, f1 = conv(h, w, 1, 1, c_in, c_mid)
-            h2, w2, f2 = conv(h, w, 3, 3, c_mid, c_mid, stride)
-            _, _, f3 = conv(h2, w2, 1, 1, c_mid, c_out)
-            total += f1 + f2 + f3
-            if j == 0:  # projection shortcut sees the strided output grid
-                total += 2 * h2 * w2 * c_in * c_out
-            h, w = h2, w2
-            c_in = c_out
-    return total + 2 * c_in * config.num_classes
-
-
 def build_signatures(params: dict, config: ResNetConfig) -> dict:
     from min_tfs_client_tpu.servables.servable import Signature, TensorSpec
 

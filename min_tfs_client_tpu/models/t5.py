@@ -382,9 +382,9 @@ class _T5PagedStep:
     `paged_step`): the pooled tick hands slot-batched dense state plus a
     PagedKV handle; decode() advances one token per active slot through
     paged_decoder_positions, prefill_chunk() streams a forced decoder
-    prefix through the same Sq>1 path. Token-for-token equal to the
-    dense-gather fallback (the paged-decode suite asserts it) — the only
-    difference is what the tick reads."""
+    prefix through the same Sq>1 path. Token-for-token equal to
+    decode_step_state on the dense pool (the paged-decode suite asserts
+    it) — the only difference is what the tick reads."""
 
     def __init__(self, config: T5Config, *, sampling: bool = False,
                  top_k: int = 0):
@@ -867,7 +867,15 @@ def build_signatures(params: dict, config: T5Config, *, seq_len: int,
                      kv_num_blocks: int | None = None,
                      kv_evict_policy: str | None = None,
                      kv_prefill_chunk: int | None = None) -> dict:
+    from min_tfs_client_tpu.servables import decode_signatures
+    from min_tfs_client_tpu.servables.decode_sessions import Paging
     from min_tfs_client_tpu.servables.servable import Signature, TensorSpec
+
+    # A kv_* knob left None defers to the server flags, through the
+    # loader's paging_scope; resolved here, once, for the sessions' pool
+    # and the speculative verify blocks alike.
+    paging = Paging.resolve(kv_block_size, kv_num_blocks, kv_evict_policy,
+                            kv_prefill_chunk)
 
     # With `pipeline_mesh` (a Mesh carrying a "stage" axis) the ENCODER
     # stack serves pipeline-parallel for the whole-generation surfaces
@@ -995,13 +1003,6 @@ def build_signatures(params: dict, config: T5Config, *, seq_len: int,
         # Speculation composes with paging: when the export/server enables
         # the paged KV store, the target's verify blocks run through the
         # block-table kernel path too (same knob, same default-off).
-        from min_tfs_client_tpu.servables.decode_sessions import (
-            default_paging,
-        )
-
-        spec_kv_block = (kv_block_size if kv_block_size is not None
-                         else default_paging()["block_size"])
-
         def spec_fn(bundle, inputs):
             ids = jnp.asarray(inputs["input_ids"], jnp.int32)
             lens = jnp.sum((ids != config.pad_id).astype(jnp.int32),
@@ -1010,7 +1011,7 @@ def build_signatures(params: dict, config: T5Config, *, seq_len: int,
                 bundle["target"], config, bundle["draft"],
                 draft_config, ids, lens,
                 max_decode_len=max_decode_len, k=speculative_k,
-                kv_block_size=spec_kv_block or 0)
+                kv_block_size=paging.block_size)
             return {"output_ids": out_ids,
                     "output_lengths": out_lengths,
                     "target_passes": jnp.broadcast_to(
@@ -1031,15 +1032,15 @@ def build_signatures(params: dict, config: T5Config, *, seq_len: int,
             batch_buckets=(1, 4, 16, 32),
         )
 
-    signatures.update(build_session_signatures(
-        params, config, seq_len=seq_len, max_decode_len=max_decode_len,
+    signatures.update(decode_signatures.build_session_signatures(
+        params,
+        decode_model(config, max_decode_len=max_decode_len,
+                     sampling=session_sampling,
+                     sampling_top_k=sampling_top_k,
+                     sampling_top_p=sampling_top_p),
+        seq_len=seq_len, max_decode_len=max_decode_len,
         max_sessions=max_sessions, session_ttl_s=session_ttl_s,
-        continuous_batching=continuous_batching,
-        sampling=session_sampling, sampling_top_k=sampling_top_k,
-        sampling_top_p=sampling_top_p,
-        kv_block_size=kv_block_size, kv_num_blocks=kv_num_blocks,
-        kv_evict_policy=kv_evict_policy,
-        kv_prefill_chunk=kv_prefill_chunk))
+        continuous_batching=continuous_batching, paging=paging))
     return signatures
 
 
@@ -1140,88 +1141,50 @@ def decode_step_state(params: dict, config: T5Config, state: dict,
     return new_state, next_token
 
 
-def _sampling_session_helpers(config: T5Config, max_decode_len: int,
-                              sampling: bool, use_top_p: bool = False):
-    """(prefill_fn, read_sampling_inputs, extra_input_specs) shared by
-    the pooled and unpooled session builders — the ONLY place the
-    sampled/greedy prefill wiring exists."""
+def decode_model(config: T5Config, *, max_decode_len: int,
+                 sampling: bool = False, sampling_top_k: int = 0,
+                 sampling_top_p: bool = False):
+    """T5 as servables/decode_signatures.DecodeModel: everything the
+    session surface needs to know about this model, and all that is T5
+    in it — the prefill, the dense one-token step, the paged step
+    contract, which leaves page, the two token ids."""
     from min_tfs_client_tpu.models.quantize import maybe_dequantize
-    from min_tfs_client_tpu.servables.servable import TensorSpec
-    from min_tfs_client_tpu.utils.status import ServingError
+    from min_tfs_client_tpu.servables.decode_signatures import DecodeModel
 
-    names = (("temperature", np.float32), ("seed", np.int32))
-    if use_top_p:
-        names += (("top_p", np.float32),)
-    n_extra = len(names) if sampling else 0
+    names = ()
+    if sampling:
+        names = ("temperature", "seed") + (("top_p",) if sampling_top_p
+                                            else ())
 
     def prefill_fn(p, ids, *rest):
         """rest: the sampling extras (when built with sampling), then
         optionally a forced decoder prefix — the trailing-arity call is
         decode_init_prefix's monolithic dense path; each arity jits its
         own trace."""
-        extras = rest[:n_extra]
-        prefix = rest[n_extra] if len(rest) > n_extra else None
-        kw = {}
-        if sampling:
-            kw["temperature"], kw["seed"] = extras[0], extras[1]
-            if use_top_p:
-                kw["top_p"] = extras[2]
+        extras = dict(zip(names, rest))
+        prefix = rest[len(names)] if len(rest) > len(names) else None
         return prefill_state(maybe_dequantize(p), config, ids,
                              max_decode_len=max_decode_len,
-                             prefix_ids=prefix, **kw)
+                             prefix_ids=prefix, **extras)
 
-    if sampling:
-        def read_inputs(inputs, batch):
-            out = []
-            for name, dtype in names:
-                arr = np.asarray(inputs[name], dtype).reshape(-1)
-                if arr.shape != (batch,):
-                    raise ServingError.invalid_argument(
-                        f"{name} must have {batch} elements (one per "
-                        f"input_ids row); got {arr.shape[0]}")
-                out.append(jax.device_put(arr))
-            return tuple(out)
+    def step_fn(p, state):
+        return decode_step_state(maybe_dequantize(p), config, state,
+                                 top_k=sampling_top_k)
 
-        extra_specs = {name: TensorSpec(dtype, (None,))
-                       for name, dtype in names}
-    else:
-        read_inputs = None
-        extra_specs = {}
-    return prefill_fn, read_inputs, extra_specs
+    def paged_axis_fn(path):
+        # Page the decoder self-attention caches: leaves under "caches"
+        # named k/v, seq axis 2 of their (1, H, max_decode_len, d_kv)
+        # layout. Everything else (encoded prompt, token, PRNG keys, ...)
+        # stays dense — it is fully used from the first step.
+        return 2 if ("caches" in path and path[-1] in ("k", "v")) else None
 
-
-def _read_prefix(inputs, config: T5Config):
-    """decode_init_prefix's prefix_ids: (1, max_decode_len) int32, real
-    tokens then pad — returns (array, true length). Single-sequence: the
-    session state carries ONE step scalar, so a multi-row prefix init
-    would need per-row lengths it cannot represent."""
-    from min_tfs_client_tpu.utils.status import ServingError
-
-    pre = np.asarray(inputs["prefix_ids"]).astype(np.int32)
-    if pre.ndim != 2 or pre.shape[0] != 1:
-        raise ServingError.invalid_argument(
-            "prefix_ids must be a single-sequence (1, max_decode_len) "
-            f"tensor; got shape {pre.shape}")
-    row = pre[0]
-    pads = np.flatnonzero(row == config.pad_id)
-    plen = int(pads[0]) if pads.size else int(row.shape[0])
-    if plen == 0:
-        raise ServingError.invalid_argument(
-            "prefix_ids holds no tokens (row starts with pad)")
-    if plen >= row.shape[0]:
-        # A full-width prefix leaves zero decode budget — and the first
-        # step would write K/V at max_decode_len, which the cache write
-        # CLAMPS to the last row, silently corrupting the prefix.
-        raise ServingError.invalid_argument(
-            f"prefix_ids fills the entire max_decode_len budget "
-            f"({row.shape[0]}); at least one position must remain to "
-            "decode")
-    if pads.size and not (row[plen:] == config.pad_id).all():
-        raise ServingError.invalid_argument(
-            "prefix_ids must be real tokens followed only by pad "
-            f"(pad_id {config.pad_id}); found tokens after position "
-            f"{plen}")
-    return pre, plen
+    return DecodeModel(
+        name="t5", prefill=prefill_fn, step=step_fn,
+        paged_step=_T5PagedStep(config, sampling=sampling,
+                                top_k=sampling_top_k),
+        paged_axis_fn=paged_axis_fn,
+        decoder_start_id=config.decoder_start_id, pad_id=config.pad_id,
+        sampling_inputs=names)
 
 
 def build_session_signatures(params: dict, config: T5Config, *, seq_len: int,
@@ -1235,549 +1198,22 @@ def build_session_signatures(params: dict, config: T5Config, *, seq_len: int,
                              kv_block_size: int | None = None,
                              kv_num_blocks: int | None = None,
                              kv_evict_policy: str | None = None,
-                             kv_prefill_chunk: int | None = None,
-                             kv_use_step_contract: bool = True) -> dict:
-    """The repeated-Predict decode surface (BASELINE config 5):
+                             kv_prefill_chunk: int | None = None) -> dict:
+    """T5's decode sessions (decode_init / decode_init_prefix /
+    decode_step / decode_close): servables/decode_signatures'
+    `build_session_signatures` over `decode_model`. A kv_* knob left None
+    defers to the server flags (--kv_block_size etc., through the
+    loader's paging_scope); kv_block_size 0 forces the dense slot pool."""
+    from min_tfs_client_tpu.servables import decode_signatures
+    from min_tfs_client_tpu.servables.decode_sessions import Paging
 
-      decode_init:  session_id + input_ids -> prefill; KV cache parked in
-                    HBM under the session id
-      decode_init_prefix:  decode_init plus prefix_ids — a FORCED decoder
-                    prefix (continuation/forced decoding): the session
-                    resumes as if it had already emitted those tokens.
-                    Dense pools prefill the prefix monolithically; the
-                    paged step-contract pool streams it through the
-                    ragged kernel in kv_prefill_chunk-token chunks
-                    interleaved with other sessions' decode ticks.
-      decode_step:  session_id -> one greedy token per call (donated
-                    buffers: caches update in place, one token crosses
-                    the wire each way)
-      decode_close: session_id -> free the session's HBM
-
-    Host signatures: the store lookup is Python, the math is jitted.
-
-    continuous_batching=True swaps the per-session device dispatch for a
-    slot pool: concurrent decode_step requests coalesce into ONE vmapped
-    device tick (decode_sessions.SlotPool/TickBatcher) — K active
-    sessions cost one dispatch per token instead of K. Sessions are then
-    single-sequence (batch 1); the wire surface is identical.
-
-    kv_block_size > 0 additionally pages the pooled KV store
-    (decode_sessions.PagedSlotPool): session capacity scales with USED
-    tokens instead of max_decode_len slots. None defers to the server
-    flags (--kv_block_size etc., decode_sessions.default_paging); 0
-    forces the old dense slot pool byte-for-byte.
-    """
-    if continuous_batching:
-        return _build_pooled_session_signatures(
-            params, config, seq_len=seq_len, max_decode_len=max_decode_len,
-            max_slots=max_sessions, session_ttl_s=session_ttl_s,
-            sampling=sampling, sampling_top_k=sampling_top_k,
-            sampling_top_p=sampling_top_p,
-            kv_block_size=kv_block_size, kv_num_blocks=kv_num_blocks,
-            kv_evict_policy=kv_evict_policy,
-            kv_prefill_chunk=kv_prefill_chunk,
-            kv_use_step_contract=kv_use_step_contract)
-    from min_tfs_client_tpu.servables.decode_sessions import (
-        DecodeSessionStore,
-        StepDeduper,
-        read_step_ordinal,
-    )
-    from min_tfs_client_tpu.servables.servable import Signature, TensorSpec
-    from min_tfs_client_tpu.utils.status import ServingError
-
-    from min_tfs_client_tpu.models.quantize import maybe_dequantize
-
-    store = DecodeSessionStore(max_sessions=max_sessions,
-                               ttl_s=session_ttl_s, metric_label="t5")
-    # is_live = the store's membership test: a LIVE session's guard is
-    # never LRU-evicted (only closed/exhausted/expired entries shed).
-    dedup = StepDeduper(max_entries=max(2 * max_sessions, 64),
-                        is_live=store.__contains__)
-    prefill_fn, read_sampling, extra_specs = _sampling_session_helpers(
-        config, max_decode_len, sampling, sampling_top_p)
-    from min_tfs_client_tpu.observability import runtime as rt
-
-    prefill_jit = rt.instrument_jit(
-        "t5:decode:prefill", jax.jit(prefill_fn))
-    step_jit = rt.instrument_jit(
-        "t5:decode:step",
-        jax.jit(
-            lambda p, s: decode_step_state(maybe_dequantize(p), config, s,
-                                           top_k=sampling_top_k),
-            donate_argnums=(1,)))
-
-    def _session_id(inputs) -> bytes:
-        raw = np.asarray(inputs["session_id"]).reshape(-1)
-        if raw.size != 1:
-            raise ServingError.invalid_argument(
-                f"session_id must hold exactly one id, got {raw.size}")
-        value = raw[0]
-        return value if isinstance(value, bytes) else str(value).encode()
-
-    def init_fn(inputs):
-        sid = _session_id(inputs)
-        # A re-init over a previously-used id is a NEW stream: drop any
-        # surviving dedup entry or its first ordinal-guarded step would
-        # be judged against (or replayed from) the dead stream's cache.
-        dedup.forget(sid)
-        ids = np.asarray(inputs["input_ids"]).astype(np.int32)
-        args = (params, jax.device_put(ids))
-        if read_sampling is not None:
-            args += read_sampling(inputs, ids.shape[0])
-        state = prefill_jit(*args)
-        store.put(sid, (state, 0))  # host-side step mirror: no fetch later
-        return {"session_id": np.asarray(sid, object),
-                "batch": np.asarray(ids.shape[0], np.int32)}
-
-    def init_prefix_fn(inputs):
-        sid = _session_id(inputs)
-        dedup.forget(sid)  # new stream: see init_fn
-        ids = np.asarray(inputs["input_ids"]).astype(np.int32)
-        if ids.shape[0] != 1:
-            raise ServingError.invalid_argument(
-                "decode_init_prefix sessions are single-sequence: "
-                f"input_ids batch must be 1, got {ids.shape[0]}")
-        pre, plen = _read_prefix(inputs, config)
-        args = (params, jax.device_put(ids))
-        if read_sampling is not None:
-            args += read_sampling(inputs, 1)
-        # Monolithic prefill: prompt encode + the decoder run over the
-        # whole forced prefix in one pass; step mirror starts at plen so
-        # the session decodes max_decode_len - plen further tokens.
-        state = prefill_jit(*args, jax.device_put(pre))
-        store.put(sid, (state, plen))
-        return {"session_id": np.asarray(sid, object),
-                "batch": np.asarray(1, np.int32),
-                "prefix_len": np.asarray(plen, np.int32)}
-
-    def step_fn(inputs):
-        from min_tfs_client_tpu.servables.servable import fetch_outputs
-
-        sid = _session_id(inputs)
-        # At-most-once guard BEFORE the store lookup: a duplicate
-        # resend of the final step must replay from cache even after
-        # exhaustion closed the session.
-        ordinal = read_step_ordinal(inputs)
-        cached = dedup.replay(sid, ordinal)  # marks ordinal in flight
-        if cached is not None:
-            return cached
-        try:
-            state, host_step = store.take(sid)
-            state, token = step_jit(params, state)
-            host_step += 1
-            if host_step < max_decode_len:
-                store.put(sid, (state, host_step))
-            else:
-                store.close(sid)  # cache exhausted: session ends
-            # One overlapped fetch: the step's whole wire cost is one
-            # token row (+ the finished flags) each way.
-            fetched = fetch_outputs(
-                {"token": token, "finished": state["finished"]})
-            out = {"token": fetched["token"],
-                   "finished": fetched["finished"].astype(np.int32),
-                   "step": np.asarray(host_step, np.int32)}
-        except BaseException:
-            # The failed attempt never produced a response: unmark so
-            # a retry of this ordinal executes instead of waiting on a
-            # commit that will never come.
-            dedup.abandon(sid, ordinal)
-            raise
-        dedup.commit(sid, ordinal, out)
-        return out
-
-    def close_fn(inputs):
-        sid = _session_id(inputs)
-        dedup.forget(sid)
-        closed = store.close(sid)
-        return {"closed": np.asarray(int(closed), np.int32)}
-
-    session_spec = TensorSpec("DT_STRING", ())
-    init_inputs = {"session_id": session_spec,
-                   "input_ids": TensorSpec(np.int32, (None, seq_len)),
-                   **extra_specs}
-    init_sig = Signature(
-        fn=init_fn,
-        inputs=init_inputs,
-        outputs={"session_id": TensorSpec("DT_STRING", ()),
-                 "batch": TensorSpec(np.int32, ())},
-        on_host=True, batched=False,
-    )
-    step_sig = Signature(
-        fn=step_fn,
-        inputs={"session_id": session_spec},
-        # step_ordinal is the OPTIONAL at-most-once guard: absent =
-        # historical wire behavior byte-for-byte (docs/ROBUSTNESS.md
-        # "Retry & idempotency").
-        optional_inputs={"step_ordinal": TensorSpec(np.int64, ())},
-        outputs={"token": TensorSpec(np.int32, (None,)),
-                 "finished": TensorSpec(np.int32, (None,)),
-                 "step": TensorSpec(np.int32, ())},
-        on_host=True, batched=False,
-    )
-    close_sig = Signature(
-        fn=close_fn,
-        inputs={"session_id": session_spec},
-        outputs={"closed": TensorSpec(np.int32, ())},
-        on_host=True, batched=False,
-    )
-    init_prefix_sig = Signature(
-        fn=init_prefix_fn,
-        inputs={**init_inputs,
-                "prefix_ids": TensorSpec(np.int32, (None, max_decode_len))},
-        outputs={"session_id": TensorSpec("DT_STRING", ()),
-                 "batch": TensorSpec(np.int32, ()),
-                 "prefix_len": TensorSpec(np.int32, ())},
-        on_host=True, batched=False,
-    )
-    init_sig.warmup_fn = _session_warmup_fn(
-        init_fn, step_fn, close_fn, seq_len, sampling=sampling,
-        use_top_p=sampling_top_p, init_prefix_fn=init_prefix_fn,
-        warmup_prefix=_warmup_prefix(config, max_decode_len))
-    # The loader re-labels the store's gauge with the real model:version
-    # (platforms.make_loader) — the family builder doesn't know it.
-    for sig in (init_sig, init_prefix_sig, step_sig, close_sig):
-        sig._decode_store = store
-    return {"decode_init": init_sig, "decode_init_prefix": init_prefix_sig,
-            "decode_step": step_sig, "decode_close": close_sig}
-
-
-def _warmup_prefix(config: T5Config, max_decode_len: int) -> np.ndarray:
-    """A minimal valid decode_init_prefix row for warmup: one non-pad
-    token, pad-suffixed."""
-    row = np.full((1, max_decode_len), config.pad_id, np.int32)
-    row[0, 0] = 1 if config.pad_id != 1 else 2
-    return row
-
-
-def _session_warmup_fn(init_fn, step_fn, close_fn, seq_len: int,
-                       sampling: bool = False, use_top_p: bool = False,
-                       init_prefix_fn=None, warmup_prefix=None):
-    """Prime prefill + step/tick executables with a throwaway session so
-    the first real decode_init/step never compiles (synthesize_warmup
-    calls this through the warmup_fn hook). With `init_prefix_fn` +
-    `warmup_prefix` (a 1-token pad-suffixed prefix row) a second
-    throwaway session also primes the decode_init_prefix path — the
-    prefix-arity monolithic prefill on dense pools, the chunked-prefill
-    program on step-contract pools."""
-    def _warm():
-        def _base_inputs(sid):
-            inputs = {"session_id": np.asarray(sid, object),
-                      "input_ids": np.zeros((1, seq_len), np.int32)}
-            if sampling:
-                inputs["temperature"] = np.zeros((1,), np.float32)
-                inputs["seed"] = np.zeros((1,), np.int32)
-                if use_top_p:
-                    inputs["top_p"] = np.ones((1,), np.float32)
-            return inputs
-
-        sid = b"__warmup__"
-        init_fn(_base_inputs(sid))
-        step_fn({"session_id": np.asarray(sid, object)})
-        close_fn({"session_id": np.asarray(sid, object)})
-        if init_prefix_fn is not None:
-            pid = b"__warmup_prefix__"
-            inputs = _base_inputs(pid)
-            inputs["prefix_ids"] = warmup_prefix
-            init_prefix_fn(inputs)
-            step_fn({"session_id": np.asarray(pid, object)})
-            close_fn({"session_id": np.asarray(pid, object)})
-    return _warm
-
-
-def _build_pooled_session_signatures(params: dict, config: T5Config, *,
-                                     seq_len: int, max_decode_len: int,
-                                     max_slots: int,
-                                     session_ttl_s: float,
-                                     sampling: bool = False,
-                                     sampling_top_k: int = 0,
-                                     sampling_top_p: bool = False,
-                                     kv_block_size: int | None = None,
-                                     kv_num_blocks: int | None = None,
-                                     kv_evict_policy: str | None = None,
-                                     kv_prefill_chunk: int | None = None,
-                                     kv_use_step_contract: bool = True
-                                     ) -> dict:
-    """Continuous-batching variant: same wire surface, slot-pool device
-    state, one vmapped tick per token across all concurrently-stepping
-    sessions. See decode_sessions.SlotPool; with kv_block_size > 0 the KV
-    caches live in the block-table-paged PagedSlotPool, driven through
-    the _T5PagedStep paging-aware contract (the tick reads block tables,
-    never a dense gather). kv_use_step_contract=False is the testing
-    escape hatch that builds the paged pool WITHOUT the contract — the
-    dense-gather fallback — so suites can A/B the two programs on one
-    model; prefix sessions then raise UNIMPLEMENTED (chunked prefill
-    needs the contract's multi-row program)."""
-    from min_tfs_client_tpu.servables.decode_sessions import (
-        PREFILL_PENDING,
-        DecodeSessionStore,
-        PagedSlotPool,
-        SlotPool,
-        StepDeduper,
-        TickBatcher,
-        default_paging,
-        read_step_ordinal,
-    )
-    from min_tfs_client_tpu.servables.servable import Signature, TensorSpec
-    from min_tfs_client_tpu.utils.status import ServingError
-
-    from min_tfs_client_tpu.models.quantize import maybe_dequantize
-
-    prefill_fn, read_sampling, extra_specs = _sampling_session_helpers(
-        config, max_decode_len, sampling, sampling_top_p)
-    template_args = [params, jax.ShapeDtypeStruct((1, seq_len), jnp.int32)]
-    if sampling:
-        template_args += [jax.ShapeDtypeStruct((1,), jnp.float32),
-                          jax.ShapeDtypeStruct((1,), jnp.int32)]
-        if sampling_top_p:
-            template_args.append(jax.ShapeDtypeStruct((1,), jnp.float32))
-    template = jax.eval_shape(prefill_fn, *template_args)
-
-    def one_step(p, state):
-        new_state, token = decode_step_state(
-            maybe_dequantize(p), config, state, top_k=sampling_top_k)
-        return new_state, {"token": token,
-                           "finished": new_state["finished"]}
-
-    defaults = default_paging()
-    if kv_block_size is None:
-        kv_block_size = defaults["block_size"]
-    if kv_num_blocks is None:
-        kv_num_blocks = defaults["num_blocks"]
-    if kv_evict_policy is None:
-        kv_evict_policy = defaults["evict_policy"]
-    if kv_prefill_chunk is None:
-        kv_prefill_chunk = defaults["prefill_chunk"]
-
-    paged = bool(kv_block_size)
-    if paged:
-        # Page the decoder self-attention caches: leaves under "caches"
-        # named k/v, seq axis 2 of their (1, H, max_decode_len, d_kv)
-        # layout. Everything else (encoded prompt, token, PRNG keys, ...)
-        # stays dense — it is fully used from the first step.
-        def paged_axis_fn(path):
-            return 2 if ("caches" in path and path[-1] in ("k", "v")) \
-                else None
-
-        contract = _T5PagedStep(config, sampling=sampling,
-                                top_k=sampling_top_k) \
-            if kv_use_step_contract else None
-        pool = PagedSlotPool(
-            template, one_step, max_slots=max_slots, params=params,
-            block_size=kv_block_size, num_blocks=kv_num_blocks or None,
-            paged_axis_fn=paged_axis_fn, evict_policy=kv_evict_policy,
-            paged_step=contract, prefill_chunk=kv_prefill_chunk or 0,
-            metric_label="t5-paged")
-    else:
-        pool = SlotPool(template, one_step, max_slots=max_slots,
-                        params=params, metric_label="t5-pooled")
-    # cost_fn: each delivered step charges its session's pages-held
-    # onto the CALLER's trace (pages x ticks, the paged pool's
-    # HBM-residency cost unit; None on the dense pool).
-    batcher = TickBatcher(pool.tick, cost_fn=pool.step_cost)
-    store = DecodeSessionStore(
-        max_sessions=max_slots, ttl_s=session_ttl_s,
-        metric_label="t5-pooled",
-        on_evict=lambda entry: pool.release_slot(entry[0]))
-    # is_live = store membership: a live session's guard never sheds.
-    dedup = StepDeduper(max_entries=max(2 * max_slots, 64),
-                        is_live=store.__contains__)
-    from min_tfs_client_tpu.observability import runtime as rt
-    from min_tfs_client_tpu.observability import tracing
-
-    prefill_jit = rt.instrument_jit(
-        "t5:pooled:prefill", jax.jit(prefill_fn))
-
-    def _session_id(inputs) -> bytes:
-        raw = np.asarray(inputs["session_id"]).reshape(-1)
-        if raw.size != 1:
-            raise ServingError.invalid_argument(
-                f"session_id must hold exactly one id, got {raw.size}")
-        value = raw[0]
-        return value if isinstance(value, bytes) else str(value).encode()
-
-    def init_fn(inputs):
-        sid = _session_id(inputs)
-        # A re-init over a previously-used id is a NEW stream: drop any
-        # surviving dedup entry (cache deliberately outlives exhaustion,
-        # so only close/init may clear it).
-        dedup.forget(sid)
-        ids = np.asarray(inputs["input_ids"]).astype(np.int32)
-        if ids.shape[0] != 1:
-            raise ServingError.invalid_argument(
-                "continuous-batching decode sessions are single-sequence: "
-                f"input_ids batch must be 1, got {ids.shape[0]}")
-        args = (params, jax.device_put(ids))
-        if read_sampling is not None:
-            args += read_sampling(inputs, 1)
-        # The prefill takes the device, and the pool write the pool's
-        # lock, against the ticks of the sessions that are stepping.
-        with tracing.span("decode/init", tokens=int(ids.shape[1])):
-            state = prefill_jit(*args)
-            slot = pool.acquire_slot()
-            try:
-                pool.write(state, slot, session_key=sid)
-                store.put(sid, (slot, 0))
-            except Exception:
-                pool.release_slot(slot)
-                raise
-        return {"session_id": np.asarray(sid, object),
-                "batch": np.asarray(1, np.int32)}
-
-    def init_prefix_fn(inputs):
-        sid = _session_id(inputs)
-        dedup.forget(sid)  # new stream: see init_fn
-        ids = np.asarray(inputs["input_ids"]).astype(np.int32)
-        if ids.shape[0] != 1:
-            raise ServingError.invalid_argument(
-                "continuous-batching decode sessions are single-sequence: "
-                f"input_ids batch must be 1, got {ids.shape[0]}")
-        pre, plen = _read_prefix(inputs, config)
-        args = (params, jax.device_put(ids))
-        if read_sampling is not None:
-            args += read_sampling(inputs, 1)
-        if paged and getattr(pool, "_paged_step", None) is None:
-            # A monolithic prefill's cache rows would be silently DROPPED
-            # by the paged write program (paged leaves live in arenas, and
-            # only the contract has a multi-row program to fill them).
-            raise ServingError.unimplemented(
-                "decode_init_prefix on a paged pool needs the paging-aware "
-                "step contract; this pool runs the dense-gather fallback")
-        slot = pool.acquire_slot()
-        try:
-            with tracing.span("decode/init", tokens=int(ids.shape[1])):
-                if paged:
-                    # Step-contract pool: encoder-only prefill; the
-                    # forced prefix streams through the ragged kernel in
-                    # chunks, interleaved with other sessions' ticks.
-                    state = prefill_jit(*args)
-                    tokens = pre[0][:plen]
-                    prefix_inputs = np.concatenate(
-                        [np.asarray([config.decoder_start_id], np.int32),
-                         tokens[:-1].astype(np.int32)])
-                    pool.write(state, slot, prefill_inputs=prefix_inputs,
-                               prefill_next=int(tokens[-1]),
-                               session_key=sid)
-                else:
-                    # Dense slot pool: one monolithic prefill.
-                    state = prefill_jit(*args, jax.device_put(pre))
-                    pool.write(state, slot, session_key=sid)
-            store.put(sid, (slot, plen))
-        except Exception:
-            pool.release_slot(slot)
-            raise
-        return {"session_id": np.asarray(sid, object),
-                "batch": np.asarray(1, np.int32),
-                "prefix_len": np.asarray(plen, np.int32)}
-
-    def step_fn(inputs):
-        sid = _session_id(inputs)
-        # At-most-once guard BEFORE the store lookup: a duplicate
-        # resend of the final step must replay from cache even after
-        # exhaustion released the slot.
-        ordinal = read_step_ordinal(inputs)
-        cached = dedup.replay(sid, ordinal)  # marks ordinal in flight
-        if cached is not None:
-            return cached
-        try:
-            slot, host_step = store.take(sid)
-            try:
-                row = batcher.step(slot)
-                while row is PREFILL_PENDING:
-                    # The slot is mid-prefix: each batcher round
-                    # streamed one chunk; re-entering lets tick-mates'
-                    # decode steps (and other prefills) interleave
-                    # until this session's first real token arrives.
-                    row = batcher.step(slot)
-            except Exception:
-                # The pool row may be in an undefined state; retire the
-                # slot rather than hand it to a future session
-                # mid-generation.
-                pool.release_slot(slot)
-                raise
-            if isinstance(row, Exception):
-                # Per-slot failure from the paged pool's tick (typed
-                # capacity errors, eviction under kv_evict_policy=
-                # close). slot_fatal distinguishes a dead session from
-                # a capacity REFUSAL whose state is intact and may
-                # retry after others close.
-                if getattr(row, "slot_fatal", True):
-                    pool.release_slot(slot)
-                else:
-                    store.put(sid, (slot, host_step))
-                raise row
-            host_step += 1
-            if host_step < max_decode_len:
-                store.put(sid, (slot, host_step))
-            else:
-                pool.release_slot(slot)  # cache exhausted: session ends
-            out = {"token": row["token"].reshape(-1),
-                   "finished":
-                       row["finished"].reshape(-1).astype(np.int32),
-                   "step": np.asarray(host_step, np.int32)}
-        except BaseException:
-            # Failed attempt = no response to replay: unmark so a
-            # retry of this ordinal executes.
-            dedup.abandon(sid, ordinal)
-            raise
-        dedup.commit(sid, ordinal, out)
-        return out
-
-    def close_fn(inputs):
-        sid = _session_id(inputs)
-        dedup.forget(sid)
-        closed = store.close(sid)  # on_evict frees slot
-        return {"closed": np.asarray(int(closed), np.int32)}
-
-    session_spec = TensorSpec("DT_STRING", ())
-    init_inputs = {"session_id": session_spec,
-                   "input_ids": TensorSpec(np.int32, (None, seq_len)),
-                   **extra_specs}
-    init_sig = Signature(
-        fn=init_fn,
-        inputs=init_inputs,
-        outputs={"session_id": TensorSpec("DT_STRING", ()),
-                 "batch": TensorSpec(np.int32, ())},
-        on_host=True, batched=False,
-    )
-    step_sig = Signature(
-        fn=step_fn,
-        inputs={"session_id": session_spec},
-        # step_ordinal is the OPTIONAL at-most-once guard: absent =
-        # historical wire behavior byte-for-byte (docs/ROBUSTNESS.md
-        # "Retry & idempotency").
-        optional_inputs={"step_ordinal": TensorSpec(np.int64, ())},
-        outputs={"token": TensorSpec(np.int32, (None,)),
-                 "finished": TensorSpec(np.int32, (None,)),
-                 "step": TensorSpec(np.int32, ())},
-        on_host=True, batched=False,
-    )
-    close_sig = Signature(
-        fn=close_fn,
-        inputs={"session_id": session_spec},
-        outputs={"closed": TensorSpec(np.int32, ())},
-        on_host=True, batched=False,
-    )
-
-    init_prefix_sig = Signature(
-        fn=init_prefix_fn,
-        inputs={**init_inputs,
-                "prefix_ids": TensorSpec(np.int32, (None, max_decode_len))},
-        outputs={"session_id": TensorSpec("DT_STRING", ()),
-                 "batch": TensorSpec(np.int32, ()),
-                 "prefix_len": TensorSpec(np.int32, ())},
-        on_host=True, batched=False,
-    )
-    # Paged pools without the contract have no prefix program to warm
-    # (decode_init_prefix raises UNIMPLEMENTED there).
-    can_prefix = not paged or getattr(pool, "_paged_step", None) is not None
-    init_sig.warmup_fn = _session_warmup_fn(
-        init_fn, step_fn, close_fn, seq_len, sampling=sampling,
-        use_top_p=sampling_top_p,
-        init_prefix_fn=init_prefix_fn if can_prefix else None,
-        warmup_prefix=_warmup_prefix(config, max_decode_len))
-    for sig in (init_sig, init_prefix_sig, step_sig, close_sig):
-        sig._decode_store = store
-        if paged:
-            sig._kv_pool = pool  # loader re-labels gauges with model:version
-    return {"decode_init": init_sig, "decode_init_prefix": init_prefix_sig,
-            "decode_step": step_sig, "decode_close": close_sig}
+    return decode_signatures.build_session_signatures(
+        params,
+        decode_model(config, max_decode_len=max_decode_len,
+                     sampling=sampling, sampling_top_k=sampling_top_k,
+                     sampling_top_p=sampling_top_p),
+        seq_len=seq_len, max_decode_len=max_decode_len,
+        max_sessions=max_sessions, session_ttl_s=session_ttl_s,
+        continuous_batching=continuous_batching,
+        paging=Paging.resolve(kv_block_size, kv_num_blocks,
+                              kv_evict_policy, kv_prefill_chunk))

@@ -24,8 +24,8 @@ Invariants are checked DURING the run, per event, not by a final sweep:
    no UNAVAILABLE-from-all latch, and no fault events beyond the armed
    plan's.
 
-The harness (tests/integration/test_fleet_storm.py, bench.py's
-fleet_storm leg) owns the subprocess fleet; this module owns the
+The harness (tests/integration/test_fleet_storm.py) owns the
+subprocess fleet; this module owns the
 schedule, the workers, and the verdict.
 """
 

@@ -22,6 +22,7 @@ from __future__ import annotations
 
 import collections
 import contextlib
+import dataclasses
 import math
 import threading
 import time
@@ -31,56 +32,57 @@ from typing import Callable, Optional
 from min_tfs_client_tpu.observability import tracing
 from min_tfs_client_tpu.utils.status import ServingError
 
-# -- server-level paging defaults --------------------------------------------
+# -- which paging a pool gets ---------------------------------------------
 #
-# The builders that construct decode-session pools (models/t5.py) run inside
-# an exported servable.py whose saved signature_kwargs predate the paging
-# knobs; the server flags (--kv_block_size / --kv_num_blocks /
-# --kv_evict_policy) therefore flow here as module defaults, installed by
-# platforms.make_loader around the factory call and consulted by the
-# builders when no explicit kwarg was given. block_size 0 = paging off (the
-# old max-length slot pool, byte-for-byte).
-
-_paging_defaults_lock = threading.Lock()
-_paging_defaults = {"block_size": 0, "num_blocks": 0,
-                    "evict_policy": "swap",
-                    "prefill_chunk": 0}  # guarded_by: _paging_defaults_lock
+# The builder of the decode-session signatures (decode_signatures.py) runs
+# inside an exported servable.py whose saved signature_kwargs predate the
+# paging knobs, so the server flags (--kv_block_size / --kv_num_blocks /
+# --kv_evict_policy / --kv_prefill_chunk) reach it through a side channel:
+# platforms.make_loader wraps the factory call in `paging_scope`, and
+# `Paging.resolve` reads the scope for every knob the caller left None.
+# block_size 0 = paging off (the max-length slot pool).
 
 EVICT_POLICIES = ("swap", "close", "refuse")
 
 
-def set_default_paging(block_size: int = 0, num_blocks: int = 0,
-                       evict_policy: str = "swap",
-                       prefill_chunk: int = 0) -> dict:
-    """Install process defaults for new decode pools; returns the previous
-    defaults so a loader can scope them to one factory call.
-    prefill_chunk sizes chunked-prefill rounds (0 = one page per round,
-    i.e. block_size tokens)."""
-    if evict_policy not in EVICT_POLICIES:
-        raise ServingError.invalid_argument(
-            f"kv_evict_policy must be one of {EVICT_POLICIES}, "
-            f"got {evict_policy!r}")
-    global _paging_defaults
-    with _paging_defaults_lock:
-        previous = dict(_paging_defaults)
-        _paging_defaults = {"block_size": int(block_size),
-                            "num_blocks": int(num_blocks),
-                            "evict_policy": evict_policy,
-                            "prefill_chunk": int(prefill_chunk)}
-    return previous
+@dataclasses.dataclass(frozen=True)
+class Paging:
+    """The paging a decode pool gets, as ONE value: page size in tokens
+    (0 = the dense slot pool), pages in the arena (0 = the dense pool's
+    byte budget), what happens when the arena runs dry, and how many
+    forced-prefix tokens a chunked-prefill round streams (0 = one page).
+    It validates itself where it is made; nothing downstream checks or
+    defaults a knob again."""
+
+    block_size: int = 0
+    num_blocks: int = 0
+    evict_policy: str = "swap"
+    prefill_chunk: int = 0
+
+    def __post_init__(self):
+        if self.evict_policy not in EVICT_POLICIES:
+            raise ServingError.invalid_argument(
+                f"kv_evict_policy must be one of {EVICT_POLICIES}, "
+                f"got {self.evict_policy!r}")
+
+    @classmethod
+    def resolve(cls, block_size: Optional[int] = None,
+                num_blocks: Optional[int] = None,
+                evict_policy: Optional[str] = None,
+                prefill_chunk: Optional[int] = None) -> "Paging":
+        """Explicit knobs win; a knob left None takes the value of this
+        thread's `paging_scope` (the loader's server flags), and outside
+        any scope the class defaults (paging off)."""
+        scope = getattr(_paging_tls, "scope", None) or _NO_PAGING
+        return cls(
+            int(scope.block_size if block_size is None else block_size),
+            int(scope.num_blocks if num_blocks is None else num_blocks),
+            scope.evict_policy if evict_policy is None else evict_policy,
+            int(scope.prefill_chunk if prefill_chunk is None
+                else prefill_chunk))
 
 
-def default_paging() -> dict:
-    """The paging knobs a builder should apply when given no explicit
-    kwargs: this thread's paging_scope override if one is active (the
-    loader path), else the process defaults (set_default_paging)."""
-    override = getattr(_paging_tls, "override", None)
-    if override is not None:
-        return dict(override)
-    with _paging_defaults_lock:
-        return dict(_paging_defaults)
-
-
+_NO_PAGING = Paging()
 _paging_tls = threading.local()
 
 
@@ -93,19 +95,13 @@ def paging_scope(block_size: int = 0, num_blocks: int = 0,
     either races concurrent loads into the wrong pool flavor (a dense-
     configured load observing a paged scope, or vice versa) or serializes
     every load on one lock; thread-locality removes both failure modes."""
-    if evict_policy not in EVICT_POLICIES:
-        raise ServingError.invalid_argument(
-            f"kv_evict_policy must be one of {EVICT_POLICIES}, "
-            f"got {evict_policy!r}")
-    previous = getattr(_paging_tls, "override", None)
-    _paging_tls.override = {"block_size": int(block_size),
-                            "num_blocks": int(num_blocks),
-                            "evict_policy": evict_policy,
-                            "prefill_chunk": int(prefill_chunk)}
+    previous = getattr(_paging_tls, "scope", None)
+    _paging_tls.scope = Paging(int(block_size), int(num_blocks),
+                               evict_policy, int(prefill_chunk))
     try:
         yield
     finally:
-        _paging_tls.override = previous
+        _paging_tls.scope = previous
 
 
 # -- per-session decode timelines --------------------------------------------
@@ -818,8 +814,8 @@ class PagedSlotPool:
     """Block-table-paged continuous batching (ROADMAP open item 1).
 
     Same tick surface as SlotPool — S single-sequence sessions advanced by
-    ONE vmapped jitted call per token — but KV-cache leaves live in shared
-    page arenas instead of per-slot max-length blocks:
+    ONE jitted call per token — but KV-cache leaves live in shared page
+    arenas instead of per-slot max-length blocks:
 
       * per cache leaf, ONE HBM arena laid out by ops/attention's
         `PagedKV.arena`: `(num_blocks + 1, block_size, F)`, a page
@@ -831,34 +827,22 @@ class PagedSlotPool:
         not max_decode_len × max_slots;
       * a free-list PageAllocator guarded by its own declared lock.
 
-    Two decode programs, dispatched on whether the model declares a
-    paging-aware step contract (`paged_step`):
-
-      direct (contract declared)  the tick hands the model a PagedKV
-          handle (ops/attention.PagedKV): arenas + block tables +
-          per-session lengths, no dense materialization. The model
-          appends exactly this step's new K/V rows (inactive slots and
-          padded chunk rows route to the trash page) and attends via
-          ops/attention.paged_attention() — the ragged Pallas kernel on
-          TPU, the gather oracle elsewhere — so per-tick KV reads scale
-          with the pages sessions actually own, not the table width.
-          The same contract powers chunked prefill (`prefill_chunk`
-          rounds streaming a forced decoder prefix through the Sq>1
-          kernel path) and is what paged speculative verify blocks ride.
-
-      dense-gather (fallback, byte-for-byte the pre-contract behavior)
-          gather each session's pages back to a contiguous view sized by
-          the CURRENT table width, run the unmodified per-session
-          step_fn under vmap, scatter back each session's NEWEST page
-          only — the step contract for paged leaves is append-only along
-          the paged axis (one new row per step at the step index,
-          earlier rows pass through), which is what makes them KV caches
-          at all.
+    One decode program, driven through the model's paging-aware step
+    contract (`paged_step`, required): the tick hands the model a PagedKV
+    handle (ops/attention.PagedKV): arenas + block tables + per-session
+    lengths, no dense materialization. The model appends exactly this
+    step's new K/V rows (inactive slots and padded chunk rows route to
+    the trash page) and attends via ops/attention.paged_attention() — the
+    ragged Pallas kernel on TPU, the gather oracle elsewhere — so
+    per-tick KV reads scale with the pages sessions actually own, not the
+    table width. The same contract powers chunked prefill
+    (`prefill_chunk` rounds streaming a forced decoder prefix through the
+    Sq>1 kernel path) and is what paged speculative verify blocks ride.
 
     Recycled pages are NOT zeroed: rows at or beyond a session's written
     length are masked inside the model (exp(NEG_INF) underflows to exactly
     0.0), so garbage never reaches an output — the paged-decode suite
-    asserts token-exactness against the dense pool on both programs.
+    asserts token-exactness against the dense pool.
 
     Phase separation: `write()` only QUEUES a prefilled state (prefill
     phase); the next tick integrates pending prefills through a separate
@@ -876,17 +860,15 @@ class PagedSlotPool:
               typed capacity error and stays live for retry.
     """
 
-    def __init__(self, template_state, step_fn, *, max_slots: int,
-                 params=None, block_size: int = 16,
-                 num_blocks: Optional[int] = None,
-                 paged_axis_fn: Callable[[tuple], Optional[int]] = None,
-                 evict_policy: str = "swap",
-                 max_prefills_per_tick: int = 8,
-                 paged_step=None,
-                 prefill_chunk: int = 0,
+    def __init__(self, template_state, *, max_slots: int, paging: Paging,
+                 paged_step,
+                 paged_axis_fn: Callable[[tuple], Optional[int]],
+                 params=None, max_prefills_per_tick: int = 8,
                  metric_label: str = "default"):
-        """`paged_step` declares the paging-aware step contract: an object
-        with
+        """`paging` sizes the pages, the arena, the eviction policy and
+        the prefill chunk. `paged_axis_fn(path)` names the KV-cache
+        leaves of the state and their paged (seq) axis. `paged_step` is
+        the paging-aware step contract: an object with
           decode(params, tree, kv) -> (new_tree, kv, outputs)
           prefill_chunk(params, tree, kv, tokens, chunk_lens, next_tokens)
               -> (new_tree, kv)
@@ -895,29 +877,18 @@ class PagedSlotPool:
         None, and `kv` is an ops/attention.PagedKV keyed by the paged
         leaves' pytree paths. Both are traced (called inside jit, state
         donated); decode's outputs and every returned dense leaf must be
-        slot-batched, inactive rows merge away. `prefill_chunk` (tokens,
-        default block_size) sizes the chunk a forced decoder prefix
-        streams through per round."""
+        slot-batched, inactive rows merge away."""
         import jax
         import jax.numpy as jnp
 
-        if evict_policy not in EVICT_POLICIES:
-            raise ServingError.invalid_argument(
-                f"evict_policy must be one of {EVICT_POLICIES}, "
-                f"got {evict_policy!r}")
-        if paged_axis_fn is None:
-            raise ValueError("paged_axis_fn is required: it names the "
-                             "KV-cache leaves and their paged (seq) axis")
         self._jax = jax
         self._jnp = jnp
         self.max_slots = int(max_slots)
-        self.block_size = int(block_size)
+        self.block_size = paging.block_size
         self._params = params
-        self._policy = evict_policy
+        self._policy = paging.evict_policy
         self._max_prefills = int(max_prefills_per_tick)
-        self._paged_step = paged_step
-        self.prefill_chunk = int(prefill_chunk) if prefill_chunk \
-            else int(block_size)
+        self.prefill_chunk = paging.prefill_chunk or paging.block_size
         self.metric_label = metric_label
 
         shapes = jax.eval_shape(lambda: template_state)
@@ -948,11 +919,10 @@ class PagedSlotPool:
         self._paged_axes = paged_axes
         self.max_len = seq_len
         self.pages_per_session = -(-seq_len // self.block_size)
-        if not num_blocks:
-            # Default: the same KV byte budget as the dense slot pool —
-            # identical worst case, strictly better short-sequence packing.
-            num_blocks = self.max_slots * self.pages_per_session
-        self.num_blocks = int(num_blocks)
+        # Default: the same KV byte budget as the dense slot pool —
+        # identical worst case, strictly better short-sequence packing.
+        self.num_blocks = (paging.num_blocks
+                           or self.max_slots * self.pages_per_session)
         self._trash = self.num_blocks  # extra arena page absorbing masked writes
         self.allocator = PageAllocator(self.num_blocks,
                                        metric_label=metric_label)
@@ -1037,64 +1007,6 @@ class PagedSlotPool:
                     (slot,) + (0,) * s.ndim)
             return out
 
-        def tick_fn(params, dense_list, arenas, tables, active, cur_pages):
-            """Decode-phase program: gather pages -> vmapped step ->
-            masked merge (dense) + newest-page scatter (paged). Table
-            width W is a trace-time shape: a MONOTONE high-water bucket
-            (1, 2, 4, ... capped at pages_per_session) that grows when a
-            live session needs more pages and deliberately never shrinks
-            — at most log2(pages_per_session)+1 compiles over the pool's
-            lifetime, vs a recompile every time the longest session
-            closes.
-
-            Paged leaves are APPEND-ONLY per step (KV-cache semantics:
-            the step writes exactly one new row at its step index and
-            passes every earlier row through), so only each session's
-            CURRENT page — cur_pages[slot] = tokens // block_size, the
-            page holding the newly written row — is scattered back;
-            earlier pages in the arena are already ground truth."""
-            width = tables.shape[1]
-            full = []
-            for i, leaf in enumerate(self._leaves):
-                axis = paged_axes.get(i)
-                if axis is None:
-                    full.append(dense_list[i])
-                    continue
-                # (slots, W, bs, F): a session's token rows, in order.
-                g = arenas[self._arena_pos[i]][tables]
-                g = g.reshape((self.max_slots, width * self.block_size)
-                              + self._token_shapes[i])
-                full.append(jnp.moveaxis(g, 1, axis)[:, None])
-            tree = jax.tree_util.tree_unflatten(treedef, full)
-            if params is None:
-                new_tree, outputs = jax.vmap(step_fn)(tree)
-            else:
-                new_tree, outputs = jax.vmap(
-                    lambda s: step_fn(params, s))(tree)
-            new_leaves = jax.tree_util.tree_leaves(new_tree)
-
-            cur_ids = jnp.take_along_axis(tables, cur_pages[:, None],
-                                          axis=1)[:, 0]
-            scatter_idx = jnp.where(active, cur_ids, self._trash)
-            out_dense = list(dense_list)
-            out_arenas = list(arenas)
-            for i, leaf in enumerate(self._leaves):
-                axis = paged_axes.get(i)
-                if axis is None:
-                    mask = active.reshape(
-                        (-1,) + (1,) * (new_leaves[i].ndim - 1))
-                    out_dense[i] = jnp.where(mask, new_leaves[i],
-                                             dense_list[i])
-                    continue
-                arena = arenas[self._arena_pos[i]]
-                n = jnp.moveaxis(new_leaves[i][:, 0], axis, 1)
-                n = n.reshape((self.max_slots, width) + arena.shape[1:])
-                page = jnp.take_along_axis(
-                    n, cur_pages[:, None, None, None], axis=1)[:, 0]
-                out_arenas[self._arena_pos[i]] = arena.at[scatter_idx].set(
-                    page.astype(arena.dtype))
-            return out_dense, out_arenas, outputs
-
         def _contract_tree(dense_list):
             """Session-state tree for the step contract: dense leaves
             slot-batched, paged leaves None (they live in the arenas the
@@ -1127,9 +1039,13 @@ class PagedSlotPool:
 
         def direct_tick_fn(params, dense_list, arenas, tables, active,
                            lengths):
-            """Contract decode program: no dense materialization — the
+            """Decode-phase program: no dense materialization — the
             model appends this step's K/V rows and attends through the
-            block tables (ops/attention.paged_attention)."""
+            block tables (ops/attention.paged_attention). Table width W
+            is a trace-time shape: a pow2 bucket (1, 2, 4, ... capped at
+            pages_per_session) that grows when a live session needs more
+            pages and shrinks only when the widest one departs — at most
+            log2(pages_per_session)+1 compiles, not one per length."""
             kv = _contract_kv(arenas, tables, lengths, active)
             new_tree, kv, outputs = paged_step.decode(
                 params, _contract_tree(dense_list), kv)
@@ -1173,18 +1089,12 @@ class PagedSlotPool:
         self._write_jit = rt.instrument_jit(
             f"paged:{metric_label}:prefill_write",
             jax.jit(write_fn, donate_argnums=(0,)))
-        if paged_step is not None:
-            self._tick_jit = rt.instrument_jit(
-                f"paged:{metric_label}:tick_direct",
-                jax.jit(direct_tick_fn, donate_argnums=(1, 2)))
-            self._chunk_jit = rt.instrument_jit(
-                f"paged:{metric_label}:prefill_chunk",
-                jax.jit(chunk_fn, donate_argnums=(1, 2)))
-        else:
-            self._tick_jit = rt.instrument_jit(
-                f"paged:{metric_label}:tick",
-                jax.jit(tick_fn, donate_argnums=(1, 2)))
-            self._chunk_jit = None
+        self._tick_jit = rt.instrument_jit(
+            f"paged:{metric_label}:tick_direct",
+            jax.jit(direct_tick_fn, donate_argnums=(1, 2)))
+        self._chunk_jit = rt.instrument_jit(
+            f"paged:{metric_label}:prefill_chunk",
+            jax.jit(chunk_fn, donate_argnums=(1, 2)))
         self._gather_jit = jax.jit(gather_fn)
         self._restore_jit = jax.jit(restore_fn, donate_argnums=(0,))
         with self._lock:
@@ -1228,7 +1138,9 @@ class PagedSlotPool:
             "evict_policy": self._policy,
             "arena_bytes": self.arena_bytes,
             "dense_equivalent_bytes": self.dense_equivalent_bytes,
-            "step_contract": self._paged_step is not None,
+            # Wire surface of /monitoring/runtime and the fleet view: the
+            # contract is the pool's only decode program.
+            "step_contract": True,
             "prefill_chunk_size": self.prefill_chunk,
             "chunking_sessions": len(self._prefix),
             "kv_gather_bytes_per_tick": self._gather_bytes_last,
@@ -1298,16 +1210,9 @@ class PagedSlotPool:
         contract's Sq>1 path `prefill_chunk` tokens per round, interleaved
         with in-flight decode ticks, instead of one monolithic prefill
         stalling the pool. `prefill_next` is the input token the first
-        decode step after the prefix consumes. Requires a step contract —
-        the dense-gather fallback has no multi-row program to stream
-        through."""
+        decode step after the prefix consumes."""
         import numpy as np
 
-        if prefill_inputs is not None and self._paged_step is None:
-            raise ServingError.unimplemented(
-                "chunked prefill needs a paging-aware step contract; this "
-                "pool runs the dense-gather fallback (model declared no "
-                "paged_step)")
         self.timeline.begin(slot, session_key)
         with self._lock:
             self._pending[slot] = state
@@ -1476,9 +1381,9 @@ class PagedSlotPool:
 
     def tick(self, slots: list[int],
              of_round: Optional[TickRound] = None) -> dict[int, object]:
-        """Advance the given slots in ONE device call (plus, on the
-        contract path, at most one chunked-prefill round for sessions
-        still streaming a forced prefix). Returns per-slot host outputs;
+        """Advance the given slots in ONE device call (plus at most one
+        chunked-prefill round for sessions still streaming a forced
+        prefix). Returns per-slot host outputs;
         slots that could not run carry their TYPED error as the value
         (per-slot failure isolation — a capacity refusal for one session
         must not poison its tick-mates), and slots still mid-prefix carry
@@ -1546,20 +1451,12 @@ class PagedSlotPool:
                     tables[s, :len(pages)] = pages
                 active = np.zeros((self.max_slots,), bool)
                 active[live] = True
-                # Where each slot's step stands: its token count on the
-                # contract path, its current page on the fallback.
-                cursor = np.zeros((self.max_slots,), np.int32)
-                if self._paged_step is not None:
-                    for s, t in self._tokens.items():
-                        cursor[s] = t
-                    # What the ragged kernel actually reads: the pages
-                    # live sessions own — not slots × table width.
-                    gather_pages = sum(len(self._pages[s]) for s in live)
-                else:
-                    for s in live:
-                        cursor[s] = self._tokens[s] // self.block_size
-                    # The fallback materializes the full gathered view.
-                    gather_pages = self.max_slots * width
+                lengths = np.zeros((self.max_slots,), np.int32)
+                for s, t in self._tokens.items():
+                    lengths[s] = t
+                # What the ragged kernel actually reads: the pages
+                # live sessions own — not slots × table width.
+                gather_pages = sum(len(self._pages[s]) for s in live)
                 gather_bytes = self.page_bytes * gather_pages
             tracing.add_span(
                 "decode/prepare", t_entry, time.perf_counter(),
@@ -1575,7 +1472,7 @@ class PagedSlotPool:
                         self._params, self._dense_pool, self._arenas,
                         self._jnp.asarray(tables),
                         self._jnp.asarray(active),
-                        self._jnp.asarray(cursor))
+                        self._jnp.asarray(lengths))
                 self._dense_pool = tuple(dense)
                 self._arenas = tuple(arenas)
                 now = time.monotonic()

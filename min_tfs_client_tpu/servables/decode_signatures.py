@@ -1,0 +1,543 @@
+"""The decode-session wire surface, once, for any decode model.
+
+    decode_init:         session_id + input_ids -> prefill; the session's
+                         device state is parked under the session id
+    decode_init_prefix:  decode_init plus prefix_ids — a FORCED decoder
+                         prefix (continuation / forced decoding): the
+                         session resumes as if it had already emitted
+                         those tokens
+    decode_step:         session_id -> one token per call (one token
+                         crosses the wire each way); the optional
+                         step_ordinal makes a resend at-most-once
+    decode_close:        session_id -> free the session's device state
+
+Host signatures: the bookkeeping is Python, the math is jitted.
+
+What a model supplies is ONE object, `DecodeModel`: a model file builds
+it and calls `build_session_signatures`; nothing under servables/ knows
+which model it serves. Where a prefilled state is parked and who advances
+it is the one seam (`_Backend`), with two implementations:
+
+  per session   the state tree sits in a DecodeSessionStore and each step
+                is its own device dispatch (buffers donated, the caches
+                update in place);
+  pooled        `continuous_batching=True`: the state sits in a slot of a
+                pool (decode_sessions.SlotPool, or PagedSlotPool when
+                `paging.block_size` > 0) and concurrent decode_step
+                requests coalesce into ONE device tick through a
+                TickBatcher — K stepping sessions cost one dispatch per
+                token instead of K. Sessions are then single-sequence.
+
+Arrows: models/* -> this module -> decode_sessions -> ops/attention.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+from typing import Callable, NamedTuple, Optional
+
+import numpy as np
+
+from min_tfs_client_tpu.servables.decode_sessions import (
+    PREFILL_PENDING,
+    DecodeSessionStore,
+    PagedSlotPool,
+    Paging,
+    SlotPool,
+    StepDeduper,
+    TickBatcher,
+    read_step_ordinal,
+)
+from min_tfs_client_tpu.servables.servable import (
+    Signature,
+    TensorSpec,
+    fetch_outputs,
+)
+from min_tfs_client_tpu.utils.status import ServingError
+
+# The per-example sampling inputs a session may be opened with: wire
+# dtype, and the value the warm-up sends. A model names the ones its
+# prefill takes (`DecodeModel.sampling_inputs`), in this order.
+SAMPLING_INPUTS = {"temperature": (np.float32, 0.0),
+                   "seed": (np.int32, 0),
+                   "top_p": (np.float32, 1.0)}
+
+
+@dataclasses.dataclass(frozen=True)
+class DecodeModel:
+    """What a decode model implements to be served as sessions — all of
+    it. Every callable is pure and traced under jit; `params` rides as a
+    jit ARGUMENT (a closed-over tree would be re-baked into each
+    executable as constants).
+
+    name              stem of the metric and compile-ledger labels:
+                      "<name>" (per-session store), "<name>-pooled",
+                      "<name>-paged".
+    prefill           (params, input_ids (B, seq_len), *sampling
+                      [, prefix_ids (B, max_decode_len)]) -> state: the
+                      device state one session carries between steps, a
+                      dict tree that holds at least "finished" (B,) bool.
+                      `sampling` are the arrays named by
+                      `sampling_inputs`, each (B,). With the trailing
+                      `prefix_ids` (pad-suffixed, one shared length) the
+                      prefill also runs the decoder over the forced
+                      prefix, monolithically; each arity is its own trace.
+    step              (params, state) -> (state', token (B,)): advance one
+                      token. The per-session and dense-pool backends run
+                      it (the dense pool under vmap over its slots).
+    paged_step        the paging-aware step contract of
+                      decode_sessions.PagedSlotPool: an object with
+                      decode(params, tree, kv) -> (tree', kv', outputs)
+                      and prefill_chunk(params, tree, kv, tokens,
+                      chunk_lens, next_tokens) -> (tree', kv'); `tree` is
+                      the prefill's state slot-batched with the paged
+                      leaves None, `kv` an ops/attention.PagedKV keyed by
+                      those leaves' pytree paths, `outputs` holds "token"
+                      and "finished", both (slots, 1). Token for token it
+                      must say what `step` says.
+    paged_axis_fn     (pytree path of a state leaf) -> the axis along
+                      which that leaf is a KV cache (one row a token,
+                      append-only), or None for a leaf that stays dense.
+    decoder_start_id  the decoder input at position 0.
+    pad_id            pads prompts and prefixes; a finished row emits it.
+    sampling_inputs   names from SAMPLING_INPUTS, () for greedy.
+    """
+
+    name: str
+    prefill: Callable
+    step: Callable
+    paged_step: object
+    paged_axis_fn: Callable[[tuple], Optional[int]]
+    decoder_start_id: int
+    pad_id: int
+    sampling_inputs: tuple = ()
+
+
+class _Step:
+    """One decode_step under the at-most-once guard — the ONE place the
+    StepDeduper dance lives. Built from the request: reads the session
+    id and the optional step_ordinal and asks the guard BEFORE any store
+    lookup (a duplicate resend of the final step must replay from cache
+    even after exhaustion closed the session). `out` is then the cached
+    answer, or None with the ordinal marked in flight; in the latter
+    case the backend advances the session inside `with step:` and calls
+    `step.answer(...)`. Leaving the block commits the answer, or, on an
+    exception, abandons the mark: the failed attempt produced no
+    response, so a retry of this ordinal executes instead of waiting on
+    a commit that will never come.
+
+    A context manager and not a wrapper function on purpose: the
+    backend's step function calls the device round itself, so no frame
+    of this module lies between the two (PERF.md §6, PR 29: how deep the
+    Python stack is where a tick program is first traced moves its
+    lowering time by a second a program)."""
+
+    __slots__ = ("_dedup", "sid", "_ordinal", "out", "_replayed")
+
+    def __init__(self, dedup: StepDeduper, inputs):
+        self._dedup = dedup
+        self.sid = _session_id(inputs)
+        self._ordinal = read_step_ordinal(inputs)
+        self.out = dedup.replay(self.sid, self._ordinal)
+        self._replayed = self.out is not None
+
+    def answer(self, token, finished, host_step: int) -> None:
+        """The step's wire answer: `token` and `finished` (B,), int32."""
+        self.out = {"token": token, "finished": finished,
+                    "step": np.asarray(host_step, np.int32)}
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        if self._replayed:
+            return False
+        if exc_type is None:
+            self._dedup.commit(self.sid, self._ordinal, self.out)
+        else:
+            self._dedup.abandon(self.sid, self._ordinal)
+        return False
+
+
+class _Backend(NamedTuple):
+    """Where a prefilled state is parked and who advances it.
+
+    admit(sid, args, forced)  prefill `args` (params, input_ids,
+        *sampling) and park the state under `sid`; `forced` is None or
+        (prefix_ids, prefix_len).
+    advance(inputs) -> outputs  decode_step itself: one token under a
+        `_Step`; at max_decode_len the session ends and its state is
+        freed.
+    release(sid) -> bool  free the state; False when there was none.
+
+    `store` and `kv_pool` (the paged pool, else None) are what the loader
+    re-labels with the real model:version; `dedup` is the guard the
+    inits and the close forget a session in.
+    """
+
+    admit: Callable
+    advance: Callable
+    release: Callable
+    store: DecodeSessionStore
+    dedup: StepDeduper
+    kv_pool: Optional[PagedSlotPool] = None
+
+
+def _deduper(store: DecodeSessionStore, max_sessions: int) -> StepDeduper:
+    # is_live = the store's membership test: a LIVE session's guard is
+    # never LRU-evicted (only closed/exhausted/expired entries shed).
+    return StepDeduper(max_entries=max(2 * max_sessions, 64),
+                       is_live=store.__contains__)
+
+
+def _per_session_backend(params, model: DecodeModel, prefill_jit, *,
+                         max_sessions: int, session_ttl_s: float,
+                         max_decode_len: int) -> _Backend:
+    import jax
+
+    from min_tfs_client_tpu.observability import runtime as rt
+
+    store = DecodeSessionStore(max_sessions=max_sessions,
+                               ttl_s=session_ttl_s, metric_label=model.name)
+    dedup = _deduper(store, max_sessions)
+    step_jit = rt.instrument_jit(
+        f"{model.name}:decode:step",
+        jax.jit(model.step, donate_argnums=(1,)))
+
+    def admit(sid, args, forced):
+        if forced is None:
+            store.put(sid, (prefill_jit(*args), 0))
+            return
+        # Monolithic prefill: prompt encode + the decoder run over the
+        # whole forced prefix in one pass; the host-side step mirror
+        # starts at its length, so the session decodes max_decode_len -
+        # prefix_len further tokens.
+        prefix, prefix_len = forced
+        store.put(sid, (prefill_jit(*args, jax.device_put(prefix)),
+                        prefix_len))
+
+    def step_fn(inputs):
+        with _Step(dedup, inputs) as step:
+            if step.out is None:
+                state, host_step = store.take(step.sid)
+                state, token = step_jit(params, state)
+                host_step += 1
+                if host_step < max_decode_len:
+                    store.put(step.sid, (state, host_step))
+                else:
+                    store.close(step.sid)  # cache exhausted: session ends
+                # One overlapped fetch: the step's whole wire cost is one
+                # token row (+ the finished flags) each way.
+                fetched = fetch_outputs(
+                    {"token": token, "finished": state["finished"]})
+                step.answer(fetched["token"],
+                            fetched["finished"].astype(np.int32), host_step)
+        return step.out
+
+    return _Backend(admit, step_fn, store.close, store, dedup)
+
+
+def _pooled_backend(params, model: DecodeModel, prefill_jit, *,
+                    seq_len: int, max_slots: int, session_ttl_s: float,
+                    max_decode_len: int, paging: Paging) -> _Backend:
+    import jax
+    import jax.numpy as jnp
+
+    from min_tfs_client_tpu.observability import tracing
+
+    template = jax.eval_shape(
+        model.prefill, params,
+        jax.ShapeDtypeStruct((1, seq_len), jnp.int32),
+        *(jax.ShapeDtypeStruct((1,), SAMPLING_INPUTS[name][0])
+          for name in model.sampling_inputs))
+    paged = bool(paging.block_size)
+    if paged:
+        pool = PagedSlotPool(
+            template, max_slots=max_slots, params=params, paging=paging,
+            paged_step=model.paged_step, paged_axis_fn=model.paged_axis_fn,
+            metric_label=f"{model.name}-paged")
+    else:
+        def one_step(p, state):
+            new_state, token = model.step(p, state)
+            return new_state, {"token": token,
+                               "finished": new_state["finished"]}
+
+        pool = SlotPool(template, one_step, max_slots=max_slots,
+                        params=params, metric_label=f"{model.name}-pooled")
+    # cost_fn: each delivered step charges its session's pages-held onto
+    # the CALLER's trace (pages x ticks, the paged pool's HBM-residency
+    # cost unit; None on the dense pool).
+    batcher = TickBatcher(pool.tick, cost_fn=pool.step_cost)
+    store = DecodeSessionStore(
+        max_sessions=max_slots, ttl_s=session_ttl_s,
+        metric_label=f"{model.name}-pooled",
+        on_evict=lambda entry: pool.release_slot(entry[0]))
+    dedup = _deduper(store, max_slots)
+
+    def admit(sid, args, forced):
+        chunked, start = {}, 0
+        if forced is not None:
+            prefix, start = forced
+            if paged:
+                # Encoder-only prefill; the forced prefix streams through
+                # the ragged kernel in chunks, interleaved with other
+                # sessions' ticks.
+                tokens = prefix[0][:start]
+                chunked = {
+                    "prefill_inputs": np.concatenate(
+                        [np.asarray([model.decoder_start_id], np.int32),
+                         tokens[:-1]]),
+                    "prefill_next": int(tokens[-1])}
+            else:
+                # Dense slot pool: one monolithic prefill.
+                args += (jax.device_put(prefix),)
+        # The prefill takes the device, and the pool write the pool's
+        # lock, against the ticks of the sessions that are stepping.
+        with tracing.span("decode/init", tokens=int(args[1].shape[1])):
+            state = prefill_jit(*args)
+            slot = pool.acquire_slot()
+            try:
+                pool.write(state, slot, session_key=sid, **chunked)
+                store.put(sid, (slot, start))
+            except Exception:
+                pool.release_slot(slot)
+                raise
+
+    def step_fn(inputs):
+        with _Step(dedup, inputs) as step:
+            if step.out is None:
+                sid = step.sid
+                slot, host_step = store.take(sid)
+                try:
+                    row = batcher.step(slot)
+                    while row is PREFILL_PENDING:
+                        # The slot is mid-prefix: each batcher round
+                        # streamed one chunk; re-entering lets tick-mates'
+                        # decode steps (and other prefills) interleave
+                        # until this session's first real token arrives.
+                        row = batcher.step(slot)
+                except Exception:
+                    # The pool row may be in an undefined state; retire
+                    # the slot rather than hand it to a future session
+                    # mid-generation.
+                    pool.release_slot(slot)
+                    raise
+                if isinstance(row, Exception):
+                    # Per-slot failure from the paged pool's tick (typed
+                    # capacity errors, eviction under kv_evict_policy=
+                    # close). slot_fatal distinguishes a dead session from
+                    # a capacity REFUSAL whose state is intact and may
+                    # retry after others close.
+                    if getattr(row, "slot_fatal", True):
+                        pool.release_slot(slot)
+                    else:
+                        store.put(sid, (slot, host_step))
+                    raise row
+                host_step += 1
+                if host_step < max_decode_len:
+                    store.put(sid, (slot, host_step))
+                else:
+                    pool.release_slot(slot)  # cache exhausted: session ends
+                step.answer(row["token"].reshape(-1),
+                            row["finished"].reshape(-1).astype(np.int32),
+                            host_step)
+        return step.out
+
+    # release: the store's on_evict hands the slot back to the pool.
+    return _Backend(admit, step_fn, store.close, store, dedup,
+                    pool if paged else None)
+
+
+def build_session_signatures(params, model: DecodeModel, *, seq_len: int,
+                             max_decode_len: int, max_sessions: int = 64,
+                             session_ttl_s: float = 600.0,
+                             continuous_batching: bool = False,
+                             paging: Optional[Paging] = None) -> dict:
+    """The four decode-session signatures of `model` (module docstring).
+    `paging` decides the pooled backend's pool: None takes the loader's
+    scope (decode_sessions.Paging.resolve); block_size 0 is the dense
+    slot pool. Without `continuous_batching` it is not consulted."""
+    import jax
+
+    from min_tfs_client_tpu.observability import runtime as rt
+
+    pooled = bool(continuous_batching)
+    prefill_jit = rt.instrument_jit(
+        f"{model.name}:{'pooled' if pooled else 'decode'}:prefill",
+        jax.jit(model.prefill))
+    if pooled:
+        backend = _pooled_backend(
+            params, model, prefill_jit, seq_len=seq_len,
+            max_slots=max_sessions, session_ttl_s=session_ttl_s,
+            max_decode_len=max_decode_len,
+            paging=paging if paging is not None else Paging.resolve())
+    else:
+        backend = _per_session_backend(
+            params, model, prefill_jit, max_sessions=max_sessions,
+            session_ttl_s=session_ttl_s, max_decode_len=max_decode_len)
+    store, dedup, step_fn = backend.store, backend.dedup, backend.advance
+    sampling = tuple((name, SAMPLING_INPUTS[name][0])
+                     for name in model.sampling_inputs)
+
+    def read_sampling(inputs, batch):
+        out = ()
+        for name, dtype in sampling:
+            arr = np.asarray(inputs[name], dtype).reshape(-1)
+            if arr.shape != (batch,):
+                raise ServingError.invalid_argument(
+                    f"{name} must have {batch} elements (one per "
+                    f"input_ids row); got {arr.shape[0]}")
+            out += (jax.device_put(arr),)
+        return out
+
+    def init(inputs, with_prefix):
+        sid = _session_id(inputs)
+        # A re-init over a previously-used id is a NEW stream: drop any
+        # surviving dedup entry (it deliberately outlives exhaustion, so
+        # only close/init may clear it) or its first ordinal-guarded step
+        # would be judged against, or replayed from, the dead stream's.
+        dedup.forget(sid)
+        ids = np.asarray(inputs["input_ids"]).astype(np.int32)
+        batch = ids.shape[0]
+        if batch != 1 and (pooled or with_prefix):
+            kind = ("continuous-batching decode" if pooled
+                    else "decode_init_prefix")
+            raise ServingError.invalid_argument(
+                f"{kind} sessions are single-sequence: "
+                f"input_ids batch must be 1, got {batch}")
+        forced = _read_prefix(inputs, model.pad_id) if with_prefix else None
+        args = (params, jax.device_put(ids)) + read_sampling(inputs, batch)
+        backend.admit(sid, args, forced)
+        out = {"session_id": np.asarray(sid, object),
+               "batch": np.asarray(batch, np.int32)}
+        if with_prefix:
+            out["prefix_len"] = np.asarray(forced[1], np.int32)
+        return out
+
+    def init_fn(inputs):
+        return init(inputs, False)
+
+    def init_prefix_fn(inputs):
+        return init(inputs, True)
+
+    def close_fn(inputs):
+        sid = _session_id(inputs)
+        dedup.forget(sid)
+        return {"closed": np.asarray(int(backend.release(sid)), np.int32)}
+
+    session_spec = TensorSpec("DT_STRING", ())
+    init_inputs = {"session_id": session_spec,
+                   "input_ids": TensorSpec(np.int32, (None, seq_len)),
+                   **{name: TensorSpec(dtype, (None,))
+                      for name, dtype in sampling}}
+    init_outputs = {"session_id": TensorSpec("DT_STRING", ()),
+                    "batch": TensorSpec(np.int32, ())}
+    signatures = {
+        "decode_init": Signature(
+            fn=init_fn, inputs=init_inputs, outputs=init_outputs,
+            on_host=True, batched=False),
+        "decode_init_prefix": Signature(
+            fn=init_prefix_fn,
+            inputs={**init_inputs,
+                    "prefix_ids": TensorSpec(np.int32,
+                                             (None, max_decode_len))},
+            outputs={**init_outputs,
+                     "prefix_len": TensorSpec(np.int32, ())},
+            on_host=True, batched=False),
+        "decode_step": Signature(
+            fn=step_fn,
+            inputs={"session_id": session_spec},
+            # step_ordinal is the OPTIONAL at-most-once guard: absent =
+            # historical wire behavior byte-for-byte (docs/ROBUSTNESS.md
+            # "Retry & idempotency").
+            optional_inputs={"step_ordinal": TensorSpec(np.int64, ())},
+            outputs={"token": TensorSpec(np.int32, (None,)),
+                     "finished": TensorSpec(np.int32, (None,)),
+                     "step": TensorSpec(np.int32, ())},
+            on_host=True, batched=False),
+        "decode_close": Signature(
+            fn=close_fn,
+            inputs={"session_id": session_spec},
+            outputs={"closed": TensorSpec(np.int32, ())},
+            on_host=True, batched=False),
+    }
+    signatures["decode_init"].warmup_fn = _session_warmup_fn(
+        init_fn, init_prefix_fn, step_fn, close_fn, seq_len=seq_len,
+        max_decode_len=max_decode_len, model=model)
+    # The loader re-labels the store's gauge (and a paged pool's) with the
+    # real model:version (platforms.make_loader): the builder cannot know it.
+    for sig in signatures.values():
+        sig._decode_store = store
+        if backend.kv_pool is not None:
+            sig._kv_pool = backend.kv_pool
+    return signatures
+
+
+def _session_id(inputs) -> bytes:
+    raw = np.asarray(inputs["session_id"]).reshape(-1)
+    if raw.size != 1:
+        raise ServingError.invalid_argument(
+            f"session_id must hold exactly one id, got {raw.size}")
+    value = raw[0]
+    return value if isinstance(value, bytes) else str(value).encode()
+
+
+def _read_prefix(inputs, pad_id: int):
+    """decode_init_prefix's prefix_ids: (1, max_decode_len) int32, real
+    tokens then pad — returns (array, true length). Single-sequence: the
+    session state carries ONE step scalar, so a multi-row prefix init
+    would need per-row lengths it cannot represent."""
+    pre = np.asarray(inputs["prefix_ids"]).astype(np.int32)
+    if pre.ndim != 2 or pre.shape[0] != 1:
+        raise ServingError.invalid_argument(
+            "prefix_ids must be a single-sequence (1, max_decode_len) "
+            f"tensor; got shape {pre.shape}")
+    row = pre[0]
+    pads = np.flatnonzero(row == pad_id)
+    plen = int(pads[0]) if pads.size else int(row.shape[0])
+    if plen == 0:
+        raise ServingError.invalid_argument(
+            "prefix_ids holds no tokens (row starts with pad)")
+    if plen >= row.shape[0]:
+        # A full-width prefix leaves zero decode budget — and the first
+        # step would write K/V at max_decode_len, which the cache write
+        # CLAMPS to the last row, silently corrupting the prefix.
+        raise ServingError.invalid_argument(
+            f"prefix_ids fills the entire max_decode_len budget "
+            f"({row.shape[0]}); at least one position must remain to "
+            "decode")
+    if pads.size and not (row[plen:] == pad_id).all():
+        raise ServingError.invalid_argument(
+            "prefix_ids must be real tokens followed only by pad "
+            f"(pad_id {pad_id}); found tokens after position "
+            f"{plen}")
+    return pre, plen
+
+
+def _session_warmup_fn(init_fn, init_prefix_fn, step_fn, close_fn, *,
+                       seq_len: int, max_decode_len: int,
+                       model: DecodeModel):
+    """Prime the prefill and step/tick executables with two throwaway
+    sessions, so that the first real decode_init, decode_init_prefix or
+    decode_step never compiles (synthesize_warmup calls this through the
+    warmup_fn hook). The second opens with a one-token forced prefix:
+    the prefix-arity monolithic prefill on the per-session store and the
+    dense pool, the chunked-prefill program on the paged pool."""
+    prefix = np.full((1, max_decode_len), model.pad_id, np.int32)
+    prefix[0, 0] = 1 if model.pad_id != 1 else 2
+
+    def _warm():
+        for sid, init, extra in (
+                (b"__warmup__", init_fn, {}),
+                (b"__warmup_prefix__", init_prefix_fn,
+                 {"prefix_ids": prefix})):
+            session = {"session_id": np.asarray(sid, object)}
+            init({**session,
+                  "input_ids": np.zeros((1, seq_len), np.int32),
+                  **{name: np.full((1,), SAMPLING_INPUTS[name][1],
+                                   SAMPLING_INPUTS[name][0])
+                     for name in model.sampling_inputs},
+                  **extra})
+            step_fn(session)
+            close_fn(session)
+    return _warm

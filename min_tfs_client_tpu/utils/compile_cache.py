@@ -1,8 +1,8 @@
 """Where XLA's persistent compilation cache lives.
 
 Every process that compiles for the device calls `configure()` before its
-first jit: the server CLI, the `tpu://` boot, `bench.py`'s child,
-`chip_smoke.py`'s children and the `tests/tpu` driver. A cache that moves
+first jit: the server CLI, the `tpu://` boot, `chip_smoke.py`'s
+children and the `tests/tpu` driver. A cache that moves
 between runs never hits, so the directory is decided in one place:
 
  * `JAX_COMPILATION_CACHE_DIR` set — JAX reads the variable itself; code

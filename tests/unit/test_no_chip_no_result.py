@@ -1,6 +1,6 @@
 """Where JAX_PLATFORMS excludes the TPU (this test process's environment,
-the tier-1 command), the two chip entry points say so, exit non-zero, and
-print no result — neither falls back to another backend."""
+the tier-1 command), the chip entry point says so, exits non-zero, and
+prints no result — it does not fall back to another backend."""
 
 import os
 import pathlib
@@ -12,7 +12,7 @@ import pytest
 REPO = pathlib.Path(__file__).resolve().parents[2]
 
 
-@pytest.mark.parametrize("script", ["chip_smoke.py", "bench.py"])
+@pytest.mark.parametrize("script", ["chip_smoke.py"])
 def test_entry_point_refuses_to_run_without_a_chip(script):
     res = subprocess.run(
         [sys.executable, str(REPO / script)], capture_output=True, text=True,

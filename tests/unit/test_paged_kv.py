@@ -22,8 +22,8 @@ import pytest
 from min_tfs_client_tpu.models import t5
 from min_tfs_client_tpu.servables.decode_sessions import (
     PageAllocator,
-    default_paging,
-    set_default_paging,
+    Paging,
+    paging_scope,
 )
 from min_tfs_client_tpu.utils.status import ServingError
 
@@ -116,18 +116,15 @@ class TestPagedTokenExactness:
         got = _run(paged, _sid("p"), ids)
         assert got == want
 
-    @pytest.mark.parametrize("contract", [True, False])
-    def test_streams_match_dense_pool_at_lane_dense_rows(self, contract):
+    def test_streams_match_dense_pool_at_lane_dense_rows(self):
         """H * d_kv = 128, a whole lane tile a token row (T5's own head
-        size, 64): the paged stream, through the step contract and
-        through the pool's generic gather/scatter tick, is the dense
-        pool's token for token."""
+        size, 64): the paged stream is the dense pool's token for
+        token."""
         config = t5.T5Config.tiny(d_kv=64, num_heads=2, d_model=32)
         lane_model = (config, t5.init_params(jax.random.PRNGKey(3), config))
         ids = _prompt(config, np.random.default_rng(4))
         want = _run(_sigs(lane_model), _sid("ld"), ids)
-        paged = _sigs(lane_model, kv_block_size=3,
-                      kv_use_step_contract=contract)
+        paged = _sigs(lane_model, kv_block_size=3)
         pool = paged["decode_init"]._kv_pool
         assert pool._arenas[0].shape == (pool.num_blocks + 1, 3, 128)
         assert _run(paged, _sid("lp"), ids) == want
@@ -350,8 +347,6 @@ class TestEviction:
         stats = pool.stats()
         assert stats["evicted_swap"] > 0
         assert stats["restored"] == stats["evicted_swap"]
-        # The satellite bar: this mid-stream swap/restore exactness ran
-        # THROUGH the paged step contract, not the dense-gather fallback.
         assert stats["step_contract"] is True
 
     def test_swap_out_and_restore_carry_the_pages_bitwise(self, model):
@@ -445,15 +440,13 @@ class TestEviction:
 
 class TestServerSurface:
     def test_module_paging_defaults_scope(self):
-        prev = set_default_paging(block_size=4, num_blocks=7,
-                                  evict_policy="close", prefill_chunk=6)
-        try:
-            assert default_paging() == {"block_size": 4, "num_blocks": 7,
-                                        "evict_policy": "close",
-                                        "prefill_chunk": 6}
-        finally:
-            set_default_paging(**prev)
-        assert default_paging()["block_size"] == 0
+        with paging_scope(block_size=4, num_blocks=7,
+                          evict_policy="close", prefill_chunk=6):
+            assert Paging.resolve() == Paging(4, 7, "close", 6)
+            # An explicit knob wins; the others still come from the scope.
+            assert Paging.resolve(block_size=0) == Paging(0, 7, "close", 6)
+        assert Paging.resolve() == Paging()
+        assert Paging().block_size == 0
 
     def test_paging_scope_isolates_concurrent_loads(self):
         """Regression (review): a process-global set/restore pair races
@@ -462,10 +455,6 @@ class TestServerSurface:
         factory observes a configured load's scope and silently builds a
         paged pool. The thread-local paging_scope gives every factory
         exactly its own knobs."""
-        from min_tfs_client_tpu.servables.decode_sessions import (
-            paging_scope,
-        )
-
         seen = []
         errors = []
         start = threading.Barrier(5)
@@ -476,8 +465,8 @@ class TestServerSurface:
                 with paging_scope(block_size=block_size, num_blocks=7):
                     # The "factory": reads the knobs a builder would.
                     for _ in range(50):
-                        got = default_paging()
-                        assert got["block_size"] == block_size, got
+                        got = Paging.resolve()
+                        assert got.block_size == block_size, got
                     seen.append(block_size)
             except Exception as exc:  # noqa: BLE001
                 errors.append(exc)
@@ -488,8 +477,8 @@ class TestServerSurface:
             try:
                 start.wait(5)
                 for _ in range(200):
-                    got = default_paging()
-                    assert got["block_size"] == 0, got
+                    got = Paging.resolve()
+                    assert got.block_size == 0, got
                 seen.append(0)
             except Exception as exc:  # noqa: BLE001
                 errors.append(exc)
@@ -503,19 +492,20 @@ class TestServerSurface:
             t.join()
         assert not errors, errors
         assert sorted(seen) == [0, 2, 4, 8, 16]
-        assert default_paging()["block_size"] == 0  # no scope leaked
+        assert Paging.resolve().block_size == 0  # no scope leaked
 
     def test_bad_evict_policy_rejected(self):
         with pytest.raises(ServingError) as err:
-            set_default_paging(block_size=2, evict_policy="lru")
+            with paging_scope(block_size=2, evict_policy="lru"):
+                pass
         assert err.value.code == 3  # INVALID_ARGUMENT
+        with pytest.raises(ServingError) as err:
+            Paging.resolve(block_size=2, evict_policy="lru")
+        assert err.value.code == 3
 
     def test_builder_consults_module_defaults(self, model):
-        prev = set_default_paging(block_size=2, num_blocks=6)
-        try:
+        with paging_scope(block_size=2, num_blocks=6):
             sigs = _sigs(model)
-        finally:
-            set_default_paging(**prev)
         pool = getattr(sigs["decode_init"], "_kv_pool", None)
         assert pool is not None
         assert pool.block_size == 2 and pool.num_blocks == 6
@@ -572,21 +562,23 @@ class TestServerSurface:
 class TestStepContract:
     """ISSUE 11 tentpole: the pooled tick drives the ragged paged path
     through the model's paging-aware step contract — no dense
-    materialization — with the dense-gather tick as the byte-for-byte
-    fallback for models that don't declare it."""
+    materialization. Since ISSUE 29 it is the paged pool's only decode
+    program."""
 
     def test_contract_on_by_default_and_fallback_matches(self, model):
+        """The contract's streams against the dense pool's (the
+        dense-gather fallback it was once compared with is gone);
+        `step_contract` stays in the monitoring payload, always True."""
         config, _ = model
         rng = np.random.default_rng(20)
         prompts = [_prompt(config, rng) for _ in range(3)]
         direct = _sigs(model, kv_block_size=3)
         assert direct["decode_init"]._kv_pool.stats()["step_contract"] \
             is True
-        fallback = _sigs(model, kv_block_size=3, kv_use_step_contract=False)
-        assert fallback["decode_init"]._kv_pool.stats()["step_contract"] \
-            is False
+        dense = _sigs(model)
+        assert not hasattr(dense["decode_init"], "_kv_pool")
         for i, ids in enumerate(prompts):
-            want = _run(fallback, _sid(f"fb-{i}"), ids)
+            want = _run(dense, _sid(f"fb-{i}"), ids)
             got = _run(direct, _sid(f"dc-{i}"), ids)
             assert got == want
 
@@ -615,9 +607,9 @@ class TestStepContract:
         assert got == want
 
     def test_gather_bytes_scale_with_used_tokens(self, model):
-        """THE bandwidth bar, asserted: the direct tick's KV reads are
-        the pages live sessions own; the fallback materializes
-        slots x table-width. At low occupancy direct << fallback."""
+        """THE bandwidth bar, asserted: the tick's KV reads are the
+        pages live sessions own, not slots x table-width (what a dense
+        gather of the whole table would materialize)."""
         config, _ = model
         ids = _prompt(config, np.random.default_rng(21))
         sigs = _sigs(model, kv_block_size=2, max_sessions=8)
@@ -630,12 +622,11 @@ class TestStepContract:
             pages_held = -(-(step + 1) // pool.block_size)
             assert stats["kv_gather_bytes_per_tick"] == \
                 pool.page_bytes * pages_held
-        # The dense-gather fallback on the same tick shape reads the
-        # whole (slots, width) table; the direct path read 1 session's
-        # 2 pages of it.
-        fallback_bytes = pool.page_bytes * pool.max_slots * \
+        # The whole (slots, width) table on the same tick shape; the
+        # tick read 1 session's 2 pages of it.
+        table_bytes = pool.page_bytes * pool.max_slots * \
             pool.stats()["table_width"]
-        assert stats["kv_gather_bytes_per_tick"] * 4 <= fallback_bytes
+        assert stats["kv_gather_bytes_per_tick"] * 4 <= table_bytes
         from min_tfs_client_tpu.server import metrics
 
         assert metrics.kv_gather_bytes_per_tick.value("t5-paged") == \
@@ -842,17 +833,6 @@ class TestChunkedPrefill:
         unpooled = _sigs(model, continuous_batching=False)
         got = self._run_prefix(unpooled, "up-u", ids, pre, MAXDEC - 4)
         assert got == want
-
-    def test_prefix_on_contractless_paged_pool_is_typed(self, model):
-        config, _ = model
-        rng = np.random.default_rng(28)
-        ids, pre = _prompt(config, rng), self._prefix(config, rng, 4)
-        sigs = _sigs(model, kv_block_size=2, kv_use_step_contract=False)
-        with pytest.raises(ServingError) as err:
-            sigs["decode_init_prefix"].run(
-                {"session_id": _sid("nc"), "input_ids": ids,
-                 "prefix_ids": pre})
-        assert err.value.code == 12  # UNIMPLEMENTED, never INTERNAL
 
     def test_bad_prefixes_rejected(self, model):
         config, _ = model

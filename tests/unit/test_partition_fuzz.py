@@ -195,12 +195,21 @@ def test_fuzz_corpus_actually_covers_multi_segment():
 
 @pytest.mark.parametrize("seed", range(12))
 def test_pipelined_matches_serial_on_random_graphs(seed):
-    """ISSUE 5 oracle variant: the microbatch software pipeline must be
-    bit-identical to the serial partition path on every random DAG — for
+    """ISSUE 5 oracle variant: the microbatch software pipeline must
+    serve what the serial partition path serves on every random DAG — for
     multi-segment graphs it actually pipelines, for single-segment or
-    declined shapes it must fall through to serial untouched. The serial
-    results themselves are already oracle-checked against the all-host
-    interpreter above, so array_equal here closes the full chain."""
+    declined shapes it must fall through to serial untouched.
+
+    Integer and string outputs are held to array_equal: a dropped,
+    doubled or reordered row shows there. Float outputs are held to the
+    tolerance the serial path itself is held to against the all-host
+    interpreter above, NOT to bit-identity: a microbatch pads to a
+    smaller bucket than the whole batch, so its interiors run a program
+    compiled at another batch size, and XLA's CPU matmul rounds the same
+    row differently at another size (read 1.9e-6; the pipelined rows are
+    bit-identical to the same rows served serially chunk by chunk, so
+    the pipeline itself adds nothing). Bit-identity across bucket sizes
+    was the wrong oracle."""
     rng = np.random.default_rng(seed)
     gd, tables, fetches = _build_random_graph(rng)
     part = try_partition(gd, ["x:0"], fetches,
@@ -223,6 +232,8 @@ def test_pipelined_matches_serial_on_random_graphs(seed):
                 if w.dtype.kind in "OSU":
                     np.testing.assert_array_equal(g.astype(object),
                                                   w.astype(object))
+                elif w.dtype.kind == "f":
+                    np.testing.assert_allclose(g, w, rtol=2e-4, atol=2e-5)
                 else:
                     np.testing.assert_array_equal(g, w)
 
@@ -230,7 +241,7 @@ def test_pipelined_matches_serial_on_random_graphs(seed):
 def test_pipelined_fuzz_corpus_actually_pipelines():
     """Coverage guard for the variant above: enough seeds must take the
     pipelined path for real (multi-segment, batch large enough, not
-    declined), or the bit-identical check silently collapses into
+    declined), or the check above silently collapses into
     serial-vs-serial."""
     pipelined = 0
     for seed in range(12):
