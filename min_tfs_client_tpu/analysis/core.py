@@ -91,7 +91,7 @@ DEFAULT_SPAN_EXEMPT = (
 # raised exceptions reach a wire status. Servicer classes and
 # `@_instrumented` handler methods are detected structurally; these are
 # the boundary entries structure can't see (router forwards + the tick
-# leader body that runs followers' steps).
+# batcher's step, which raises what the loop thread's tick raised).
 DEFAULT_BOUNDARY_FUNCTIONS = (
     "min_tfs_client_tpu/router/proxy.py::GrpcProxy._handle",
     "min_tfs_client_tpu/router/proxy.py::GrpcProxy._handle_routed",

@@ -98,7 +98,15 @@ def vector_from_trace(trace) -> dict:
     events = trace.costs or {}
     queue_wait_s = sum(stages.get(s, 0.0) for s in _QUEUE_STAGES)
     host_island_s = sum(stages.get(s, 0.0) for s in _HOST_ISLAND_STAGES)
-    decode_s = sum(stages.get(s, 0.0) for s in _DECODE_STAGES)
+    # A pooled decode round runs on the pool's loop thread, ahead of the
+    # requests; the request that collects one of its tokens carries the
+    # round's spans. It is billed the part of them inside its own
+    # envelope (the device time it waited through), so that no vector
+    # attributes more than its request's wall.
+    end = trace.end if trace.end is not None else float("inf")
+    decode_s = sum(max(0.0, min(t1, end) - max(t0, trace.start))
+                   for name, t0, t1, _ in list(trace.spans)
+                   if name in _DECODE_STAGES)
 
     total = meta.get("batch_size")
     bucket = meta.get("padding_bucket")

@@ -154,12 +154,15 @@ STAGES = (
     "pipeline/materialize",
     # Pooled decode sessions (servables/decode_sessions.py). On the
     # request that opens a session: its prefill and pool write. On every
-    # stepping request: the time until a tick's round took the step. On
-    # the round leader's trace, the loop's consecutive phases: from the
-    # previous round's delivery to this round's snapshot, the host work
-    # before the first transfer (one chunked-prefill round nested in
-    # it), the transfers and the ENQUEUE of the device program (not its
-    # run), the wait for its outputs, and handing each rider its row.
+    # stepping request: the time until the round that computes its
+    # token was snapshotted (nothing when the loop was ahead of it).
+    # Recorded by the tick loop's own thread, on the trace of the first
+    # request that collects a token of the round, the loop's phases:
+    # from the previous round's fetch to this round's snapshot, the
+    # host work before the first transfer (one chunked-prefill round
+    # nested in it), the transfers and the ENQUEUE of the device program
+    # (not its run), the wait for its outputs, and from there to the
+    # riders' wake-up, which comes after the next round's launch.
     "decode/init",
     "decode/wait",
     "decode/handoff",

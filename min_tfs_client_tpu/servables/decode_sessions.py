@@ -23,6 +23,7 @@ from __future__ import annotations
 import collections
 import contextlib
 import dataclasses
+import functools
 import math
 import threading
 import time
@@ -666,9 +667,9 @@ class SlotPool:
 
         of_round = of_round or TickRound(0, 0.0)
         ordinal, t_entry = of_round.ordinal, time.perf_counter()
-        # Pre-tick faultpoint: a delay stretches every tick-mate's step
-        # (the TickBatcher propagates one leader's fate to all riders),
-        # a typed error fails the whole tick loudly.
+        # Pre-tick faultpoint: a delay stretches every tick-mate's step,
+        # a typed error fails the whole tick loudly (the TickBatcher
+        # gives it to every rider of the round).
         faults.point("backend.tick.pre", slots=len(slots))
         t0 = time.perf_counter()
         with self._lock:
@@ -684,6 +685,7 @@ class SlotPool:
                 self._pool, outputs = self._tick_jit(
                     self._params, self._pool,
                     self._jax.numpy.asarray(active))
+            of_round.launched()
         with tracing.span("decode/fetch", round=ordinal):
             fetched = fetch_outputs(outputs)
         of_round.fetched = time.perf_counter()
@@ -793,8 +795,8 @@ def _plain_path(path) -> tuple:
 
 # Sentinel a paged tick returns for a slot still streaming its prefill
 # chunks: the session consumed a chunk round but has no token yet — the
-# caller re-enters the tick batcher (other sessions' decode steps ride the
-# rounds in between) until a real row arrives.
+# tick batcher keeps the slot due (other sessions' decode steps ride the
+# same rounds) until a real row arrives.
 PREFILL_PENDING = object()
 
 
@@ -981,6 +983,10 @@ class PagedSlotPool:
                           "prefill_chunks": 0}     # guarded_by: self._lock
         self._stats_lock = threading.Lock()
         self._stats_cache: dict = {}               # guarded_by: self._stats_lock
+        # The tick loop's own counters (TickBatcher.counters:
+        # decode_steps_ahead, decode_tokens_dropped), shown beside the
+        # pool's; whoever drives the pool through a batcher sets it.
+        self.loop_counters: Callable[[], dict] = dict
         # Pages held per slot at its most recent device round — the
         # per-step cost tap (step_cost). Its OWN cheap lock: a stepping
         # caller reading its page count must never queue behind the
@@ -1115,7 +1121,9 @@ class PagedSlotPool:
         discipline the /monitoring/runtime payload promises). Mutators
         publish via _publish_stats_locked."""
         with self._stats_lock:
-            return dict(self._stats_cache)
+            snap = dict(self._stats_cache)
+        snap.update(self.loop_counters())
+        return snap
 
     def _publish_stats_locked(self) -> None:
         """Called under self._lock at the end of every state-changing
@@ -1264,8 +1272,12 @@ class PagedSlotPool:
 
     # -- page management ------------------------------------------------------
 
-    def _alloc_page_locked(self, busy: tuple) -> int:
-        if self._policy == "refuse":
+    def _alloc_page_locked(self, busy: tuple, evict: bool = True) -> int:
+        """One page, evicting by the pool's policy when the arena is dry.
+        `evict` False (a step that runs ahead of its client,
+        `TickRound.asked`) refuses instead: no session loses its pages
+        to a token nobody has asked for yet."""
+        if self._policy == "refuse" or not evict:
             return self.allocator.alloc(1)[0]
         while True:
             pages = self.allocator.try_alloc(1)
@@ -1338,14 +1350,15 @@ class PagedSlotPool:
         self.allocator.free(pages)
         self._shrink_width_locked()
 
-    def _restore_locked(self, slot: int, busy: tuple) -> None:
+    def _restore_locked(self, slot: int, busy: tuple,
+                        evict: bool = True) -> None:
         from min_tfs_client_tpu.observability import runtime
 
         swap = self._swapped.pop(slot)
         pages: list[int] = []
         try:
             for _ in range(swap.n_pages):
-                pages.append(self._alloc_page_locked(busy))
+                pages.append(self._alloc_page_locked(busy, evict))
         except ServingError:
             if pages:
                 self.allocator.free(pages)
@@ -1387,10 +1400,12 @@ class PagedSlotPool:
         slots that could not run carry their TYPED error as the value
         (per-slot failure isolation — a capacity refusal for one session
         must not poison its tick-mates), and slots still mid-prefix carry
-        the PREFILL_PENDING sentinel (the caller re-enters the batcher so
-        tick-mates' decodes interleave with the remaining chunks).
-        `of_round` is the TickBatcher's (None for a direct call): the
-        phase spans carry its ordinal."""
+        the PREFILL_PENDING sentinel (the batcher keeps such a slot in
+        its rounds, so tick-mates' decodes interleave with the remaining
+        chunks). `of_round` is the TickBatcher's (None for a direct
+        call): the phase spans carry its ordinal, `asked` says which
+        slots may take another session's pages, and `launched()` is
+        called once the program is enqueued."""
         import numpy as np
 
         from min_tfs_client_tpu.robustness import faults
@@ -1401,7 +1416,7 @@ class PagedSlotPool:
         slots = list(slots)
         # Pre-tick faultpoint, OUTSIDE the pool lock: a delay models a
         # slow device round; a typed error fails the whole tick (the
-        # TickBatcher propagates it to every waiter).
+        # TickBatcher gives it to every rider of the round).
         faults.point("backend.tick.pre", slots=len(slots), paged=True)
         results: dict[int, object] = {}
         live: list[int] = []
@@ -1416,8 +1431,13 @@ class PagedSlotPool:
             if self._prefix:
                 with tracing.span("decode/prefill_chunk"):
                     chunk_errors = self._run_chunk_round_locked(
-                        requested=tuple(slots))
-            for s in slots:
+                        requested=tuple(slots), asks=of_round.asks)
+            # The steps that are asked for first: one of them may take
+            # the pages of a session that only runs ahead in this round
+            # (whose own step is then refused, and waits for its
+            # request), never the other way round.
+            asked = frozenset(s for s in slots if of_round.asks(s))
+            for s in sorted(slots, key=lambda s: s not in asked):
                 err = self._dead.get(s)
                 if err is not None:
                     err.slot_fatal = True
@@ -1434,7 +1454,8 @@ class PagedSlotPool:
                     results[s] = PREFILL_PENDING
                     continue
                 try:
-                    self._prepare_slot_locked(s, busy=tuple(slots))
+                    self._prepare_slot_locked(s, busy=asked,
+                                              evict=s in asked)
                 except ServingError as exc:
                     if not hasattr(exc, "slot_fatal"):
                         # Capacity refusal: the session's pages/state are
@@ -1473,6 +1494,9 @@ class PagedSlotPool:
                         self._jnp.asarray(tables),
                         self._jnp.asarray(active),
                         self._jnp.asarray(lengths))
+                # The program is enqueued: the riders of the round before
+                # may go (the bookkeeping below is the pool's own).
+                of_round.launched()
                 self._dense_pool = tuple(dense)
                 self._arenas = tuple(arenas)
                 now = time.monotonic()
@@ -1486,6 +1510,7 @@ class PagedSlotPool:
                 self._gather_bytes_last = gather_bytes
                 self._report_gather_bytes(gather_bytes)
             self._publish_stats_locked()
+        of_round.launched()  # a round that enqueued nothing
         if live:
             with tracing.span("decode/fetch", round=ordinal):
                 fetched = fetch_outputs(outputs)
@@ -1523,14 +1548,17 @@ class PagedSlotPool:
         except Exception:  # pragma: no cover - metrics must not break serving
             pass
 
-    def _run_chunk_round_locked(self, requested: tuple) -> dict:
+    def _run_chunk_round_locked(self, requested: tuple,
+                                asks=lambda slot: True) -> dict:
         """ONE chunked-prefill round: stream the next `prefill_chunk`
         forced-prefix positions for up to max_prefills_per_tick chunking
         slots (requested slots always ride — their callers are parked on
         this very round) through the contract's Sq>1 program. Bounded per
         tick so an init flood of long prefixes cannot stall in-flight
-        decodes; callers of still-chunking slots get PREFILL_PENDING and
-        re-enter, so chunks interleave with tick-mates' decode rounds.
+        decodes; still-chunking slots get PREFILL_PENDING and stay in the
+        rounds, so chunks interleave with tick-mates' decode steps.
+        `asks(slot)`: whether the slot's step is asked for
+        (TickRound.asks); the others take no page from another session.
         Returns {slot: ServingError} for REQUESTED slots whose chunk hit
         a capacity refusal (progress intact, caller retries)."""
         import numpy as np
@@ -1538,6 +1566,7 @@ class PagedSlotPool:
         errors: dict[int, ServingError] = {}
         urgent = [s for s in requested if s in self._prefix]
         order = urgent + [s for s in self._prefix if s not in set(urgent)]
+        order.sort(key=lambda s: not asks(s))  # as in tick: asked first
         # Only flushed sessions hold a block table; unflushed ones catch
         # the next round after their write-program flush.
         ready = [s for s in order
@@ -1545,7 +1574,7 @@ class PagedSlotPool:
         chosen = ready[:max(self._max_prefills, len(urgent))]
         if not chosen:
             return errors
-        busy = tuple(set(chosen) | set(requested))
+        busy = tuple(s for s in set(chosen) | set(requested) if asks(s))
         chunk = self.prefill_chunk
         tokens = np.zeros((self.max_slots, chunk), np.int32)
         chunk_lens = np.zeros((self.max_slots,), np.int32)
@@ -1555,13 +1584,15 @@ class PagedSlotPool:
         for s in chosen:
             pf = self._prefix[s]
             try:
+                evict = asks(s)
                 if s in self._swapped:
-                    self._restore_locked(s, busy)
+                    self._restore_locked(s, busy, evict)
                 inputs, done = pf["inputs"], pf["done"]
                 n = min(chunk, len(inputs) - done)
                 needed = -(-(done + n) // self.block_size)
                 while len(self._pages[s]) < needed:
-                    self._pages[s].append(self._alloc_page_locked(busy))
+                    self._pages[s].append(
+                        self._alloc_page_locked(busy, evict))
                 if needed > self._width:
                     grown = 1 << (needed - 1).bit_length()
                     self._width = min(self.pages_per_session, grown)
@@ -1621,92 +1652,176 @@ class PagedSlotPool:
         except Exception:  # pragma: no cover - metrics must not break serving
             pass
 
-    def _prepare_slot_locked(self, slot: int, busy: tuple) -> None:
+    def _prepare_slot_locked(self, slot: int, busy: tuple,
+                             evict: bool = True) -> None:
         if slot in self._swapped:
-            self._restore_locked(slot, busy)
+            self._restore_locked(slot, busy, evict)
         if slot not in self._pages:
-            exc = ServingError.failed_precondition(
+            raise _slot_fatal(
                 f"slot {slot} holds no parked session state (released or "
                 "never written)")
-            exc.slot_fatal = True
-            raise exc
         needed = -(-(self._tokens[slot] + 1) // self.block_size)
         if needed > self.pages_per_session:
-            exc = ServingError.failed_precondition(
+            raise _slot_fatal(
                 f"slot {slot} stepped past max_len {self.max_len}")
-            exc.slot_fatal = True
-            raise exc
         while len(self._pages[slot]) < needed:
-            self._pages[slot].append(self._alloc_page_locked(busy))
+            self._pages[slot].append(self._alloc_page_locked(busy, evict))
         if needed > self._width:
             grown = 1 << (needed - 1).bit_length()
             self._width = min(self.pages_per_session, grown)
 
 
 class TickRound:
-    """One round of the TickBatcher, as the pool's `tick` sees it: the
-    per-batcher ordinal that every phase span of the round carries (and
-    each rider's `decode/wait`, which ties a rider's trace to the
-    leader's; 0 for a direct call of `tick`), when the round snapshotted
-    its riders, and, back from the tick, when its fetch ended: where
-    `decode/deliver` starts."""
+    """One round of the TickBatcher, as the pool's `tick` sees it.
 
-    __slots__ = ("ordinal", "taken", "fetched")
+    ordinal   the per-batcher count that every phase span of the round
+              carries, and the `decode/wait` of each step whose token it
+              computes (0 for a direct call of `tick`).
+    taken     when the round snapshotted its slots.
+    asked     the slots of it that a `decode_step` was waiting for at
+              that snapshot. The others run AHEAD of their clients: the
+              pool takes no page from another session for them (a
+              refusal is tried again once the request is there). None,
+              a direct call: every slot is asked for.
+    fetched   set by the tick when its fetch ended: where
+              `decode/deliver` starts.
+    launched  the tick calls it once its program is enqueued (it takes
+              no lock). From then on a slot of the round may be released
+              and handed on, and the batcher wakes the riders of the
+              round before (their answers are serialised under this
+              round's program, not beside its launch). The batcher calls
+              it itself when a tick returns without having done so.
 
-    def __init__(self, ordinal: int, taken: float):
+    The round is also where the loop thread's spans go (a loop thread
+    has no request trace): it is the trace-like target active around
+    the tick, and a request that collects a token of the round moves
+    what is in `spans` onto its own trace (the first to come takes the
+    phases, and whoever comes after `decode/deliver` was written takes
+    that: each span is recorded once, as a leader's were).
+    """
+
+    __slots__ = ("ordinal", "taken", "asked", "fetched", "spans",
+                 "_on_launched", "_is_launched", "_handed")
+
+    def __init__(self, ordinal: int, taken: float,
+                 asked: Optional[frozenset] = None):
         self.ordinal = ordinal
         self.taken = taken
+        self.asked = asked
         self.fetched: Optional[float] = None
+        self.spans: list[tuple] = []  # (name, t0, t1, args|None)
+        # The batcher's: what `launched` runs, the event it sets (a
+        # release of one of the round's slots waits for it), and the
+        # waiters that hold a row of the round (the loop thread's alone).
+        self._on_launched = None
+        self._is_launched = threading.Event()
+        self._handed: list = []
+
+    def asks(self, slot: int) -> bool:
+        return self.asked is None or slot in self.asked
+
+    def launched(self) -> None:
+        done, self._on_launched = self._on_launched, None
+        if done is not None:
+            done()
+
+    # The trace-like surface (`tracing.activate`): spans only.
+    def add_span(self, name: str, t0: float, t1: float,
+                 args: Optional[dict] = None) -> None:
+        self.spans.append((name, t0, t1, args))
+
+    def annotate(self, **kv) -> None:
+        pass
 
 
-class _TickEntry:
-    __slots__ = ("done", "result", "error", "arrived", "round")
+class _Waiter:
+    """One `decode_step` waiting for its token."""
+
+    __slots__ = ("ready", "outcome")
 
     def __init__(self):
-        self.done = False
-        self.result = None
-        self.error = None
-        self.arrived = time.perf_counter()
-        self.round: Optional[TickRound] = None  # the one that took it
+        # Set when the request may leave with `outcome`: each waiter has
+        # its own, so that a round wakes its riders and nobody else.
+        self.ready = threading.Event()
+        self.outcome = None  # (row, round, raised)
+
+
+class _SlotEntry:
+    """One open session, as the loop sees it. A new session on the same
+    slot number is a new entry: a round hands its rows to the entries it
+    snapshotted, never to a slot number."""
+
+    __slots__ = ("room", "due_at", "round", "parked", "waiter")
+
+    def __init__(self, room: int, due_at: Optional[float]):
+        self.room = room        # tokens the session's cache still takes
+        self.due_at: Optional[float] = due_at  # due since; None: not due
+        self.round: Optional[TickRound] = None  # the one in flight
+        self.parked = None      # (row, round, raised): not collected yet
+        self.waiter: Optional[_Waiter] = None
 
 
 class TickBatcher:
-    """Coalesces concurrent decode_step requests into shared ticks.
+    """Keeps every open session ONE token ahead of its client.
 
-    The first arriving thread becomes the leader: it waits a short join
-    window, snapshots all pending slots, runs one tick for the union, and
-    delivers each waiter its row — then keeps draining rounds until the
-    queue is empty (arrivals during a tick ride the next round). The
-    leader role hands off safely: a waiter that wakes to find no leader
-    takes over. Same-slot serialization is the session store's job (take/
-    put), not this class's.
+    `decode_step` carries no token from the client, so an open session's
+    stream is fixed by its state: the batcher computes token k+1 as soon
+    as token k has been collected, without waiting to be asked, and the
+    client's round trip runs under the next device program.
+
+    A slot is DUE for the next round when its session is open (`admit`),
+    no token of it is parked uncollected, none is in flight, and its
+    cache has room. `step(slot)` COLLECTS: it takes the parked token if
+    there is one (no wait, no device work), else waits for the round
+    that computes it; collecting makes the slot due again. The rounds
+    run on a loop thread of the batcher's own, started when a slot
+    becomes due and no loop runs, and ended when nothing is due (so
+    nothing is in flight either): no thread outlives its work. One tick
+    is in flight at a time. A round: fetch N; under the lock hand
+    each row to the request that waits for it (that slot is due at once)
+    or park it; snapshot N+1 = every due slot; prepare and enqueue N+1;
+    only then wake N's riders (`TickRound.launched`).
+
+    What a step that runs ahead may not do: a per-slot refusal
+    (`slot_fatal` False) of a step nobody had asked for at the snapshot
+    is not parked; the slot is simply not ahead, and is due again (asked
+    for, so the pool may evict for it) once its request is there. A
+    slot-fatal error, and an exception of the whole tick, are parked
+    like a token and reach the slot's next `step`. `release(slot)` drops
+    a parked token and the row of a round in flight.
+    Same-slot serialization is the session store's job (take/put).
 
     Spans (docs/OBSERVABILITY.md "Decode loop phases"): every step
     records `decode/wait` on its own trace, entry to the snapshot of the
-    round that took it; the round's leader records on its trace
-    `decode/handoff` (the previous round's delivery, or the round's
-    first arrival if later, to the snapshot) and `decode/deliver` (end
-    of the tick's fetch to the riders' wake-up). With the pool's
-    prepare, tick and fetch between them they cover the leader thread
-    from one snapshot to the next.
+    round that computes its token (0 when the snapshot came first;
+    `ahead=1` when the token was parked or its round snapshotted at
+    entry). The loop records each round's `decode/handoff` (the previous
+    round's fetch, or the round's first due slot if later, to the
+    snapshot), the pool's prepare, tick and fetch, and `decode/deliver`
+    (end of the fetch to the riders' wake-up, which now comes after the
+    next round's launch) on the round; the first request that collects
+    a token of the round takes them onto its trace.
     """
 
-    def __init__(self, tick_fn, *, join_window_s: float = 0.0005,
-                 cost_fn=None):
+    def __init__(self, tick_fn, *, cost_fn=None):
         # (sorted list[slot], TickRound) -> {slot: result}
         self._tick_fn = tick_fn
-        self._join_window_s = join_window_s
-        # Optional per-slot cost hook (pool.step_cost): charged onto
-        # the CALLER's trace after its round delivers — leader and
-        # followers alike run it on their own thread, where their own
-        # RequestTrace is the active one.
+        # Optional per-slot cost hook (pool.step_cost): charged onto the
+        # trace of the request that collects the step.
         self._cost_fn = cost_fn
-        self._cv = threading.Condition()
-        self._pending: dict[int, _TickEntry] = {}
-        self._inflight: set[int] = set()
-        self._leader = False
-        self._rounds = 0        # ordinal of the newest round
-        self._delivered = 0.0   # perf_counter of its notify_all
+        self._lock = threading.Lock()
+        self._slots: dict[int, _SlotEntry] = {}  # guarded_by: self._lock
+        self._running = False     # guarded_by: self._lock
+        self._rounds = 0          # guarded_by: self._lock
+        # decode_steps_ahead: steps whose token was parked or under way
+        # when they arrived; decode_tokens_dropped: tokens computed and
+        # never collected (their session closed first).
+        self._counters = {"decode_steps_ahead": 0,
+                          "decode_tokens_dropped": 0}  # guarded_by: self._lock
+
+    def counters(self) -> dict:
+        with self._lock:
+            return dict(self._counters)
 
     def _note_cost(self, slot: int) -> None:
         if self._cost_fn is None:
@@ -1718,104 +1833,250 @@ class TickBatcher:
         if cost:
             tracing.add_cost(**cost)
 
-    def _note_wait(self, entry: _TickEntry, led: bool) -> None:
-        took = entry.round
-        if took is not None:
-            tracing.add_span("decode/wait", entry.arrived, took.taken,
-                             round=took.ordinal, led=led)
+    # -- the sessions' side ---------------------------------------------------
+
+    def admit(self, slot: int, room: int) -> None:
+        """Open `slot` for a session whose cache takes `room` more
+        tokens. The slot is due at once: its first token is under way
+        before its first `decode_step` arrives."""
+        with self._lock:
+            self._slots[slot] = _SlotEntry(
+                int(room), time.perf_counter() if room > 0 else None)
+            self._start_loop_locked()
+
+    def release(self, slot: int) -> None:
+        """Forget `slot` (close, TTL eviction, exhaustion, a failed
+        step). A parked token is dropped, and so is the row of a round in
+        flight when it comes back. Returns once that round, if it has
+        not enqueued its program yet, has: until then the pool's tick
+        may still read the slot's state, and the slot number must not be
+        handed to another session."""
+        with self._lock:
+            entry = self._slots.pop(slot, None)
+            if entry is None:
+                return
+            if entry.parked is not None and _is_token(entry.parked):
+                self._counters["decode_tokens_dropped"] += 1
+            entry.parked = None
+            waiter, entry.waiter = entry.waiter, None
+            if waiter is not None:  # only a caller's own bug gets here
+                waiter.outcome = (ServingError.not_found(
+                    f"decode slot {slot} was released under its step"),
+                    None, True)
+                waiter.ready.set()
+            took = entry.round
+        # Timed + loop-on-predicate (servelint DL003): the loop sets the
+        # event in its `finally` too.
+        while took is not None and not took._is_launched.wait(timeout=0.1):
+            pass
 
     def step(self, slot: int):
-        entry = _TickEntry()
-        with self._cv:
-            while slot in self._pending or slot in self._inflight:
-                # Timed + loop-on-predicate (servelint DL003): a leader
-                # lost to an interpreter-level failure must not park
-                # same-slot followers forever.
-                self._cv.wait(timeout=0.1)
-            self._pending[slot] = entry
-            if self._leader:
-                # A leader is running; wait for delivery — or take over
-                # if leadership lapses before our round runs.
-                while not entry.done:
-                    if not self._leader:
-                        self._leader = True
-                        break
-                    # Timed (servelint DL003): wake to re-check the
-                    # leadership-lapse predicate above even if the
-                    # leader died between notify rounds.
-                    self._cv.wait(timeout=0.1)
-                if entry.done:
-                    self._note_wait(entry, led=False)
-                    if entry.error is not None:
-                        raise entry.error
-                    self._note_cost(slot)
-                    return entry.result
-                # fell through: we are the new leader
+        """Collect the slot's next token: its row, or the typed error the
+        pool's tick gave for the slot (returned, not raised); an
+        exception of the whole tick is raised."""
+        arrived, waiter = time.perf_counter(), None
+        with self._lock:
+            entry = self._slots.get(slot)
+            if entry is None:
+                return _slot_fatal(
+                    f"decode slot {slot} is not open in the tick loop")
+            if entry.room <= 0 and entry.parked is None:
+                return _slot_fatal(
+                    f"decode slot {slot} stepped past its cache")
+            outcome = entry.parked
+            ahead = outcome is not None or entry.round is not None
+            if outcome is not None:
+                entry.parked = None
+                self._collected_locked(entry, outcome, arrived)
             else:
-                self._leader = True
-        try:
-            result = self._lead(entry)
-        finally:
-            self._note_wait(entry, led=True)
-        self._note_cost(slot)
-        return result
+                waiter = entry.waiter = _Waiter()
+                if entry.round is None and entry.due_at is None:
+                    # Not ahead (its step was refused while nobody asked
+                    # for it): now that it is asked for, it is due.
+                    entry.due_at = arrived
+                self._start_loop_locked()
+            self._counters["decode_steps_ahead"] += ahead
+        if waiter is not None:
+            while not waiter.ready.wait(timeout=0.1):
+                # Timed (servelint DL003): a loop lost to an
+                # interpreter-level failure must not park its riders
+                # forever; whoever times out starts the next.
+                with self._lock:
+                    self._start_loop_locked()
+            outcome = waiter.outcome
+        row, took, raised = outcome
+        if took is not None:
+            tracing.add_span("decode/wait", arrived,
+                             max(arrived, took.taken),
+                             round=took.ordinal, ahead=int(ahead))
+            trace = tracing.current_trace()
+            try:
+                # Each span of the round goes onto ONE trace: that of
+                # the first request to collect a token of the round
+                # after the loop wrote it (a pop is atomic; another
+                # collector may empty the list under this one).
+                while trace is not None:
+                    trace.add_span(*took.spans.pop(0))
+            except IndexError:
+                pass
+        if raised:
+            raise row
+        if not isinstance(row, Exception):
+            self._note_cost(slot)
+        return row
 
-    def _lead(self, own: _TickEntry):
-        new_leader = True
+    def _collected_locked(self, entry: _SlotEntry, outcome: tuple,
+                          now: float) -> None:
+        """The entry's token went to its request: due again, if that
+        was a token and the cache has room for another."""
+        if _is_token(outcome) and entry.room > 0:
+            entry.due_at = now
+            self._start_loop_locked()
+
+    # -- the loop's side ------------------------------------------------------
+
+    def _start_loop_locked(self) -> None:
+        if self._running or not any(
+                e.due_at is not None for e in self._slots.values()):
+            return
+        self._running = True
+        # Not a daemon: a tick in flight at interpreter exit is waited
+        # for (the loop ends with its work, a round later at most).
+        threading.Thread(target=self._run, name="decode-tick-loop",
+                         daemon=False).start()
+
+    def _snapshot_locked(self, now: float):
+        """Every due slot, as the next round: (round, {slot: entry},
+        when the first of them became due), or None."""
+        batch = {s: e for s, e in self._slots.items()
+                 if e.due_at is not None}
+        if not batch:
+            return None
+        self._rounds += 1
+        took = TickRound(
+            self._rounds, now,
+            frozenset(s for s, e in batch.items() if e.waiter is not None))
+        first_due = min(e.due_at for e in batch.values())
+        for e in batch.values():
+            e.due_at, e.round = None, took
+        return took, batch, first_due
+
+    def _settle_locked(self, took: TickRound, batch: dict, results: dict,
+                       err: Optional[BaseException], now: float) -> None:
+        """Round `took` is back: each row to the request that waits for
+        it, or parked; the rows of entries released meanwhile dropped."""
+        for slot, entry in batch.items():
+            entry.round = None
+            if err is not None:
+                outcome = (err, took, True)
+            else:
+                row = results.get(slot)
+                if row is None:
+                    row = ServingError.internal(
+                        f"decode tick returned no row for slot {slot}")
+                outcome = (row, took, False)
+            if self._slots.get(slot) is not entry:
+                if _is_token(outcome):
+                    self._counters["decode_tokens_dropped"] += 1
+                continue
+            row = outcome[0]
+            if row is PREFILL_PENDING:
+                entry.due_at = now  # mid-prefix: due until a real token
+                continue
+            refused = (err is None and isinstance(row, Exception)
+                       and not getattr(row, "slot_fatal", True))
+            if refused and not took.asks(slot):
+                # Nobody had asked for this step: the slot is not ahead.
+                # Its request, if it has come since, makes it due.
+                entry.due_at = now if entry.waiter is not None else None
+                continue
+            if _is_token(outcome):
+                entry.room -= 1
+            waiter, entry.waiter = entry.waiter, None
+            if waiter is None:
+                entry.parked = outcome
+            else:
+                # The request has its row; it leaves when the next round
+                # is launched (`_wake`), not before.
+                waiter.outcome = outcome
+                took._handed.append(waiter)
+                self._collected_locked(entry, outcome, now)
+
+    def _run(self) -> None:
+        back = None     # (round, batch, results, err): back, not settled
+        flying = None   # (round, batch): in the tick
+        settled = 0.0   # when the round before's fetch ended
         try:
-            if self._join_window_s:
-                time.sleep(self._join_window_s)
             while True:
-                with self._cv:
-                    batch = self._pending
-                    self._pending = {}
-                    self._inflight = set(batch)
-                    of_round = TickRound(self._rounds + 1,
-                                         time.perf_counter())
-                    since = self._delivered
-                    if batch:
-                        self._rounds = of_round.ordinal
-                if not batch:
-                    break
-                for e in batch.values():
-                    e.round = of_round
-                # Time in which nothing was pending is no hand-off: the
-                # span starts no earlier than the round's first arrival
-                # (which may lie before this leader's own request began).
-                tracing.add_span(
-                    "decode/handoff",
-                    max(since, min(e.arrived for e in batch.values())),
-                    of_round.taken, round=of_round.ordinal,
-                    riders=len(batch), new_leader=new_leader)
-                new_leader = False
-                err = None
-                results: dict = {}
+                with self._lock:
+                    now = time.perf_counter()
+                    if back is not None:
+                        self._settle_locked(*back, now)
+                    before = back[0] if back is not None else None
+                    taken = self._snapshot_locked(now)
+                    if taken is None:
+                        self._running = False
+                back = None
+                if taken is None:
+                    self._wake(before, None)
+                    return
+                took, batch, first_due = taken
+                took._on_launched = functools.partial(
+                    self._wake, before, took)
+                flying = (took, batch)
+                # Time in which nothing was due is no hand-off.
+                took.add_span("decode/handoff", max(settled, first_due),
+                              took.taken, {"round": took.ordinal,
+                                           "riders": len(batch)})
+                results, err = {}, None
                 try:
-                    results = self._tick_fn(sorted(batch), of_round)
-                except Exception as exc:  # noqa: BLE001 - delivered to waiters
+                    with tracing.activate(took):
+                        results = self._tick_fn(sorted(batch), took)
+                except Exception as exc:  # noqa: BLE001 - parked for the riders
                     err = exc
-                back = time.perf_counter()
-                with self._cv:
-                    for s, e in batch.items():
-                        e.done = True
-                        e.error = err
-                        e.result = results.get(s)
-                    self._inflight = set()
-                    self._cv.notify_all()
-                    delivered = self._delivered = time.perf_counter()
-                tracing.add_span(
-                    "decode/deliver", of_round.fetched or back, delivered,
-                    round=of_round.ordinal)
-                # Return as soon as our own round ran — pending arrivals
-                # elect a new leader via the handoff path in step() (a
-                # leader that kept draining would give its own caller
-                # unbounded latency under sustained load).
-                if own.done:
-                    break
+                settled = took.fetched or time.perf_counter()
+                took.launched()
+                back, flying = (took, batch, results, err), None
         finally:
-            with self._cv:
-                self._leader = False
-                self._cv.notify_all()
-        if own.error is not None:
-            raise own.error
-        return own.result
+            # Only an interpreter-level failure leaves a round behind:
+            # its riders get that as their tick's error, and the next
+            # step that wakes starts a new loop.
+            for lost in (flying, back):
+                if lost is not None:
+                    with self._lock:
+                        self._settle_locked(
+                            lost[0], lost[1], {},
+                            RuntimeError("the decode tick loop died"),
+                            time.perf_counter())
+                    self._wake(lost[0], lost[0])
+            with self._lock:
+                self._running = False
+
+    def _wake(self, before: Optional[TickRound],
+              took: Optional[TickRound]) -> None:
+        """Round `took` is launched (None: there is no next round): wake
+        the riders of the round `before` it, whose `decode/deliver` ends
+        here, and whoever waits to release a slot of `took`. On the loop
+        thread, under no lock."""
+        now = time.perf_counter()
+        if took is not None:
+            took._is_launched.set()
+        if before is not None:
+            before.add_span("decode/deliver", before.fetched or now, now,
+                            {"round": before.ordinal})
+            handed, before._handed = before._handed, []
+            for waiter in handed:
+                waiter.ready.set()
+
+
+def _slot_fatal(message: str) -> ServingError:
+    """A typed per-slot error after which the slot's session is over."""
+    exc = ServingError.failed_precondition(message)
+    exc.slot_fatal = True
+    return exc
+
+
+def _is_token(outcome: tuple) -> bool:
+    """A computed row, as against an error or a raised exception."""
+    row, _, raised = outcome
+    return not raised and not isinstance(row, Exception)

@@ -23,10 +23,14 @@ it is the one seam (`_Backend`), with two implementations:
                 update in place);
   pooled        `continuous_batching=True`: the state sits in a slot of a
                 pool (decode_sessions.SlotPool, or PagedSlotPool when
-                `paging.block_size` > 0) and concurrent decode_step
-                requests coalesce into ONE device tick through a
-                TickBatcher — K stepping sessions cost one dispatch per
-                token instead of K. Sessions are then single-sequence.
+                `paging.block_size` > 0) and a TickBatcher's own loop
+                advances every open session that is due in ONE device
+                tick — K sessions cost one dispatch per token instead
+                of K. decode_step carries no token from the client, so
+                the loop computes a session's next token as soon as the
+                last was collected, one ahead of the client and no
+                more: decode_step collects it (parked, or from the
+                round under way). Sessions are then single-sequence.
 
 Arrows: models/* -> this module -> decode_sessions -> ops/attention.
 """
@@ -39,7 +43,6 @@ from typing import Callable, NamedTuple, Optional
 import numpy as np
 
 from min_tfs_client_tpu.servables.decode_sessions import (
-    PREFILL_PENDING,
     DecodeSessionStore,
     PagedSlotPool,
     Paging,
@@ -172,7 +175,8 @@ class _Backend(NamedTuple):
 
     `store` and `kv_pool` (the paged pool, else None) are what the loader
     re-labels with the real model:version; `dedup` is the guard the
-    inits and the close forget a session in.
+    inits and the close forget a session in; `loop_counters` reads the
+    pooled backend's tick loop (TickBatcher.counters).
     """
 
     admit: Callable
@@ -181,6 +185,7 @@ class _Backend(NamedTuple):
     store: DecodeSessionStore
     dedup: StepDeduper
     kv_pool: Optional[PagedSlotPool] = None
+    loop_counters: Optional[Callable[[], dict]] = None
 
 
 def _deduper(store: DecodeSessionStore, max_sessions: int) -> StepDeduper:
@@ -268,10 +273,19 @@ def _pooled_backend(params, model: DecodeModel, prefill_jit, *,
     # the CALLER's trace (pages x ticks, the paged pool's HBM-residency
     # cost unit; None on the dense pool).
     batcher = TickBatcher(pool.tick, cost_fn=pool.step_cost)
+    if paged:
+        pool.loop_counters = batcher.counters
+
+    def release_slot(slot):
+        # The loop first (a parked token, or the row of a round in
+        # flight, is dropped), then the pool's slot and pages.
+        batcher.release(slot)
+        pool.release_slot(slot)
+
     store = DecodeSessionStore(
         max_sessions=max_slots, ttl_s=session_ttl_s,
         metric_label=f"{model.name}-pooled",
-        on_evict=lambda entry: pool.release_slot(entry[0]))
+        on_evict=lambda entry: release_slot(entry[0]))
     dedup = _deduper(store, max_slots)
 
     def admit(sid, args, forced):
@@ -302,6 +316,9 @@ def _pooled_backend(params, model: DecodeModel, prefill_jit, *,
             except Exception:
                 pool.release_slot(slot)
                 raise
+        # Due at once: the session's first token is under way before its
+        # first decode_step arrives.
+        batcher.admit(slot, max_decode_len - start)
 
     def step_fn(inputs):
         with _Step(dedup, inputs) as step:
@@ -309,18 +326,15 @@ def _pooled_backend(params, model: DecodeModel, prefill_jit, *,
                 sid = step.sid
                 slot, host_step = store.take(sid)
                 try:
+                    # Collects: the token is parked already, or the round
+                    # that computes it is awaited (a slot mid-prefix
+                    # stays in the rounds until its first real token).
                     row = batcher.step(slot)
-                    while row is PREFILL_PENDING:
-                        # The slot is mid-prefix: each batcher round
-                        # streamed one chunk; re-entering lets tick-mates'
-                        # decode steps (and other prefills) interleave
-                        # until this session's first real token arrives.
-                        row = batcher.step(slot)
                 except Exception:
-                    # The pool row may be in an undefined state; retire
-                    # the slot rather than hand it to a future session
-                    # mid-generation.
-                    pool.release_slot(slot)
+                    # The whole tick failed: the pool row may be in an
+                    # undefined state; retire the slot rather than hand
+                    # it to a future session mid-generation.
+                    release_slot(slot)
                     raise
                 if isinstance(row, Exception):
                     # Per-slot failure from the paged pool's tick (typed
@@ -329,7 +343,7 @@ def _pooled_backend(params, model: DecodeModel, prefill_jit, *,
                     # a capacity REFUSAL whose state is intact and may
                     # retry after others close.
                     if getattr(row, "slot_fatal", True):
-                        pool.release_slot(slot)
+                        release_slot(slot)
                     else:
                         store.put(sid, (slot, host_step))
                     raise row
@@ -337,7 +351,7 @@ def _pooled_backend(params, model: DecodeModel, prefill_jit, *,
                 if host_step < max_decode_len:
                     store.put(sid, (slot, host_step))
                 else:
-                    pool.release_slot(slot)  # cache exhausted: session ends
+                    release_slot(slot)  # cache exhausted: session ends
                 step.answer(row["token"].reshape(-1),
                             row["finished"].reshape(-1).astype(np.int32),
                             host_step)
@@ -345,7 +359,7 @@ def _pooled_backend(params, model: DecodeModel, prefill_jit, *,
 
     # release: the store's on_evict hands the slot back to the pool.
     return _Backend(admit, step_fn, store.close, store, dedup,
-                    pool if paged else None)
+                    pool if paged else None, batcher.counters)
 
 
 def build_session_signatures(params, model: DecodeModel, *, seq_len: int,
@@ -470,6 +484,8 @@ def build_session_signatures(params, model: DecodeModel, *, seq_len: int,
         sig._decode_store = store
         if backend.kv_pool is not None:
             sig._kv_pool = backend.kv_pool
+        if backend.loop_counters is not None:
+            sig._loop_counters = backend.loop_counters
     return signatures
 
 

@@ -1006,3 +1006,22 @@ def own_attribution(threads: dict, harness: frozenset) -> tuple:
     other = sum(info["samples"] for info in own.values()
                 if info["subsystem"] == "other")
     return samples, named, other
+
+
+def until(predicate, timeout_s: float = 10.0) -> None:
+    """Poll for what a background thread does by itself (the decode
+    pool's tick loop runs ahead of the requests, on its own thread)."""
+    import time
+
+    deadline = time.monotonic() + timeout_s
+    while not predicate():
+        assert time.monotonic() < deadline, "timed out"
+        time.sleep(0.002)
+
+
+def tick_loop_threads() -> list:
+    """The live loop threads of decode_sessions.TickBatcher: none once
+    nothing is due."""
+    import threading
+
+    return [t for t in threading.enumerate() if t.name == "decode-tick-loop"]

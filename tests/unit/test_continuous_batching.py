@@ -9,6 +9,7 @@ which other sessions tick alongside.
 from __future__ import annotations
 
 import threading
+import time
 
 import jax
 import numpy as np
@@ -17,6 +18,7 @@ import pytest
 from min_tfs_client_tpu.models import t5
 from min_tfs_client_tpu.servables.decode_sessions import TickBatcher
 from min_tfs_client_tpu.utils.status import ServingError
+from tests.fixtures import tick_loop_threads, until
 
 SEQ, MAXDEC = 12, 8
 
@@ -216,9 +218,10 @@ def test_synthesize_warmup_primes_session_executables():
 
 class TestDensePoolPhases:
     def test_dense_pool_records_the_paged_pool_s_phase_names(self, pooled):
-        """One session, each step its own round: the leader's trace has
-        the wait, the hand-off and the four phases in order, all of one
-        round, and `decode/init` sits on the opening request."""
+        """One session: each step's trace has its wait and, copied from
+        the loop's round that computed its token, the hand-off and the
+        four phases in order, all of one round; consecutive steps are
+        consecutive rounds; `decode/init` sits on the opening request."""
         from min_tfs_client_tpu.observability import tracing
 
         config, _, sigs = pooled
@@ -234,18 +237,24 @@ class TestDensePoolPhases:
         for _ in range(3):
             with tracing.request_trace("decode_step") as trace:
                 sigs["decode_step"].run({"session_id": sid})
-            spans = [s for s in trace.spans if s[0].startswith("decode/")]
-            # Alone, a step's wait and its round's hand-off coincide.
-            spans.sort(key=lambda s: (s[1], s[0] != "decode/wait"))
-            assert [s[0] for s in spans] == [
-                "decode/wait", "decode/handoff", "decode/prepare",
-                "decode/tick", "decode/fetch", "decode/deliver"]
-            assert len({s[3]["round"] for s in spans}) == 1
-            assert "width" not in spans[3][3]  # no block table here
-            assert spans[0][3]["led"] and spans[3][3]["slots"] == 1
-            for a, b in zip(spans[1:], spans[2:]):
-                assert a[2] <= b[1]
-            rounds.append(spans[0][3]["round"])
+            mine = [s for s in trace.spans if s[0].startswith("decode/")]
+            spans = {s[0]: s for s in mine}
+            phases = ["decode/handoff", "decode/prepare", "decode/tick",
+                      "decode/fetch", "decode/deliver"]
+            assert sorted(s[0] for s in mine) \
+                == sorted(["decode/wait"] + phases)
+            assert len({s[3]["round"] for s in mine}) == 1
+            tick = spans["decode/tick"][3]
+            assert "width" not in tick  # no block table here
+            assert tick["slots"] == 1
+            for a, b in zip(phases, phases[1:]):
+                assert spans[a][2] <= spans[b][1], (a, b)
+            wait = spans["decode/wait"]
+            assert wait[3]["ahead"] in (0, 1)
+            assert trace.start <= wait[1] <= wait[2]
+            # Entry to the round's snapshot, nothing where that came first.
+            assert wait[2] == max(wait[1], spans["decode/handoff"][2])
+            rounds.append(wait[3]["round"])
         assert rounds == [rounds[0], rounds[0] + 1, rounds[0] + 2]
         sigs["decode_close"].run({"session_id": sid})
 
@@ -300,105 +309,336 @@ class TestPooledAtMostOnce:
         sigs["decode_close"].run({"session_id": sid_b})
 
 
+class _Ticks:
+    """A tick function with no device behind it. Records every round
+    (ordinal, slots, the slots asked for), answers each slot with
+    (slot, ordinal), stamps `fetched` as a pool does, and can hold a
+    round open (`hold` = its ordinal) until `let_go` is set."""
+
+    def __init__(self, hold=None, rows=None):
+        self.rounds = []
+        self.hold, self.holding = hold, threading.Event()
+        self.let_go = threading.Event()
+        self.rows = rows
+        self.in_flight = self.most_in_flight = 0
+        self._lock = threading.Lock()
+
+    def __call__(self, slots, of_round):
+        with self._lock:
+            self.in_flight += 1
+            self.most_in_flight = max(self.most_in_flight, self.in_flight)
+            self.rounds.append((of_round.ordinal, list(slots),
+                                sorted(of_round.asked)))
+        try:
+            of_round.launched()
+            if of_round.ordinal == self.hold:
+                self.holding.set()
+                assert self.let_go.wait(10)
+            if self.rows is not None:
+                return self.rows(slots, of_round)
+            return {s: (s, of_round.ordinal) for s in slots}
+        finally:
+            of_round.fetched = time.perf_counter()
+            with self._lock:
+                self.in_flight -= 1
+
+    def slots_of(self, ordinal):
+        return [slots for o, slots, _ in self.rounds if o == ordinal][0]
+
+
 class TestTickBatcher:
-    def test_concurrent_steps_coalesce(self):
-        batch_sizes = []
-        release = threading.Event()
+    """The loop's contract (decode_sessions.TickBatcher): run-ahead of
+    depth one, on a thread that belongs to the batcher."""
 
-        def tick(slots, of_round):
-            if not release.is_set():
-                release.wait(5)
-            batch_sizes.append(len(slots))
-            return {s: s * 10 for s in slots}
+    @pytest.fixture(autouse=True)
+    def _no_thread_outlives_its_work(self):
+        until(lambda: not tick_loop_threads())  # an earlier test's last round
+        yield
+        until(lambda: not tick_loop_threads())
 
-        batcher = TickBatcher(tick, join_window_s=0.05)
-        results = {}
-        lock = threading.Lock()
+    def test_admission_makes_a_slot_due_and_its_token_is_parked(self):
+        ticks = _Ticks()
+        batcher = TickBatcher(ticks)
+        batcher.admit(3, room=4)
+        # Nobody has asked: the first token is computed all the same.
+        until(lambda: ticks.rounds == [(1, [3], [])])
+        until(lambda: not tick_loop_threads())
+        # A parked token is returned at once: no tick for it...
+        assert batcher.step(3) == (3, 1)
+        assert ticks.rounds[0] == (1, [3], [])
+        # ...and collecting it made the slot due: it rides the next
+        # round with no request waiting.
+        until(lambda: len(ticks.rounds) == 2)
+        assert ticks.rounds[1] == (2, [3], [])
+        assert batcher.step(3) == (3, 2)
+        assert batcher.counters()["decode_steps_ahead"] == 2
+        batcher.release(3)
 
-        def worker(slot):
-            r = batcher.step(slot)
-            with lock:
-                results[slot] = r
+    def test_a_slot_with_a_parked_token_is_not_ticked_again(self):
+        """Depth one, whatever the wait: other slots' rounds go by and
+        the parked slot is in none of them."""
+        ticks = _Ticks()
+        batcher = TickBatcher(ticks)
+        batcher.admit(1, room=16)
+        until(lambda: len(ticks.rounds) == 1 and not tick_loop_threads())
+        batcher.admit(2, room=16)
+        for k in range(1, 6):
+            assert batcher.step(2) == (2, k + 1)
+        until(lambda: len(ticks.rounds) == 7 and not tick_loop_threads())
+        assert [slots for _, slots, _ in ticks.rounds] == [[1]] + [[2]] * 6
+        assert batcher.step(1) == (1, 1)
+        until(lambda: len(ticks.rounds) == 8 and not tick_loop_threads())
+        batcher.release(1)
+        batcher.release(2)
+        assert batcher.counters()["decode_tokens_dropped"] == 2
 
-        threads = [threading.Thread(target=worker, args=(i,))
-                   for i in range(8)]
+    def test_a_spent_cache_is_not_due(self):
+        """`room` tokens are computed and no more: a slot whose cache is
+        full is not ticked, and a step past it is a typed error."""
+        ticks = _Ticks()
+        batcher = TickBatcher(ticks)
+        batcher.admit(5, room=2)
+        assert [batcher.step(5), batcher.step(5)] == [(5, 1), (5, 2)]
+        until(lambda: not tick_loop_threads())
+        assert len(ticks.rounds) == 2
+        past = batcher.step(5)
+        assert isinstance(past, ServingError) and past.slot_fatal
+        batcher.release(5)
+        assert batcher.counters()["decode_tokens_dropped"] == 0
+
+    def test_arrivals_during_a_round_ride_the_next(self):
+        ticks = _Ticks(hold=1)
+        batcher = TickBatcher(ticks)
+        batcher.admit(1, room=8)
+        assert ticks.holding.wait(5)
+        batcher.admit(2, room=8)      # a new session, mid-round
+        out = {}
+        rider = threading.Thread(
+            target=lambda: out.update(two=batcher.step(2)))
+        rider.start()                 # and its request, mid-round
+        time.sleep(0.05)
+        assert len(ticks.rounds) == 1
+        ticks.let_go.set()
+        rider.join(5)
+        assert out == {"two": (2, 2)}
+        # Round 2: slot 2, asked for. Slot 1's token was parked, so it
+        # is not in it.
+        assert ticks.rounds[1] == (2, [2], [2])
+        assert batcher.step(1) == (1, 1)
+        batcher.release(1)
+        batcher.release(2)
+
+    def test_one_tick_in_flight_at_a_time_and_concurrent_steps_share_it(
+            self):
+        ticks = _Ticks()
+        batcher = TickBatcher(ticks)
+        n, steps = 8, 20
+        for slot in range(n):
+            batcher.admit(slot, room=steps)
+        got = {slot: [] for slot in range(n)}
+
+        def client(slot):
+            for _ in range(steps):
+                got[slot].append(batcher.step(slot))
+
+        threads = [threading.Thread(target=client, args=(slot,))
+                   for slot in range(n)]
         for t in threads:
             t.start()
-        release.set()
         for t in threads:
-            t.join()
-        assert results == {i: i * 10 for i in range(8)}
-        # 8 slots must NOT have cost 8 ticks: the join window coalesces.
-        assert sum(batch_sizes) == 8
-        assert len(batch_sizes) < 8
-        assert max(batch_sizes) > 1
+            t.join(10)
+        assert ticks.most_in_flight == 1
+        for slot in range(n):
+            assert [s for s, _ in got[slot]] == [slot] * steps
+            ordinals = [o for _, o in got[slot]]
+            assert ordinals == sorted(set(ordinals))  # one token a round
+        # 160 tokens did not cost 160 ticks: the sessions share rounds.
+        assert sum(len(slots) for _, slots, _ in ticks.rounds) == n * steps
+        assert len(ticks.rounds) < n * steps
+        for slot in range(n):
+            batcher.release(slot)
 
-    def test_sequential_steps_each_get_a_tick(self):
-        calls = []
-
-        def tick(slots, of_round):
-            calls.append(list(slots))
+    def test_a_tick_wide_exception_reaches_every_rider_at_its_next_step(
+            self):
+        def rows(slots, of_round):
+            if of_round.ordinal == 2:
+                raise RuntimeError("device fell over")
             return {s: "ok" for s in slots}
 
-        batcher = TickBatcher(tick, join_window_s=0)
-        assert batcher.step(3) == "ok"
-        assert batcher.step(3) == "ok"
-        assert calls == [[3], [3]]
-
-    def test_tick_error_propagates_to_every_waiter(self):
-        def tick(slots, of_round):
-            raise RuntimeError("device fell over")
-
-        batcher = TickBatcher(tick, join_window_s=0.02)
-        errors = []
-
-        def worker(slot):
-            try:
-                batcher.step(slot)
-            except RuntimeError as exc:
-                errors.append(str(exc))
-
-        threads = [threading.Thread(target=worker, args=(i,))
-                   for i in range(4)]
-        for t in threads:
-            t.start()
-        for t in threads:
-            t.join()
-        assert errors == ["device fell over"] * 4
-
-    def test_arrivals_during_tick_ride_next_round(self):
-        rounds = []
-        first_tick_started = threading.Event()
-        let_first_finish = threading.Event()
-
-        def tick(slots, of_round):
-            rounds.append(list(slots))
-            if len(rounds) == 1:
-                first_tick_started.set()
-                let_first_finish.wait(5)
-            return {s: len(rounds) for s in slots}
-
-        batcher = TickBatcher(tick, join_window_s=0)
+        ticks = _Ticks(hold=1, rows=rows)
+        batcher = TickBatcher(ticks)
+        batcher.admit(0, room=8)
+        assert ticks.holding.wait(5)
+        for slot in (1, 2, 3):
+            batcher.admit(slot, room=8)
         out = {}
 
-        def first():
-            out[1] = batcher.step(1)
+        def asked():
+            try:
+                batcher.step(3)
+            except RuntimeError as exc:
+                out[3] = str(exc)
 
-        def second():
-            first_tick_started.wait(5)
-            out[2] = batcher.step(2)
+        rider = threading.Thread(target=asked)
+        rider.start()
+        time.sleep(0.02)
+        ticks.let_go.set()
+        rider.join(5)
+        until(lambda: not tick_loop_threads())
+        # Round 2 took slots 1 to 3 (slot 0's token was parked) and
+        # failed: each of its riders gets the exception from its next
+        # step, the one that was asked for and those that ran ahead.
+        assert ticks.rounds == [(1, [0], []), (2, [1, 2, 3], [3])]
+        assert out == {3: "device fell over"}
+        for slot in (1, 2):
+            with pytest.raises(RuntimeError, match="device fell over"):
+                batcher.step(slot)
+        assert batcher.step(0) == "ok"
+        # Parked like a token: no slot of the failed round is ticked
+        # again before its session is retired.
+        until(lambda: len(ticks.rounds) == 3 and not tick_loop_threads())
+        assert ticks.rounds[2] == (3, [0], [])
+        for slot in range(4):
+            batcher.release(slot)
 
-        t1 = threading.Thread(target=first)
-        t2 = threading.Thread(target=second)
-        t1.start()
-        t2.start()
-        first_tick_started.wait(5)
-        # Give the second thread a moment to enqueue mid-tick.
-        import time as _time
+    def test_a_run_ahead_refusal_is_not_parked_and_runs_again_on_request(
+            self):
+        def rows(slots, of_round):
+            out = {}
+            for s in slots:
+                if of_round.asks(s):
+                    out[s] = ("row", of_round.ordinal)
+                else:  # the pool evicts nobody for a step not asked for
+                    out[s] = ServingError.resource_exhausted("no page")
+                    out[s].slot_fatal = False
+            return out
 
-        _time.sleep(0.1)
-        let_first_finish.set()
-        t1.join()
-        t2.join()
-        assert out[1] == 1 and out[2] == 2
-        assert rounds == [[1], [2]]
+        ticks = _Ticks(rows=rows)
+        batcher = TickBatcher(ticks)
+        batcher.admit(7, room=8)
+        until(lambda: len(ticks.rounds) == 1 and not tick_loop_threads())
+        # Refused, not parked, and not tried over and over either.
+        time.sleep(0.05)
+        assert ticks.rounds == [(1, [7], [])]
+        # The request makes it due again, asked for this time.
+        assert batcher.step(7) == ("row", 2)
+        assert ticks.rounds[1] == (2, [7], [7])
+        assert batcher.counters()["decode_steps_ahead"] == 0
+        batcher.release(7)
+
+    def test_a_refusal_of_a_step_asked_for_goes_to_its_request(self):
+        def rows(slots, of_round):
+            exc = ServingError.resource_exhausted("no page, no victim")
+            exc.slot_fatal = False
+            return {s: exc for s in slots}
+
+        ticks = _Ticks(rows=rows)
+        batcher = TickBatcher(ticks)
+        batcher.admit(2, room=8)
+        until(lambda: len(ticks.rounds) == 1 and not tick_loop_threads())
+        refused = batcher.step(2)
+        assert isinstance(refused, ServingError) and not refused.slot_fatal
+        # The session is intact and simply not ahead: asked again, it is
+        # tried again.
+        until(lambda: not tick_loop_threads())
+        assert len(ticks.rounds) == 2
+        assert isinstance(batcher.step(2), ServingError)
+        assert len(ticks.rounds) == 3
+        batcher.release(2)
+
+    def test_a_slot_mid_prefix_stays_due_until_its_first_token(self):
+        from min_tfs_client_tpu.servables.decode_sessions import (
+            PREFILL_PENDING,
+        )
+
+        def rows(slots, of_round):
+            return {s: (PREFILL_PENDING if of_round.ordinal < 3
+                        else "first") for s in slots}
+
+        ticks = _Ticks(rows=rows)
+        batcher = TickBatcher(ticks)
+        batcher.admit(4, room=1)
+        assert batcher.step(4) == "first"
+        assert [o for o, _, _ in ticks.rounds] == [1, 2, 3]
+        batcher.release(4)
+
+    def test_release_during_a_round_drops_its_row_for_good(self):
+        """The row of a round in flight never reaches a new session on
+        the same slot number, and neither does a parked one."""
+        ticks = _Ticks(hold=1)
+        batcher = TickBatcher(ticks)
+        batcher.admit(6, room=8)
+        assert ticks.holding.wait(5)
+        batcher.release(6)            # close: round 1 holds slot 6
+        batcher.admit(6, room=8)      # a new session, same slot number
+        out = {}
+        rider = threading.Thread(
+            target=lambda: out.update(new=batcher.step(6)))
+        rider.start()
+        time.sleep(0.05)
+        ticks.let_go.set()
+        rider.join(5)
+        assert out == {"new": (6, 2)}     # round 2's row, not round 1's
+        assert batcher.counters()["decode_tokens_dropped"] == 1
+        until(lambda: len(ticks.rounds) == 3 and not tick_loop_threads())
+        batcher.release(6)            # a parked token: dropped too
+        assert batcher.counters()["decode_tokens_dropped"] == 2
+        batcher.admit(6, room=8)
+        assert batcher.step(6) == (6, 4)
+        batcher.release(6)
+
+    def test_release_waits_for_a_round_that_has_not_launched(self):
+        """Until a round's program is enqueued the pool's tick may still
+        read the slot's state: `release` returns only then, so the slot
+        number cannot be handed on under it."""
+        launch, released = threading.Event(), threading.Event()
+
+        def tick(slots, of_round):
+            assert launch.wait(10)
+            of_round.launched()
+            return {s: "row" for s in slots}
+
+        batcher = TickBatcher(tick)
+        batcher.admit(1, room=8)
+        until(lambda: bool(tick_loop_threads()))
+        time.sleep(0.02)              # the round is snapshotted, held
+
+        def close():
+            batcher.release(1)
+            released.set()
+
+        closer = threading.Thread(target=close)
+        closer.start()
+        assert not released.wait(0.1)
+        launch.set()
+        assert released.wait(5)
+        closer.join(5)
+
+    def test_the_loop_ends_with_its_work_and_a_later_step_starts_another(
+            self):
+        before = set(threading.enumerate())
+        ticks = _Ticks()
+        batcher = TickBatcher(ticks)
+        batcher.admit(1, room=8)
+        until(lambda: len(ticks.rounds) == 1)
+        until(lambda: not tick_loop_threads())
+        assert set(threading.enumerate()) <= before
+        assert batcher.step(1) == (1, 1)      # starts a new loop
+        until(lambda: len(ticks.rounds) == 2 and not tick_loop_threads())
+        batcher.release(1)
+        assert set(threading.enumerate()) <= before
+
+    def test_every_wait_of_the_batcher_is_timed(self):
+        """servelint DL003: no wait that can park a thread for ever."""
+        import ast
+        import inspect
+        import textwrap
+
+        tree = ast.parse(textwrap.dedent(inspect.getsource(TickBatcher)))
+        waits = [node for node in ast.walk(tree)
+                 if isinstance(node, ast.Call)
+                 and isinstance(node.func, ast.Attribute)
+                 and node.func.attr in ("wait", "wait_for", "join")]
+        assert waits
+        assert all(any(kw.arg == "timeout" for kw in call.keywords)
+                   for call in waits)

@@ -100,6 +100,21 @@ class TestVectorFromTrace:
         assert v["kv_page_ticks"] == 3
 
 
+    def test_a_decode_round_is_billed_inside_the_request_only(self):
+        """The pool's loop runs ahead of the requests: the request that
+        collects a token carries its round's spans, whose tick and fetch
+        may lie before it began. It is billed what it waited through."""
+        trace = _finished_trace(
+            spans=[("decode/tick", -0.004, -0.003),    # before the request
+                   ("decode/fetch", -0.003, 0.002),    # 2 ms of 5 inside
+                   ("decode/prefill_chunk", 0.002, 0.003),
+                   ("decode/deliver", 0.002, 0.004)],  # not a device stage
+            duration_s=0.005)
+        v = costs.vector_from_trace(trace)
+        assert v["decode_tick_us"] == pytest.approx(3000.0, rel=1e-6)
+        assert v["decode_tick_us"] <= v["total_us"]
+
+
 class TestFanoutCostSplit:
     def test_add_cost_splits_across_riders(self):
         a = tracing.RequestTrace("predict")
