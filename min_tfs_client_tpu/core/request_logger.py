@@ -132,6 +132,11 @@ class ServerRequestLogger:
                 logger.collector.flush()
                 logger.collector.close()
 
+    def logs(self, model_name: str) -> bool:
+        """Whether requests of this model are logged at all (a log write
+        may wait: such a request stays off the event loop)."""
+        return model_name in self._loggers
+
     def maybe_log(self, model_name: str, build_log: Callable[[], apis.PredictionLog],
                   model_spec: apis.ModelSpec) -> None:
         logger = self._loggers.get(model_name)

@@ -86,6 +86,7 @@ _SUBSYSTEM_EXACT = {
     "rest-server": "rest-frontend",
     "router-rest-server": "rest-frontend",
     "router-aio-data-plane": "router-event-loop",
+    "grpc-aio-loop": "grpc-event-loop",
     "router-membership-poll": "membership-poller",
     "router-fleet-scrape": "fleet-scraper",
     "fs-source-poll": "model-discovery",
@@ -118,9 +119,12 @@ def subsystem_for(thread_name: str) -> str:
     for prefix, name in _SUBSYSTEM_PREFIX:
         if thread_name.startswith(prefix):
             return name
-    # grpc.server() names its poll thread for its target function:
-    # "Thread-1 (_serve)". Not ours to rename, but always present.
-    if thread_name.startswith("Thread-") and thread_name.endswith("(_serve)"):
+    # grpc names its poll threads for their target functions:
+    # "Thread-1 (_serve)" (grpc.server(): the router's threaded plane)
+    # and "Thread-1 (_poll_wrapper)" (grpc.aio's completion-queue
+    # poller, one a process). Not ours to rename, but always present.
+    if thread_name.startswith("Thread-") and thread_name.endswith(
+            ("(_serve)", "(_poll_wrapper)")):
         return "grpc-server"
     return "other"
 

@@ -30,6 +30,8 @@ import threading
 import time
 import weakref
 
+from min_tfs_client_tpu.utils import aio_loop
+
 _LEDGER_CAPACITY = 256
 
 _lock = threading.Lock()
@@ -337,6 +339,9 @@ def snapshot(include_live_arrays: bool = False) -> dict:
         "profiler": profiler.status(),
         "pipeline": pipeline_stats(),
         "kv_pool": kv_pool_stats(),
+        # The gRPC front end: requests answered on the event loop and on
+        # the worker pool, and the loop's sampled lag (utils/aio_loop.py).
+        "grpc": aio_loop.stats(),
     }
     if include_live_arrays:
         payload["live_arrays"] = live_array_stats()

@@ -335,6 +335,16 @@ def armed() -> bool:
     return _engine is not None
 
 
+def covers(name: str) -> bool:
+    """Whether an armed rule names point `name` (by its pattern alone:
+    match, every and max_fires are not consulted), so that a caller that
+    may not sleep can stay away from the point. Disarmed: a
+    module-global read."""
+    engine = _engine
+    return engine is not None and any(
+        fnmatch.fnmatchcase(name, rule.point) for rule in engine.rules)
+
+
 def stats() -> Optional[dict]:
     engine = _engine
     return engine.stats() if engine is not None else None

@@ -43,6 +43,14 @@ the warn threshold — a wedged loop is this plane's analogue of a
 saturated thread pool, and it must be visible BEFORE it becomes tail
 latency.
 
+The loop is not this plane's own: gRPC's completion queue takes ONE
+asyncio loop a process (a second one races the C core's
+PollerCompletionQueue and dies with BlockingIOError deep inside the
+cython layer, long after construction and only under load), so the
+plane's serve coroutine runs on the process's shared loop
+(utils/aio_loop.py), beside any other plane and any in-process model
+server's front end.
+
 The threaded plane stays available behind `--data_plane=threads` for
 one release (docs/MIGRATING.md).
 """
@@ -50,6 +58,7 @@ one release (docs/MIGRATING.md).
 from __future__ import annotations
 
 import asyncio
+import concurrent.futures
 import logging
 import threading
 import time
@@ -68,6 +77,7 @@ from min_tfs_client_tpu.router.proxy import (
     routing_info,
     step_ordinal_guarded,
 )
+from min_tfs_client_tpu.utils import aio_loop
 from min_tfs_client_tpu.utils.status import (
     ServingError,
     error_from_exception,
@@ -81,46 +91,6 @@ log = logging.getLogger(__name__)
 # asyncio bookkeeping (no syscalls beyond the timerfd) while catching
 # any stall long enough to matter against a millisecond-scale forward.
 LAG_TICK_S = 0.1
-
-# ONE grpc.aio event loop per process — not a style preference, a crash
-# boundary: a second loop in one process races grpc's C-core
-# PollerCompletionQueue and dies with BlockingIOError deep inside the
-# cython layer, long after construction and only under load. This
-# registry turns that latent crash into a typed error AT START.
-# (pid, plane) so a fork doesn't inherit the parent's claim.
-_active_plane_lock = threading.Lock()
-_active_plane = None  # guarded_by: _active_plane_lock
-
-
-def _claim_aio_plane(plane) -> None:
-    import os
-
-    from min_tfs_client_tpu.utils.status import ServingError
-
-    global _active_plane
-    with _active_plane_lock:
-        pid = os.getpid()
-        if _active_plane is not None and _active_plane[0] == pid:
-            raise ServingError.failed_precondition(
-                "a grpc.aio data plane is already running in this "
-                "process: grpc's completion queue supports ONE asyncio "
-                "event loop per process (a second crashes "
-                "PollerCompletionQueue with BlockingIOError under "
-                "load). Run additional routers as separate processes, "
-                "or use --data_plane=threads for an in-process "
-                "second router.")
-        _active_plane = (pid, plane)
-
-
-def _release_aio_plane(plane) -> None:
-    import os
-
-    global _active_plane
-    with _active_plane_lock:
-        if _active_plane is not None and \
-                _active_plane == (os.getpid(), plane):
-            _active_plane = None
-
 
 class AioChannelPool:
     """One persistent `grpc.aio` channel per backend. Created and used
@@ -161,10 +131,13 @@ class AioChannelPool:
 
 
 class AioDataPlane:
-    """The asyncio byte proxy: its own thread running its own loop,
-    started/stopped from the (threaded) control plane. The membership
-    poller, REST surface, and flight recorder stay exactly where they
-    were — only the gRPC data path moves onto the loop."""
+    """The asyncio byte proxy, on the process's ONE gRPC event loop
+    (utils/aio_loop.py: a second loop in one process crashes grpc's
+    completion queue under load, so every aio plane and every in-process
+    ModelServer's front end share that one), started/stopped from the
+    (threaded) control plane. The membership poller, REST surface, and
+    flight recorder stay exactly where they were — only the gRPC data
+    path runs on the loop."""
 
     def __init__(self, core: RouterCore, *,
                  default_timeout_s: float = 60.0,
@@ -175,8 +148,7 @@ class AioDataPlane:
         self._loop_lag_warn_ms = loop_lag_warn_ms
         self._grace_s = grace_s
         self._channels = AioChannelPool()
-        self._loop: Optional[asyncio.AbstractEventLoop] = None
-        self._thread: Optional[threading.Thread] = None
+        self._served = None  # Future of _serve, from aio_loop.submit
         self._started = threading.Event()
         self._boot_error: Optional[BaseException] = None
         self._bound_port: Optional[int] = None
@@ -186,47 +158,37 @@ class AioDataPlane:
     # -- lifecycle -----------------------------------------------------------
 
     def start(self, port: int) -> int:
-        """Boot the loop thread, bind the port, return the bound port.
-        Raises the boot error (e.g. port in use) in the caller — and a
-        typed FAILED_PRECONDITION when this process already runs an aio
-        plane (the one-loop-per-process invariant; see _claim_aio_plane)."""
-        _claim_aio_plane(self)
-        # servelint: thread-ok written once HERE, before the loop
-        # thread spawns below; the loop thread only reads it
+        """Serve on the process's loop, bind the port, return the bound
+        port. Raises the boot error (e.g. port in use) in the caller."""
+        # servelint: thread-ok written once HERE, before the serve
+        # coroutine is submitted below; the loop thread only reads it
         self._requested_port = port
-        self._thread = threading.Thread(
-            target=self._run, name="router-aio-data-plane", daemon=True)
-        self._thread.start()
+        self._served = aio_loop.submit(self._serve())
         if not self._started.wait(timeout=30.0):
-            _release_aio_plane(self)
             raise RuntimeError("aio data plane failed to start within 30s")
         if self._boot_error is not None:
-            self._thread.join(timeout=5.0)
-            _release_aio_plane(self)
             raise self._boot_error
         self._core.loop_health.set_mode("aio")
         return self._bound_port
 
     def stop(self, grace: float = 2.0) -> None:
-        loop = self._loop
-        if loop is not None and not loop.is_closed():
-            try:
-                loop.call_soon_threadsafe(self._request_stop, grace)
-            except RuntimeError:  # pragma: no cover - loop already gone
-                pass
-        if self._thread is not None:
+        if self._served is None or self._served.done():
+            return
+        aio_loop.get().call_soon_threadsafe(self._request_stop, grace)
+        try:
             # Bounded teardown: grace for in-flight RPCs + slack for the
-            # channel closes; past that the daemon thread dies with the
-            # process (same discipline as the threaded plane's stop).
-            self._thread.join(timeout=grace + 10.0)
-        _release_aio_plane(self)
+            # channel closes; past that the serve task is left to the
+            # loop (same discipline as the threaded plane's stop).
+            self._served.result(timeout=grace + 10.0)
+        except concurrent.futures.TimeoutError:
+            pass
 
     def wait_for_termination(self) -> None:
-        if self._thread is not None:
+        if self._served is not None:
             # servelint: blocks the router main thread parks here for
             # the process lifetime, exactly like grpc's own
             # wait_for_termination; SIGINT/stop() unblocks it
-            self._thread.join()
+            self._served.result()
 
     def _request_stop(self, grace: float | None = None) -> None:
         # Runs ON the loop via call_soon_threadsafe: flip the flag the
@@ -243,25 +205,6 @@ class AioDataPlane:
         event = getattr(self, "_stop_event", None)
         if event is not None:
             event.set()
-
-    def _run(self) -> None:
-        loop = asyncio.new_event_loop()
-        # servelint: thread-ok atomic reference publish; foreign-thread
-        # readers (stop) only call the loop's threadsafe entry points
-        self._loop = loop
-        asyncio.set_event_loop(loop)
-        try:
-            loop.run_until_complete(self._serve())
-        except BaseException as exc:  # pragma: no cover - boot failures
-            if not self._started.is_set():
-                # servelint: thread-ok written before _started.set();
-                # start() reads only after wait() — Event handoff
-                self._boot_error = exc
-                self._started.set()
-            else:
-                log.exception("aio data plane crashed")
-        finally:
-            loop.close()
 
     async def _serve(self) -> None:
         import grpc

@@ -20,6 +20,7 @@ silent eviction of a live session mid-generation.
 
 from __future__ import annotations
 
+import asyncio
 import collections
 import contextlib
 import dataclasses
@@ -31,6 +32,7 @@ import weakref
 from typing import Callable, Optional
 
 from min_tfs_client_tpu.observability import tracing
+from min_tfs_client_tpu.utils import aio_loop
 from min_tfs_client_tpu.utils.status import ServingError
 
 # -- which paging a pool gets ---------------------------------------------
@@ -376,10 +378,15 @@ class DecodeSessionStore:
     def close(self, session_id: bytes) -> bool:
         with self._lock:
             entry = self._states.pop(session_id, None)
-            if entry is not None and self._on_evict is not None:
-                self._on_evict(entry[0])
             self._report()
-            return entry is not None
+        # Outside the lock: a pooled state's on_evict waits for the tick
+        # in flight to launch (TickBatcher.release) and for the pool's
+        # lock, a launch's length together, and every `take` of every
+        # other session would queue behind it: on the gRPC event loop,
+        # where the steps run, that is every request of the process.
+        if entry is not None and self._on_evict is not None:
+            self._on_evict(entry[0])
+        return entry is not None
 
     def clear(self) -> None:
         with self._lock:
@@ -1739,10 +1746,11 @@ class _Waiter:
 
     __slots__ = ("ready", "outcome")
 
-    def __init__(self):
-        # Set when the request may leave with `outcome`: each waiter has
-        # its own, so that a round wakes its riders and nobody else.
-        self.ready = threading.Event()
+    def __init__(self, ready):
+        # Set (a thread's Event) or resolved (a coroutine's future) when
+        # the request may leave with `outcome`: each waiter has its own,
+        # so that a round wakes its riders and nobody else.
+        self.ready = ready
         self.outcome = None  # (row, round, raised)
 
 
@@ -1863,7 +1871,7 @@ class TickBatcher:
                 waiter.outcome = (ServingError.not_found(
                     f"decode slot {slot} was released under its step"),
                     None, True)
-                waiter.ready.set()
+                _set_ready([waiter])
             took = entry.round
         # Timed + loop-on-predicate (servelint DL003): the loop sets the
         # event in its `finally` too.
@@ -1873,29 +1881,10 @@ class TickBatcher:
     def step(self, slot: int):
         """Collect the slot's next token: its row, or the typed error the
         pool's tick gave for the slot (returned, not raised); an
-        exception of the whole tick is raised."""
-        arrived, waiter = time.perf_counter(), None
-        with self._lock:
-            entry = self._slots.get(slot)
-            if entry is None:
-                return _slot_fatal(
-                    f"decode slot {slot} is not open in the tick loop")
-            if entry.room <= 0 and entry.parked is None:
-                return _slot_fatal(
-                    f"decode slot {slot} stepped past its cache")
-            outcome = entry.parked
-            ahead = outcome is not None or entry.round is not None
-            if outcome is not None:
-                entry.parked = None
-                self._collected_locked(entry, outcome, arrived)
-            else:
-                waiter = entry.waiter = _Waiter()
-                if entry.round is None and entry.due_at is None:
-                    # Not ahead (its step was refused while nobody asked
-                    # for it): now that it is asked for, it is due.
-                    entry.due_at = arrived
-                self._start_loop_locked()
-            self._counters["decode_steps_ahead"] += ahead
+        exception of the whole tick is raised. Blocks the calling thread
+        until the round that computes the token has handed it out."""
+        arrived = time.perf_counter()
+        outcome, ahead, waiter = self._ask(slot, arrived, threading.Event)
         if waiter is not None:
             while not waiter.ready.wait(timeout=0.1):
                 # Timed (servelint DL003): a loop lost to an
@@ -1904,11 +1893,64 @@ class TickBatcher:
                 with self._lock:
                     self._start_loop_locked()
             outcome = waiter.outcome
+        return self._answer(slot, outcome, arrived, ahead)
+
+    async def astep(self, slot: int):
+        """`step` for a caller on the gRPC event loop (utils/aio_loop):
+        the same contract, but where `step` blocks its thread this one
+        awaits, so the loop goes on answering other requests; the round
+        that computes the token resolves the future from the tick
+        loop's thread (`_wake`), every rider of a round in one call."""
+        arrived = time.perf_counter()
+        outcome, ahead, waiter = self._ask(
+            slot, arrived, asyncio.get_running_loop().create_future)
+        if waiter is not None:
+            # No timeout, unlike `step`'s wait: no thread is parked here,
+            # and a loop that dies settles its rounds on its way out
+            # (`_run`'s finally), which resolves this.
+            await waiter.ready
+            outcome = waiter.outcome
+        return self._answer(slot, outcome, arrived, ahead)
+
+    def _ask(self, slot: int, arrived: float, make_ready):
+        """The locked half of a step: (outcome, ahead, waiter). A parked
+        token is collected here, and `waiter` is None; else `outcome` is
+        None and the round that computes the token gives it to `waiter`
+        and sets its `ready` (made by `make_ready`: an Event for a
+        thread, a future for a coroutine)."""
+        with self._lock:
+            entry = self._slots.get(slot)
+            if entry is None:
+                return (_slot_fatal(f"decode slot {slot} is not open in "
+                                    "the tick loop"), None, False), 0, None
+            if entry.room <= 0 and entry.parked is None:
+                return (_slot_fatal(f"decode slot {slot} stepped past its "
+                                    "cache"), None, False), 0, None
+            outcome, waiter = entry.parked, None
+            ahead = outcome is not None or entry.round is not None
+            if outcome is not None:
+                entry.parked = None
+                self._collected_locked(entry, outcome, arrived)
+            else:
+                waiter = entry.waiter = _Waiter(make_ready())
+                if entry.round is None and entry.due_at is None:
+                    # Not ahead (its step was refused while nobody asked
+                    # for it): now that it is asked for, it is due.
+                    entry.due_at = arrived
+                self._start_loop_locked()
+            self._counters["decode_steps_ahead"] += ahead
+        return outcome, int(ahead), waiter
+
+    def _answer(self, slot: int, outcome: tuple, arrived: float,
+                ahead: int):
+        """The rest of a step, under no lock: its span, the round's
+        spans, its cost; the row, or the raise."""
         row, took, raised = outcome
         if took is not None:
             tracing.add_span("decode/wait", arrived,
                              max(arrived, took.taken),
-                             round=took.ordinal, ahead=int(ahead))
+                             round=took.ordinal, ahead=ahead,
+                             inline=int(aio_loop.on_loop_thread()))
             trace = tracing.current_trace()
             try:
                 # Each span of the round goes onto ONE trace: that of
@@ -2006,6 +2048,7 @@ class TickBatcher:
         back = None     # (round, batch, results, err): back, not settled
         flying = None   # (round, batch): in the tick
         settled = 0.0   # when the round before's fetch ended
+        ended = False   # left by the front door: `_running` is cleared
         try:
             while True:
                 with self._lock:
@@ -2015,7 +2058,7 @@ class TickBatcher:
                     before = back[0] if back is not None else None
                     taken = self._snapshot_locked(now)
                     if taken is None:
-                        self._running = False
+                        self._running, ended = False, True
                 back = None
                 if taken is None:
                     self._wake(before, None)
@@ -2049,8 +2092,13 @@ class TickBatcher:
                             RuntimeError("the decode tick loop died"),
                             time.perf_counter())
                     self._wake(lost[0], lost[0])
-            with self._lock:
-                self._running = False
+            if not ended:
+                # A loop that ended by itself cleared the flag under the
+                # lock of its last snapshot; a step may have started its
+                # successor since, and clearing it again here would let a
+                # third loop tick beside that one.
+                with self._lock:
+                    self._running = False
 
     def _wake(self, before: Optional[TickRound],
               took: Optional[TickRound]) -> None:
@@ -2065,8 +2113,29 @@ class TickBatcher:
             before.add_span("decode/deliver", before.fetched or now, now,
                             {"round": before.ordinal})
             handed, before._handed = before._handed, []
-            for waiter in handed:
-                waiter.ready.set()
+            _set_ready(handed)
+
+
+def _set_ready(waiters: list) -> None:
+    """Wake the requests that hold an outcome: a thread by its Event; the
+    coroutines, which wait on the gRPC event loop, all in ONE call onto
+    that loop, where a future may alone be resolved (one wake-up of the
+    loop thread a round, not one a rider)."""
+    by_loop: dict = {}
+    for waiter in waiters:
+        if isinstance(waiter.ready, threading.Event):
+            waiter.ready.set()
+        else:
+            by_loop.setdefault(waiter.ready.get_loop(), []).append(
+                waiter.ready)
+    for loop, futures in by_loop.items():
+        loop.call_soon_threadsafe(_resolve, futures)
+
+
+def _resolve(futures: list) -> None:
+    for future in futures:
+        if not future.done():  # its request was cancelled meanwhile
+            future.set_result(None)
 
 
 def _slot_fatal(message: str) -> ServingError:

@@ -176,7 +176,11 @@ class _Backend(NamedTuple):
     `store` and `kv_pool` (the paged pool, else None) are what the loader
     re-labels with the real model:version; `dedup` is the guard the
     inits and the close forget a session in; `loop_counters` reads the
-    pooled backend's tick loop (TickBatcher.counters).
+    pooled backend's tick loop (TickBatcher.counters). `aadvance` is
+    `advance` as a coroutine function, decode_step's `Signature.afn`,
+    where the backend can await what `advance` blocks on: the tick
+    loop's round. The per-session store has none (its step is a device
+    dispatch of its own).
     """
 
     admit: Callable
@@ -186,6 +190,7 @@ class _Backend(NamedTuple):
     dedup: StepDeduper
     kv_pool: Optional[PagedSlotPool] = None
     loop_counters: Optional[Callable[[], dict]] = None
+    aadvance: Optional[Callable] = None
 
 
 def _deduper(store: DecodeSessionStore, max_sessions: int) -> StepDeduper:
@@ -320,21 +325,26 @@ def _pooled_backend(params, model: DecodeModel, prefill_jit, *,
         # first decode_step arrives.
         batcher.admit(slot, max_decode_len - start)
 
-    def step_fn(inputs):
+    def stepping(inputs):
+        """decode_step, written once as a generator for its two callers:
+        it yields where it may have to wait, `(collect, slot)` for the
+        slot's row (the token is parked already, or the round that
+        computes it is waited for; a slot mid-prefix stays in the rounds
+        until its first real token) and `(release, slot)` to retire the
+        slot (the pool's lock, which a tick holds through its launch),
+        and returns the step's outputs. `step_fn` drives it on a thread
+        that may block, `astep_fn` on the event loop, where it awaits."""
         with _Step(dedup, inputs) as step:
             if step.out is None:
                 sid = step.sid
                 slot, host_step = store.take(sid)
                 try:
-                    # Collects: the token is parked already, or the round
-                    # that computes it is awaited (a slot mid-prefix
-                    # stays in the rounds until its first real token).
-                    row = batcher.step(slot)
+                    row = yield "collect", slot
                 except Exception:
                     # The whole tick failed: the pool row may be in an
                     # undefined state; retire the slot rather than hand
                     # it to a future session mid-generation.
-                    release_slot(slot)
+                    yield "release", slot
                     raise
                 if isinstance(row, Exception):
                     # Per-slot failure from the paged pool's tick (typed
@@ -343,7 +353,7 @@ def _pooled_backend(params, model: DecodeModel, prefill_jit, *,
                     # a capacity REFUSAL whose state is intact and may
                     # retry after others close.
                     if getattr(row, "slot_fatal", True):
-                        release_slot(slot)
+                        yield "release", slot
                     else:
                         store.put(sid, (slot, host_step))
                     raise row
@@ -351,15 +361,63 @@ def _pooled_backend(params, model: DecodeModel, prefill_jit, *,
                 if host_step < max_decode_len:
                     store.put(sid, (slot, host_step))
                 else:
-                    release_slot(slot)  # cache exhausted: session ends
+                    yield "release", slot  # cache exhausted: session ends
                 step.answer(row["token"].reshape(-1),
                             row["finished"].reshape(-1).astype(np.int32),
                             host_step)
         return step.out
 
+    def step_fn(inputs):
+        return _drive(stepping(inputs), {"collect": batcher.step,
+                                         "release": release_slot})
+
+    async def astep_fn(inputs):
+        import asyncio
+
+        def release(slot):
+            # Off the loop: it can wait a launch's length for two locks.
+            return asyncio.get_running_loop().run_in_executor(
+                None, release_slot, slot)
+
+        return await _adrive(stepping(inputs), {"collect": batcher.astep,
+                                                "release": release})
+
     # release: the store's on_evict hands the slot back to the pool.
     return _Backend(admit, step_fn, store.close, store, dedup,
-                    pool if paged else None, batcher.counters)
+                    pool if paged else None, batcher.counters, astep_fn)
+
+
+def _drive(steps, do: dict):
+    """Run a generator of `(name, argument)` requests to its end on this
+    thread: each is answered by `do[name](argument)`, whose value, or
+    exception, goes back into the generator; the generator's return
+    value is the result."""
+    try:
+        name, arg = next(steps)
+        while True:
+            try:
+                value = do[name](arg)
+            except Exception as exc:  # noqa: BLE001 - the generator's to handle
+                name, arg = steps.throw(exc)
+            else:
+                name, arg = steps.send(value)
+    except StopIteration as done:
+        return done.value
+
+
+async def _adrive(steps, do: dict):
+    """`_drive` on an event loop: `do[name](argument)` is awaited."""
+    try:
+        name, arg = next(steps)
+        while True:
+            try:
+                value = await do[name](arg)
+            except Exception as exc:  # noqa: BLE001 - the generator's to handle
+                name, arg = steps.throw(exc)
+            else:
+                name, arg = steps.send(value)
+    except StopIteration as done:
+        return done.value
 
 
 def build_session_signatures(params, model: DecodeModel, *, seq_len: int,
@@ -468,7 +526,7 @@ def build_session_signatures(params, model: DecodeModel, *, seq_len: int,
             outputs={"token": TensorSpec(np.int32, (None,)),
                      "finished": TensorSpec(np.int32, (None,)),
                      "step": TensorSpec(np.int32, ())},
-            on_host=True, batched=False),
+            on_host=True, batched=False, afn=backend.aadvance),
         "decode_close": Signature(
             fn=close_fn,
             inputs={"session_id": session_spec},

@@ -21,7 +21,7 @@ from __future__ import annotations
 import threading
 
 from dataclasses import dataclass, field as dc_field
-from typing import Callable, Mapping, Optional, Sequence
+from typing import Awaitable, Callable, Mapping, Optional, Sequence
 
 import numpy as np
 
@@ -204,6 +204,17 @@ class Signature:
     mesh: Optional[object] = dc_field(default=None, repr=False,
                                       compare=False)
 
+    # Optional coroutine function, `fn` for a caller on an event loop:
+    # the same outputs by the same work, but it AWAITS wherever `fn`
+    # blocks its thread (a device round, a batch-mate, a lock held
+    # through either) and never sleeps or waits otherwise. None, the
+    # default, says there is no such form. The gRPC front end runs a
+    # request whose signature has one on its event-loop thread, with no
+    # thread hand-off (`arun`; docs/MIGRATING.md "A signature that can
+    # await instead of block"). Host, unpartitioned signatures only.
+    afn: Optional[Callable[..., Awaitable[dict[str, object]]]] = \
+        dc_field(default=None, repr=False, compare=False)
+
     # "model:version:signature", stamped by Servable.__init__ — keys the
     # compile-event ledger (observability/runtime.py).
     telemetry_label: str = ""
@@ -217,6 +228,12 @@ class Signature:
                                              compare=False)
 
     def __post_init__(self):
+        if self.afn is not None and (
+                not self.on_host or self.partition is not None
+                or self.params is not None):
+            raise ValueError(
+                "afn is supported on host signatures that call fn(inputs) "
+                "directly (no device dispatch, partition or params)")
         if self.optional_inputs:
             if not self.on_host or self.batched:
                 raise ValueError(
@@ -434,6 +451,21 @@ class Signature:
         different threads to overlap batch k+1's dispatch with batch k's
         outstanding D2H copies."""
         return self.dispatch(inputs, output_filter).result()
+
+    async def arun(
+        self,
+        inputs: Mapping[str, np.ndarray],
+        output_filter: Sequence[str] = (),
+    ) -> dict[str, np.ndarray]:
+        """`run` through `afn`, on an event loop: what `run` does for a
+        host signature, with the wait awaited."""
+        with tracing.span("serving/validate"):
+            arrays = self.validate(inputs, output_filter)
+        keys = list(output_filter) if output_filter else list(self.outputs)
+        with tracing.span("host/execute"):
+            outputs = await self.afn(arrays)
+        self._check_produced(outputs, keys)
+        return {k: np.asarray(outputs[k]) for k in keys}
 
     def dispatch(
         self,

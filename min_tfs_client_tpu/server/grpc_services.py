@@ -4,14 +4,28 @@ Parity with model_servers/prediction_service_impl.cc and
 model_service_impl.cc — the servicers only translate deadline/metadata and
 map ServingError codes onto the gRPC trailer (ToGRPCStatus,
 grpc_status_util.cc:23).
+
+The server they are registered on is a `grpc.aio` server on the
+process's one event loop (server/server.py, utils/aio_loop.py). A plain
+method here is a synchronous servicer, which that server runs on its
+worker pool (`--grpc_max_threads`): everything but `Predict`. `Predict`
+is a coroutine that chooses by what it can observe in the request: where
+the request's signature has a form that awaits instead of blocking
+(`Handlers.can_await`: a decode_step of a pooled backend, whose token is
+parked already or comes with the tick loop's round), the handler runs
+there and then on the loop thread, with no thread hand-off, and the loop
+answers other requests while it awaits; every other request runs on the
+same worker pool.
 """
 
 from __future__ import annotations
 
-import grpc
+import asyncio
+import contextvars
 
 from min_tfs_client_tpu.protos import grpc_service as gs
 from min_tfs_client_tpu.server.handlers import Handlers
+from min_tfs_client_tpu.utils import aio_loop
 from min_tfs_client_tpu.utils.status import (
     error_from_exception,
     to_grpc_code,
@@ -32,6 +46,7 @@ def _incoming_trace_id(context):
 def _guard(handler_fn, request, context):
     from min_tfs_client_tpu.observability import tracing
 
+    aio_loop.note_request(inline=False)
     try:
         # Adopt the propagated trace id (None = mint locally): the
         # RequestTrace the handler opens then shares the caller's id, so
@@ -44,12 +59,45 @@ def _guard(handler_fn, request, context):
         context.abort(to_grpc_code(err.code), err.message)
 
 
-class PredictionServiceImpl(gs.PredictionServiceServicer):
-    def __init__(self, handlers: Handlers):
-        self._handlers = handlers
+def _retrieve(task) -> None:
+    """A shielded task's exception is looked at even where its RPC has
+    gone (asyncio logs one that nobody retrieved)."""
+    if not task.cancelled():
+        task.exception()
 
-    def Predict(self, request, context):
-        return _guard(self._handlers.predict, request, context)
+
+class PredictionServiceImpl(gs.PredictionServiceServicer):
+    def __init__(self, handlers: Handlers, pool):
+        self._handlers = handlers
+        # The server's worker pool: where a Predict that may wait runs.
+        self._pool = pool
+
+    async def Predict(self, request, context):
+        from min_tfs_client_tpu.observability import tracing
+
+        handlers = self._handlers
+        loop = asyncio.get_running_loop()
+        # The RPC runs in its own task, so the two context variables are
+        # the task's (`_guard` has what they are for).
+        with tracing.transport("grpc"), \
+                tracing.adopt(_incoming_trace_id(context)):
+            try:
+                if handlers.can_await(request):
+                    aio_loop.note_request(inline=True)
+                    # A task of its own (under a copy of this context),
+                    # shielded: a client that gives up cancels the RPC,
+                    # not the step, which ends as it would on a pool
+                    # thread, its answer kept for the resend.
+                    work = loop.create_task(handlers.apredict(request))
+                    work.add_done_callback(_retrieve)
+                    return await asyncio.shield(work)
+                aio_loop.note_request(inline=False)
+                return await loop.run_in_executor(
+                    self._pool, contextvars.copy_context().run,
+                    handlers.predict, request)
+            except Exception as exc:  # noqa: BLE001 - mapped onto the wire
+                err = error_from_exception(exc)
+                await context.abort(to_grpc_code(err.code), err.message)
 
     def Classify(self, request, context):
         return _guard(self._handlers.classify, request, context)

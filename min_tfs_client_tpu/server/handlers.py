@@ -50,70 +50,88 @@ def _effective_spec(target, model_spec, version: int, signature_name: str) -> No
         target.signature_name = signature_name
 
 
-def _instrumented(api: str):
+def _spec_of(request):
+    spec = getattr(request, "model_spec", None)
+    if spec is None:
+        tasks = getattr(request, "tasks", None)
+        spec = tasks[0].model_spec if tasks else None
+    return spec
+
+
+class _counted:
     """Request count/latency instrumentation (the serving-path metrics the
-    reference records in servables/tensorflow/util.cc:36-71) + the
-    request-trace envelope: every transport (gRPC, REST, tpu://) funnels
-    through these methods, so opening the RequestTrace here puts ALL entry
-    points on the tracing spine."""
+    reference records in servables/tensorflow/util.cc:36-71) and the error
+    tap, around one handler invocation: entered OUTSIDE the request's
+    trace, so that the trace has finished, with its status, when an
+    error is counted (`opened` hands it the trace's id). A plain class with slots: this wraps every
+    request."""
+
+    __slots__ = ("api", "model", "signature", "trace_id", "_start")
+
+    def __init__(self, api: str, request):
+        spec = _spec_of(request)
+        self.api = api
+        self.model = spec.name if spec is not None else ""
+        self.signature = spec.signature_name if spec is not None else ""
+        self.trace_id = ""
+
+    def __enter__(self):
+        self._start = time.perf_counter()
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        from min_tfs_client_tpu.server import metrics
+
+        if exc is None:
+            metrics.request_count.increment(self.api, "0")
+            metrics.request_latency.observe(
+                (time.perf_counter() - self._start) * 1e6, self.api)
+        elif isinstance(exc, Exception):
+            # Same mapping the transports apply to the wire status
+            # (error_from_exception): an unexpected RuntimeError IS an
+            # INTERNAL to the client, so it must count — and trigger the
+            # flight-recorder dump — as one here too.
+            from min_tfs_client_tpu.utils.status import error_from_exception
+
+            code = error_from_exception(exc).code
+            metrics.request_count.increment(self.api, str(code))
+            # Black-box ring entry (and the one-shot dump when the code
+            # is INTERNAL): every transport funnels through here, so
+            # this is THE error tap.
+            from min_tfs_client_tpu.observability import flight_recorder
+
+            flight_recorder.record_error(
+                self.api, self.model, self.signature, code, str(exc),
+                trace_id=self.trace_id)
+        return False
+
+    def opened(self, trace) -> None:
+        """The request's trace is open (None: tracing is off). Inside
+        the trace + error funnel: an injected typed error counts,
+        records, and surfaces on the wire exactly like a real handler
+        failure; a delay lands in this request's stage timeline."""
+        from min_tfs_client_tpu.robustness import faults
+
+        if trace is not None:
+            self.trace_id = trace.trace_id
+        faults.point("backend.handle.pre", api=self.api, model=self.model,
+                     signature=self.signature)
+
+
+def _instrumented(api: str):
+    """`_counted` + the request-trace envelope around a handler method:
+    every transport (gRPC, REST, tpu://) funnels through these methods,
+    so opening the RequestTrace here puts ALL entry points on the
+    tracing spine."""
 
     def wrap(fn):
         @functools.wraps(fn)
         def inner(self, request):
-            from min_tfs_client_tpu.server import metrics
-
-            spec = getattr(request, "model_spec", None)
-            if spec is None:
-                tasks = getattr(request, "tasks", None)
-                spec = tasks[0].model_spec if tasks else None
-            start = time.perf_counter()
-            trace_id = ""
-            try:
-                with tracing.request_trace(
-                        api,
-                        model=spec.name if spec is not None else "",
-                        signature=(spec.signature_name
-                                   if spec is not None else "")) as trace:
-                    if trace is not None:
-                        trace_id = trace.trace_id
-                    # Inside the trace + error funnel: an injected
-                    # typed error counts, records, and surfaces on the
-                    # wire exactly like a real handler failure; a delay
-                    # lands in this request's stage timeline.
-                    from min_tfs_client_tpu.robustness import faults
-
-                    faults.point(
-                        "backend.handle.pre", api=api,
-                        model=spec.name if spec is not None else "",
-                        signature=(spec.signature_name
-                                   if spec is not None else ""))
-                    response = fn(self, request)
-            except Exception as exc:
-                # Same mapping the transports apply to the wire status
-                # (error_from_exception): an unexpected RuntimeError IS
-                # an INTERNAL to the client, so it must count — and
-                # trigger the flight-recorder dump — as one here too.
-                from min_tfs_client_tpu.utils.status import (
-                    error_from_exception,
-                )
-
-                code = error_from_exception(exc).code
-                metrics.request_count.increment(api, str(code))
-                # Black-box ring entry (and the one-shot dump when the
-                # code is INTERNAL): every transport funnels through
-                # here, so this is THE error tap.
-                from min_tfs_client_tpu.observability import flight_recorder
-
-                flight_recorder.record_error(
-                    api,
-                    spec.name if spec is not None else "",
-                    spec.signature_name if spec is not None else "",
-                    code, str(exc), trace_id=trace_id)
-                raise
-            metrics.request_count.increment(api, "0")
-            metrics.request_latency.observe(
-                (time.perf_counter() - start) * 1e6, api)
-            return response
+            with _counted(api, request) as tap, tracing.request_trace(
+                    api, model=tap.model,
+                    signature=tap.signature) as trace:
+                tap.opened(trace)
+                return fn(self, request)
         return inner
     return wrap
 
@@ -138,39 +156,85 @@ class Handlers:
 
     @_instrumented("predict")
     def predict(self, request: apis.PredictRequest) -> apis.PredictResponse:
+        with self.core.servable_handle(request.model_spec) as handle:
+            signature, inputs = self._predict_inputs(handle, request)
+            outputs = signature.run(inputs, tuple(request.output_filter))
+            return self._predict_response(handle, request, outputs)
+
+    def can_await(self, request: apis.PredictRequest) -> bool:
+        """Whether `apredict(request)` may run on the gRPC event-loop
+        thread, where nothing may block: only where the request's own
+        signature has a form that awaits instead (`Signature.afn`; None,
+        the default, says no) and nothing around it can sleep either (an
+        armed fault point, a request log that writes). A request that
+        cannot even be asked about, for an unknown model or signature,
+        answers no: the pool's run gives the error its trace and its
+        wire form."""
+        from min_tfs_client_tpu.robustness import faults
+
+        spec = request.model_spec
+        if faults.covers("backend.handle.pre") or \
+                self.core.request_logger.logs(spec.name):
+            return False
+        try:
+            with self.core.servable_handle(spec) as handle:
+                return handle.servable.signature(
+                    spec.signature_name).afn is not None
+        except Exception:  # servelint: fallback-ok no outcome here: the
+            return False   # request's own run, on the pool, reports it
+
+    async def apredict(
+            self, request: apis.PredictRequest) -> apis.PredictResponse:
+        """`predict` on an event loop, for a request that `can_await`:
+        the same envelope, the same stages, the signature's wait
+        awaited."""
+        with _counted("predict", request) as tap, tracing.request_trace(
+                "predict", model=tap.model,
+                signature=tap.signature) as trace:
+            tap.opened(trace)
+            with self.core.servable_handle(request.model_spec) as handle:
+                signature, inputs = self._predict_inputs(handle, request)
+                outputs = await signature.arun(
+                    inputs, tuple(request.output_filter))
+                return self._predict_response(handle, request, outputs)
+
+    def _predict_inputs(self, handle, request: apis.PredictRequest):
+        """(signature, decoded inputs) of a Predict, annotated on its
+        trace."""
         from min_tfs_client_tpu.tensor.codec import tensor_protos_to_dict
 
-        with self.core.servable_handle(request.model_spec) as handle:
-            servable = handle.servable
-            tracing.annotate(version=handle.id.version)
-            sig_name = request.model_spec.signature_name
-            signature = servable.signature(sig_name)
-            inputs = tensor_protos_to_dict(request.inputs, writable=False)
-            sid = inputs.get("session_id")
-            if sid is not None:
-                # Sessioned decode surface: the session id on the trace
-                # is what cross-links /monitoring/traces to the
-                # per-session timeline at /monitoring/sessions.
-                raw = np.asarray(sid).reshape(-1)
-                if raw.size == 1:
-                    value = raw[0]
-                    tracing.annotate(session_id=(
-                        value.decode("utf-8", "replace")
-                        if isinstance(value, bytes) else str(value)))
-            outputs = signature.run(inputs, tuple(request.output_filter))
-            response = apis.PredictResponse()
-            with tracing.span("serving/serialize"):
-                _effective_spec(response.model_spec, request.model_spec,
-                                handle.id.version,
-                                request.model_spec.signature_name)
-                for alias, arr in outputs.items():
-                    response.outputs[alias].CopyFrom(ndarray_to_tensor_proto(
-                        arr, use_tensor_content=self._as_content))
-            self.core.request_logger.maybe_log(
-                request.model_spec.name,
-                lambda: _predict_log(request, response),
-                response.model_spec)
-            return response
+        tracing.annotate(version=handle.id.version)
+        signature = handle.servable.signature(
+            request.model_spec.signature_name)
+        inputs = tensor_protos_to_dict(request.inputs, writable=False)
+        sid = inputs.get("session_id")
+        if sid is not None:
+            # Sessioned decode surface: the session id on the trace
+            # is what cross-links /monitoring/traces to the
+            # per-session timeline at /monitoring/sessions.
+            raw = np.asarray(sid).reshape(-1)
+            if raw.size == 1:
+                value = raw[0]
+                tracing.annotate(session_id=(
+                    value.decode("utf-8", "replace")
+                    if isinstance(value, bytes) else str(value)))
+        return signature, inputs
+
+    def _predict_response(self, handle, request: apis.PredictRequest,
+                          outputs) -> apis.PredictResponse:
+        response = apis.PredictResponse()
+        with tracing.span("serving/serialize"):
+            _effective_spec(response.model_spec, request.model_spec,
+                            handle.id.version,
+                            request.model_spec.signature_name)
+            for alias, arr in outputs.items():
+                response.outputs[alias].CopyFrom(ndarray_to_tensor_proto(
+                    arr, use_tensor_content=self._as_content))
+        self.core.request_logger.maybe_log(
+            request.model_spec.name,
+            lambda: _predict_log(request, response),
+            response.model_spec)
+        return response
 
     def _example_signature(self, servable, model_spec, want_method: str) -> Signature:
         signature = servable.signature(model_spec.signature_name)

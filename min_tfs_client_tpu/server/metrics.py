@@ -324,6 +324,13 @@ router_event_loop_lag_ms = Gauge(
     "analogue of thread-pool saturation; every in-flight forward's "
     "completion is late by about this much.", ())
 
+grpc_event_loop_lag_ms = Gauge(
+    ":tpu/serving/grpc_event_loop_lag_ms",
+    "Sampled scheduling lag of the process's gRPC event loop "
+    "(utils/aio_loop.py; overshoot of a fixed-interval ticker, ms): "
+    "every request answered on the loop, and every hand-off to the "
+    "worker pool, is late by about this much.", ())
+
 # -- fleet-view re-exports (router/fleet.py; docs/OBSERVABILITY.md) ----------
 fleet_backend_stale = Gauge(
     ":tpu/serving/fleet_backend_stale",

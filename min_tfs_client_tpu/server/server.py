@@ -10,6 +10,7 @@ optional SSL, and optionally re-polls the model config file
 
 from __future__ import annotations
 
+import asyncio
 import logging
 import os
 import pathlib
@@ -34,6 +35,7 @@ from min_tfs_client_tpu.server.grpc_services import (
     SessionServiceImpl,
 )
 from min_tfs_client_tpu.server.handlers import Handlers
+from min_tfs_client_tpu.utils import aio_loop
 from min_tfs_client_tpu.utils.status import ServingError
 
 
@@ -267,7 +269,7 @@ class Server:
     def __init__(self, options: ServerOptions):
         self.options = options
         self.core: Optional[ServerCore] = None
-        self._grpc_server: Optional[grpc.Server] = None
+        self._grpc_front: Optional[_GrpcFront] = None
         self._rest_server = None
         self._config_poll_stop = threading.Event()
         self._config_poll_thread: Optional[threading.Thread] = None
@@ -410,35 +412,8 @@ class Server:
         inter_op = opts.effective_inter_op_parallelism()
         grpc_threads = (min(opts.grpc_max_threads, inter_op) if inter_op
                         else opts.grpc_max_threads)
-        self._grpc_server = grpc.server(
-            futures.ThreadPoolExecutor(max_workers=grpc_threads),
-            options=_parse_channel_arguments(opts.grpc_channel_arguments))
-        gs.add_PredictionServiceServicer_to_server(
-            PredictionServiceImpl(handlers), self._grpc_server)
-        gs.add_ModelServiceServicer_to_server(
-            ModelServiceImpl(handlers), self._grpc_server)
-        gs.add_SessionServiceServicer_to_server(
-            SessionServiceImpl(handlers), self._grpc_server)
-        # tensorflow.ProfilerService on the MAIN port (server.cc:324,339).
-        from min_tfs_client_tpu.server.profiler import ProfilerServiceImpl
-
-        gs.add_ProfilerServiceServicer_to_server(
-            ProfilerServiceImpl(), self._grpc_server)
-        # grpc.health.v1.Health on the MAIN port — readiness for standard
-        # probe tooling (observability/health.py).
-        from min_tfs_client_tpu.server.grpc_services import (
-            health_service_handler,
-        )
-
-        self._grpc_server.add_generic_rpc_handlers(
-            (health_service_handler(),))
-        self.grpc_port = self._bind(self._grpc_server, opts.grpc_port)
-        if opts.grpc_socket_path:
-            if not self._grpc_server.add_insecure_port(
-                    f"unix:{opts.grpc_socket_path}"):
-                raise ServingError.unavailable(
-                    f"could not bind UNIX socket {opts.grpc_socket_path}")
-        self._grpc_server.start()
+        self._grpc_front = _GrpcFront(self, handlers, grpc_threads)
+        self.grpc_port = self._grpc_front.start()
 
         if opts.rest_api_port or opts.monitoring_config_file:
             from min_tfs_client_tpu.server.native_http import (
@@ -487,7 +462,7 @@ class Server:
             watchdog.start()
         return self
 
-    def _bind(self, server: grpc.Server, port: int) -> int:
+    def _bind(self, server: "grpc.aio.Server", port: int) -> int:
         opts = self.options
         if opts.ssl_config_file:
             ssl = _parse_text_proto(opts.ssl_config_file,
@@ -521,7 +496,9 @@ class Server:
     # -- lifecycle -----------------------------------------------------------
 
     def wait_for_termination(self) -> None:
-        self._grpc_server.wait_for_termination()
+        # servelint: blocks the main thread parks here for the process
+        # lifetime, as in grpc's own wait_for_termination; stop() ends it
+        self._grpc_front.wait()
 
     def stop(self, grace: float = 5.0,
              drain_grace: Optional[float] = None) -> None:
@@ -542,13 +519,8 @@ class Server:
               else drain_grace)
         if dg > 0:
             self._await_session_drain(dg)
-        if self._grpc_server is not None:
-            # Bounded (servelint DL003): grpc's stop() event fires when
-            # in-flight RPCs finish, but a handler wedged on a sick
-            # device would otherwise hold process shutdown hostage
-            # forever. Past grace + slack the server teardown proceeds;
-            # the daemonized handler threads die with the process.
-            self._grpc_server.stop(grace).wait(timeout=grace + 5.0)
+        if self._grpc_front is not None:
+            self._grpc_front.stop(grace)
         if self._rest_server is not None:
             self._rest_server.shutdown()
         if self.core is not None:
@@ -575,6 +547,115 @@ class Server:
             "drain grace %.1fs expired with %d decode session(s) still "
             "live; proceeding with shutdown", drain_grace,
             int(metrics.gauge_total(metrics.decode_session_count)))
+
+
+class _GrpcFront:
+    """The gRPC front end of one Server: a `grpc.aio` server on the
+    process's one event loop (utils/aio_loop.py; any number of Servers,
+    and a router's data plane, share it). `Predict` is a coroutine that
+    answers on the loop thread where the request needs no waiting
+    (server/grpc_services.py); everything else registered here is a
+    synchronous servicer, which the aio server runs on `pool`: the
+    `--grpc_max_threads` workers that blocking requests wait on."""
+
+    def __init__(self, server: "Server", handlers: Handlers, threads: int):
+        self._server = server
+        self._handlers = handlers
+        self._pool = futures.ThreadPoolExecutor(max_workers=threads)
+        self._started = threading.Event()
+        self._boot_error: Optional[BaseException] = None
+        self._port = 0
+        self._grace = 0.0      # the loop thread's, like _stopping
+        self._stopping = None  # asyncio.Event, made on the loop
+        self._served = None    # Future of _serve, from aio_loop.submit
+
+    def start(self) -> int:
+        """Bind and serve; returns the bound TCP port. A bind that fails
+        raises here, in the caller."""
+        self._served = aio_loop.submit(self._serve())
+        # Timed + loop-on-predicate (servelint DL003): _serve sets the
+        # event on every path.
+        while not self._started.wait(timeout=1.0):
+            pass
+        if self._boot_error is not None:
+            self._pool.shutdown(wait=False)
+            raise self._boot_error
+        return self._port
+
+    def _build(self) -> "grpc.aio.Server":
+        from min_tfs_client_tpu.server.grpc_services import (
+            health_service_handler,
+        )
+        from min_tfs_client_tpu.server.profiler import ProfilerServiceImpl
+
+        opts, handlers = self._server.options, self._handlers
+        server = grpc.aio.server(
+            migration_thread_pool=self._pool,
+            options=_parse_channel_arguments(opts.grpc_channel_arguments))
+        gs.add_PredictionServiceServicer_to_server(
+            PredictionServiceImpl(handlers, self._pool), server)
+        gs.add_ModelServiceServicer_to_server(
+            ModelServiceImpl(handlers), server)
+        gs.add_SessionServiceServicer_to_server(
+            SessionServiceImpl(handlers), server)
+        # tensorflow.ProfilerService on the MAIN port (server.cc:324,339).
+        gs.add_ProfilerServiceServicer_to_server(
+            ProfilerServiceImpl(), server)
+        # grpc.health.v1.Health on the MAIN port — readiness for standard
+        # probe tooling (observability/health.py).
+        server.add_generic_rpc_handlers((health_service_handler(),))
+        # servelint: thread-ok written before _started.set(); start()
+        # reads only after wait() — Event handoff
+        self._port = self._server._bind(server, opts.grpc_port)
+        if opts.grpc_socket_path:
+            if not server.add_insecure_port(
+                    f"unix:{opts.grpc_socket_path}"):
+                raise ServingError.unavailable(
+                    f"could not bind UNIX socket {opts.grpc_socket_path}")
+        return server
+
+    async def _serve(self) -> None:
+        self._stopping = asyncio.Event()
+        try:
+            server = self._build()
+            await server.start()
+        except BaseException as exc:  # noqa: BLE001 - raised by start()
+            # servelint: thread-ok same Event handoff as _port
+            self._boot_error = exc
+            return
+        finally:
+            self._started.set()
+        # servelint: blocks the serve coroutine parks here for the
+        # server's lifetime; stop() sets the event
+        await self._stopping.wait()
+        await server.stop(self._grace)
+
+    def _request_stop(self, grace: float) -> None:
+        # On the loop (call_soon_threadsafe), so that the grace is there
+        # before the serve coroutine wakes.
+        self._grace = grace
+        self._stopping.set()
+
+    def stop(self, grace: float) -> None:
+        """New RPCs are refused at once; those in flight get `grace`
+        seconds, then are cancelled."""
+        if self._served is None or self._served.done():
+            return
+        aio_loop.get().call_soon_threadsafe(self._request_stop, grace)
+        try:
+            # Bounded (servelint DL003): a handler wedged on a sick
+            # device would otherwise hold process shutdown hostage
+            # forever. Past grace + slack the teardown proceeds; the
+            # pool's daemonized threads die with the process.
+            self._served.result(timeout=grace + 5.0)
+        except futures.TimeoutError:
+            pass
+        self._pool.shutdown(wait=False)
+
+    def wait(self) -> None:
+        # servelint: blocks the main thread parks here for the process
+        # lifetime, as in grpc's own wait_for_termination; stop() ends it
+        self._served.result()
 
 
 def _parse_mesh_axes(spec: str) -> dict[str, int]:

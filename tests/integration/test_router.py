@@ -447,42 +447,57 @@ class TestRoutedAtMostOnce:
                                    signature_name="decode_close")
 
 
-class TestAioLoopGuard:
-    def test_second_aio_plane_in_one_process_is_typed_error(
+class TestAioLoopShared:
+    def test_second_aio_plane_in_one_process_shares_the_loop(
             self, fleet, tmp_path_factory):
-        """ONE grpc.aio event loop per process: a second used to be a
-        latent PollerCompletionQueue crash (BlockingIOError deep in
-        cython, under load, long after boot); now it is a typed
-        FAILED_PRECONDITION at start, with the escape hatch named."""
-        from min_tfs_client_tpu.utils.status import Code, ServingError
+        """ONE grpc.aio event loop per process (a second is a latent
+        PollerCompletionQueue crash: BlockingIOError deep in cython,
+        under load, long after boot). It used to be a typed refusal at
+        start; now every plane runs on the process's one loop
+        (utils/aio_loop.py), so a second router's aio plane starts
+        beside the module fleet's live one, and both answer."""
+        import threading
 
-        with pytest.raises(ServingError) as err:
-            Fleet(tmp_path_factory.mktemp("second_aio"), n=1)
-        assert err.value.code == Code.FAILED_PRECONDITION
-        assert "--data_plane=threads" in err.value.message
+        from min_tfs_client_tpu.utils import aio_loop
 
-    def test_claim_is_released_on_stop(self):
-        """The registry frees the slot when a plane stops — stop/start
-        cycles (and the threads escape hatch) must keep working.
-        Registry exercised directly with the module fleet's live claim
-        parked aside."""
-        from min_tfs_client_tpu.router import aio_proxy
-
-        with aio_proxy._active_plane_lock:
-            saved = aio_proxy._active_plane
-            aio_proxy._active_plane = None
+        second = Fleet(tmp_path_factory.mktemp("second_aio"), n=1)
         try:
-            sentinel = object()
-            aio_proxy._claim_aio_plane(sentinel)
-            with pytest.raises(Exception, match="already running"):
-                aio_proxy._claim_aio_plane(object())
-            aio_proxy._release_aio_plane(sentinel)
-            follower = object()
-            aio_proxy._claim_aio_plane(follower)  # freed: claim works
-            aio_proxy._release_aio_plane(follower)
+            second.wait_live(1)
+            for f in (second, fleet, second):
+                with f.client() as client:
+                    sid = np.asarray(b"shared-loop", object)
+                    client.predict_request(
+                        "sess", {"session_id": sid,
+                                 "base": np.asarray(7, np.int32)},
+                        signature_name="decode_init")
+                    client.predict_request("sess", {"session_id": sid},
+                                           signature_name="decode_close")
+            for f in (second, fleet):
+                assert f.snapshot()["data_plane"]["mode"] == "aio"
+            loops = [t.name for t in threading.enumerate()
+                     if t.name == aio_loop.THREAD_NAME]
+            assert loops == [aio_loop.THREAD_NAME]
         finally:
-            with aio_proxy._active_plane_lock:
-                aio_proxy._active_plane = saved
+            second.close()
+
+    def test_planes_start_and_stop_beside_each_other(self):
+        """Stop/start cycles keep working on the shared loop, and a
+        plane that stops takes nobody else's listener down."""
+        from min_tfs_client_tpu.router.aio_proxy import AioDataPlane
+        from min_tfs_client_tpu.router.core import RouterCore
+
+        cores = [RouterCore([], poll_interval_s=3600.0) for _ in range(2)]
+        first, follower = (AioDataPlane(core) for core in cores)
+        port = first.start(0)
+        assert port > 0
+        other = follower.start(0)
+        assert other not in (0, port)
+        first.stop(grace=0.5)
+        again = AioDataPlane(cores[0])
+        assert again.start(0) > 0  # stopped: a new plane starts
+        again.stop(grace=0.5)
+        follower.stop(grace=0.5)
+        follower.stop(grace=0.5)  # idempotent
 
 
 @pytest.mark.proc_timeout(300)
@@ -492,11 +507,8 @@ class TestDrain:
         SIGTERM -> NOT_SERVING immediately -> router stops sending new
         sessions -> the in-flight sessioned stream finishes against the
         draining process -> it exits cleanly once its sessions close."""
-        # threads plane: the module-scoped fleet's aio router is still
-        # live in this process, and a SECOND grpc.aio loop per process
-        # is now a typed error at start (aio_proxy._claim_aio_plane) —
-        # the PollerCompletionQueue crash it prevents is real. The
-        # drain choreography under test is plane-independent.
+        # threads plane: the drain choreography under test is
+        # plane-independent, and the threaded plane keeps a user here.
         f = Fleet(tmp_path_factory.mktemp("drain"), n=2,
                   drain_grace_s=30.0, data_plane="threads")
         try:

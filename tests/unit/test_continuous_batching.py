@@ -628,6 +628,100 @@ class TestTickBatcher:
         batcher.release(1)
         assert set(threading.enumerate()) <= before
 
+    def test_a_loop_that_has_ended_does_not_clear_its_successors_flag(self):
+        """Between a loop's last snapshot (it clears `_running` under the
+        lock) and its thread's exit a new slot may start the next loop;
+        the old one must not clear the flag again, or a third loop could
+        tick beside the second."""
+        ticks = _Ticks(hold=2)
+        batcher = TickBatcher(ticks)
+        wake = batcher._wake
+
+        def wake_then_admit(before, took):
+            wake(before, took)
+            if took is None and len(ticks.rounds) == 1:
+                # The first loop is on its way out: its successor starts
+                # here, and is held in its tick.
+                batcher.admit(2, room=8)
+                assert ticks.holding.wait(5)
+
+        batcher._wake = wake_then_admit
+        batcher.admit(1, room=1)
+        until(lambda: len(tick_loop_threads()) == 1 and ticks.holding.is_set())
+        batcher.admit(3, room=8)    # must not start a loop of its own
+        time.sleep(0.05)
+        assert len(tick_loop_threads()) == 1
+        ticks.let_go.set()
+        until(lambda: len(ticks.rounds) == 3 and not tick_loop_threads())
+        assert ticks.most_in_flight == 1
+        assert [slots for _, slots, _ in ticks.rounds] == [[1], [2], [3]]
+        for slot in (1, 2, 3):
+            batcher.release(slot)
+
+    def test_astep_awaits_its_round_and_one_call_wakes_all_its_riders(self):
+        """The coroutine form of `step` (what the gRPC event loop runs):
+        a parked token is collected at once; riders that await a round
+        are all resolved by ONE call onto their loop, and ride the next
+        round like riders that block."""
+        import asyncio
+
+        ticks = _Ticks(hold=2)
+        batcher = TickBatcher(ticks)
+        for slot in (1, 2, 3):
+            batcher.admit(slot, room=8)
+        until(lambda: len(ticks.rounds) == 1 and not tick_loop_threads())
+
+        async def main():
+            loop = asyncio.get_running_loop()
+            calls, threadsafe = [], loop.call_soon_threadsafe
+
+            def counted(fn, *args):
+                calls.append(getattr(fn, "__name__", ""))
+                return threadsafe(fn, *args)
+
+            loop.call_soon_threadsafe = counted
+            first = [await batcher.astep(slot) for slot in (1, 2, 3)]
+            assert first == [(1, 1), (2, 1), (3, 1)]     # parked: no wait
+            await loop.run_in_executor(None, ticks.holding.wait, 5)
+            riders = [loop.create_task(batcher.astep(slot))
+                      for slot in (1, 2, 3)]
+            await asyncio.sleep(0.05)
+            assert not any(r.done() for r in riders)     # round 2 is held
+            ticks.let_go.set()
+            second = await asyncio.wait_for(asyncio.gather(*riders),
+                                            timeout=10)
+            assert second == [(1, 2), (2, 2), (3, 2)]
+            assert calls.count("_resolve") == 1
+            return calls
+
+        asyncio.run(main())
+        until(lambda: len(ticks.rounds) == 3)    # handed out: due at once
+        assert ticks.slots_of(3) == [1, 2, 3]
+        for slot in (1, 2, 3):
+            batcher.release(slot)
+
+    def test_astep_raises_a_tick_wide_exception_and_returns_a_slots_error(
+            self):
+        import asyncio
+
+        def rows(slots, of_round):
+            if of_round.ordinal == 2:
+                raise RuntimeError("the device is gone")
+            return {s: (s, of_round.ordinal) for s in slots}
+
+        batcher = TickBatcher(_Ticks(rows=rows))
+        batcher.admit(1, room=8)
+
+        async def main():
+            assert await batcher.astep(1) == (1, 1)
+            with pytest.raises(RuntimeError, match="the device is gone"):
+                await batcher.astep(1)
+            row = await batcher.astep(9)     # never opened: typed, returned
+            assert isinstance(row, ServingError) and row.slot_fatal
+
+        asyncio.run(main())
+        batcher.release(1)
+
     def test_every_wait_of_the_batcher_is_timed(self):
         """servelint DL003: no wait that can park a thread for ever."""
         import ast

@@ -1225,7 +1225,7 @@ class TestDecodeLoopPhases:
             assert len(waits) == 1, tr.spans
             t0, t1, args = waits[0]
             assert tr.start <= t0 <= t1 <= tr.end
-            assert set(args) == {"round", "ahead"}
+            assert set(args) == {"round", "ahead", "inline"}
             ahead += args["ahead"]
             # Entry to the snapshot; nothing when the snapshot came first.
             assert t1 == max(t0, taken[args["round"]])
@@ -1325,11 +1325,11 @@ class TestDecodeLoopPhases:
         late.join()
         assert ticks == [(1, [1]), (2, [2])]
         (wait,) = [s for s in traces[2].spans if s[0] == "decode/wait"]
-        assert wait[3] == {"round": 2, "ahead": 0}
+        assert wait[3] == {"round": 2, "ahead": 0, "inline": 0}
         assert wait[2] - wait[1] >= 0.05 and wait[2] >= released
         # The first rider's token was under way when it asked.
         (ahead,) = [s for s in traces[1].spans if s[0] == "decode/wait"]
-        assert ahead[3] == {"round": 1, "ahead": 1}
+        assert ahead[3] == {"round": 1, "ahead": 1, "inline": 0}
         deliver = [s for s in traces[1].spans if s[0] == "decode/deliver"]
         (handoff,) = [s for s in traces[2].spans if s[0] == "decode/handoff"]
         # Round 2's hand-off starts where round 1's fetch ended (not at

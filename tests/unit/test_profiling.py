@@ -66,6 +66,8 @@ class TestSubsystemAttribution:
         ("inflight-native", "completion"),
         ("trace-metrics-export", "tracing-drain"),
         ("router-aio-data-plane", "router-event-loop"),
+        ("grpc-aio-loop", "grpc-event-loop"),
+        ("Thread-2 (_poll_wrapper)", "grpc-server"),
         ("router-membership-poll", "membership-poller"),
         ("router-grpc_0", "router-data-plane"),
         ("watchdog-ticker", "watchdog"),
