@@ -21,7 +21,7 @@ from typing import Mapping
 import jax
 import numpy as np
 
-FAMILIES = ("bert", "t5", "resnet", "use")
+FAMILIES = ("bert", "t5", "resnet", "use", "mimo")
 
 _SERVABLE_STUB = """\
 # Auto-generated by min_tfs_client_tpu.models.export — loads a native
@@ -382,4 +382,12 @@ def build_family_signatures(family, config_kwargs, sig_kwargs, params, *,
         config = use.USEConfig(**config_kwargs)
         return _attach_mesh(
             use.build_signatures(params, config, **sig_kwargs), mesh)
+    if family == "mimo":
+        from min_tfs_client_tpu.models import mimo
+
+        if "batch_buckets" in sig_kwargs:
+            sig_kwargs["batch_buckets"] = tuple(sig_kwargs["batch_buckets"])
+        config = mimo.MimoConfig(**config_kwargs)
+        return _attach_mesh(
+            mimo.build_signatures(params, config, **sig_kwargs), mesh)
     raise ValueError(f"unknown family {family!r}")  # pragma: no cover

@@ -1,0 +1,468 @@
+"""A decoder with no encoder, of the MiMo-V2 kind: pre-RMSNorm blocks
+whose attention layers are of two kinds in one stack (full causal
+attention, and a sliding window with a learned sink logit a head, each
+kind with its own number of K/V heads and its own rotary base; fused
+q/k/v projection, query/key heads wider than value heads, rotary on the
+leading part of a head), and whose feed-forward layers are SwiGLU, dense
+in the leading layers and a sigmoid-routed top-k expert layer after
+(parallel/moe.py: this chip's share of the experts, dropless).
+
+Served as whole generations on `serving_default` through the
+whole-generation front (servables/decode_signatures.whole_generation)
+over the decode contract: `prefill(params, ids) -> state`, `step(params,
+state) -> (state', token)`. The state carries two kinds of cache through
+one loop: a full-length K/V cache for each full layer, a ring of `window`
+rows for each window layer (a position is written at position mod
+window), with each example's own length.
+
+Numerics: matrices and their operands in the parameters' dtype
+(bfloat16 as served) with float32 accumulation; the residual stream, the
+norms, the softmax, the router's scores and the logits in float32.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import time
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from min_tfs_client_tpu.models import layers as nn
+from min_tfs_client_tpu.ops.attention import NEG_INF, attention
+from min_tfs_client_tpu.parallel.moe import HeldExperts, held_experts_ffn
+
+ROUTE_COUNTS = ("prompt_tokens", "pairs_prefill", "held_prefill",
+                "pairs_decode", "held_decode", "max_load", "load_total")
+
+
+@dataclasses.dataclass(frozen=True)
+class MimoConfig:
+    vocab_size: int = 152576
+    hidden_size: int = 4096
+    num_layers: int = 48
+    num_heads: int = 64
+    head_dim: int = 192            # of a query and of a key
+    v_head_dim: int = 128
+    num_kv_heads: int = 4          # full-attention layers
+    swa_num_kv_heads: int = 8      # window layers
+    # One entry a layer (longer lists are cut to num_layers): 1 = window
+    # attention, 0 = full; 1 = expert layer, 0 = dense SwiGLU.
+    layer_pattern: tuple = (0, 1, 1, 1, 1, 0)
+    moe_pattern: tuple = (0, 1, 1, 1, 1, 1)
+    window: int = 128
+    intermediate_size: int = 16384
+    moe_intermediate_size: int = 2048
+    n_routed_experts: int = 256    # the router's width
+    experts_held: int = 256        # this chip's share of them ...
+    expert_offset: int = 0         # ... starting at this expert
+    top_k: int = 8
+    rope_theta: float = 1e7
+    swa_rope_theta: float = 1e4
+    partial_rotary_factor: float = 0.334
+    value_scale: float = 0.707
+    eps: float = 1e-5
+    pad_id: int = 0
+    eos_id: int = 1
+    dtype: str = "bfloat16"
+    # Examples the prefill takes through the stack at a time: bounds its
+    # activations (the dense layer's gate/up rows above all).
+    prefill_rows: int = 8
+
+    def __post_init__(self):
+        for name in ("layer_pattern", "moe_pattern"):
+            object.__setattr__(self, name, tuple(
+                int(v) for v in getattr(self, name))[:self.num_layers])
+            if len(getattr(self, name)) != self.num_layers:
+                raise ValueError(f"{name} has fewer entries than layers")
+        if not 0 <= self.expert_offset <= \
+                self.n_routed_experts - self.experts_held:
+            raise ValueError("the held experts lie outside the router")
+
+    @property
+    def rotary_dim(self) -> int:
+        return int(self.head_dim * self.partial_rotary_factor) // 2 * 2
+
+    def kv_heads(self, layer: int) -> int:
+        return (self.swa_num_kv_heads if self.layer_pattern[layer]
+                else self.num_kv_heads)
+
+
+# -- parameters ---------------------------------------------------------------
+
+
+def init_params(rng: jax.Array, config: MimoConfig) -> dict:
+    """Leaves in `config.dtype` (the small float32 ones apart: norm
+    scales, sinks, the router and its bias). Every projection has unit
+    gain (std fan_in ** -0.5), so scores and logits are of unit scale;
+    the experts' down projections are sqrt(top_k) times that, so that a
+    token's top_k experts, each entering with a weight near 1 / top_k,
+    together add what a dense layer adds; the embedding is N(0, 1)."""
+    dtype = jnp.dtype(config.dtype)
+    d, h = config.hidden_size, config.num_heads
+
+    def normal(key, shape, std):
+        return (jax.random.normal(key, shape, jnp.float32) * std).astype(dtype)
+
+    keys = iter(jax.random.split(rng, 8 * config.num_layers + 2))
+    layers = []
+    for index in range(config.num_layers):
+        kv = config.kv_heads(index)
+        fused = h * config.head_dim + kv * (config.head_dim
+                                            + config.v_head_dim)
+        attn = {"qkv": {"kernel": normal(next(keys), (d, fused), d ** -0.5)},
+                "out": {"kernel": normal(next(keys), (h * config.v_head_dim,
+                                                      d),
+                                         (h * config.v_head_dim) ** -0.5)}}
+        if config.layer_pattern[index]:
+            attn["sink"] = jax.random.normal(next(keys), (h,), jnp.float32)
+        layer = {"attn_norm": nn.rms_norm_init(d), "attn": attn,
+                 "ffn_norm": nn.rms_norm_init(d)}
+        if config.moe_pattern[index]:
+            f, held = config.moe_intermediate_size, config.experts_held
+            layer["moe"] = {
+                "router": jax.random.normal(
+                    next(keys), (d, config.n_routed_experts),
+                    jnp.float32) * d ** -0.5,
+                "bias": jax.random.normal(
+                    next(keys), (config.n_routed_experts,),
+                    jnp.float32) * 0.02,
+                "w_in": normal(next(keys), (held, d, 2 * f), d ** -0.5),
+                "w_out": normal(next(keys), (held, f, d),
+                                (config.top_k / f) ** 0.5)}
+        else:
+            f = config.intermediate_size
+            layer["mlp"] = {
+                "wi": {"kernel": normal(next(keys), (d, 2 * f), d ** -0.5)},
+                "wo": {"kernel": normal(next(keys), (f, d), f ** -0.5)}}
+        layers.append(layer)
+    return {"embed": {"embedding": normal(next(keys),
+                                          (config.vocab_size, d), 1.0)},
+            "layers": layers, "final_norm": nn.rms_norm_init(d),
+            "head": {"kernel": normal(next(keys), (d, config.vocab_size),
+                                      d ** -0.5)}}
+
+
+# -- pieces -------------------------------------------------------------------
+
+
+def _mm(x: jax.Array, kernel: jax.Array, out_dtype=jnp.float32) -> jax.Array:
+    """x @ kernel with the operands in the kernel's dtype and float32
+    accumulation."""
+    return jnp.dot(x.astype(kernel.dtype), kernel,
+                   preferred_element_type=jnp.float32).astype(out_dtype)
+
+
+def _rope(x: jax.Array, positions: jax.Array, theta: float,
+          rot: int) -> jax.Array:
+    """Rotary embedding on the first `rot` dims of a head, halves paired
+    (dim i with dim i + rot / 2). x (..., H, D); positions (...)."""
+    half = rot // 2
+    inv = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    angle = positions.astype(jnp.float32)[..., None, None] * inv
+    cos, sin = jnp.cos(angle), jnp.sin(angle)
+    xf = x.astype(jnp.float32)
+    a, b = xf[..., :half], xf[..., half:rot]
+    return jnp.concatenate(
+        [a * cos - b * sin, b * cos + a * sin, xf[..., rot:]],
+        axis=-1).astype(x.dtype)
+
+
+def _qkv(config: MimoConfig, layer: int, attn: dict, x: jax.Array,
+         positions: jax.Array):
+    """The fused projection of x (..., D), split into q (..., H, dk),
+    k (..., kv, dk) (both rotated at `positions`) and v (..., kv, dv)
+    (times the value scale), in the parameters' dtype."""
+    h, dk, dv = config.num_heads, config.head_dim, config.v_head_dim
+    kv = config.kv_heads(layer)
+    dtype = attn["qkv"]["kernel"].dtype
+    fused = _mm(x, attn["qkv"]["kernel"], dtype)
+    lead = fused.shape[:-1]
+    q = fused[..., :h * dk].reshape(*lead, h, dk)
+    k = fused[..., h * dk:(h + kv) * dk].reshape(*lead, kv, dk)
+    v = fused[..., (h + kv) * dk:].reshape(*lead, kv, dv)
+    theta = (config.swa_rope_theta if config.layer_pattern[layer]
+             else config.rope_theta)
+    q = _rope(q, positions, theta, config.rotary_dim)
+    k = _rope(k, positions, theta, config.rotary_dim)
+    return q, k, (v.astype(jnp.float32) * config.value_scale).astype(dtype)
+
+
+def _ffn(config: MimoConfig, layer: dict, x: jax.Array,
+         valid: jax.Array | None):
+    """x (T, D) float32 (normed) -> (y (T, D) float32, Routed or None)."""
+    if "mlp" in layer:
+        wi, wo = layer["mlp"]["wi"]["kernel"], layer["mlp"]["wo"]["kernel"]
+        f = wo.shape[0]
+        hidden = _mm(x, wi, wi.dtype)
+        hidden = (jax.nn.silu(hidden[:, :f].astype(jnp.float32))
+                  * hidden[:, f:].astype(jnp.float32))
+        return _mm(hidden, wo), None
+    return held_experts_ffn(
+        HeldExperts(**layer["moe"]), x, top_k=config.top_k,
+        experts_held=config.experts_held,
+        expert_offset=config.expert_offset, valid=valid)
+
+
+def _norm(params: dict, x: jax.Array, config: MimoConfig) -> jax.Array:
+    return nn.rms_norm(params, x, eps=config.eps)
+
+
+def _logits(params: dict, config: MimoConfig, h: jax.Array) -> jax.Array:
+    return _mm(_norm(params["final_norm"], h, config),
+               params["head"]["kernel"])
+
+
+# -- prefill ------------------------------------------------------------------
+
+
+def _ring_of(rows: jax.Array, lengths: jax.Array, window: int) -> jax.Array:
+    """rows (b, kv, S, d) of positions 0..S-1 -> the ring (b, kv, window,
+    d) as decoding finds it: slot s holds the last position below the
+    example's length that is s modulo the window (any row where there is
+    none: the reader masks it)."""
+    slots = jnp.arange(window)[None, :]
+    last = lengths[:, None] - 1
+    position = jnp.maximum(last - jnp.mod(last - slots, window), 0)
+    return jnp.take_along_axis(rows, position[:, None, :, None], axis=2)
+
+
+def _prefill_rows(params: dict, config: MimoConfig, ids: jax.Array,
+                  max_decode_len: int):
+    """Some examples (b, S) through the whole stack -> (caches, logits
+    at each example's last position (b, V), held pairs (b,), load
+    (expert layers, held experts))."""
+    b, s = ids.shape
+    lengths = jnp.sum((ids != config.pad_id).astype(jnp.int32), axis=-1)
+    positions = jnp.broadcast_to(jnp.arange(s)[None, :], (b, s))
+    valid = (positions < lengths[:, None]).reshape(-1)
+    h = params["embed"]["embedding"][ids].astype(jnp.float32)
+    caches, held, loads = [], jnp.zeros((b,), jnp.int32), []
+    for index, layer in enumerate(params["layers"]):
+        windowed = bool(config.layer_pattern[index])
+        attn = layer["attn"]
+        q, k, v = _qkv(config, index, attn,
+                       _norm(layer["attn_norm"], h, config), positions)
+        k, v = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
+        out = attention(
+            q.transpose(0, 2, 1, 3), k, v, causal=True, lengths=lengths,
+            causal_offset=0, window=config.window if windowed else None,
+            sink=attn.get("sink"), queries_ragged=True)
+        h = h + _mm(out.transpose(0, 2, 1, 3).reshape(b, s, -1),
+                    attn["out"]["kernel"])
+        y, routed = _ffn(config, layer,
+                         _norm(layer["ffn_norm"], h, config).reshape(b * s,
+                                                                     -1),
+                         valid)
+        h = h + y.reshape(b, s, -1)
+        if routed is not None:
+            held = held + jnp.sum(routed.held.reshape(b, s), axis=-1)
+            loads.append(routed.load)
+        if windowed:
+            caches.append({"k": _ring_of(k, lengths, config.window),
+                           "v": _ring_of(v, lengths, config.window)})
+        else:
+            room = ((0, 0), (0, 0), (0, max_decode_len), (0, 0))
+            caches.append({"k": jnp.pad(k, room), "v": jnp.pad(v, room)})
+    last = jnp.maximum(lengths - 1, 0)[:, None, None]
+    logits = _logits(params, config,
+                     jnp.take_along_axis(h, last, axis=1)[:, 0])
+    load = (jnp.stack(loads) if loads
+            else jnp.zeros((0, config.experts_held), jnp.int32))
+    return caches, logits, held, load
+
+
+def prefill(params: dict, config: MimoConfig, input_ids: jax.Array, *,
+            max_decode_len: int) -> dict:
+    """The prompts (B, seq_len), right-padded with pad_id, through the
+    stack (`config.prefill_rows` examples at a time) -> the state a
+    generation carries: per full layer K/V of seq_len + max_decode_len
+    positions, per window layer a ring of `window` rows, each example's
+    length, the logits its next token is chosen from, `token` (the last
+    one chosen), `finished`, and what the expert layers counted."""
+    ids = jnp.asarray(input_ids, jnp.int32)
+    b, s = ids.shape
+    rows = min(config.prefill_rows, b)
+    if b % rows:
+        rows = b
+    caches, logits, held, load = jax.lax.map(
+        lambda chunk: _prefill_rows(params, config, chunk, max_decode_len),
+        ids.reshape(b // rows, rows, s))
+    merge = lambda x: x.reshape(b, *x.shape[2:])  # noqa: E731
+    load = jnp.sum(load, axis=0)
+    lengths = jnp.sum((ids != config.pad_id).astype(jnp.int32), axis=-1)
+    return {
+        "caches": jax.tree_util.tree_map(merge, caches),
+        "length": lengths,
+        "logits": merge(logits),
+        "token": jnp.full((b, 1), config.pad_id, jnp.int32),
+        "finished": jnp.zeros((b,), jnp.bool_),
+        "counts": {"prompt_tokens": lengths, "held_prefill": merge(held),
+                   "held_decode": jnp.zeros((b,), jnp.int32),
+                   "steps": jnp.zeros((b,), jnp.int32),
+                   "max_load": jnp.max(load, initial=0),
+                   "load_total": jnp.sum(load)},
+    }
+
+
+# -- one decode step ----------------------------------------------------------
+
+
+def _attend_cache(q: jax.Array, cache: dict, seen: jax.Array,
+                  sink: jax.Array | None) -> jax.Array:
+    """One query row a head over a cache, in plain jnp: q (B, H, dk),
+    cache k (B, kv, S, dk) / v (B, kv, S, dv), `seen` (B, S) bool the
+    rows this example's query may read. -> (B, H * dv) in q's dtype."""
+    b, h, dk = q.shape
+    kv = cache["k"].shape[1]
+    scores = jnp.einsum("bngd,bnsd->bngs", q.reshape(b, kv, h // kv, dk),
+                        cache["k"], preferred_element_type=jnp.float32)
+    scores = jnp.where(seen[:, None, None, :], scores * dk ** -0.5, NEG_INF)
+    top = jnp.max(scores, axis=-1, keepdims=True)
+    if sink is not None:
+        sink = sink.astype(jnp.float32).reshape(1, kv, h // kv, 1)
+        top = jnp.maximum(top, sink)
+    weights = jnp.exp(scores - top)
+    total = jnp.sum(weights, axis=-1, keepdims=True)
+    if sink is not None:
+        total = total + jnp.exp(sink - top)
+    weights = (weights / total).astype(cache["v"].dtype)
+    out = jnp.einsum("bngs,bnsd->bngd", weights, cache["v"],
+                     preferred_element_type=jnp.float32)
+    return out.reshape(b, -1).astype(q.dtype)
+
+
+def step(params: dict, config: MimoConfig, state: dict):
+    """(state) -> (state', token (B,)): choose each example's next token
+    from the state's logits (greedy; a finished example gives pad_id),
+    feed it through the stack at the example's own position, through
+    both kinds of cache, and leave the logits of the token after it."""
+    token = jnp.argmax(state["logits"], axis=-1).astype(jnp.int32)
+    token = jnp.where(state["finished"], config.pad_id, token)
+    finished = jnp.logical_or(state["finished"], token == config.eos_id)
+    position = state["length"]
+    b = token.shape[0]
+    each = jnp.arange(b)
+    h = params["embed"]["embedding"][token].astype(jnp.float32)
+    caches, held = [], jnp.zeros((b,), jnp.int32)
+    for index, (layer, cache) in enumerate(zip(params["layers"],
+                                               state["caches"])):
+        attn = layer["attn"]
+        q, k, v = _qkv(config, index, attn,
+                       _norm(layer["attn_norm"], h, config), position)
+        rows = jnp.arange(cache["k"].shape[2])[None, :]
+        if config.layer_pattern[index]:
+            # The ring: this position's slot, and every slot whose last
+            # writer is a position at or after 0.
+            slot = jnp.mod(position, config.window)
+            seen = (position[:, None]
+                    - jnp.mod(position[:, None] - rows, config.window)) >= 0
+        else:
+            slot = position
+            seen = rows <= position[:, None]
+        cache = {"k": cache["k"].at[each, :, slot].set(k),
+                 "v": cache["v"].at[each, :, slot].set(v)}
+        caches.append(cache)
+        h = h + _mm(_attend_cache(q, cache, seen, attn.get("sink")),
+                    attn["out"]["kernel"])
+        y, routed = _ffn(config, layer, _norm(layer["ffn_norm"], h, config),
+                         None)
+        h = h + y
+        if routed is not None:
+            held = held + routed.held
+    counts = dict(state["counts"])
+    counts["held_decode"] = counts["held_decode"] + held
+    counts["steps"] = counts["steps"] + 1
+    return {"caches": caches, "length": position + 1,
+            "logits": _logits(params, config, h), "token": token[:, None],
+            "finished": finished, "counts": counts}, token
+
+
+def route_counts(config: MimoConfig, state: dict) -> jax.Array:
+    """(B, len(ROUTE_COUNTS)) int32, one row an example: what
+    `generate/route` carries (the batch's two load figures on every
+    row)."""
+    counts = state["counts"]
+    per_token = config.top_k * sum(config.moe_pattern)
+    b = counts["steps"].shape[0]
+    columns = {
+        "prompt_tokens": counts["prompt_tokens"],
+        "pairs_prefill": counts["prompt_tokens"] * per_token,
+        "held_prefill": counts["held_prefill"],
+        "pairs_decode": counts["steps"] * per_token,
+        "held_decode": counts["held_decode"],
+        "max_load": jnp.broadcast_to(counts["max_load"], (b,)),
+        "load_total": jnp.broadcast_to(counts["load_total"], (b,)),
+    }
+    return jnp.stack([columns[name].astype(jnp.int32)
+                      for name in ROUTE_COUNTS], axis=-1)
+
+
+# -- serving ------------------------------------------------------------------
+
+
+def note_route(signature, outputs) -> None:
+    """The `on_answer` of the generation signature: a request's own rows
+    of `route_counts` as the span `generate/route` on its trace, and into
+    the process's counters (`/monitoring/runtime`, `route`, under the
+    signature's label)."""
+    from min_tfs_client_tpu.observability import runtime, tracing
+
+    rows = outputs.get("route_counts")
+    if rows is None:
+        return
+    rows = np.asarray(rows).reshape(-1, len(ROUTE_COUNTS))
+    sums = rows.sum(axis=0)
+    args = {name: int(rows[:, i].max() if name in ("max_load", "load_total")
+                      else sums[i])
+            for i, name in enumerate(ROUTE_COUNTS)}
+    now = time.perf_counter()
+    tracing.add_span("generate/route", now, now, **args)
+    runtime.count_route(signature.telemetry_label or "unlabeled", {
+        k: v for k, v in args.items()
+        if k not in ("max_load", "load_total")})
+
+
+def build_signatures(params: dict, config: MimoConfig, *, seq_len: int,
+                     max_decode_len: int,
+                     batch_buckets: tuple = (1, 4, 16, 32)) -> dict:
+    """`serving_default`: input_ids (B, seq_len) -> output_ids (B,
+    max_decode_len), output_lengths, and of the timed path itself the
+    float32 logits the first and the last generated token were chosen
+    from, with the expert layers' counts (`route_counts`, columns
+    ROUTE_COUNTS). No session signatures yet (ROADMAP, Reach)."""
+    from min_tfs_client_tpu.servables.decode_signatures import (
+        whole_generation,
+    )
+    from min_tfs_client_tpu.servables.servable import Signature, TensorSpec
+
+    def generate_fn(tree, inputs):
+        found = whole_generation(
+            lambda p, ids: prefill(p, config, ids,
+                                   max_decode_len=max_decode_len),
+            lambda p, state: step(p, config, state),
+            tree, inputs["input_ids"], max_decode_len=max_decode_len,
+            pad_id=config.pad_id)
+        return {"output_ids": found["output_ids"],
+                "output_lengths": found["output_lengths"],
+                "first_logits": found["first"]["logits"],
+                "last_logits": found["before_last"]["logits"],
+                "route_counts": route_counts(config, found["final"])}
+
+    generate = Signature(
+        fn=generate_fn, params=params,
+        inputs={"input_ids": TensorSpec(np.int32, (None, seq_len))},
+        outputs={
+            "output_ids": TensorSpec(np.int32, (None, max_decode_len)),
+            "output_lengths": TensorSpec(np.int32, (None,)),
+            "first_logits": TensorSpec(np.float32,
+                                       (None, config.vocab_size)),
+            "last_logits": TensorSpec(np.float32, (None, config.vocab_size)),
+            "route_counts": TensorSpec(np.int32,
+                                       (None, len(ROUTE_COUNTS)))},
+        batch_buckets=tuple(batch_buckets),
+        # a padding row is a prompt of length 0: no attention, no expert
+        batch_pad_values={"input_ids": config.pad_id},
+        on_answer=note_route)
+    return {"serving_default": generate}

@@ -29,6 +29,7 @@ import collections
 import threading
 import time
 import weakref
+from typing import Mapping
 
 from min_tfs_client_tpu.utils import aio_loop
 
@@ -312,6 +313,26 @@ def count_transfer(direction: str, nbytes: int) -> None:
         pass
 
 
+_route_counts: dict[str, dict[str, int]] = {}   # guarded_by: _lock
+
+
+def count_route(label: str, counts: Mapping[str, int]) -> None:
+    """Accumulate what a model's expert layers counted for one answered
+    request (prompt tokens, token-choice pairs in all and on held
+    experts), under the model's label: `route` in /monitoring/runtime."""
+    with _lock:
+        totals = _route_counts.setdefault(label, {"requests": 0})
+        totals["requests"] += 1
+        for name, value in counts.items():
+            totals[name] = totals.get(name, 0) + int(value)
+
+
+def route_totals() -> dict:
+    with _lock:
+        return {label: dict(totals)
+                for label, totals in _route_counts.items()}
+
+
 def transfer_totals() -> dict:
     try:
         from min_tfs_client_tpu.server import metrics
@@ -339,6 +360,7 @@ def snapshot(include_live_arrays: bool = False) -> dict:
         "profiler": profiler.status(),
         "pipeline": pipeline_stats(),
         "kv_pool": kv_pool_stats(),
+        "route": route_totals(),
         # The gRPC front end: requests answered on the event loop and on
         # the worker pool, and the loop's sampled lag (utils/aio_loop.py).
         "grpc": aio_loop.stats(),

@@ -171,6 +171,11 @@ STAGES = (
     "decode/tick",
     "decode/fetch",
     "decode/deliver",
+    # A whole generation's expert-layer counts (models/mimo.py), on the
+    # request's own trace after its batch was split: no duration, its
+    # arguments are the numbers (prompt_tokens, pairs_* and held_* for
+    # prefill and decode, max_load, load_total).
+    "generate/route",
     "serving/serialize",
 )
 

@@ -48,73 +48,110 @@ def attention_reference(
     bias: Optional[jax.Array] = None,
     scale: Optional[float] = None,
     causal_offset: Optional[int] = None,
+    window: Optional[int] = None,
+    sink: Optional[jax.Array] = None,
+    queries_ragged: bool = False,
 ) -> jax.Array:
     """Plain softmax(q k^T / sqrt(d) + bias) v.
 
-    Shapes: q (B, H, Sq, D); k, v (B, H, Skv, D); lengths (B,) int32 valid
-    key counts; bias broadcastable to (B, H, Sq, Skv). Returns (B, H, Sq, D)
-    in q.dtype; softmax runs in f32. `causal_offset` is query row 0's
-    absolute key position (default Skv-Sq: right-aligned, the KV-cache
-    decode convention; pass 0 for cache prefill).
+    Shapes: q (B, H, Sq, D); k (B, Hkv, Skv, D) and v (B, Hkv, Skv, Dv)
+    with H a multiple of Hkv (query head h reads K/V head h // (H //
+    Hkv)) and Dv free; lengths (B,) int32 valid key counts; bias
+    broadcastable to (B, H, Sq, Skv). Returns (B, H, Sq, Dv) in q.dtype;
+    softmax runs in f32. `causal_offset` is query row 0's absolute key
+    position (default Skv-Sq: right-aligned, the KV-cache decode
+    convention; pass 0 for cache prefill). `window` (causal only) keeps
+    the keys with query position - key position < window. `sink` (H,) is
+    one more logit a head, in the softmax's denominator and with no
+    value: a row's weights sum to less than one. `queries_ragged` says
+    the batch is self-attention over padded rows, so `lengths` bounds the
+    QUERY rows too: a row at or past its example's length gives zeros.
     """
-    *_, sq, d = q.shape
-    skv = k.shape[-2]
+    _, h, sq, d = q.shape
+    hkv, skv = k.shape[1], k.shape[-2]
     if scale is None:
         scale = 1.0 / float(np.sqrt(d))
+    if window is not None and not causal:
+        raise ValueError("a window is a causal window")
+    if h != hkv:
+        # The oracle repeats the shared heads; the kernel does not.
+        k = jnp.repeat(k, h // hkv, axis=1)
+        v = jnp.repeat(v, h // hkv, axis=1)
     s = jnp.einsum("bhqd,bhkd->bhqk", q, k, preferred_element_type=jnp.float32)
     s = s * scale
     if bias is not None:
         s = s + bias.astype(jnp.float32)
+    offset = skv - sq if causal_offset is None else causal_offset
+    qi = jnp.arange(sq)[:, None] + offset
+    ki = jnp.arange(skv)[None, :]
     if causal:
-        offset = skv - sq if causal_offset is None else causal_offset
-        qi = jnp.arange(sq)[:, None] + offset
-        ki = jnp.arange(skv)[None, :]
         s = jnp.where(qi >= ki, s, NEG_INF)
+    if window is not None:
+        s = jnp.where(qi - ki < window, s, NEG_INF)
     if lengths is not None:
-        ki = jnp.arange(skv)[None, None, None, :]
-        s = jnp.where(ki < lengths[:, None, None, None], s, NEG_INF)
-    p = jax.nn.softmax(s, axis=-1)
-    if lengths is not None:
-        # Fully-masked rows -> zeros (not a uniform mean over masked V),
-        # matching the flash kernel's row_valid semantics.
-        all_masked = jnp.max(s, axis=-1, keepdims=True) <= NEG_INF * 0.5
-        p = jnp.where(all_masked, 0.0, p)
+        s = jnp.where(ki[None, None] < lengths[:, None, None, None],
+                      s, NEG_INF)
+    if sink is None:
+        p = jax.nn.softmax(s, axis=-1)
+    else:
+        sink = sink.astype(jnp.float32).reshape(1, h, 1, 1)
+        m = jnp.maximum(jnp.max(s, axis=-1, keepdims=True), sink)
+        e = jnp.exp(s - m)
+        p = e / (jnp.sum(e, axis=-1, keepdims=True) + jnp.exp(sink - m))
+    # Fully-masked rows -> zeros (not a uniform mean over masked V),
+    # matching the flash kernel's row_valid semantics.
+    p = jnp.where(jnp.max(s, axis=-1, keepdims=True) <= NEG_INF * 0.5,
+                  0.0, p)
+    if queries_ragged and lengths is not None:
+        p = jnp.where(qi[None, None] < lengths[:, None, None, None], p, 0.0)
     return jnp.einsum(
         "bhqk,bhkd->bhqd", p.astype(v.dtype), v,
         preferred_element_type=jnp.float32).astype(q.dtype)
 
 
-def _flash_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, *,
-                  scale: float, causal: bool, block_kv: int,
-                  kv_seq_len: int, q_offset: int):
+def _flash_kernel(len_ref, *refs, scale: float, causal: bool, block_kv: int,
+                  kv_seq_len: int, q_offset: int, heads: int,
+                  window: Optional[int], has_sink: bool,
+                  queries_ragged: bool):
     """One (batch*head, q-block) grid cell.
 
-    Refs: len_ref (1,1) SMEM int32; q_ref (block_q, D); k_ref/v_ref
-    (kv_seq_len, D); o_ref (block_q, D). Online softmax over KV chunks with
-    f32 running (max, denom, acc) carried through a fori_loop.
+    Refs: len_ref (B*H,) SMEM int32; with a sink, sink_ref (H,) SMEM
+    f32; q_ref (block_q, D); k_ref (kv_seq_len, D) and v_ref
+    (kv_seq_len, Dv) of the K/V head this query head reads; o_ref
+    (block_q, Dv). Online softmax over KV chunks with f32 running (max,
+    denom, acc) carried through a fori_loop; the MXU takes the operands
+    in their own dtype. The loop runs only the KV chunks that hold a key
+    some row of this block may see: none above the diagonal, none wholly
+    outside the window, none past the example's length.
     """
-    block_q, d = q_ref.shape
-    q = q_ref[...].astype(jnp.float32) * scale
+    if has_sink:
+        sink_ref, q_ref, k_ref, v_ref, o_ref = refs
+    else:
+        q_ref, k_ref, v_ref, o_ref = refs
+    block_q = q_ref.shape[0]
+    d_v = v_ref.shape[-1]
+    q = q_ref[...]
     valid_len = len_ref[pl.program_id(0)]
-    q_block_start = pl.program_id(1) * block_q
+    q_start = q_offset + pl.program_id(1) * block_q   # absolute first row
 
     n_kv = kv_seq_len // block_kv
 
     def body(i, carry):
         m_prev, l_prev, acc = carry
         kv_start = i * block_kv
-        k_blk = k_ref[pl.ds(kv_start, block_kv), :].astype(jnp.float32)
-        v_blk = v_ref[pl.ds(kv_start, block_kv), :].astype(jnp.float32)
+        k_blk = k_ref[pl.ds(kv_start, block_kv), :]
+        v_blk = v_ref[pl.ds(kv_start, block_kv), :]
         s = jax.lax.dot_general(
             q, k_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)  # (block_q, block_kv)
+            preferred_element_type=jnp.float32) * scale  # (block_q, block_kv)
 
         ki = kv_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         mask = ki < valid_len
         if causal:
-            qi = (q_offset + q_block_start
-                  + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0))
+            qi = q_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
             mask = jnp.logical_and(mask, qi >= ki)
+            if window is not None:
+                mask = jnp.logical_and(mask, qi - ki < window)
         s = jnp.where(mask, s, NEG_INF)
 
         m_cur = jnp.max(s, axis=1, keepdims=True)
@@ -123,26 +160,42 @@ def _flash_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, *,
         correction = jnp.exp(m_prev - m_new)
         l_new = correction * l_prev + jnp.sum(p, axis=1, keepdims=True)
         acc = acc * correction + jax.lax.dot_general(
-            p, v_blk, (((1,), (0,)), ((), ())),
+            p.astype(v_blk.dtype), v_blk, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         return m_new, l_new, acc
 
-    m0 = jnp.full((block_q, 1), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((block_q, 1), jnp.float32)
-    acc0 = jnp.zeros((block_q, d), jnp.float32)
+    if has_sink:
+        # The sink is a key with no value: the running maximum starts at
+        # its logit and the denominator at exp(0).
+        sink = sink_ref[pl.program_id(0) % heads]
+        m0 = jnp.full((block_q, 1), sink, jnp.float32)
+        l0 = jnp.ones((block_q, 1), jnp.float32)
+    else:
+        m0 = jnp.full((block_q, 1), NEG_INF, jnp.float32)
+        l0 = jnp.zeros((block_q, 1), jnp.float32)
+    acc0 = jnp.zeros((block_q, d_v), jnp.float32)
+    n_run = jnp.minimum(n_kv, (valid_len + block_kv - 1) // block_kv)
+    first = 0
     if causal:
         # Skip KV blocks strictly above this Q block's diagonal.
-        q_end = q_offset + q_block_start + block_q  # exclusive global row end
-        n_run = jnp.minimum(n_kv, (q_end + block_kv - 1) // block_kv)
-    else:
-        n_run = n_kv
-    m, l, acc = jax.lax.fori_loop(0, n_run, body, (m0, l0, acc0))
-    # Fully-masked rows (valid_len 0, or causal skip ran zero blocks) must
+        n_run = jnp.minimum(n_run, (q_start + block_q + block_kv - 1)
+                            // block_kv)
+        if window is not None:
+            first = jnp.maximum(q_start - window + 1, 0) // block_kv
+    if queries_ragged:
+        n_run = jnp.where(q_start >= valid_len, 0, n_run)
+    m, l, acc = jax.lax.fori_loop(first, n_run, body, (m0, l0, acc0))
+    # Fully-masked rows (valid_len 0, or the skips ran zero blocks) must
     # return zeros: m never left NEG_INF there (exp(s-m)=1 would otherwise
-    # leak a mean over masked V rows into acc).
+    # leak a mean over masked V rows into acc). With a sink the
+    # denominator is never 0 and acc is 0 there already.
     row_valid = m > NEG_INF * 0.5
     l = jnp.where(l == 0.0, 1.0, l)
-    o_ref[...] = jnp.where(row_valid, acc / l, 0.0).astype(o_ref.dtype)
+    out = jnp.where(row_valid, acc / l, 0.0)
+    if queries_ragged:
+        rows = q_start + jax.lax.broadcasted_iota(jnp.int32, out.shape, 0)
+        out = jnp.where(rows < valid_len, out, 0.0)
+    o_ref[...] = out.astype(o_ref.dtype)
 
 
 def _pad_to(x: jax.Array, axis: int, multiple: int) -> jax.Array:
@@ -155,9 +208,12 @@ def _pad_to(x: jax.Array, axis: int, multiple: int) -> jax.Array:
     return jnp.pad(x, widths)
 
 
-def _flash_call(q, k, v, lengths, *, causal, scale, interpret, q_offset):
+def _flash_call(q, k, v, lengths, sink, *, causal, scale, interpret,
+                q_offset, window, queries_ragged):
     """The pallas_call over the shapes one device sees."""
     b, h, sq, d = q.shape
+    hkv, d_v = k.shape[1], v.shape[-1]
+    group = h // hkv
     block_q = min(_BLOCK_Q, max(8, 1 << (sq - 1).bit_length()))
     q_p = _pad_to(q, 2, block_q)
     k_p = _pad_to(k, 2, _BLOCK_KV)
@@ -165,29 +221,38 @@ def _flash_call(q, k, v, lengths, *, causal, scale, interpret, q_offset):
     sq_p, skv_p = q_p.shape[2], k_p.shape[2]
 
     # Fold heads into the batch grid dim; lengths replicate per head.
+    # K and V keep their own (fewer) heads: a query head's grid cells
+    # name the block of the K/V head it reads, so no K/V row is repeated
+    # in memory, and consecutive cells of one group fetch it once.
     q_f = q_p.reshape(b * h, sq_p, d)
-    k_f = k_p.reshape(b * h, skv_p, d)
-    v_f = v_p.reshape(b * h, skv_p, d)
+    k_f = k_p.reshape(b * hkv, skv_p, d)
+    v_f = v_p.reshape(b * hkv, skv_p, d_v)
     len_f = jnp.repeat(lengths, h)  # (b*h,) in SMEM
+
+    def kv_index(bh, i):
+        return ((bh // h) * hkv + (bh % h) // group, 0, 0)
 
     kernel = functools.partial(
         _flash_kernel, scale=scale, causal=causal, block_kv=_BLOCK_KV,
-        kv_seq_len=skv_p, q_offset=q_offset)
+        kv_seq_len=skv_p, q_offset=q_offset, heads=h, window=window,
+        has_sink=sink is not None, queries_ragged=queries_ragged)
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)   # a whole vector
+    scalars = (len_f,) if sink is None else (len_f, sink.astype(jnp.float32))
     out = pl.pallas_call(
         kernel,
         grid=(b * h, sq_p // block_q),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),  # full lengths vector
+        in_specs=[smem] * len(scalars) + [
             pl.BlockSpec((None, block_q, d), lambda bh, i: (bh, i, 0)),
-            pl.BlockSpec((None, skv_p, d), lambda bh, i: (bh, 0, 0)),
-            pl.BlockSpec((None, skv_p, d), lambda bh, i: (bh, 0, 0)),
+            pl.BlockSpec((None, skv_p, d), kv_index),
+            pl.BlockSpec((None, skv_p, d_v), kv_index),
         ],
-        out_specs=pl.BlockSpec((None, block_q, d), lambda bh, i: (bh, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((b * h, sq_p, d), q.dtype),
+        out_specs=pl.BlockSpec((None, block_q, d_v),
+                               lambda bh, i: (bh, i, 0)),
+        out_shape=jax.ShapeDtypeStruct((b * h, sq_p, d_v), q.dtype),
         interpret=interpret,
         name="_flash_kernel",  # the device-trace reduction finds it by name
-    )(len_f, q_f, k_f, v_f)
-    return out.reshape(b, h, sq_p, d)[:, :, :sq, :]
+    )(*scalars, q_f, k_f, v_f)
+    return out.reshape(b, h, sq_p, d_v)[:, :, :sq, :]
 
 
 def _auto_mesh_axes() -> set:
@@ -197,13 +262,15 @@ def _auto_mesh_axes() -> set:
     return set(mesh.axis_names) - set(mesh.manual_axes)
 
 
-def _flash_over_mesh(call, q, k, v, lengths):
+def _flash_over_mesh(call, q, k, v, lengths, sink):
     """XLA cannot split a Mosaic kernel over a mesh by itself ("Mosaic
     kernels cannot be automatically partitioned"), so under a serving mesh
     (servables/servable.py runs meshed signatures inside `jax.set_mesh`)
     the kernel says how: batch and heads are independent grid cells and
-    shard over the data and model axes, sequence and head dim stay whole
-    on every device, and each device runs `call` on its own shard."""
+    shard over the data and model axes (heads only where the axis divides
+    the K/V heads too, so a query head and its K/V head stay together),
+    sequence and head dim stay whole on every device, and each device
+    runs `call` on its own shard."""
     from min_tfs_client_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS
 
     mesh = jax.sharding.get_abstract_mesh()
@@ -211,23 +278,29 @@ def _flash_over_mesh(call, q, k, v, lengths):
     # manual here; a dim an axis does not divide is replicated over it.
     auto = _auto_mesh_axes()
     if not auto:
-        return call(q, k, v, lengths)
+        return call(q, k, v, lengths, sink)
 
-    def axis_for(name, dim):
-        return name if (name in auto and dim % mesh.shape[name] == 0) \
-            else None
+    def axis_for(name, *dims):
+        return name if (name in auto and all(
+            dim % mesh.shape[name] == 0 for dim in dims)) else None
 
     batch_axis = axis_for(DATA_AXIS, q.shape[0])
-    qkv = PartitionSpec(batch_axis, axis_for(MODEL_AXIS, q.shape[1]),
-                        None, None)
+    head_axis = axis_for(MODEL_AXIS, q.shape[1], k.shape[1])
+    qkv = PartitionSpec(batch_axis, head_axis, None, None)
+    operands = [q, k, v, lengths]
+    specs = [qkv, qkv, qkv, PartitionSpec(batch_axis)]
+    if sink is not None:
+        operands.append(sink)
+        specs.append(PartitionSpec(head_axis))
     return jax.shard_map(
-        call, in_specs=(qkv, qkv, qkv, PartitionSpec(batch_axis)),
-        out_specs=qkv, axis_names=auto, check_vma=False,
-    )(q, k, v, lengths)
+        lambda q, k, v, n, sink=None: call(q, k, v, n, sink),
+        in_specs=tuple(specs), out_specs=qkv, axis_names=auto,
+        check_vma=False)(*operands)
 
 
 @functools.partial(
-    jax.jit, static_argnames=("causal", "scale", "interpret", "causal_offset"))
+    jax.jit, static_argnames=("causal", "scale", "interpret", "causal_offset",
+                              "window", "queries_ragged"))
 def flash_attention(
     q: jax.Array,
     k: jax.Array,
@@ -238,6 +311,9 @@ def flash_attention(
     scale: Optional[float] = None,
     interpret: bool = False,
     causal_offset: Optional[int] = None,
+    window: Optional[int] = None,
+    sink: Optional[jax.Array] = None,
+    queries_ragged: bool = False,
 ) -> jax.Array:
     """Pallas flash attention. Same contract as attention_reference
     (minus bias). Sequence dims are padded to block multiples internally;
@@ -246,6 +322,8 @@ def flash_attention(
     skv = k.shape[-2]
     if scale is None:
         scale = 1.0 / float(np.sqrt(d))
+    if window is not None and not causal:
+        raise ValueError("a window is a causal window")
     if lengths is None:
         lengths = jnp.full((b,), skv, jnp.int32)
     # Right-align causal masking when decoding with a KV cache, unless
@@ -253,8 +331,9 @@ def flash_attention(
     q_offset = ((skv - sq if causal_offset is None else causal_offset)
                 if causal else 0)
     call = functools.partial(_flash_call, causal=causal, scale=scale,
-                             interpret=interpret, q_offset=q_offset)
-    return _flash_over_mesh(call, q, k, v, lengths.astype(jnp.int32))
+                             interpret=interpret, q_offset=q_offset,
+                             window=window, queries_ragged=queries_ragged)
+    return _flash_over_mesh(call, q, k, v, lengths.astype(jnp.int32), sink)
 
 
 # -- ragged paged attention (block-table KV) ---------------------------------
@@ -759,15 +838,18 @@ def _paged_kernel_applies(q: jax.Array, k_pages: jax.Array,
             and not _auto_mesh_axes())
 
 
-def _flash_kernel_applies(q: jax.Array, k: jax.Array) -> bool:
-    """The shapes `_flash_kernel` compiles for: head dim on a sublane
-    multiple, at least one sublane tile of query rows, and one (batch,
-    head)'s whole K and V — double-buffered, lanes padded to 128 —
-    resident in VMEM."""
-    d = q.shape[-1]
+def _flash_kernel_applies(q: jax.Array, k: jax.Array,
+                          v: Optional[jax.Array] = None) -> bool:
+    """The shapes `_flash_kernel` compiles for: head dims on a sublane
+    multiple, query heads a multiple of the K/V heads, at least one
+    sublane tile of query rows, and one (batch, K/V head)'s whole K and
+    V — double-buffered, lanes padded to 128 — resident in VMEM."""
+    d, d_v = q.shape[-1], (k if v is None else v).shape[-1]
     skv_p = -(-k.shape[-2] // _BLOCK_KV) * _BLOCK_KV
-    kv_bytes = 2 * 2 * skv_p * (-(-d // 128) * 128) * k.dtype.itemsize
-    return (d % 8 == 0 and q.shape[-2] >= 8
+    lanes = -(-d // 128) * 128 + -(-d_v // 128) * 128
+    kv_bytes = 2 * skv_p * lanes * k.dtype.itemsize
+    return (d % 8 == 0 and d_v % 8 == 0 and q.shape[-2] >= 8
+            and q.shape[1] % k.shape[1] == 0
             and kv_bytes <= _FLASH_KV_VMEM_BYTES)
 
 
@@ -781,6 +863,9 @@ def attention(
     bias: Optional[jax.Array] = None,
     scale: Optional[float] = None,
     causal_offset: Optional[int] = None,
+    window: Optional[int] = None,
+    sink: Optional[jax.Array] = None,
+    queries_ragged: bool = False,
 ) -> jax.Array:
     """Dispatch: Pallas kernel on TPU for every shape it is written for
     (`_flash_kernel_applies`, no additive bias), jnp reference otherwise.
@@ -788,7 +873,7 @@ def attention(
     use_pallas = (
         _on_tpu()
         and bias is None
-        and _flash_kernel_applies(q, k)
+        and _flash_kernel_applies(q, k, v)
         # The kernel takes causal_offset as a static arg; a traced offset
         # (speculative verify blocks at a dynamic step) uses the
         # reference path.
@@ -797,7 +882,9 @@ def attention(
     if use_pallas:
         return flash_attention(
             q, k, v, causal=causal, lengths=lengths, scale=scale,
-            causal_offset=causal_offset)
+            causal_offset=causal_offset, window=window, sink=sink,
+            queries_ragged=queries_ragged)
     return attention_reference(
         q, k, v, causal=causal, lengths=lengths, bias=bias, scale=scale,
-        causal_offset=causal_offset)
+        causal_offset=causal_offset, window=window, sink=sink,
+        queries_ragged=queries_ragged)

@@ -147,3 +147,106 @@ def moe_ffn_reference(params: MoeParams, x: jax.Array) -> jax.Array:
 
     out = jax.vmap(one)(tokens, idx, gate)
     return out.reshape(b, s, d)
+
+
+# -- a chip's share of a dropless top-k expert layer -------------------------
+#
+# The served form of expert parallelism (models/mimo.py): the layer is told
+# WHICH experts this chip holds, routes every token over ALL the experts
+# (the router keeps its published width), and computes the part of the
+# result that its own experts give. What the absent experts would add is
+# left out; the exchange that a deployment runs between its chips is not
+# here, and nothing stands in for it. Nothing is dropped: the static bound
+# is every (token, choice) pair, walked in row blocks by a loop whose trip
+# count is the number of pairs that fell on held experts.
+
+
+class HeldExperts(NamedTuple):
+    router: jax.Array  # (D, E) over ALL experts
+    bias: jax.Array    # (E,) the selection's correction bias (noaux_tc)
+    w_in: jax.Array    # (held, D, 2 F): gate and up, side by side
+    w_out: jax.Array   # (held, F, D)
+
+
+class Routed(NamedTuple):
+    """What the layer counted: `held` (T,) pairs of each token that fell
+    on held experts, `load` (held,) rows each held expert computed."""
+    held: jax.Array
+    load: jax.Array
+
+
+ROW_BLOCK = 2048   # rows a grouped product takes at a time
+
+
+def sigmoid_top_k(x: jax.Array, router: jax.Array, bias: jax.Array,
+                  top_k: int) -> tuple[jax.Array, jax.Array]:
+    """Scores sigmoid(x W) over all experts in float32 at full matmul
+    precision (a choice among near-equal scores must not turn on how a
+    product was rounded); the top_k of scores + bias are chosen, and the
+    weights are the chosen scores over their sum. -> (experts (T, k)
+    int32, weights (T, k) float32)."""
+    scores = jax.nn.sigmoid(jnp.dot(
+        x.astype(jnp.float32), router.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST))
+    _, experts = jax.lax.top_k(scores + bias.astype(jnp.float32), top_k)
+    chosen = jnp.take_along_axis(scores, experts, axis=-1)
+    return (experts.astype(jnp.int32),
+            chosen / jnp.sum(chosen, axis=-1, keepdims=True))
+
+
+def held_experts_ffn(params: HeldExperts, x: jax.Array, *, top_k: int,
+                     experts_held: int, expert_offset: int,
+                     valid: jax.Array | None = None,
+                     row_block: int = ROW_BLOCK
+                     ) -> tuple[jax.Array, Routed]:
+    """x (T, D) -> (y (T, D) float32, Routed): y = sum over a token's
+    chosen experts e in [expert_offset, expert_offset + experts_held) of
+    w_e * SwiGLU_e(x). A token none of whose choices is held gets 0.
+    `valid` (T,) bool leaves padding rows out of the routing altogether.
+
+    The pairs are sorted by held expert (pairs on absent experts last),
+    and the held ones pass the grouped gate/up/down products
+    (`jax.lax.ragged_dot`, rows in the weights' dtype, float32
+    accumulation) in blocks of `row_block` rows: as many blocks as the
+    held pairs fill. The router reads x as it is given (float32 from the
+    models)."""
+    t, _ = x.shape
+    if params.w_in.shape[0] != experts_held:
+        raise ValueError("w_in holds another number of experts than held")
+    d_ff = params.w_out.shape[1]
+    experts, weights = sigmoid_top_k(x, params.router, params.bias, top_k)
+    local = experts - expert_offset
+    held = jnp.logical_and(local >= 0, local < experts_held)
+    if valid is not None:
+        held = jnp.logical_and(held, valid[:, None])
+    key = jnp.where(held, local, experts_held).reshape(-1)      # (T k,)
+    pairs = t * top_k
+    block = min(row_block, -(-pairs // 8) * 8)
+    padded = -(-pairs // block) * block
+    order = jnp.pad(jnp.argsort(key, stable=True), (0, padded - pairs))
+    load = jnp.zeros((experts_held + 1,), jnp.int32).at[key].add(
+        1)[:experts_held]
+    ends = jnp.cumsum(load)
+    starts = ends - load
+    total = ends[-1]
+    flat_weights = weights.reshape(-1)
+
+    def rows_of(i, y):
+        lo = i * block
+        pair = jax.lax.dynamic_slice(order, (lo,), (block,))
+        token = pair // top_k
+        sizes = (jnp.clip(ends, lo, lo + block)
+                 - jnp.clip(starts, lo, lo + block))
+        h = jax.lax.ragged_dot(x[token].astype(params.w_in.dtype),
+                               params.w_in, sizes,
+                               preferred_element_type=jnp.float32)
+        h = jax.nn.silu(h[:, :d_ff]) * h[:, d_ff:]
+        out = jax.lax.ragged_dot(h.astype(params.w_out.dtype), params.w_out,
+                                 sizes, preferred_element_type=jnp.float32)
+        live = (lo + jnp.arange(block) < total)[:, None]
+        out = jnp.where(live, out * flat_weights[pair][:, None], 0.0)
+        return y.at[token].add(out)
+
+    y = jax.lax.fori_loop(0, (total + block - 1) // block, rows_of,
+                          jnp.zeros(x.shape, jnp.float32))
+    return y, Routed(held=jnp.sum(held, axis=-1, dtype=jnp.int32), load=load)

@@ -116,6 +116,35 @@ class DecodeModel:
     sampling_inputs: tuple = ()
 
 
+def whole_generation(prefill: Callable, step: Callable, params,
+                     input_ids, *, max_decode_len: int, pad_id: int) -> dict:
+    """A whole greedy generation in one traced program, written once over
+    the decode contract: `prefill(params, input_ids) -> state`, then
+    `max_decode_len` times `step(params, state) -> (state', token (B,))`
+    (a `lax.scan` of all but the last, so that the state the last token
+    was chosen from can be given too). A model's `step` decides what a
+    finished example emits (pad_id by the contract); the lengths count
+    what is not pad_id. -> {"output_ids" (B, max_decode_len),
+    "output_lengths" (B,), "first": the prefill's state, "before_last":
+    the state before the last step, "final": the state after it}."""
+    import jax
+    import jax.numpy as jnp
+
+    first = prefill(params, jnp.asarray(input_ids, jnp.int32))
+
+    def body(state, _):
+        return step(params, state)
+
+    before_last, head = jax.lax.scan(body, first, None,
+                                     length=max_decode_len - 1)
+    final, tail = step(params, before_last)
+    output_ids = jnp.concatenate([head, tail[None]], axis=0).T
+    return {"output_ids": output_ids,
+            "output_lengths": jnp.sum(
+                (output_ids != pad_id).astype(jnp.int32), axis=-1),
+            "first": first, "before_last": before_last, "final": final}
+
+
 class _Step:
     """One decode_step under the at-most-once guard — the ONE place the
     StepDeduper dance lives. Built from the request: reads the session

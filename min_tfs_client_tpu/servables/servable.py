@@ -182,6 +182,14 @@ class Signature:
     # widths with THIS value — pad_ragged's first-element rule would
     # inject fake valid data.
     ragged_pad_values: Optional[dict[str, object]] = None
+    # Optional alias -> value: the rows that pad a batch up to its bucket
+    # are filled with this value instead of repeating row 0. For a model
+    # to which such a row is valid AND costs nothing (models/mimo.py: a
+    # row of pad_id is a prompt of length 0, which attention and the
+    # expert layer skip): a repeated real row would be computed in full,
+    # once for every padding row, and a program's time would follow the
+    # length of whichever request came first.
+    batch_pad_values: Optional[dict[str, object]] = None
     # Optional alias -> dtype map: cast these inputs on the HOST before the
     # device transfer. For inputs the model immediately casts down anyway
     # (f32 images -> bf16 convs), this halves host->HBM DMA bytes without
@@ -213,6 +221,16 @@ class Signature:
     # thread hand-off (`arun`; docs/MIGRATING.md "A signature that can
     # await instead of block"). Host, unpartitioned signatures only.
     afn: Optional[Callable[..., Awaitable[dict[str, object]]]] = \
+        dc_field(default=None, repr=False, compare=False)
+
+    # Optional `on_answer(signature, outputs)`: called by the Predict
+    # handlers with a request's OWN outputs (its rows, after a batch was
+    # split) before they are serialised: where a model puts what its
+    # program counted onto the request's trace (models/mimo.py:
+    # `generate/route`). It returns nothing; the handler logs what it
+    # raises and answers all the same.
+    on_answer: Optional[Callable[["Signature", Mapping[str, np.ndarray]],
+                                 None]] = \
         dc_field(default=None, repr=False, compare=False)
 
     # "model:version:signature", stamped by Servable.__init__ — keys the
@@ -615,11 +633,8 @@ class Signature:
             if padded_batch != batch:
                 arrays = {
                     alias: np.concatenate(
-                        # Pad with a repeat of row 0 (valid data keeps XLA
-                        # out of NaN paths — the batching_session.h:94-99
-                        # trick).
-                        [arr, np.repeat(arr[:1], padded_batch - batch,
-                                        axis=0)])
+                        [arr, self._padding_rows(alias, arr,
+                                                 padded_batch - batch)])
                     for alias, arr in arrays.items()
                 }
         tracing.annotate(batch_size=batch, padding_bucket=padded_batch,
@@ -636,6 +651,17 @@ class Signature:
         # XProf timeline when profiling).
         with tracing.span("device/execute"):
             return self._execute(arrays), batch
+
+    def _padding_rows(self, alias: str, arr: np.ndarray,
+                      count: int) -> np.ndarray:
+        """The rows that fill a batch up to its bucket: a repeat of row 0
+        (valid data keeps XLA out of NaN paths — the
+        batching_session.h:94-99 trick) unless the signature names a
+        value for the alias (`batch_pad_values`)."""
+        pad = self.batch_pad_values
+        if pad and alias in pad:
+            return np.full((count, *arr.shape[1:]), pad[alias], arr.dtype)
+        return np.repeat(arr[:1], count, axis=0)
 
     # Below this the device_put plumbing (~0.2 ms of pure Python)
     # outweighs what an explicit transfer can save. The threshold predates

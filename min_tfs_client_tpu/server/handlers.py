@@ -17,6 +17,7 @@ the REST front-end. Semantics follow the reference implementations:
 from __future__ import annotations
 
 import functools
+import logging
 import time
 
 import numpy as np
@@ -159,6 +160,7 @@ class Handlers:
         with self.core.servable_handle(request.model_spec) as handle:
             signature, inputs = self._predict_inputs(handle, request)
             outputs = signature.run(inputs, tuple(request.output_filter))
+            self._answered(signature, outputs)
             return self._predict_response(handle, request, outputs)
 
     def can_await(self, request: apis.PredictRequest) -> bool:
@@ -196,7 +198,22 @@ class Handlers:
                 signature, inputs = self._predict_inputs(handle, request)
                 outputs = await signature.arun(
                     inputs, tuple(request.output_filter))
+                self._answered(signature, outputs)
                 return self._predict_response(handle, request, outputs)
+
+    @staticmethod
+    def _answered(signature, outputs) -> None:
+        """The signature's `on_answer`, where it has one, on a Predict's
+        own outputs. What it notes is telemetry: if it raises, the answer
+        still goes out, and the log says what was lost."""
+        if signature.on_answer is None:
+            return
+        try:
+            signature.on_answer(signature, outputs)
+        except Exception:  # servelint: fallback-ok the answer is sound
+            logging.getLogger(__name__).exception(
+                "on_answer of %s raised; its note is lost",
+                signature.telemetry_label or "a signature")
 
     def _predict_inputs(self, handle, request: apis.PredictRequest):
         """(signature, decoded inputs) of a Predict, annotated on its

@@ -24,7 +24,7 @@ pytestmark = pytest.mark.integration
 SERVER_SCHEMAS = {
     "/monitoring/slo": {"default_objective", "dropped_keys", "entries"},
     "/monitoring/runtime": {"compile", "devices", "transfer", "profiler",
-                            "pipeline", "kv_pool", "grpc"},
+                            "pipeline", "kv_pool", "route", "grpc"},
     "/monitoring/sessions": {"pools"},
     "/monitoring/costs": {"schema", "window_s", "context", "dropped_keys",
                           "entries", "tick_utilization", "log"},

@@ -1,0 +1,127 @@
+"""Device times of models/mimo.py's pieces alone on the chip, at the
+widths of perfbench/configs/mimo-v2.5.json (PERF.md section 5 quotes
+them). Not a test and not part of the benchmark: run it on a machine
+with the chip,
+
+    python tests/tpu/mimo_pieces.py
+
+and read chiprun_out/mimo_pieces.json: the expert layer by
+`jax.lax.ragged_dot` against a dense product over the held experts at
+the decode shape (32 rows) and the prefill shape (16,384 rows),
+`_flash_kernel` in a full and a window layer on full and mixed lengths,
+then one whole prefill of a mixed batch of 32 and one decode step.
+"""
+
+import json
+import pathlib
+import sys
+import time
+
+ROOT = pathlib.Path(__file__).resolve().parents[2]
+sys.path.insert(0, str(ROOT))
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+import numpy as np  # noqa: E402
+
+from min_tfs_client_tpu.models import mimo  # noqa: E402
+from min_tfs_client_tpu.ops.attention import flash_attention  # noqa: E402
+from min_tfs_client_tpu.parallel.moe import (  # noqa: E402
+    HeldExperts,
+    held_experts_ffn,
+    sigmoid_top_k,
+)
+from perfbench import children  # noqa: E402
+
+D, F, HELD, ROUTER, TOP_K = 4096, 2048, 16, 256, 8
+
+
+def timed(fn, *args, n=5) -> float:
+    """Milliseconds a call, after one call that compiles."""
+    jax.block_until_ready(fn(*args))
+    clock = time.perf_counter()
+    for _ in range(n):
+        found = fn(*args)
+    jax.block_until_ready(found)
+    return (time.perf_counter() - clock) / n * 1e3
+
+
+def dense_over_held(p: HeldExperts, x):
+    """Every held expert on every row, weighted after: what
+    `ragged_dot` is measured against."""
+    experts, weights = sigmoid_top_k(x, p.router, p.bias, TOP_K)
+    w = jnp.sum(jnp.where(experts[:, :, None] == jnp.arange(HELD)[None, None],
+                          weights[:, :, None], 0.0), 1)        # (T, HELD)
+    h = jnp.einsum("td,edf->etf", x.astype(jnp.bfloat16), p.w_in,
+                   preferred_element_type=jnp.float32)
+    h = (jax.nn.silu(h[..., :F]) * h[..., F:]).astype(jnp.bfloat16)
+    y = jnp.einsum("etf,efd->etd", h, p.w_out,
+                   preferred_element_type=jnp.float32)
+    return jnp.einsum("etd,te->td", y, w)
+
+
+def main() -> None:
+    out = {"device": str(jax.devices()[0].device_kind)}
+    ks = jax.random.split(jax.random.PRNGKey(0), 8)
+    p = HeldExperts(
+        router=jax.random.normal(ks[0], (D, ROUTER), jnp.float32) / 64,
+        bias=jnp.zeros((ROUTER,), jnp.float32),
+        w_in=(jax.random.normal(ks[1], (HELD, D, 2 * F), jnp.float32)
+              / 64).astype(jnp.bfloat16),
+        w_out=(jax.random.normal(ks[2], (HELD, F, D), jnp.float32)
+               / 45).astype(jnp.bfloat16))
+    ffn = jax.jit(lambda p, x, valid: held_experts_ffn(
+        p, x, top_k=TOP_K, experts_held=HELD, expert_offset=0,
+        valid=valid)[0])
+    x32 = jax.random.normal(ks[3], (32, D), jnp.float32)
+    out["experts_decode_32rows_ragged_ms"] = timed(
+        ffn, p, x32, jnp.ones((32,), bool), n=20)
+    out["experts_decode_32rows_dense_over_held_ms"] = timed(
+        jax.jit(dense_over_held), p, x32, n=20)
+    out["experts_bytes_floor_ms"] = HELD * 3 * D * F * 2 / 819e9 * 1e3
+    xp = jax.random.normal(ks[4], (16384, D), jnp.float32)
+    out["experts_prefill_16384rows_39pct_valid_ms"] = timed(
+        ffn, p, xp, (jnp.arange(16384) % 2048) < 805, n=3)
+    out["experts_prefill_all_valid_ms"] = timed(
+        ffn, p, xp, jnp.ones((16384,), bool), n=3)
+    del xp
+
+    for name, kv, window in (("full", 4, None), ("window", 8, 128)):
+        q = jax.random.normal(ks[5], (8, 64, 2048, 192), jnp.bfloat16)
+        k = jax.random.normal(ks[6], (8, kv, 2048, 192), jnp.bfloat16)
+        v = jax.random.normal(ks[7], (8, kv, 2048, 128), jnp.bfloat16)
+        sink = jnp.zeros((64,), jnp.float32) if window else None
+        fn = jax.jit(lambda q, k, v, n, s=sink, w=window: flash_attention(
+            q, k, v, causal=True, lengths=n, causal_offset=0, window=w,
+            sink=s, queries_ragged=True))
+        for label, lengths in (
+                ("full_lengths", [2048] * 8),
+                ("mixed", [93, 231, 366, 521, 692, 930, 1332, 2048])):
+            out[f"flash_{name}_{label}_ms"] = timed(
+                fn, q, k, v, jnp.asarray(lengths, jnp.int32))
+        del q, k, v
+
+    config = json.loads(
+        (ROOT / "perfbench/configs/mimo-v2.5.json").read_text())
+    pc = mimo.MimoConfig(**children.program_config_kwargs(config))
+    params = jax.jit(lambda k: mimo.init_params(k, pc))(jax.random.PRNGKey(1))
+    grid = json.loads((ROOT / "perfbench/traffic/mixed-generate.json")
+                      .read_text())["input_length_grid"]
+    rng = np.random.default_rng(0)
+    ids = np.zeros((32, 2048), np.int32)
+    for row, n in enumerate(rng.permutation(grid)[:32]):
+        ids[row, :n] = rng.integers(2, pc.vocab_size, n)
+    prefill = jax.jit(lambda p, ids: mimo.prefill(p, pc, ids,
+                                                  max_decode_len=128))
+    out["prefill_32x2048_mixed_ms"] = timed(prefill, params, ids, n=2)
+    state = prefill(params, ids)
+    step = jax.jit(lambda p, s: mimo.step(p, pc, s)[0])
+    out["decode_step_ms"] = timed(step, params, state, n=20)
+    print(json.dumps(out, indent=1))
+    (ROOT / "chiprun_out").mkdir(exist_ok=True)
+    (ROOT / "chiprun_out/mimo_pieces.json").write_text(
+        json.dumps(out, indent=1))
+
+
+if __name__ == "__main__":
+    main()

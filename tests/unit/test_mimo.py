@@ -1,0 +1,301 @@
+"""models/mimo.py at a small size on the CPU, float32, against the plain
+reference of its benchmark configuration
+(perfbench/configs/mimo-v2.5.reference.py): logits, not sampled tokens.
+Hidden 64, 4 query heads over 1 (full) and 2 (window) K/V heads, qk 24 /
+v 16, window 8, 16 experts of which 4 held, top-2, one dense layer and
+six more in the published pattern."""
+
+import itertools
+import json
+import pathlib
+import threading
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from min_tfs_client_tpu.models import export, mimo
+from min_tfs_client_tpu.ops.attention import (
+    attention_reference,
+    flash_attention,
+)
+from min_tfs_client_tpu.parallel.moe import HeldExperts, held_experts_ffn
+from perfbench import children
+
+ROOT = pathlib.Path(__file__).resolve().parents[2]
+LABEL = "mimo:1:serving_default"    # of the served signature (`route`)
+SEQ, STEPS = 40, 30                  # 30 steps: more than three windows
+LENGTHS = (5, 8, 29, 0, 40, 9)       # below, at and above the window; none
+
+
+def tiny_config() -> dict:
+    config = json.loads(
+        (ROOT / "perfbench/configs/mimo-v2.5.json").read_text())
+    config.update(hidden_size=64, num_attention_heads=4, head_dim=24,
+                  v_head_dim=16, swa_head_dim=24, swa_v_head_dim=16,
+                  num_key_value_heads=1, swa_num_key_value_heads=2,
+                  sliding_window=8, intermediate_size=128,
+                  moe_intermediate_size=32, n_routed_experts=4,
+                  num_experts_per_tok=2, vocab_size=96)
+    config["published"]["n_routed_experts"] = 16
+    config["serve"]["config_kwargs"].update(
+        n_routed_experts=16, dtype="float32", prefill_rows=2)
+    config["serve"]["signature_kwargs"].update(
+        seq_len=SEQ, max_decode_len=STEPS, batch_buckets=[8])
+    return config
+
+
+@pytest.fixture(scope="module")
+def tiny():
+    config = tiny_config()
+    program_config = mimo.MimoConfig(
+        **children.program_config_kwargs(config))
+    params = mimo.init_params(jax.random.PRNGKey(7), program_config)
+    rng = np.random.default_rng(7)
+    ids = np.zeros((len(LENGTHS), SEQ), np.int32)
+    for row, n in enumerate(LENGTHS):
+        ids[row, :n] = rng.integers(2, config["vocab_size"], n)
+    return {"config": config, "program_config": program_config,
+            "params": params, "ids": ids,
+            "reference": children.load_reference(config)}
+
+
+def test_prefill_logits_agree_with_the_references_full_forward(tiny):
+    state = jax.jit(lambda p, ids: mimo.prefill(
+        p, tiny["program_config"], ids, max_decode_len=STEPS))(
+            tiny["params"], tiny["ids"])
+    assert np.isfinite(np.asarray(state["logits"])).all()   # length 0 too
+    rows = [r for r, n in enumerate(LENGTHS) if n]
+    want = tiny["reference"].forward(
+        tiny["params"], tiny["config"],
+        [tiny["ids"][r, :LENGTHS[r]] for r in rows],
+        [[LENGTHS[r] - 1] for r in rows])
+    for r, logits in zip(rows, want):
+        np.testing.assert_allclose(np.asarray(state["logits"][r]),
+                                   logits[0], atol=2e-4)
+
+
+def test_every_decode_step_through_ring_and_full_caches_agrees(tiny):
+    config = tiny["program_config"]
+    state = jax.jit(lambda p, ids: mimo.prefill(
+        p, config, ids, max_decode_len=STEPS))(tiny["params"], tiny["ids"])
+    step = jax.jit(lambda p, s: mimo.step(p, config, s))
+    chosen_from, tokens = [], []
+    for _ in range(STEPS):
+        chosen_from.append(np.asarray(state["logits"]))
+        state, token = step(tiny["params"], state)
+        tokens.append(np.asarray(token))
+    chosen_from, tokens = np.stack(chosen_from, 1), np.stack(tokens, 1)
+    assert np.isfinite(chosen_from).all()
+    rows = [r for r, n in enumerate(LENGTHS) if n]
+    want = tiny["reference"].forward(
+        tiny["params"], tiny["config"],
+        [np.concatenate([tiny["ids"][r, :LENGTHS[r]], tokens[r, :-1]])
+         for r in rows],
+        [np.arange(LENGTHS[r] - 1, LENGTHS[r] - 1 + STEPS) for r in rows])
+    for r, logits in zip(rows, want):
+        np.testing.assert_allclose(chosen_from[r], logits, atol=5e-4)
+    counts = np.asarray(mimo.route_counts(config, state))
+    per_token = config.top_k * sum(config.moe_pattern)
+    assert counts[:, 0].tolist() == list(LENGTHS)
+    assert (counts[:, 1] == np.asarray(LENGTHS) * per_token).all()
+    assert (counts[:, 3] == STEPS * per_token).all()
+    assert (counts[:, 2] <= counts[:, 1]).all() and counts[3, 2] == 0
+
+
+def test_whole_generation_equals_prefill_then_steps(tiny):
+    config = tiny["program_config"]
+    sigs = mimo.build_signatures(tiny["params"], config, seq_len=SEQ,
+                                 max_decode_len=STEPS, batch_buckets=(8,))
+    out = sigs["serving_default"].run({"input_ids": tiny["ids"]})
+    state = mimo.prefill(tiny["params"], config, tiny["ids"],
+                         max_decode_len=STEPS)
+    np.testing.assert_allclose(out["first_logits"],
+                               np.asarray(state["logits"]), atol=1e-5)
+    tokens = []
+    for _ in range(STEPS):
+        last = np.asarray(state["logits"])
+        state, token = mimo.step(tiny["params"], config, state)
+        tokens.append(np.asarray(token))
+    np.testing.assert_array_equal(out["output_ids"], np.stack(tokens, 1))
+    np.testing.assert_allclose(out["last_logits"], last, atol=1e-4)
+    assert out["route_counts"].shape == (len(LENGTHS),
+                                         len(mimo.ROUTE_COUNTS))
+
+
+def test_the_rows_that_pad_a_batch_are_prompts_of_length_zero(tiny):
+    """Three requests in a bucket of eight: the five padding rows are
+    rows of pad_id, which no attention and no expert computes, so the
+    batch's load is its real rows' and the answers are the direct ones."""
+    config = tiny["program_config"]
+    signature = mimo.build_signatures(
+        tiny["params"], config, seq_len=SEQ, max_decode_len=STEPS,
+        batch_buckets=(8,))["serving_default"]
+    assert signature._padding_rows(
+        "input_ids", tiny["ids"][:3], 5).tolist() == [[config.pad_id] * SEQ] * 5
+    three = signature.run({"input_ids": tiny["ids"][:3]})
+    whole = signature.run({"input_ids": tiny["ids"]})
+    np.testing.assert_array_equal(three["output_ids"],
+                                  whole["output_ids"][:3])
+    counts = dict(zip(mimo.ROUTE_COUNTS, three["route_counts"].T))
+    assert (counts["load_total"] == np.sum(counts["held_prefill"])).all()
+
+
+@pytest.mark.parametrize("window, sink, kv_heads, d_v", list(
+    itertools.product((None, 8, 128), (False, True), (1, 2, 4), (16, 24))))
+def test_flash_kernel_window_sink_grouped_heads_unequal_sizes(
+        window, sink, kv_heads, d_v):
+    rng = np.random.default_rng(0)
+    b, h, s, d = 3, 4, 300, 24
+    q = jnp.asarray(rng.standard_normal((b, h, s, d)), jnp.float32)
+    k = jnp.asarray(rng.standard_normal((b, kv_heads, s, d)), jnp.float32)
+    v = jnp.asarray(rng.standard_normal((b, kv_heads, s, d_v)), jnp.float32)
+    kw = dict(causal=True, lengths=jnp.asarray([300, 0, 131], jnp.int32),
+              causal_offset=0, window=window, queries_ragged=True,
+              sink=(jnp.asarray(rng.standard_normal((h,)), jnp.float32)
+                    if sink else None))
+    got = flash_attention(q, k, v, interpret=True, **kw)
+    want = attention_reference(q, k, v, **kw)
+    assert got.shape == (b, h, s, d_v) and bool(jnp.isfinite(got).all())
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
+
+
+def _expert_layer(rng, tokens=50, d=32, f=16, experts=16):
+    return {
+        "x": jnp.asarray(rng.standard_normal((tokens, d)), jnp.float32),
+        "router": jnp.asarray(rng.standard_normal((d, experts)) / d ** 0.5,
+                              jnp.float32),
+        "bias": jnp.asarray(rng.standard_normal((experts,)) * 0.1,
+                            jnp.float32),
+        "w_in": jnp.asarray(rng.standard_normal((experts, d, 2 * f))
+                            / d ** 0.5, jnp.float32),
+        "w_out": jnp.asarray(rng.standard_normal((experts, f, d))
+                             / f ** 0.5, jnp.float32)}
+
+
+def test_the_four_shares_add_up_to_the_uncut_reference(tiny):
+    """The share is tied to the model: what each of the 4 chips (4
+    experts each) gives for one layer adds up to what the reference
+    gives when it is handed all 16."""
+    case = _expert_layer(np.random.default_rng(1))
+    uncut = dict(tiny["config"], n_routed_experts=16, num_experts_per_tok=2,
+                 deployment={"expert_offset": 0})
+    with jax.default_matmul_precision("highest"):
+        whole = np.asarray(tiny["reference"]._experts(
+            uncut, {k: case[k] for k in ("router", "bias", "w_in", "w_out")},
+            case["x"]))
+    parts, loads = 0.0, []
+    for share in range(4):
+        held = slice(4 * share, 4 * share + 4)
+        y, routed = jax.jit(
+            lambda p, x, offset=4 * share: held_experts_ffn(
+                p, x, top_k=2, experts_held=4, expert_offset=offset,
+                row_block=16))(
+            HeldExperts(case["router"], case["bias"], case["w_in"][held],
+                        case["w_out"][held]), case["x"])
+        parts = parts + np.asarray(y)
+        loads.append(int(np.sum(routed.load)))
+        assert int(np.sum(routed.held)) == loads[-1]
+    assert sum(loads) == 50 * 2                  # every pair, once
+    np.testing.assert_allclose(parts, whole, atol=1e-4)
+
+
+def test_the_expert_layer_drops_nothing_when_every_token_picks_one():
+    case = _expert_layer(np.random.default_rng(2), tokens=70)
+    bias = jnp.zeros((16,), jnp.float32).at[jnp.asarray([2, 9])].set(10.0)
+    y, routed = held_experts_ffn(
+        HeldExperts(case["router"], bias, case["w_in"][:4],
+                    case["w_out"][:4]), case["x"], top_k=2, experts_held=4,
+        expert_offset=0, row_block=16)
+    assert np.asarray(routed.load).tolist() == [0, 0, 70, 0]
+    assert np.asarray(routed.held).tolist() == [1] * 70
+    scores = jax.nn.sigmoid(case["x"] @ case["router"])
+    weight = scores[:, 2] / (scores[:, 2] + scores[:, 9])
+    hidden = case["x"] @ case["w_in"][2]
+    want = ((jax.nn.silu(hidden[:, :16]) * hidden[:, 16:])
+            @ case["w_out"][2]) * weight[:, None]
+    np.testing.assert_allclose(np.asarray(y), np.asarray(want), atol=1e-4)
+
+
+def test_serving_default_through_a_real_server_with_batching(tiny, tmp_path):
+    """An export made by export_servable, the gRPC front, the batcher and
+    Signature.dispatch: single-example requests ride one batch and each
+    gets what the direct call gives; each request's trace carries its own
+    `generate/route`, and the process's counters add them up."""
+    import dataclasses
+
+    from min_tfs_client_tpu.client import TensorServingClient
+    from min_tfs_client_tpu.observability import runtime, tracing
+    from min_tfs_client_tpu.server.server import Server, ServerOptions
+    from min_tfs_client_tpu.tensor.codec import tensor_proto_to_ndarray
+
+    config = tiny["program_config"]
+    export.export_servable(
+        tmp_path / "mimo", 1, "mimo", dataclasses.asdict(config),
+        tiny["params"],
+        signature_kwargs={"seq_len": SEQ, "max_decode_len": STEPS,
+                          "batch_buckets": [8]})
+    direct = mimo.build_signatures(
+        tiny["params"], config, seq_len=SEQ, max_decode_len=STEPS,
+        batch_buckets=(8,))["serving_default"].run(
+            {"input_ids": tiny["ids"]})
+    batching = tmp_path / "batching.config"
+    batching.write_text("max_batch_size { value: 8 }\n"
+                        "batch_timeout_micros { value: 300000 }\n"
+                        "allowed_batch_sizes: 8\n")
+    before = runtime.route_totals().get(LABEL, {}).get("prompt_tokens", 0)
+    server = Server(ServerOptions(
+        grpc_port=0, model_name="mimo", model_base_path=str(tmp_path / "mimo"),
+        model_platform="jax", enable_batching=True,
+        batching_parameters_file=str(batching),
+        file_system_poll_wait_seconds=0)).build_and_start()
+    try:
+        answers = {}
+
+        def call(row):
+            with TensorServingClient("127.0.0.1", server.grpc_port) as c:
+                resp = c.predict_request(
+                    "mimo", {"input_ids": tiny["ids"][row:row + 1]},
+                    timeout=300)
+            answers[row] = {k: tensor_proto_to_ndarray(v)
+                            for k, v in resp.outputs.items()}
+
+        threads = [threading.Thread(target=call, args=(row,))
+                   for row in range(len(LENGTHS))]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=600)
+    finally:
+        server.stop()
+    for row in range(len(LENGTHS)):
+        np.testing.assert_array_equal(answers[row]["output_ids"][0],
+                                      direct["output_ids"][row])
+        np.testing.assert_allclose(answers[row]["first_logits"][0],
+                                   direct["first_logits"][row], atol=1e-4)
+        np.testing.assert_allclose(answers[row]["last_logits"][0],
+                                   direct["last_logits"][row], atol=1e-3)
+    routes = [args for trace in tracing.ring_snapshot()
+              for name, _, _, args in trace.spans
+              if name == "generate/route"][-len(LENGTHS):]
+    assert sorted(r["prompt_tokens"] for r in routes) == sorted(LENGTHS)
+    assert all(r["pairs_decode"] == STEPS * 2 * 6 for r in routes)
+    assert runtime.route_totals()[LABEL]["prompt_tokens"] - before \
+        == sum(LENGTHS)
+    assert "route" in runtime.snapshot()
+
+
+def test_an_on_answer_that_raises_loses_its_note_and_not_the_answer(caplog):
+    import types
+
+    from min_tfs_client_tpu.server.handlers import Handlers
+
+    def raises(signature, outputs):
+        raise ZeroDivisionError("a counter overflowed")
+
+    noisy = types.SimpleNamespace(on_answer=raises,
+                                  telemetry_label="m:1:serving_default")
+    Handlers._answered(noisy, {})                    # returns: no raise
+    assert "on_answer of m:1:serving_default raised" in caplog.text
+    Handlers._answered(types.SimpleNamespace(on_answer=None), {})
