@@ -34,7 +34,10 @@ from min_tfs_client_tpu.ops.attention import NEG_INF, attention
 from min_tfs_client_tpu.parallel.moe import HeldExperts, held_experts_ffn
 
 ROUTE_COUNTS = ("prompt_tokens", "pairs_prefill", "held_prefill",
-                "pairs_decode", "held_decode", "max_load", "load_total")
+                "pairs_decode", "held_decode", "max_load", "load_total",
+                "prefill_rows")
+# ... of which these are the whole batch's, the same on every row
+BATCH_COUNTS = ("max_load", "load_total", "prefill_rows")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -189,9 +192,9 @@ def _qkv(config: MimoConfig, layer: int, attn: dict, x: jax.Array,
     return q, k, (v.astype(jnp.float32) * config.value_scale).astype(dtype)
 
 
-def _ffn(config: MimoConfig, layer: dict, x: jax.Array,
-         valid: jax.Array | None):
-    """x (T, D) float32 (normed) -> (y (T, D) float32, Routed or None)."""
+def _ffn(config: MimoConfig, layer: dict, x: jax.Array, **routing):
+    """x (T, D) float32 (normed) -> (y (T, D) float32, Routed or None);
+    `routing` is the expert layer's own (`rows`, `onto`)."""
     if "mlp" in layer:
         wi, wo = layer["mlp"]["wi"]["kernel"], layer["mlp"]["wo"]["kernel"]
         f = wo.shape[0]
@@ -202,7 +205,7 @@ def _ffn(config: MimoConfig, layer: dict, x: jax.Array,
     return held_experts_ffn(
         HeldExperts(**layer["moe"]), x, top_k=config.top_k,
         experts_held=config.experts_held,
-        expert_offset=config.expert_offset, valid=valid)
+        expert_offset=config.expert_offset, **routing)
 
 
 def _norm(params: dict, x: jax.Array, config: MimoConfig) -> jax.Array:
@@ -228,36 +231,99 @@ def _ring_of(rows: jax.Array, lengths: jax.Array, window: int) -> jax.Array:
     return jnp.take_along_axis(rows, position[:, None, :, None], axis=2)
 
 
-def _prefill_rows(params: dict, config: MimoConfig, ids: jax.Array,
-                  max_decode_len: int):
+PREFILL_ROW_BLOCK = 512   # packed rows the per-token work takes at a time
+
+
+def _prefill_chunk(params: dict, config: MimoConfig, ids: jax.Array,
+                   max_decode_len: int, row_block: int):
     """Some examples (b, S) through the whole stack -> (caches, logits
     at each example's last position (b, V), held pairs (b,), load
-    (expert layers, held experts))."""
+    (expert layers, held experts), rows of per-token work run).
+
+    The residual stream is PACKED: the chunk's real tokens first, in
+    (example, position) order, and everything that treats rows one by
+    one (norms, projections, rotation, dense layer, router, residual
+    sums) runs in blocks of `row_block` rows, as many as the real tokens
+    fill. Attention alone sees the (example, position) grid: q, k and v
+    are cut out of the packed rows an example at a time (the rows behind
+    an example's last are whatever lies there; the kernel masks them),
+    and its output is read back by row index."""
     b, s = ids.shape
+    block = min(row_block, b * s)
+    t = -(-b * s // block) * block
     lengths = jnp.sum((ids != config.pad_id).astype(jnp.int32), axis=-1)
-    positions = jnp.broadcast_to(jnp.arange(s)[None, :], (b, s))
-    valid = (positions < lengths[:, None]).reshape(-1)
-    h = params["embed"]["embedding"][ids].astype(jnp.float32)
+    ends = jnp.cumsum(lengths)
+    starts, total = ends - lengths, ends[-1]
+    blocks = (total + block - 1) // block
+    row = jnp.arange(t)
+    example = jnp.minimum(jnp.searchsorted(ends, row, side="right"), b - 1)
+    position = row - starts[example]
+    # where a packed row lies on the grid (rows past the last: anywhere)
+    on_grid = jnp.clip(example * s + position, 0, b * s - 1)
+    h = params["embed"]["embedding"][jnp.where(
+        row < total, ids.reshape(-1)[on_grid], config.pad_id)].astype(
+            jnp.float32)
+
+    def over_blocks(body, carry):
+        return jax.lax.fori_loop(
+            0, blocks, lambda i, c: body(i * block, c), carry)
+
+    def cut(x, lo):
+        return jax.lax.dynamic_slice_in_dim(x, lo, block)
+
+    def put(x, part, lo):
+        return jax.lax.dynamic_update_slice_in_dim(x, part, lo, 0)
+
+    def grid(packed, heads):
+        """(t, heads * d) packed -> (b, heads, S, d): example e's S rows
+        from its first (starts[e] + S <= (e + 1) S: inside the buffer)."""
+        rows = jnp.stack([jax.lax.dynamic_slice_in_dim(packed, starts[e], s)
+                          for e in range(b)])
+        return rows.reshape(b, s, heads, -1).transpose(0, 2, 1, 3)
+
+    dtype = params["layers"][0]["attn"]["qkv"]["kernel"].dtype
     caches, held, loads = [], jnp.zeros((b,), jnp.int32), []
     for index, layer in enumerate(params["layers"]):
         windowed = bool(config.layer_pattern[index])
-        attn = layer["attn"]
-        q, k, v = _qkv(config, index, attn,
-                       _norm(layer["attn_norm"], h, config), positions)
-        k, v = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
+        attn, kv = layer["attn"], config.kv_heads(index)
+        widths = (config.num_heads * config.head_dim, kv * config.head_dim,
+                  kv * config.v_head_dim)
+
+        def project(lo, qkv, h=h, index=index, layer=layer, attn=attn):
+            parts = _qkv(config, index, attn,
+                         _norm(layer["attn_norm"], cut(h, lo), config),
+                         cut(position, lo))
+            return tuple(put(all_, part.reshape(block, -1), lo)
+                         for all_, part in zip(qkv, parts))
+
+        # rows that no block writes stay zeros: masked positions read them
+        qkv = over_blocks(project, tuple(jnp.zeros((t, w), dtype)
+                                         for w in widths))
+        q, k, v = (grid(x, heads) for x, heads in
+                   zip(qkv, (config.num_heads, kv, kv)))
         out = attention(
-            q.transpose(0, 2, 1, 3), k, v, causal=True, lengths=lengths,
-            causal_offset=0, window=config.window if windowed else None,
+            q, k, v, causal=True, lengths=lengths, causal_offset=0,
+            window=config.window if windowed else None,
             sink=attn.get("sink"), queries_ragged=True)
-        h = h + _mm(out.transpose(0, 2, 1, 3).reshape(b, s, -1),
-                    attn["out"]["kernel"])
-        y, routed = _ffn(config, layer,
-                         _norm(layer["ffn_norm"], h, config).reshape(b * s,
-                                                                     -1),
-                         valid)
-        h = h + y.reshape(b, s, -1)
-        if routed is not None:
-            held = held + jnp.sum(routed.held.reshape(b, s), axis=-1)
+        out = out.transpose(0, 2, 1, 3).reshape(b * s, -1)
+        dense = "mlp" in layer
+
+        def mix(lo, carry, layer=layer, attn=attn, out=out, dense=dense):
+            h, normed = carry
+            rows = cut(h, lo) + _mm(out[cut(on_grid, lo)],
+                                    attn["out"]["kernel"])
+            x = _norm(layer["ffn_norm"], rows, config)
+            if dense:
+                return put(h, rows + _ffn(config, layer, x)[0], lo), normed
+            return put(h, rows, lo), put(normed, x, lo)
+
+        h, normed = over_blocks(mix, (h, None if dense else jnp.zeros(
+            (t, config.hidden_size), jnp.float32)))
+        if not dense:
+            h, routed = _ffn(config, layer, normed, rows=total, onto=h)
+            counted = jnp.concatenate([jnp.zeros((1,), jnp.int32),
+                                       jnp.cumsum(routed.held)])
+            held = held + counted[ends] - counted[starts]
             loads.append(routed.load)
         if windowed:
             caches.append({"k": _ring_of(k, lengths, config.window),
@@ -265,29 +331,34 @@ def _prefill_rows(params: dict, config: MimoConfig, ids: jax.Array,
         else:
             room = ((0, 0), (0, 0), (0, max_decode_len), (0, 0))
             caches.append({"k": jnp.pad(k, room), "v": jnp.pad(v, room)})
-    last = jnp.maximum(lengths - 1, 0)[:, None, None]
-    logits = _logits(params, config,
-                     jnp.take_along_axis(h, last, axis=1)[:, 0])
+    # each example's last row (an example of no token: zeros, whatever its
+    # neighbours are)
+    last = jnp.where(lengths[:, None] > 0, h[jnp.maximum(ends - 1, 0)], 0.0)
+    logits = _logits(params, config, last)
     load = (jnp.stack(loads) if loads
             else jnp.zeros((0, config.experts_held), jnp.int32))
-    return caches, logits, held, load
+    return caches, logits, held, load, blocks * block
 
 
 def prefill(params: dict, config: MimoConfig, input_ids: jax.Array, *,
-            max_decode_len: int) -> dict:
+            max_decode_len: int,
+            row_block: int = PREFILL_ROW_BLOCK) -> dict:
     """The prompts (B, seq_len), right-padded with pad_id, through the
-    stack (`config.prefill_rows` examples at a time) -> the state a
+    stack (`config.prefill_rows` examples at a time, their real tokens
+    packed and taken `row_block` rows at a time) -> the state a
     generation carries: per full layer K/V of seq_len + max_decode_len
     positions, per window layer a ring of `window` rows, each example's
     length, the logits its next token is chosen from, `token` (the last
-    one chosen), `finished`, and what the expert layers counted."""
+    one chosen), `finished`, and what the prefill and the expert layers
+    counted."""
     ids = jnp.asarray(input_ids, jnp.int32)
     b, s = ids.shape
     rows = min(config.prefill_rows, b)
     if b % rows:
         rows = b
-    caches, logits, held, load = jax.lax.map(
-        lambda chunk: _prefill_rows(params, config, chunk, max_decode_len),
+    caches, logits, held, load, ran = jax.lax.map(
+        lambda chunk: _prefill_chunk(params, config, chunk, max_decode_len,
+                                     row_block),
         ids.reshape(b // rows, rows, s))
     merge = lambda x: x.reshape(b, *x.shape[2:])  # noqa: E731
     load = jnp.sum(load, axis=0)
@@ -302,7 +373,8 @@ def prefill(params: dict, config: MimoConfig, input_ids: jax.Array, *,
                    "held_decode": jnp.zeros((b,), jnp.int32),
                    "steps": jnp.zeros((b,), jnp.int32),
                    "max_load": jnp.max(load, initial=0),
-                   "load_total": jnp.sum(load)},
+                   "load_total": jnp.sum(load),
+                   "prefill_rows": jnp.sum(ran)},
     }
 
 
@@ -366,8 +438,7 @@ def step(params: dict, config: MimoConfig, state: dict):
         caches.append(cache)
         h = h + _mm(_attend_cache(q, cache, seen, attn.get("sink")),
                     attn["out"]["kernel"])
-        y, routed = _ffn(config, layer, _norm(layer["ffn_norm"], h, config),
-                         None)
+        y, routed = _ffn(config, layer, _norm(layer["ffn_norm"], h, config))
         h = h + y
         if routed is not None:
             held = held + routed.held
@@ -392,8 +463,8 @@ def route_counts(config: MimoConfig, state: dict) -> jax.Array:
         "held_prefill": counts["held_prefill"],
         "pairs_decode": counts["steps"] * per_token,
         "held_decode": counts["held_decode"],
-        "max_load": jnp.broadcast_to(counts["max_load"], (b,)),
-        "load_total": jnp.broadcast_to(counts["load_total"], (b,)),
+        **{name: jnp.broadcast_to(counts[name], (b,))
+           for name in BATCH_COUNTS},
     }
     return jnp.stack([columns[name].astype(jnp.int32)
                       for name in ROUTE_COUNTS], axis=-1)
@@ -414,14 +485,18 @@ def note_route(signature, outputs) -> None:
         return
     rows = np.asarray(rows).reshape(-1, len(ROUTE_COUNTS))
     sums = rows.sum(axis=0)
-    args = {name: int(rows[:, i].max() if name in ("max_load", "load_total")
-                      else sums[i])
+    args = {name: int(rows[:, i].max() if name in BATCH_COUNTS else sums[i])
             for i, name in enumerate(ROUTE_COUNTS)}
     now = time.perf_counter()
     tracing.add_span("generate/route", now, now, **args)
-    runtime.count_route(signature.telemetry_label or "unlabeled", {
-        k: v for k, v in args.items()
-        if k not in ("max_load", "load_total")})
+    counted = {k: v for k, v in args.items() if k not in BATCH_COUNTS}
+    # The batch's rows, a request's share of them: by its share of the
+    # batch's held pairs, the one count whose batch total a row carries,
+    # so that the requests of a batch add up to the batch's figure.
+    counted["prefill_rows"] = round(
+        args["prefill_rows"] * args["held_prefill"]
+        / max(args["load_total"], 1))
+    runtime.count_route(signature.telemetry_label or "unlabeled", counted)
 
 
 def build_signatures(params: dict, config: MimoConfig, *, seq_len: int,

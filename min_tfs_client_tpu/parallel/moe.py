@@ -197,12 +197,19 @@ def sigmoid_top_k(x: jax.Array, router: jax.Array, bias: jax.Array,
 def held_experts_ffn(params: HeldExperts, x: jax.Array, *, top_k: int,
                      experts_held: int, expert_offset: int,
                      valid: jax.Array | None = None,
+                     rows: jax.Array | None = None,
+                     onto: jax.Array | None = None,
                      row_block: int = ROW_BLOCK
                      ) -> tuple[jax.Array, Routed]:
     """x (T, D) -> (y (T, D) float32, Routed): y = sum over a token's
     chosen experts e in [expert_offset, expert_offset + experts_held) of
     w_e * SwiGLU_e(x). A token none of whose choices is held gets 0.
     `valid` (T,) bool leaves padding rows out of the routing altogether.
+    `rows` (a traced count) says that only the leading `rows` rows are
+    real: the router then runs in blocks of `row_block` rows, as many as
+    those rows fill, and the rows behind them are routed nowhere. `onto`
+    (T, D) float32 is what the experts' rows are added onto in place of
+    zeros (a caller's residual stream: y = onto + the layer's result).
 
     The pairs are sorted by held expert (pairs on absent experts last),
     and the held ones pass the grouped gate/up/down products
@@ -214,7 +221,26 @@ def held_experts_ffn(params: HeldExperts, x: jax.Array, *, top_k: int,
     if params.w_in.shape[0] != experts_held:
         raise ValueError("w_in holds another number of experts than held")
     d_ff = params.w_out.shape[1]
-    experts, weights = sigmoid_top_k(x, params.router, params.bias, top_k)
+    if rows is None:
+        experts, weights = sigmoid_top_k(x, params.router, params.bias,
+                                         top_k)
+    else:
+        step = min(row_block, t)
+
+        def route(i, found):
+            lo = jnp.minimum(i * step, t - step)   # the last block may lap
+            some = sigmoid_top_k(
+                jax.lax.dynamic_slice_in_dim(x, lo, step), params.router,
+                params.bias, top_k)
+            return tuple(jax.lax.dynamic_update_slice_in_dim(all_, part, lo, 0)
+                         for all_, part in zip(found, some))
+
+        experts, weights = jax.lax.fori_loop(
+            0, (rows + step - 1) // step, route,
+            (jnp.zeros((t, top_k), jnp.int32),
+             jnp.zeros((t, top_k), jnp.float32)))
+        real = jnp.arange(t) < rows
+        valid = real if valid is None else jnp.logical_and(valid, real)
     local = experts - expert_offset
     held = jnp.logical_and(local >= 0, local < experts_held)
     if valid is not None:
@@ -247,6 +273,7 @@ def held_experts_ffn(params: HeldExperts, x: jax.Array, *, top_k: int,
         out = jnp.where(live, out * flat_weights[pair][:, None], 0.0)
         return y.at[token].add(out)
 
-    y = jax.lax.fori_loop(0, (total + block - 1) // block, rows_of,
-                          jnp.zeros(x.shape, jnp.float32))
+    y = jax.lax.fori_loop(
+        0, (total + block - 1) // block, rows_of,
+        jnp.zeros(x.shape, jnp.float32) if onto is None else onto)
     return y, Routed(held=jnp.sum(held, axis=-1, dtype=jnp.int32), load=load)

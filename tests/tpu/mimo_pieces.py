@@ -3,15 +3,23 @@ widths of perfbench/configs/mimo-v2.5.json (PERF.md section 5 quotes
 them). Not a test and not part of the benchmark: run it on a machine
 with the chip,
 
-    python tests/tpu/mimo_pieces.py
+    python tests/tpu/mimo_pieces.py [--pieces experts,flash,prefill,decode]
+                                    [--row-block N] [--out FILE]
 
-and read chiprun_out/mimo_pieces.json: the expert layer by
+and read chiprun_out/mimo_pieces.json (or FILE): the expert layer by
 `jax.lax.ragged_dot` against a dense product over the held experts at
 the decode shape (32 rows) and the prefill shape (16,384 rows),
 `_flash_kernel` in a full and a window layer on full and mixed lengths,
-then one whole prefill of a mixed batch of 32 and one decode step.
+then the prefill alone on three batches of 32 (the traffic's mixed
+lengths; 20 such rows and 12 rows that pad the batch; 32 prompts of
+2,048, where there is nothing to pack) and one decode step.
+
+To set a parent against a change, run it from a `git archive` checkout
+of each in ONE call, `--pieces prefill --out <a file of its own>`
+(`--row-block` is the change's alone: the parent's prefill has none).
 """
 
+import argparse
 import json
 import pathlib
 import sys
@@ -60,9 +68,20 @@ def dense_over_held(p: HeldExperts, x):
     return jnp.einsum("etd,te->td", y, w)
 
 
-def main() -> None:
-    out = {"device": str(jax.devices()[0].device_kind)}
-    ks = jax.random.split(jax.random.PRNGKey(0), 8)
+def prefill_batches(grid, vocab_size: int) -> dict:
+    """name -> ids (32, 2048): rows of the traffic's own lengths."""
+    rng = np.random.default_rng(0)
+    mixed = np.zeros((32, 2048), np.int32)
+    for row, n in enumerate(rng.permutation(grid)[:32]):
+        mixed[row, :n] = rng.integers(2, vocab_size, n)
+    padded = mixed.copy()
+    padded[20:] = 0
+    return {"mixed": mixed, "20_real_12_padding_rows": padded,
+            "full_length": rng.integers(2, vocab_size, (32, 2048),
+                                        dtype=np.int32)}
+
+
+def experts(out: dict, ks) -> None:
     p = HeldExperts(
         router=jax.random.normal(ks[0], (D, ROUTER), jnp.float32) / 64,
         bias=jnp.zeros((ROUTER,), jnp.float32),
@@ -84,8 +103,9 @@ def main() -> None:
         ffn, p, xp, (jnp.arange(16384) % 2048) < 805, n=3)
     out["experts_prefill_all_valid_ms"] = timed(
         ffn, p, xp, jnp.ones((16384,), bool), n=3)
-    del xp
 
+
+def flash(out: dict, ks) -> None:
     for name, kv, window in (("full", 4, None), ("window", 8, 128)):
         q = jax.random.normal(ks[5], (8, 64, 2048, 192), jnp.bfloat16)
         k = jax.random.normal(ks[6], (8, kv, 2048, 192), jnp.bfloat16)
@@ -101,26 +121,45 @@ def main() -> None:
                 fn, q, k, v, jnp.asarray(lengths, jnp.int32))
         del q, k, v
 
-    config = json.loads(
-        (ROOT / "perfbench/configs/mimo-v2.5.json").read_text())
-    pc = mimo.MimoConfig(**children.program_config_kwargs(config))
-    params = jax.jit(lambda k: mimo.init_params(k, pc))(jax.random.PRNGKey(1))
-    grid = json.loads((ROOT / "perfbench/traffic/mixed-generate.json")
-                      .read_text())["input_length_grid"]
-    rng = np.random.default_rng(0)
-    ids = np.zeros((32, 2048), np.int32)
-    for row, n in enumerate(rng.permutation(grid)[:32]):
-        ids[row, :n] = rng.integers(2, pc.vocab_size, n)
-    prefill = jax.jit(lambda p, ids: mimo.prefill(p, pc, ids,
-                                                  max_decode_len=128))
-    out["prefill_32x2048_mixed_ms"] = timed(prefill, params, ids, n=2)
-    state = prefill(params, ids)
-    step = jax.jit(lambda p, s: mimo.step(p, pc, s)[0])
-    out["decode_step_ms"] = timed(step, params, state, n=20)
+
+def main() -> None:
+    parser = argparse.ArgumentParser()
+    parser.add_argument("--pieces", default="experts,flash,prefill,decode")
+    parser.add_argument("--row-block", type=int, default=None)
+    parser.add_argument("--out", default=str(
+        ROOT / "chiprun_out/mimo_pieces.json"))
+    args = parser.parse_args()
+    pieces = set(args.pieces.split(","))
+    out = {"device": str(jax.devices()[0].device_kind)}
+    ks = jax.random.split(jax.random.PRNGKey(0), 8)
+    if "experts" in pieces:
+        experts(out, ks)
+    if "flash" in pieces:
+        flash(out, ks)
+    if pieces & {"prefill", "decode"}:
+        config = json.loads(
+            (ROOT / "perfbench/configs/mimo-v2.5.json").read_text())
+        pc = mimo.MimoConfig(**children.program_config_kwargs(config))
+        params = jax.jit(lambda k: mimo.init_params(k, pc))(
+            jax.random.PRNGKey(1))
+        grid = json.loads((ROOT / "perfbench/traffic/mixed-generate.json")
+                          .read_text())["input_length_grid"]
+        batches = prefill_batches(grid, pc.vocab_size)
+        block = ({} if args.row_block is None
+                 else {"row_block": args.row_block})
+        prefill = jax.jit(lambda p, ids: mimo.prefill(
+            p, pc, ids, max_decode_len=128, **block))
+        if "prefill" in pieces:
+            for name, ids in batches.items():
+                out[f"prefill_32x2048_{name}_ms"] = timed(
+                    prefill, params, ids, n=3)
+        if "decode" in pieces:
+            state = prefill(params, batches["mixed"])
+            step = jax.jit(lambda p, s: mimo.step(p, pc, s)[0])
+            out["decode_step_ms"] = timed(step, params, state, n=20)
     print(json.dumps(out, indent=1))
-    (ROOT / "chiprun_out").mkdir(exist_ok=True)
-    (ROOT / "chiprun_out/mimo_pieces.json").write_text(
-        json.dumps(out, indent=1))
+    pathlib.Path(args.out).parent.mkdir(parents=True, exist_ok=True)
+    pathlib.Path(args.out).write_text(json.dumps(out, indent=1))
 
 
 if __name__ == "__main__":

@@ -104,6 +104,104 @@ def test_every_decode_step_through_ring_and_full_caches_agrees(tiny):
     assert (counts[:, 2] <= counts[:, 1]).all() and counts[3, 2] == 0
 
 
+BLOCK = 16     # of the packed prefill in the cases below: 80 rows a chunk
+PACKED_CASES = {
+    # the chunks of 2 examples hold 13, 29 and 49 real tokens: every one
+    # a partial last block, the last chunk four blocks
+    "mixed_lengths": (5, 8, 29, 0, 40, 9),
+    "a_padding_row_among_real_rows": (0, 21, 7, 0, 0, 12),
+    "a_chunk_that_is_wholly_padding": (17, 30, 0, 0, 3, 40),
+    "every_row_at_seq_len": (40, 40, 40, 40, 40, 40),
+    # 32 = two whole blocks and no partial one; a chunk of one token
+    "a_multiple_of_the_block_and_a_single_token": (16, 16, 1, 0, 32, 0),
+}
+
+
+@pytest.mark.parametrize("lengths", PACKED_CASES.values(),
+                         ids=PACKED_CASES.keys())
+def test_the_packed_prefill_agrees_with_the_references_full_forward(
+        tiny, lengths):
+    """The prefill keeps a chunk's real tokens packed and runs its
+    per-token work in blocks of 16 rows. Against one full forward pass a
+    sequence: the first logits, both kinds of cache (by decoding from
+    them), the held pairs and the load (the real tokens' pairs, from the
+    reference's own routing), and the rows the blocks ran."""
+    config, steps = tiny["program_config"], 10
+    rng = np.random.default_rng(sum(lengths))
+    ids = np.zeros((len(lengths), SEQ), np.int32)
+    for row, n in enumerate(lengths):
+        ids[row, :n] = rng.integers(2, tiny["config"]["vocab_size"], n)
+    state = jax.jit(lambda p, ids: mimo.prefill(
+        p, config, ids, max_decode_len=STEPS, row_block=BLOCK))(
+            tiny["params"], ids)
+    prefilled = state
+    step = jax.jit(lambda p, s: mimo.step(p, config, s))
+    chosen_from, tokens = [], []
+    for _ in range(steps):
+        chosen_from.append(np.asarray(state["logits"]))
+        state, token = step(tiny["params"], state)
+        tokens.append(np.asarray(token))
+    chosen_from, tokens = np.stack(chosen_from, 1), np.stack(tokens, 1)
+    assert np.isfinite(chosen_from).all()            # a length of 0 too
+    rows = [r for r, n in enumerate(lengths) if n]
+    want = tiny["reference"].forward(
+        tiny["params"], tiny["config"],
+        [np.concatenate([ids[r, :lengths[r]], tokens[r, :-1]])
+         for r in rows],
+        [np.arange(lengths[r] - 1, lengths[r] - 1 + steps) for r in rows])
+    for r, logits in zip(rows, want):
+        np.testing.assert_allclose(chosen_from[r, 0], logits[0], atol=2e-4)
+        np.testing.assert_allclose(chosen_from[r], logits, atol=5e-4)
+
+    # what the expert layers counted: the pairs of the real tokens alone
+    held, load = _held_pairs_by_the_reference(tiny, ids, lengths)
+    counts = dict(zip(mimo.ROUTE_COUNTS,
+                      np.asarray(mimo.route_counts(config, prefilled)).T))
+    assert counts["prompt_tokens"].tolist() == list(lengths)
+    assert counts["held_prefill"].tolist() == held.tolist()
+    assert (counts["max_load"] == load.max()).all()
+    assert load.sum() == held.sum()
+    assert (counts["load_total"] == load.sum()).all()
+    per_chunk = np.asarray(lengths).reshape(-1, config.prefill_rows).sum(1)
+    assert (counts["prefill_rows"]
+            == sum(-(-int(n) // BLOCK) * BLOCK for n in per_chunk)).all()
+
+
+def _held_pairs_by_the_reference(tiny, ids, lengths):
+    """(held pairs of each example (B,), rows of each held expert in each
+    expert layer (layers, held)): the router of each expert layer on the
+    rows that enter it in the reference's own pass over each sequence."""
+    ref, config, program = (tiny["reference"], tiny["config"],
+                            tiny["program_config"])
+    eps = config["layernorm_epsilon"]
+    held = np.zeros((len(lengths),), np.int64)
+    load = np.zeros((sum(program.moe_pattern), program.experts_held),
+                    np.int64)
+    with jax.default_matmul_precision("highest"):
+        table = ref._f32(tiny["params"]["embed"]["embedding"])
+        for row, n in enumerate(lengths):
+            h, at = table[ids[row, :n]], 0
+            for index in range(config["layers"] if n else 0):
+                layer = ref._float32(tiny["params"]["layers"][index])
+                if config["moe_layer_freq"][index]:
+                    x = h + ref._attention(
+                        config, index, layer["attn"],
+                        ref._rms(layer["attn_norm"]["scale"], h, eps))
+                    scores = jax.nn.sigmoid(
+                        ref._rms(layer["ffn_norm"]["scale"], x, eps)
+                        @ layer["moe"]["router"])
+                    _, chosen = jax.lax.top_k(scores + layer["moe"]["bias"],
+                                              program.top_k)
+                    local = np.asarray(chosen) - program.expert_offset
+                    mine = local[(local >= 0)
+                                 & (local < program.experts_held)]
+                    held[row] += mine.size
+                    np.add.at(load[at], mine, 1)
+                    at += 1
+                h = ref._layer(config, index, layer, h)
+    return held, load
+
+
 def test_whole_generation_equals_prefill_then_steps(tiny):
     config = tiny["program_config"]
     sigs = mimo.build_signatures(tiny["params"], config, seq_len=SEQ,
@@ -244,7 +342,8 @@ def test_serving_default_through_a_real_server_with_batching(tiny, tmp_path):
     batching.write_text("max_batch_size { value: 8 }\n"
                         "batch_timeout_micros { value: 300000 }\n"
                         "allowed_batch_sizes: 8\n")
-    before = runtime.route_totals().get(LABEL, {}).get("prompt_tokens", 0)
+    before = {"prompt_tokens": 0, "prefill_rows": 0,
+              **runtime.route_totals().get(LABEL, {})}
     server = Server(ServerOptions(
         grpc_port=0, model_name="mimo", model_base_path=str(tmp_path / "mimo"),
         model_platform="jax", enable_batching=True,
@@ -281,8 +380,16 @@ def test_serving_default_through_a_real_server_with_batching(tiny, tmp_path):
               if name == "generate/route"][-len(LENGTHS):]
     assert sorted(r["prompt_tokens"] for r in routes) == sorted(LENGTHS)
     assert all(r["pairs_decode"] == STEPS * 2 * 6 for r in routes)
-    assert runtime.route_totals()[LABEL]["prompt_tokens"] - before \
-        == sum(LENGTHS)
+    totals = runtime.route_totals()[LABEL]
+    assert totals["prompt_tokens"] - before["prompt_tokens"] == sum(LENGTHS)
+    # the rows the prefill ran are a batch's figure, the same on each of
+    # its riders; the counters give each request its share of them
+    by_batch = {(r["load_total"], r["max_load"]): r["prefill_rows"]
+                for r in routes}
+    assert all(rows > 0 and rows % (2 * SEQ) == 0
+               for rows in by_batch.values())       # chunks of 80 rows
+    assert abs(totals["prefill_rows"] - before["prefill_rows"]
+               - sum(by_batch.values())) <= len(LENGTHS)
     assert "route" in runtime.snapshot()
 
 
