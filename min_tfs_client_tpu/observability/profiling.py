@@ -35,8 +35,8 @@ Served at `/monitoring/profile` on both REST backends and the router
  * ?device=1&seconds=N — programmatic `jax.profiler.trace` capture to
                      --profile_dir (the XPlane dump the chip-truth
                      campaign replays), with the stage spans mirrored
-                     into it and `host_clock.json` beside it
-                     (`traced_capture`). jax is imported inside that
+                     into it, `host_clock.json` and `host_track.json`
+                     beside it (`traced_capture`). jax is imported inside that
                      function only — this module stays stdlib+tracing so
                      the jax-free router imports it.
 
@@ -663,6 +663,14 @@ def diff_payload(seconds: float, hz: float | None = None) -> dict:
 
 
 HOST_CLOCK_FILE = "host_clock.json"
+HOST_TRACK_FILE = "host_track.json"
+# The host track is written out from this long BEFORE the capture. Inside
+# a capture the profiler's hooks slow every Python thread (the sessions
+# cell answered 305 steps a second inside one, 1,045 outside: PERF.md
+# section 6, PR 40), so what the process's own work costs (the drain, the
+# collector, the event loop's CPU) is read from the undisturbed stretch
+# before it; the capture's own spans name the device's gaps.
+TRACK_LEAD_S = 10.0
 
 
 @contextlib.contextmanager
@@ -673,7 +681,8 @@ def traced_capture(log_dir: str):
     the tracing spine mirrors its spans into TraceAnnotations, so the
     capture shows the stage names on the host threads; at its end it
     writes `host_clock.json` beside the capture, which joins the spans'
-    clock to the capture's.
+    clock to the capture's, and `host_track.json`, what the host did
+    meanwhile (`_write_host_track`).
 
     The profiler's events count nanoseconds from the start of its
     session (measured on a v5e, PERF.md section 7: not the Unix epoch),
@@ -705,6 +714,35 @@ def traced_capture(log_dir: str):
                     - zero["epoch_unix_ns"],
                     "zero": zero, "start": start, "stop": stop},
                     out, indent=1)
+    # After `stop_trace`: rendering a few thousand traces is a burst of
+    # Python that the capture should not hold, and the requests that
+    # ended while the profiler wrote its file are in the ring by now.
+    _write_host_track(log_dir, zero, stop)
+
+
+def _write_host_track(log_dir: str, zero: dict, stop: dict) -> None:
+    """`host_track.json`: every process span and every request trace of
+    the ring, of any signature, that overlaps the capture [zero, stop],
+    in the one format `/monitoring/traces` has (`tracing.chrome_trace`),
+    so that a capture is whole by itself: what an operator opens next to
+    the device trace, and what a reader joins to it through
+    `host_clock.json`. The process spans begin TRACK_LEAD_S before the
+    capture. `otherData.capture` holds the capture's two ends as `ts`
+    values, and `lead_us`, where the process spans begin. The ring
+    bounds what it can hold (256 requests unless `--trace_ring_size`
+    says more)."""
+    t0, t1 = zero["perf_counter_s"], stop["perf_counter_s"]
+    lead = t0 - TRACK_LEAD_S
+    payload = tracing.chrome_trace(
+        [tr for tr in tracing.ring_snapshot()
+         if tr.start <= t1 and (tr.end is None or tr.end >= t0)],
+        process_spans=tracing.process_snapshot(since=lead, until=t1))
+    payload["otherData"].update(
+        schema="host_track/1",
+        capture={"zero_us": zero["span_us"], "stop_us": stop["span_us"],
+                 "lead_us": tracing._us(lead)})
+    with open(os.path.join(log_dir, HOST_TRACK_FILE), "w") as out:
+        json.dump(payload, out, separators=(",", ":"))
 
 
 def device_capture(seconds: float, log_dir: str = "") -> dict:

@@ -26,11 +26,13 @@ until unload. The ledger makes that visible:
 from __future__ import annotations
 
 import collections
+import gc
 import threading
 import time
 import weakref
 from typing import Mapping
 
+from min_tfs_client_tpu.observability import tracing
 from min_tfs_client_tpu.utils import aio_loop
 
 _LEDGER_CAPACITY = 256
@@ -347,6 +349,44 @@ def transfer_totals() -> dict:
         return {}
 
 
+# -- garbage collections ------------------------------------------------------
+
+# A collection of the whole heap holds the interpreter lock for as long
+# as it runs (130-250 ms under the sessions cell, PERF.md): every thread
+# of the process stops, the chip runs dry. The collector's own callbacks
+# time each one.
+GC_SPAN_MIN_S = 1e-3
+_gc_pause_s = [0.0, 0.0, 0.0]  # by generation; written under the interpreter
+_gc_open = None                # lock, by the one collection that can run
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    global _gc_open
+    if phase == "start":
+        _gc_open = (tracing.open_annotation("host/gc"), time.perf_counter())
+    elif _gc_open is not None:
+        t1 = time.perf_counter()
+        (ann, t0), _gc_open = _gc_open, None
+        tracing.close_annotation(ann)
+        _gc_pause_s[info["generation"]] += t1 - t0
+        if t1 - t0 >= GC_SPAN_MIN_S:
+            tracing.process_span("host/gc", t0, t1, gen=info["generation"],
+                                 collected=info["collected"])
+
+
+def watch_gc() -> None:
+    """Time every collection from here on (idempotent): all of them into
+    `gc_pause_seconds` of `/monitoring/runtime`, by generation, and a
+    pause of GC_SPAN_MIN_S or more onto the tracing spine's host track
+    as `host/gc` (`gen`, `collected`). Two clock reads a collection."""
+    if _on_gc not in gc.callbacks:
+        gc.callbacks.append(_on_gc)
+
+
+def gc_pause_seconds() -> dict:
+    return {str(gen): round(s, 6) for gen, s in enumerate(_gc_pause_s)}
+
+
 # -- the /monitoring/runtime payload -----------------------------------------
 
 
@@ -364,6 +404,9 @@ def snapshot(include_live_arrays: bool = False) -> dict:
         # The gRPC front end: requests answered on the event loop and on
         # the worker pool, and the loop's sampled lag (utils/aio_loop.py).
         "grpc": aio_loop.stats(),
+        # Seconds the collector has held the interpreter, by generation,
+        # since `watch_gc` (the server's boot).
+        "gc_pause_seconds": gc_pause_seconds(),
     }
     if include_live_arrays:
         payload["live_arrays"] = live_array_stats()

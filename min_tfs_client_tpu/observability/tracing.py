@@ -1,4 +1,4 @@
-"""Per-request tracing spine: Span / RequestTrace + three export sinks.
+"""Per-request tracing spine: Span / RequestTrace + four export sinks.
 
 The reference stack threads `profiler::TraceMe` annotations and the
 monitoring registry through every hot-path stage (shared_batch_scheduler.h:39,
@@ -37,6 +37,13 @@ Sinks, fed when a trace finishes:
     the same stage names on the host threads. Off outside a capture: a
     TraceAnnotation object per span costs ~1us of pure Python even with
     no capture active, which is real money at toy-model latencies.
+ 4. the host track: a second bounded ring, of spans that belong to the
+    PROCESS and to no request (`process_span`: a garbage collection,
+    the metrics drain below, the event loop's ticker, the decode loop
+    finding nothing due). Same clock, same rendering: `chrome_trace`
+    puts them on one reserved `tid` as events of category `process`,
+    beside the requests of `/monitoring/traces` and in a capture's
+    `host_track.json` (observability/profiling.py).
 
 Clocks: spans record `time.perf_counter()` (CLOCK_MONOTONIC — comparable
 across threads); Chrome-trace `ts` values are microseconds relative to one
@@ -161,14 +168,20 @@ STAGES = (
     # from the previous round's fetch to this round's snapshot, the
     # host work before the first transfer (one chunked-prefill round
     # nested in it), the transfers and the ENQUEUE of the device program
-    # (not its run), the wait for its outputs, and from there to the
-    # riders' wake-up, which comes after the next round's launch.
+    # (not its run), the wake-up of the riders of the round before and
+    # the pool's bookkeeping while the device already runs, the wait for
+    # the program's outputs, and from there to the riders' wake-up,
+    # which comes after the next round's launch. The five that the loop
+    # thread is inside (not `deliver`, which overlaps the next round)
+    # carry `cpu_us`, the thread's CPU in them: a phase whose CPU is a
+    # tenth of its length was waiting.
     "decode/init",
     "decode/wait",
     "decode/handoff",
     "decode/prepare",
     "decode/prefill_chunk",
     "decode/tick",
+    "decode/wake",
     "decode/fetch",
     "decode/deliver",
     # A whole generation's expert-layer counts (models/mimo.py), on the
@@ -184,6 +197,8 @@ def enable(on: bool) -> None:
     """Process-wide switch. Disabled: request_trace/span become no-ops
     (used by the overhead smoke test and as the operator kill switch)."""
     global _enabled
+    # servelint: thread-ok one atomic store of a bool that every reader
+    # takes as it finds it: a span on either side of the flip is fine
     _enabled = bool(on)
 
 
@@ -200,10 +215,13 @@ def profiler_annotations():
     takes one capture at a time, so blocks do not nest); a span that is
     open when it ends closes the annotation it opened."""
     global _bridge
+    # servelint: thread-ok one atomic store; a span that reads it a
+    # moment late or early has an annotation or has none, both fine
     _bridge = True
     try:
         yield
     finally:
+        # servelint: thread-ok as above
         _bridge = False
 
 
@@ -229,6 +247,71 @@ def _annotation(name: str):
         except Exception:  # pragma: no cover - profiler lib unavailable
             _ann_cls = False
     return _ann_cls(name) if _ann_cls else None
+
+
+# ---------------------------------------------------------------------------
+# Sink 4: the host track. What the process does on nobody's behalf, on
+# the spans' clock, in a ring of its own: a stall that idles the chip
+# has to be visible where no request was open, or where the only open
+# requests are waiting for it to end.
+
+PROCESS_RING = 4096
+# Its names, each written where the work happens: a collection's pause
+# of 1 ms or more (observability/runtime.py `watch_gc`), one wake-up of
+# the metrics drain that found work (`flush_metrics`), one tick of the
+# gRPC event loop's 100 ms ticker with its overshoot and the loop
+# thread's CPU (utils/aio_loop.py), and the decode loop's time with
+# nothing due, from one loop thread's end to the next one's first
+# snapshot (servables/decode_sessions.py `TickBatcher`).
+PROCESS_SPANS = ("host/gc", "observe/drain", "loop/sample", "decode/idle")
+# `_ids` counts requests from 1: no request's `tid` is 0.
+PROCESS_TID = 0
+# No lock, on purpose: a collector's callback writes here from whatever
+# thread allocated last, also one that holds a lock of this module. A
+# bounded deque's append, its `list()` copy and its clear are each one
+# call under the interpreter lock.
+_process: collections.deque = collections.deque(maxlen=PROCESS_RING)
+
+
+def process_span(name: str, t0: float, t1: float, **args) -> None:
+    """Record a span of the process itself, its ends stamped by hand
+    with `time.perf_counter()`. Off with the kill switch, like the rest
+    of the spine."""
+    if _enabled:
+        # servelint: thread-ok one atomic append (see `_process`)
+        _process.append((name, t0, t1, args or None))
+
+
+def process_snapshot(since: float | None = None,
+                     until: float | None = None) -> list[tuple]:
+    """The host track's spans `(name, t0, t1, args|None)`, oldest first;
+    with bounds (perf_counter seconds), those that overlap them."""
+    return [s for s in list(_process)
+            if (since is None or s[2] >= since)
+            and (until is None or s[1] <= until)]
+
+
+def process_clear() -> None:
+    # servelint: thread-ok one atomic call (see `_process`)
+    _process.clear()
+
+
+def open_annotation(name: str):
+    """The profiler's side of a process span whose work runs on THIS
+    thread: inside a capture, an entered TraceAnnotation that the caller
+    closes with `close_annotation` where the work ends; None outside one.
+    (A span no thread is inside, the event loop's sample or the decode
+    loop's idle time, has no annotation, like the request spans that are
+    stamped by hand.)"""
+    ann = _annotation(name) if _bridge else None
+    if ann is not None:
+        ann.__enter__()
+    return ann
+
+
+def close_annotation(ann) -> None:
+    if ann is not None:
+        ann.__exit__(None, None, None)
 
 
 class RequestTrace:
@@ -659,14 +742,23 @@ def flush_metrics() -> None:
     scrape right after a request still sees that request's samples.
     The registry export runs OUTSIDE the lock — holding _pending_lock
     across _export_metrics would stall every finishing request behind a
-    scrape."""
+    scrape. A call that found work leaves one `observe/drain` on the host
+    track (`traces`, and `cpu_us`, this thread's CPU inside it): the
+    burst runs under the interpreter lock beside the request path."""
+    ann = open_annotation("observe/drain")
+    t0, cpu0, found = time.perf_counter(), time.thread_time(), 0
     while True:
         with _pending_lock:
             try:
                 trace = _pending.popleft()
             except IndexError:
-                return
-        _export_metrics(trace)
+                break
+        _export_metrics(trace)  # swallows what the planes raise
+        found += 1
+    close_annotation(ann)
+    if found:
+        process_span("observe/drain", t0, time.perf_counter(), traces=found,
+                     cpu_us=int((time.thread_time() - cpu0) * 1e6))
 
 
 def _export_metrics(trace: RequestTrace) -> None:
@@ -795,11 +887,15 @@ def _us(t: float) -> float:
 
 def chrome_trace(traces=None, limit: int | None = None, *, pid: int = 1,
                  process_name: str | None = None,
-                 clock: str = "process") -> dict:
+                 clock: str = "process", process_spans=()) -> dict:
     """Recent traces as a Chrome-trace (chrome://tracing / Perfetto
     "trace event") JSON object: one pid for the server, one tid per
     request, complete ("X") events for the request envelope and every
     stage span, plus thread_name metadata so the timeline is labelled.
+    `process_spans` (what `process_snapshot` gives) go onto the reserved
+    `tid` PROCESS_TID as events of category "process", on the process's
+    own clock only: a reader that groups events by `tid` finds no
+    request there.
 
     `pid`/`process_name` label the process lane (the fleet stitcher
     renders router and each backend as separate lanes); clock="wall"
@@ -839,6 +935,15 @@ def chrome_trace(traces=None, limit: int | None = None, *, pid: int = 1,
                 "dur": round(max(0.0, t1 - t0) * 1e6, 3),
                 "args": dict(sargs or {}),
             })
+    if process_spans and clock != "wall":
+        events.append({"name": "thread_name", "ph": "M", "pid": pid,
+                       "tid": PROCESS_TID, "args": {"name": "host track"}})
+        events.extend({
+            "name": name, "cat": "process", "ph": "X", "pid": pid,
+            "tid": PROCESS_TID, "ts": _us(t0),
+            "dur": round(max(0.0, t1 - t0) * 1e6, 3),
+            "args": dict(sargs or {}),
+        } for name, t0, t1, sargs in process_spans)
     return {"traceEvents": events, "displayTimeUnit": "ms",
             "otherData": {"source": "min_tfs_client_tpu /monitoring/traces"}}
 

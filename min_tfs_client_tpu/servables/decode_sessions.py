@@ -117,7 +117,9 @@ def paging_scope(block_size: int = 0, num_blocks: int = 0,
 # are pre-built tuples appended under one short lock (never while a
 # device call is in flight — tick events are pushed after the dispatch),
 # and both the per-session event count and the closed-session archive
-# are rings, so a long-lived server cannot grow without bound.
+# are rings, so a long-lived server cannot grow without bound. What a
+# round does to all its sessions (`tick`) is written ONCE a round, into
+# a ring of rounds, and joined to a session when its timeline is read.
 #
 # Cross-linking: decode-step request traces annotate `session_id`
 # (server/handlers.py), so a span timeline at /monitoring/traces and a
@@ -125,19 +127,35 @@ def paging_scope(block_size: int = 0, num_blocks: int = 0,
 
 
 class _SessionTimeline:
-    __slots__ = ("session_id", "slot", "started", "state", "events")
+    __slots__ = ("session_id", "slot", "started", "ended", "state",
+                 "events")
 
     def __init__(self, slot: int, session_id: Optional[str],
                  events_per_session: int):
         self.session_id = session_id or f"slot-{slot}"
         self.slot = slot
         self.started = time.time()
+        self.ended: Optional[float] = None  # set when it leaves its slot
         self.state = "live"
         self.events: collections.deque = collections.deque(
             maxlen=events_per_session)
 
-    def to_dict(self, max_events: Optional[int] = None) -> dict:
+    def leave(self, state: str) -> None:
+        self.state, self.ended = state, time.time()
+
+    def to_dict(self, max_events: Optional[int] = None,
+                rounds=()) -> dict:
+        """`rounds`: the pool's round events (`SessionTimelines.
+        round_event`); those of this session's slot and lifetime are
+        its own, in time order with the rest, under the same ring."""
         events = list(self.events)
+        until = self.ended if self.ended is not None else math.inf
+        mine = [(ts, kind, {**shared, **dict(zip(names, slots[self.slot]))})
+                for ts, kind, shared, names, slots in rounds
+                if self.slot in slots and self.started <= ts <= until]
+        if mine:
+            events = sorted(events + mine, key=lambda e: e[0])
+            events = events[-self.events.maxlen:]
         dropped = 0
         if max_events is not None and len(events) > max_events:
             dropped = len(events) - max_events
@@ -154,6 +172,11 @@ class _SessionTimeline:
                 for ts, kind, fields in events
             ],
         }
+
+
+# A pool's newest rounds, each one entry: at one token a session a
+# round, more than the longest session's (`events_per_session`) lives.
+ROUNDS_KEPT = 1024
 
 
 class SessionTimelines:
@@ -174,6 +197,8 @@ class SessionTimelines:
         self._live: dict[int, _SessionTimeline] = {}  # guarded_by: self._lock
         self._closed: collections.deque = collections.deque(
             maxlen=closed_capacity)                   # guarded_by: self._lock
+        self._rounds: collections.deque = collections.deque(
+            maxlen=ROUNDS_KEPT)                       # guarded_by: self._lock
         register_timelines(self)
 
     def begin(self, slot: int, session_id=None) -> None:
@@ -187,7 +212,7 @@ class SessionTimelines:
             if previous is not None:
                 # The pool reused the slot without an observed close
                 # (store-level eviction raced): archive, never splice.
-                previous.state = "superseded"
+                previous.leave("superseded")
                 self._closed.append(previous)
             self._live[slot] = timeline
 
@@ -199,14 +224,25 @@ class SessionTimelines:
                 timeline.events.append(entry)
 
     def events_many(self, entries) -> None:
-        """[(slot, kind, fields|None)] under ONE lock acquisition — the
-        tick path records one event per advanced session per round."""
+        """[(slot, kind, fields|None)] under ONE lock acquisition (a
+        chunked-prefill round's progress, per chunking session)."""
         now = time.time()
         with self._lock:
             for slot, kind, fields in entries:
                 timeline = self._live.get(slot)
                 if timeline is not None:
                     timeline.events.append((now, kind, fields))
+
+    def round_event(self, kind: str, slots: dict, names: tuple = (),
+                    **shared) -> None:
+        """One event for every session of a round, written once: `slots`
+        maps each slot of the round to its own values of the fields
+        `names`, `shared` holds the fields that are the round's. The
+        loop thread pays one append a round, whatever the round carries;
+        a reader joins it to the sessions (`_SessionTimeline.to_dict`)."""
+        entry = (time.time(), kind, shared, names, slots)
+        with self._lock:
+            self._rounds.append(entry)
 
     def close(self, slot: int, kind: str = "close") -> None:
         entry = (time.time(), kind, None)
@@ -215,18 +251,19 @@ class SessionTimelines:
             if timeline is None:
                 return
             timeline.events.append(entry)
-            timeline.state = "closed" if kind == "close" else kind
+            timeline.leave("closed" if kind == "close" else kind)
             self._closed.append(timeline)
 
     def snapshot(self, max_events: Optional[int] = None) -> dict:
         with self._lock:
             live = list(self._live.values())
             closed = list(self._closed)
+            rounds = list(self._rounds)
         return {
             "pool": self.label,
             "events_per_session": self.events_per_session,
-            "live": [t.to_dict(max_events) for t in live],
-            "closed": [t.to_dict(max_events) for t in closed],
+            "live": [t.to_dict(max_events, rounds) for t in live],
+            "closed": [t.to_dict(max_events, rounds) for t in closed],
         }
 
     def find(self, session_id: str,
@@ -236,7 +273,8 @@ class SessionTimelines:
                        if t.session_id == session_id]
             matches += [t for t in self._closed
                         if t.session_id == session_id]
-        return [dict(t.to_dict(max_events), pool=self.label)
+            rounds = list(self._rounds)
+        return [dict(t.to_dict(max_events, rounds), pool=self.label)
                 for t in matches]
 
 
@@ -256,6 +294,42 @@ def _registered_timelines() -> list[SessionTimelines]:
     with _timelines_lock:
         refs = list(_timelines)
     return [t for t in (r() for r in refs) if t is not None]
+
+
+def _stamp() -> tuple[float, float]:
+    """Now on the spans' clock and on the calling thread's CPU clock:
+    the two ends of a phase of the tick loop are read together, so that
+    the phase carries the CPU its thread spent inside it (`cpu_us`). A
+    phase whose CPU is a tenth of its length was waiting: for a lock,
+    for the interpreter, for the device."""
+    return time.perf_counter(), time.thread_time()
+
+
+def _cpu_us(since: float, now: float) -> int:
+    """Between two readings of a thread's CPU clock, in microseconds."""
+    return int((now - since) * 1e6)
+
+
+def _wake_and_fetch(of_round: "TickRound", launched: tuple, outputs):
+    """The second half of a pool's tick, after `of_round.launched()` and
+    the pool's bookkeeping: closes `decode/wake` (begun at `launched`,
+    the `_stamp` of the enqueue's end: the wake-up of the round before's
+    riders ran inside the pool's lock, then the bookkeeping), waits for
+    the program's outputs under `decode/fetch`, and tells the round when
+    that ended."""
+    from min_tfs_client_tpu.servables.servable import fetch_outputs
+
+    woke = _stamp()
+    tracing.add_span(
+        "decode/wake", launched[0], woke[0], round=of_round.ordinal,
+        woken=of_round.woken, under_pool_lock=1,
+        cpu_us=_cpu_us(launched[1], woke[1]))
+    with tracing.span("decode/fetch", round=of_round.ordinal) as fetch:
+        fetched = fetch_outputs(outputs)
+        cpu = time.thread_time()
+        fetch.args["cpu_us"] = _cpu_us(woke[1], cpu)
+    of_round.fetched, of_round.fetched_cpu = time.perf_counter(), cpu
+    return fetched
 
 
 def _note_tick_cost(label: str, busy_s: float) -> None:
@@ -670,36 +744,35 @@ class SlotPool:
         import numpy as np
 
         from min_tfs_client_tpu.robustness import faults
-        from min_tfs_client_tpu.servables.servable import fetch_outputs
 
         of_round = of_round or TickRound(0, 0.0)
-        ordinal, t_entry = of_round.ordinal, time.perf_counter()
+        ordinal, entered = of_round.ordinal, _stamp()
         # Pre-tick faultpoint: a delay stretches every tick-mate's step,
         # a typed error fails the whole tick loudly (the TickBatcher
         # gives it to every rider of the round).
         faults.point("backend.tick.pre", slots=len(slots))
         t0 = time.perf_counter()
         with self._lock:
-            lock_wait_us = int((time.perf_counter() - t0) * 1e6)
             active = np.zeros((self.max_slots,), bool)
             active[list(slots)] = True
+            prepared = _stamp()
             tracing.add_span(
-                "decode/prepare", t_entry, time.perf_counter(),
+                "decode/prepare", entered[0], prepared[0],
                 round=ordinal, slots=len(slots), live=len(slots),
-                lock_wait_us=lock_wait_us, prefills=0)
+                cpu_us=_cpu_us(entered[1], prepared[1]))
             with tracing.span("decode/tick", slots=len(slots),
-                              round=ordinal):
+                              round=ordinal) as launch:
                 self._pool, outputs = self._tick_jit(
                     self._params, self._pool,
                     self._jax.numpy.asarray(active))
+                cpu = time.thread_time()
+                launch.args["cpu_us"] = _cpu_us(prepared[1], cpu)
+            launched = (time.perf_counter(), cpu)
             of_round.launched()
-        with tracing.span("decode/fetch", round=ordinal):
-            fetched = fetch_outputs(outputs)
-        of_round.fetched = time.perf_counter()
+        fetched = _wake_and_fetch(of_round, launched, outputs)
         round_s = time.perf_counter() - t0
-        round_ms = round(round_s * 1e3, 3)
-        self.timeline.events_many(
-            [(s, "tick", {"tick_ms": round_ms}) for s in slots])
+        self.timeline.round_event("tick", dict.fromkeys(slots, ()),
+                                  tick_ms=round(round_s * 1e3, 3))
         _note_tick_cost(self.metric_label, round_s)
         return {s: {k: np.asarray(v)[s] for k, v in fetched.items()}
                 for s in slots}
@@ -1141,7 +1214,6 @@ class PagedSlotPool:
             "num_blocks": self.num_blocks,
             "blocks_used": self.allocator.used(),
             "max_slots": self.max_slots,
-            "pages_per_session": self.pages_per_session,
             "sessions": len(self._pages) + len(self._pending)
             + len(self._swapped),
             "swapped_sessions": len(self._swapped),
@@ -1416,10 +1488,9 @@ class PagedSlotPool:
         import numpy as np
 
         from min_tfs_client_tpu.robustness import faults
-        from min_tfs_client_tpu.servables.servable import fetch_outputs
 
         of_round = of_round or TickRound(0, 0.0)
-        ordinal, t_entry = of_round.ordinal, time.perf_counter()
+        ordinal, entered = of_round.ordinal, _stamp()
         slots = list(slots)
         # Pre-tick faultpoint, OUTSIDE the pool lock: a delay models a
         # slow device round; a typed error fails the whole tick (the
@@ -1428,11 +1499,10 @@ class PagedSlotPool:
         results: dict[int, object] = {}
         live: list[int] = []
         outputs = None
-        tick_events: list[tuple] = []
+        ticked: dict[int, tuple] = {}  # slot -> (tokens, pages) after it
         t0 = time.perf_counter()
         with self._lock:
-            lock_wait_us = int((time.perf_counter() - t0) * 1e6)
-            prefills = self._flush_prefills_locked(
+            self._flush_prefills_locked(
                 limit=self._max_prefills, urgent=tuple(slots))
             chunk_errors: dict[int, ServingError] = {}
             if self._prefix:
@@ -1486,23 +1556,28 @@ class PagedSlotPool:
                 # live sessions own — not slots × table width.
                 gather_pages = sum(len(self._pages[s]) for s in live)
                 gather_bytes = self.page_bytes * gather_pages
+            prepared = _stamp()
             tracing.add_span(
-                "decode/prepare", t_entry, time.perf_counter(),
+                "decode/prepare", entered[0], prepared[0],
                 round=ordinal, slots=len(slots), live=len(live),
-                lock_wait_us=lock_wait_us, prefills=prefills)
+                cpu_us=_cpu_us(entered[1], prepared[1]))
             if live:
                 # Times the three sends and the ENQUEUE of the program:
                 # its run on the device ends under `decode/fetch`.
                 with tracing.span("decode/tick", slots=len(live),
                                   round=ordinal, width=width,
-                                  pages=gather_pages):
+                                  pages=gather_pages) as launch:
                     dense, arenas, outputs = self._tick_jit(
                         self._params, self._dense_pool, self._arenas,
                         self._jnp.asarray(tables),
                         self._jnp.asarray(active),
                         self._jnp.asarray(lengths))
+                    cpu = time.thread_time()
+                    launch.args["cpu_us"] = _cpu_us(prepared[1], cpu)
+                launched = (time.perf_counter(), cpu)
                 # The program is enqueued: the riders of the round before
-                # may go (the bookkeeping below is the pool's own).
+                # may go (`decode/wake`: their wake-up, here under the
+                # pool's lock, and the bookkeeping below, the pool's own).
                 of_round.launched()
                 self._dense_pool = tuple(dense)
                 self._arenas = tuple(arenas)
@@ -1510,28 +1585,23 @@ class PagedSlotPool:
                 for s in live:
                     self._tokens[s] += 1
                     self._last_tick[s] = now
-                    tick_events.append(
-                        (s, "tick", {"tokens": self._tokens[s],
-                                     "pages": len(self._pages[s])}))
+                    ticked[s] = (self._tokens[s], len(self._pages[s]))
                 self._counters["decode_ticks"] += 1
                 self._gather_bytes_last = gather_bytes
                 self._report_gather_bytes(gather_bytes)
             self._publish_stats_locked()
         of_round.launched()  # a round that enqueued nothing
         if live:
-            with tracing.span("decode/fetch", round=ordinal):
-                fetched = fetch_outputs(outputs)
-            of_round.fetched = time.perf_counter()
-            round_ms = round((time.perf_counter() - t0) * 1e3, 3)
-            for _, _, fields in tick_events:
-                fields["tick_ms"] = round_ms
-            self.timeline.events_many(tick_events)
+            fetched = _wake_and_fetch(of_round, launched, outputs)
+            self.timeline.round_event(
+                "tick", ticked, ("tokens", "pages"),
+                tick_ms=round((time.perf_counter() - t0) * 1e3, 3))
             # Publish each advanced session's page count for the
-            # per-step cost tap (pages x ticks): pre-built list, one
-            # cheap lock, never while a device call is in flight.
+            # per-step cost tap (pages x ticks): one cheap lock, never
+            # while a device call is in flight.
             with self._page_ticks_lock:
-                for s, _, fields in tick_events:
-                    self._page_ticks[s] = fields["pages"]
+                self._page_ticks.update(
+                    (s, pages) for s, (_, pages) in ticked.items())
             for s in live:
                 results[s] = {k: np.asarray(v)[s] for k, v in fetched.items()}
         _note_tick_cost(self.metric_label, time.perf_counter() - t0)
@@ -1691,13 +1761,19 @@ class TickRound:
               refusal is tried again once the request is there). None,
               a direct call: every slot is asked for.
     fetched   set by the tick when its fetch ended: where
-              `decode/deliver` starts.
+              `decode/deliver` starts, and the next round's
+              `decode/handoff`. `fetched_cpu`: the loop thread's CPU
+              clock at that instant (`_stamp`), if the tick reads it.
     launched  the tick calls it once its program is enqueued (it takes
-              no lock). From then on a slot of the round may be released
-              and handed on, and the batcher wakes the riders of the
-              round before (their answers are serialised under this
-              round's program, not beside its launch). The batcher calls
-              it itself when a tick returns without having done so.
+              no lock of the batcher's; the pools call it while they
+              hold their own). From then on a slot of the round may be
+              released and handed on, and the batcher wakes the riders
+              of the round before (their answers are serialised under
+              this round's program, not beside its launch). The batcher
+              calls it itself when a tick returns without having done so.
+    woken     how many riders of the round before that call woke: an
+              argument of the tick's `decode/wake`, which runs from the
+              end of `decode/tick` to the start of `decode/fetch`.
 
     The round is also where the loop thread's spans go (a loop thread
     has no request trace): it is the trace-like target active around
@@ -1707,8 +1783,9 @@ class TickRound:
     that: each span is recorded once, as a leader's were).
     """
 
-    __slots__ = ("ordinal", "taken", "asked", "fetched", "spans",
-                 "_on_launched", "_is_launched", "_handed")
+    __slots__ = ("ordinal", "taken", "asked", "fetched", "fetched_cpu",
+                 "woken", "spans", "_on_launched", "_is_launched",
+                 "_handed")
 
     def __init__(self, ordinal: int, taken: float,
                  asked: Optional[frozenset] = None):
@@ -1716,6 +1793,8 @@ class TickRound:
         self.taken = taken
         self.asked = asked
         self.fetched: Optional[float] = None
+        self.fetched_cpu: Optional[float] = None
+        self.woken = 0
         self.spans: list[tuple] = []  # (name, t0, t1, args|None)
         # The batcher's: what `launched` runs, the event it sets (a
         # release of one of the round's slots waits for it), and the
@@ -1805,10 +1884,14 @@ class TickBatcher:
     `ahead=1` when the token was parked or its round snapshotted at
     entry). The loop records each round's `decode/handoff` (the previous
     round's fetch, or the round's first due slot if later, to the
-    snapshot), the pool's prepare, tick and fetch, and `decode/deliver`
-    (end of the fetch to the riders' wake-up, which now comes after the
-    next round's launch) on the round; the first request that collects
-    a token of the round takes them onto its trace.
+    snapshot), the pool's prepare, tick, wake and fetch, and
+    `decode/deliver` (end of the fetch to the riders' wake-up, which now
+    comes after the next round's launch) on the round; the first request
+    that collects a token of the round takes them onto its trace. The
+    time in which nothing is due and no loop thread exists belongs to no
+    round and no request: `decode/idle` on the tracing spine's host
+    track, from the snapshot that found nothing to the next loop's
+    first, so that the loop's spans tile its time.
     """
 
     def __init__(self, tick_fn, *, cost_fn=None):
@@ -1821,6 +1904,9 @@ class TickBatcher:
         self._slots: dict[int, _SlotEntry] = {}  # guarded_by: self._lock
         self._running = False     # guarded_by: self._lock
         self._rounds = 0          # guarded_by: self._lock
+        # When the last loop thread found nothing due and ended (None:
+        # a loop runs, or none has run yet).
+        self._idle_since: Optional[float] = None  # guarded_by: self._lock
         # decode_steps_ahead: steps whose token was parked or under way
         # when they arrived; decode_tokens_dropped: tokens computed and
         # never collected (their session closed first).
@@ -2047,37 +2133,54 @@ class TickBatcher:
     def _run(self) -> None:
         back = None     # (round, batch, results, err): back, not settled
         flying = None   # (round, batch): in the tick
-        settled = 0.0   # when the round before's fetch ended
+        # When the round before's fetch ended, on the spans' clock and
+        # on this thread's CPU clock (a new thread's starts here).
+        settled = (0.0, time.thread_time())
         ended = False   # left by the front door: `_running` is cleared
         try:
             while True:
                 with self._lock:
-                    now = time.perf_counter()
+                    now, cpu = _stamp()
                     if back is not None:
                         self._settle_locked(*back, now)
                     before = back[0] if back is not None else None
                     taken = self._snapshot_locked(now)
-                    if taken is None:
+                    idle_since = None
+                    if taken is not None:
+                        idle_since, self._idle_since = self._idle_since, None
+                    else:
                         self._running, ended = False, True
+                        if self._idle_since is None:
+                            self._idle_since = now
                 back = None
                 if taken is None:
                     self._wake(before, None)
                     return
                 took, batch, first_due = taken
+                if idle_since is not None:
+                    tracing.process_span("decode/idle", idle_since,
+                                         took.taken, restarted=1)
                 took._on_launched = functools.partial(
                     self._wake, before, took)
                 flying = (took, batch)
-                # Time in which nothing was due is no hand-off.
-                took.add_span("decode/handoff", max(settled, first_due),
-                              took.taken, {"round": took.ordinal,
-                                           "riders": len(batch)})
+                # Time in which nothing was due is no hand-off (and the
+                # thread's CPU since the fetch is the span's only as far
+                # as the span is long).
+                began = max(settled[0], first_due)
+                took.add_span(
+                    "decode/handoff", began, took.taken,
+                    {"round": took.ordinal, "riders": len(batch),
+                     "cpu_us": int(min(cpu - settled[1],
+                                       took.taken - began) * 1e6)})
                 results, err = {}, None
                 try:
                     with tracing.activate(took):
                         results = self._tick_fn(sorted(batch), took)
                 except Exception as exc:  # noqa: BLE001 - parked for the riders
                     err = exc
-                settled = took.fetched or time.perf_counter()
+                settled = _stamp()
+                if took.fetched is not None:
+                    settled = (took.fetched, took.fetched_cpu or settled[1])
                 took.launched()
                 back, flying = (took, batch, results, err), None
         finally:
@@ -2105,7 +2208,11 @@ class TickBatcher:
         """Round `took` is launched (None: there is no next round): wake
         the riders of the round `before` it, whose `decode/deliver` ends
         here, and whoever waits to release a slot of `took`. On the loop
-        thread, under no lock."""
+        thread, under no lock of the batcher's; but a pool's tick calls
+        `of_round.launched()` at its enqueue, INSIDE the pool's lock
+        (`PagedSlotPool.tick`, `SlotPool.tick`), so the wake-up of
+        `before`'s riders runs while that lock is held: the tick's
+        `decode/wake` times it and says `under_pool_lock=1`."""
         now = time.perf_counter()
         if took is not None:
             took._is_launched.set()
@@ -2113,6 +2220,8 @@ class TickBatcher:
             before.add_span("decode/deliver", before.fetched or now, now,
                             {"round": before.ordinal})
             handed, before._handed = before._handed, []
+            if took is not None:
+                took.woken = len(handed)
             _set_ready(handed)
 
 

@@ -287,7 +287,9 @@ def _json_reply(code: int, payload: dict) -> tuple[int, str, bytes]:
 def _traces_reply(query: str) -> tuple[int, str, bytes]:
     """GET /monitoring/traces[?limit=N][&summary=1][&trace_id=ID] — the
     in-memory trace ring as Chrome-trace JSON (or the aggregated
-    per-stage table). `trace_id` filters to one fleet-scope trace and
+    per-stage table), with the host track beside the requests (the
+    process's own spans, from the oldest request shown on). `trace_id`
+    filters to one fleet-scope trace and
     renders on the WALL clock (comparable across processes) — the form
     the router's stitcher fetches (docs/OBSERVABILITY.md "Fleet
     tracing")."""
@@ -314,7 +316,9 @@ def _traces_reply(query: str) -> tuple[int, str, bytes]:
         payload: dict = {"traces": len(traces),
                          "stages": tracing.stage_breakdown(traces)}
     else:
-        payload = tracing.chrome_trace(traces)
+        payload = tracing.chrome_trace(
+            traces, process_spans=tracing.process_snapshot(
+                since=min((tr.start for tr in traces), default=None)))
     return _json_reply(200, payload)
 
 

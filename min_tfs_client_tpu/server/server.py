@@ -349,6 +349,9 @@ class Server:
             })
         flight_recorder.configure(opts.flight_recorder_dir or None)
         flight_recorder.install_signal_handler()
+        from min_tfs_client_tpu.observability import runtime
+
+        runtime.watch_gc()
         # Watchdog detectors configure before the core builds (so the
         # compile-storm baseline starts at the warmup total, below) but
         # the ticker starts only after the initial loads finish.

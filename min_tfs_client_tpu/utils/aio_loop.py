@@ -21,7 +21,12 @@ every request on the loop pays too. It feeds the
 `grpc_event_loop_lag_ms` gauge, the `grpc` block of
 `/monitoring/runtime` (`stats()`; with the front end's own counts of
 requests answered on the loop and on the pool) and, over
-`LAG_WARN_MS`, a flight-recorder event.
+`LAG_WARN_MS`, a flight-recorder event. The same tick reads the loop
+THREAD's CPU clock: the one thread that serves every request is a
+station, and its CPU a request is what the station costs. Each tick is
+a `loop/sample` on the tracing spine's host track (`lag_us`, `cpu_us`),
+and `event_loop_cpu_share` in `stats()` is the thread's CPU over the
+window's wall time.
 """
 
 from __future__ import annotations
@@ -55,7 +60,9 @@ _ident = None
 _stats_lock = threading.Lock()
 _requests = {"inline": 0, "pooled": 0}           # guarded_by: _stats_lock
 _lag = {"last": 0.0, "max": 0.0, "samples": 0, "over": 0,
-        "recent": collections.deque(maxlen=LAG_WINDOW)}  # guarded_by: _stats_lock
+        "recent": collections.deque(maxlen=LAG_WINDOW),
+        # (wall s, the loop thread's CPU s) of the same samples
+        "cpu": collections.deque(maxlen=LAG_WINDOW)}  # guarded_by: _stats_lock
 
 
 def get() -> asyncio.AbstractEventLoop:
@@ -96,19 +103,30 @@ def _run(loop: asyncio.AbstractEventLoop, ready: threading.Event) -> None:
 
 
 async def _lag_ticker() -> None:
+    from min_tfs_client_tpu.observability import tracing
     from min_tfs_client_tpu.server import metrics
 
+    t1, cpu1 = time.perf_counter(), time.thread_time()
     while True:
+        # A sample runs from the end of the one before, so the samples
+        # tile the loop thread's time and their CPU adds up to its CPU;
+        # the overshoot is the sleep's own.
+        began, cpu0 = t1, cpu1
         t0 = time.perf_counter()
         await asyncio.sleep(LAG_TICK_S)
-        lag_ms = max(0.0, (time.perf_counter() - t0 - LAG_TICK_S) * 1e3)
+        t1, cpu1 = time.perf_counter(), time.thread_time()
+        lag_ms = max(0.0, (t1 - t0 - LAG_TICK_S) * 1e3)
         over = lag_ms >= LAG_WARN_MS
+        tracing.process_span("loop/sample", began, t1,
+                             lag_us=int(lag_ms * 1e3),
+                             cpu_us=int((cpu1 - cpu0) * 1e6))
         with _stats_lock:
             _lag["last"] = lag_ms
             _lag["max"] = max(_lag["max"], lag_ms)
             _lag["samples"] += 1
             _lag["over"] += over
             _lag["recent"].append(lag_ms)
+            _lag["cpu"].append((t1 - began, cpu1 - cpu0))
         metrics.safe_set(metrics.grpc_event_loop_lag_ms, lag_ms)
         if over:
             # A stalled loop delays every request of the process: put it
@@ -145,5 +163,8 @@ def stats() -> dict:
                                int(0.99 * len(recent)))], 3),
                 event_loop_lag_max_ms=round(_lag["max"], 3),
                 lag_samples=_lag["samples"],
-                lag_over_threshold=_lag["over"])
+                lag_over_threshold=_lag["over"],
+                event_loop_cpu_share=round(
+                    sum(cpu for _, cpu in _lag["cpu"])
+                    / sum(wall for wall, _ in _lag["cpu"]), 4))
     return out

@@ -220,7 +220,7 @@ class TestDensePoolPhases:
     def test_dense_pool_records_the_paged_pool_s_phase_names(self, pooled):
         """One session: each step's trace has its wait and, copied from
         the loop's round that computed its token, the hand-off and the
-        four phases in order, all of one round; consecutive steps are
+        five phases in order, all of one round; consecutive steps are
         consecutive rounds; `decode/init` sits on the opening request."""
         from min_tfs_client_tpu.observability import tracing
 
@@ -240,7 +240,7 @@ class TestDensePoolPhases:
             mine = [s for s in trace.spans if s[0].startswith("decode/")]
             spans = {s[0]: s for s in mine}
             phases = ["decode/handoff", "decode/prepare", "decode/tick",
-                      "decode/fetch", "decode/deliver"]
+                      "decode/wake", "decode/fetch", "decode/deliver"]
             assert sorted(s[0] for s in mine) \
                 == sorted(["decode/wait"] + phases)
             assert len({s[3]["round"] for s in mine}) == 1
@@ -249,6 +249,13 @@ class TestDensePoolPhases:
             assert tick["slots"] == 1
             for a, b in zip(phases, phases[1:]):
                 assert spans[a][2] <= spans[b][1], (a, b)
+            # What the loop thread is inside says how much of it was
+            # the thread's own CPU; `deliver` overlaps the next round.
+            for name in phases[:-1]:
+                length_us = (spans[name][2] - spans[name][1]) * 1e6
+                assert 0 <= spans[name][3]["cpu_us"] <= length_us + 1000, name
+            assert "cpu_us" not in spans["decode/deliver"][3]
+            assert spans["decode/wake"][3]["under_pool_lock"] == 1
             wait = spans["decode/wait"]
             assert wait[3]["ahead"] in (0, 1)
             assert trace.start <= wait[1] <= wait[2]

@@ -1097,8 +1097,8 @@ class TestDecodeLoopPhases:
     (docs/OBSERVABILITY.md "Decode loop phases"): the round leader's
     consecutive phases and every rider's wait, tied by `round`."""
 
-    PHASES = ("decode/prepare", "decode/tick", "decode/fetch",
-              "decode/deliver")
+    PHASES = ("decode/prepare", "decode/tick", "decode/wake",
+              "decode/fetch", "decode/deliver")
 
     def _step_sessions(self, sigs, config, n, steps, seed=31):
         """n sessions stepping side by side, each step inside its own
@@ -1177,7 +1177,14 @@ class TestDecodeLoopPhases:
             prepare, tick = by_name["decode/prepare"], by_name["decode/tick"]
             assert tick[2]["slots"] == prepare[2]["live"] \
                 == by_name["decode/handoff"][2]["riders"]
-            assert prepare[2]["lock_wait_us"] >= 0
+            # The loop thread's CPU inside each phase it is inside: no
+            # more than the phase is long (a clock tick of room).
+            for name in ("decode/handoff",) + self.PHASES[:-1]:
+                t0, t1, args = by_name[name]
+                assert 0 <= args["cpu_us"] <= (t1 - t0) * 1e6 + 1000, name
+            wake = by_name["decode/wake"][2]
+            assert wake["under_pool_lock"] == 1
+            assert 0 <= wake["woken"] <= n
             assert tick[2]["width"] in (1, 2, 4)
             # The pages the riders hold: at least one each, never more
             # than their table rows have entries.
@@ -1339,7 +1346,11 @@ class TestDecodeLoopPhases:
         assert len(deliver) == 1
         assert handoff[1] == deliver[0][1] >= released
         assert handoff[2] == wait[2] <= deliver[0][2]
+        cpu_us = handoff[3].pop("cpu_us")
         assert handoff[3] == {"round": 2, "riders": 1}
+        # The loop thread's CPU since round 1's fetch, and no more of it
+        # than the span is long.
+        assert 0 <= cpu_us <= (handoff[2] - handoff[1]) * 1e6
         batcher.release(1)
         batcher.release(2)
 

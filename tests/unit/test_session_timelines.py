@@ -69,6 +69,70 @@ class TestTimelineRings:
         assert tl.snapshot()["closed"] == []
 
 
+class TestRoundEvents:
+    """What a round does to all its sessions is written once a round
+    (`round_event`) and joined to a session when its timeline is read."""
+
+    @staticmethod
+    def _kinds(row):
+        return [(e["kind"], e.get("tokens")) for e in row["events"]]
+
+    def test_a_round_is_one_entry_whatever_it_carries(self):
+        tl = SessionTimelines(label="t")
+        for slot in range(3):
+            tl.begin(slot, f"s{slot}".encode())
+        tl.round_event("tick", {0: (1, 1), 2: (5, 2)}, ("tokens", "pages"),
+                       tick_ms=31.5)
+        assert len(tl._rounds) == 1
+        rode = tl.find("s2")[0]["events"][-1]
+        assert {k: rode[k] for k in ("kind", "tokens", "pages", "tick_ms")} \
+            == {"kind": "tick", "tokens": 5, "pages": 2, "tick_ms": 31.5}
+        assert self._kinds(tl.find("s1")[0]) == [("init", None)]
+        # The dense pool's round has no per-slot field.
+        tl.round_event("tick", dict.fromkeys([1], ()), tick_ms=2.0)
+        assert tl.find("s1")[0]["events"][-1]["tick_ms"] == 2.0
+
+    def test_a_session_s_own_events_and_its_rounds_are_in_time_order(self):
+        tl = SessionTimelines(label="t")
+        tl.begin(0, b"s0")
+        tl.round_event("tick", {0: (1, 1)}, ("tokens", "pages"))
+        tl.event(0, "swap_out", pages=1)
+        tl.round_event("tick", {0: (2, 1)}, ("tokens", "pages"))
+        tl.close(0)
+        (row,) = tl.snapshot()["closed"]
+        assert self._kinds(row) == [("init", None), ("tick", 1),
+                                    ("swap_out", None), ("tick", 2),
+                                    ("close", None)]
+
+    @pytest.mark.parametrize("how", ["close", "supersede"])
+    def test_a_slot_s_next_session_takes_no_round_of_the_one_before(
+            self, how):
+        tl = SessionTimelines(label="t")
+        tl.begin(4, b"first")
+        tl.round_event("tick", {4: (1, 1)}, ("tokens", "pages"))
+        if how == "close":
+            tl.close(4)
+        tl.begin(4, b"second")
+        tl.round_event("tick", {4: (7, 3)}, ("tokens", "pages"))
+        assert self._kinds(tl.find("first")[0])[:2] \
+            == [("init", None), ("tick", 1)]
+        assert ("tick", 7) not in self._kinds(tl.find("first")[0])
+        assert self._kinds(tl.find("second")[0]) \
+            == [("init", None), ("tick", 7)]
+
+    def test_both_rings_bound_what_a_view_shows(self):
+        tl = SessionTimelines(label="t", events_per_session=16)
+        tl.begin(0, b"s0")
+        for k in range(decode_sessions.ROUNDS_KEPT + 40):
+            tl.round_event("tick", {0: (k, 1)}, ("tokens", "pages"))
+        assert len(tl._rounds) == decode_sessions.ROUNDS_KEPT == 1024
+        events = tl.find("s0")[0]["events"]
+        assert len(events) == 16  # the session's ring, newest kept
+        assert events[-1]["tokens"] == decode_sessions.ROUNDS_KEPT + 39
+        row = tl.snapshot(max_events=4)["live"][0]
+        assert len(row["events"]) == 4 and row["events_dropped"] == 12
+
+
 class TestSessionsPayload:
     def test_payload_lists_registered_pools_weakly(self):
         tl = SessionTimelines(label="payload-pool")
