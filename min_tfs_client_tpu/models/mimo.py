@@ -35,9 +35,9 @@ from min_tfs_client_tpu.parallel.moe import HeldExperts, held_experts_ffn
 
 ROUTE_COUNTS = ("prompt_tokens", "pairs_prefill", "held_prefill",
                 "pairs_decode", "held_decode", "max_load", "load_total",
-                "prefill_rows")
+                "prefill_rows", "hit_decode")
 # ... of which these are the whole batch's, the same on every row
-BATCH_COUNTS = ("max_load", "load_total", "prefill_rows")
+BATCH_COUNTS = ("max_load", "load_total", "prefill_rows", "hit_decode")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -194,7 +194,7 @@ def _qkv(config: MimoConfig, layer: int, attn: dict, x: jax.Array,
 
 def _ffn(config: MimoConfig, layer: dict, x: jax.Array, **routing):
     """x (T, D) float32 (normed) -> (y (T, D) float32, Routed or None);
-    `routing` is the expert layer's own (`rows`, `onto`)."""
+    `routing` is the expert layer's own (`valid`, `rows`, `onto`)."""
     if "mlp" in layer:
         wi, wo = layer["mlp"]["wi"]["kernel"], layer["mlp"]["wo"]["kernel"]
         f = wo.shape[0]
@@ -374,7 +374,8 @@ def prefill(params: dict, config: MimoConfig, input_ids: jax.Array, *,
                    "steps": jnp.zeros((b,), jnp.int32),
                    "max_load": jnp.max(load, initial=0),
                    "load_total": jnp.sum(load),
-                   "prefill_rows": jnp.sum(ran)},
+                   "prefill_rows": jnp.sum(ran),
+                   "hit_decode": jnp.zeros((), jnp.int32)},
     }
 
 
@@ -409,7 +410,9 @@ def step(params: dict, config: MimoConfig, state: dict):
     """(state) -> (state', token (B,)): choose each example's next token
     from the state's logits (greedy; a finished example gives pad_id),
     feed it through the stack at the example's own position, through
-    both kinds of cache, and leave the logits of the token after it."""
+    both kinds of cache, and leave the logits of the token after it. A
+    prompt of length 0 (a row that pads the batch) is routed to no
+    expert."""
     token = jnp.argmax(state["logits"], axis=-1).astype(jnp.int32)
     token = jnp.where(state["finished"], config.pad_id, token)
     finished = jnp.logical_or(state["finished"], token == config.eos_id)
@@ -418,6 +421,8 @@ def step(params: dict, config: MimoConfig, state: dict):
     each = jnp.arange(b)
     h = params["embed"]["embedding"][token].astype(jnp.float32)
     caches, held = [], jnp.zeros((b,), jnp.int32)
+    hit = jnp.zeros((), jnp.int32)
+    owned = state["counts"]["prompt_tokens"] > 0
     for index, (layer, cache) in enumerate(zip(params["layers"],
                                                state["caches"])):
         attn = layer["attn"]
@@ -438,12 +443,14 @@ def step(params: dict, config: MimoConfig, state: dict):
         caches.append(cache)
         h = h + _mm(_attend_cache(q, cache, seen, attn.get("sink")),
                     attn["out"]["kernel"])
-        y, routed = _ffn(config, layer, _norm(layer["ffn_norm"], h, config))
+        y, routed = _ffn(config, layer, _norm(layer["ffn_norm"], h, config),
+                         valid=owned)
         h = h + y
         if routed is not None:
-            held = held + routed.held
+            held, hit = held + routed.held, hit + routed.hit
     counts = dict(state["counts"])
     counts["held_decode"] = counts["held_decode"] + held
+    counts["hit_decode"] = counts["hit_decode"] + hit
     counts["steps"] = counts["steps"] + 1
     return {"caches": caches, "length": position + 1,
             "logits": _logits(params, config, h), "token": token[:, None],
@@ -452,8 +459,9 @@ def step(params: dict, config: MimoConfig, state: dict):
 
 def route_counts(config: MimoConfig, state: dict) -> jax.Array:
     """(B, len(ROUTE_COUNTS)) int32, one row an example: what
-    `generate/route` carries (the batch's two load figures on every
-    row)."""
+    `generate/route` carries (the batch's figures on every row;
+    `hit_decode`: the (step, expert layer, hit expert) products the
+    decode steps ran, 0 where the pairs were sorted)."""
     counts = state["counts"]
     per_token = config.top_k * sum(config.moe_pattern)
     b = counts["steps"].shape[0]
@@ -490,12 +498,12 @@ def note_route(signature, outputs) -> None:
     now = time.perf_counter()
     tracing.add_span("generate/route", now, now, **args)
     counted = {k: v for k, v in args.items() if k not in BATCH_COUNTS}
-    # The batch's rows, a request's share of them: by its share of the
-    # batch's held pairs, the one count whose batch total a row carries,
-    # so that the requests of a batch add up to the batch's figure.
-    counted["prefill_rows"] = round(
-        args["prefill_rows"] * args["held_prefill"]
-        / max(args["load_total"], 1))
+    # The batch's rows and trips, a request's share of them: by its share
+    # of the batch's held pairs, the one count whose batch total a row
+    # carries, so that the requests of a batch add up to the batch's figure.
+    share = args["held_prefill"] / max(args["load_total"], 1)
+    for name in ("prefill_rows", "hit_decode"):
+        counted[name] = round(args[name] * share)
     runtime.count_route(signature.telemetry_label or "unlabeled", counted)
 
 

@@ -170,12 +170,24 @@ class HeldExperts(NamedTuple):
 
 class Routed(NamedTuple):
     """What the layer counted: `held` (T,) pairs of each token that fell
-    on held experts, `load` (held,) rows each held expert computed."""
+    on held experts, `load` (held,) rows each held expert computed, `hit`
+    () the products the walk over hit experts ran (0 where the pairs
+    were sorted)."""
     held: jax.Array
     load: jax.Array
+    hit: jax.Array
 
 
 ROW_BLOCK = 2048   # rows a grouped product takes at a time
+# Rows up to which the layer walks the experts that were hit, all rows
+# through each: a product of T rows with one expert's matrices does T
+# FLOPs a byte of weight, so below the chip's ridge (240 on the v5e) the
+# rows ride the weights' stream for nothing, and the sort, gathers and
+# scatter-add that keep a row off the experts it did not choose cost more
+# than they save. On the v5e the walk takes 0.65 / 1.03 / 1.23 / 1.54 ms
+# at 32 / 64 / 128 / 256 rows where the sorted pairs take 0.91 / 2.25 /
+# 2.65 / 3.06 (PERF.md section 5); above 256 nothing was read.
+DECODE_ROWS = 256
 
 
 def sigmoid_top_k(x: jax.Array, router: jax.Array, bias: jax.Array,
@@ -211,16 +223,17 @@ def held_experts_ffn(params: HeldExperts, x: jax.Array, *, top_k: int,
     (T, D) float32 is what the experts' rows are added onto in place of
     zeros (a caller's residual stream: y = onto + the layer's result).
 
-    The pairs are sorted by held expert (pairs on absent experts last),
-    and the held ones pass the grouped gate/up/down products
-    (`jax.lax.ragged_dot`, rows in the weights' dtype, float32
-    accumulation) in blocks of `row_block` rows: as many blocks as the
-    held pairs fill. The router reads x as it is given (float32 from the
-    models)."""
+    One algorithm in two forms, chosen by the static row count: up to
+    DECODE_ROWS rows (and no `rows=`) the layer walks the experts that
+    were hit (`_by_hit_expert`); above, the (token, choice) pairs are
+    sorted by held expert (`_by_sorted_pair`). Per (token, expert) every
+    product, rounding and accumulation is the same in both: rows in the
+    weights' dtype, float32 accumulation, SwiGLU and the combine in
+    float32; only the order in which a token's terms are added differs.
+    The router reads x as it is given (float32 from the models)."""
     t, _ = x.shape
     if params.w_in.shape[0] != experts_held:
         raise ValueError("w_in holds another number of experts than held")
-    d_ff = params.w_out.shape[1]
     if rows is None:
         experts, weights = sigmoid_top_k(x, params.router, params.bias,
                                          top_k)
@@ -245,6 +258,27 @@ def held_experts_ffn(params: HeldExperts, x: jax.Array, *, top_k: int,
     held = jnp.logical_and(local >= 0, local < experts_held)
     if valid is not None:
         held = jnp.logical_and(held, valid[:, None])
+    y = jnp.zeros(x.shape, jnp.float32) if onto is None else onto
+    if rows is None and t <= DECODE_ROWS:
+        y, load, hit = _by_hit_expert(params, x, local, weights, held, y)
+    else:
+        y, load, hit = _by_sorted_pair(params, x, local, weights, held, y,
+                                       row_block)
+    return y, Routed(held=jnp.sum(held, axis=-1, dtype=jnp.int32), load=load,
+                     hit=hit)
+
+
+def _by_sorted_pair(params: HeldExperts, x: jax.Array, local: jax.Array,
+                    weights: jax.Array, held: jax.Array, y: jax.Array,
+                    row_block: int) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """The pairs (`local` (T, k) the chosen experts counted from the
+    first held one, `held` (T, k) which of them count) are sorted by
+    held expert (pairs on absent experts last), and the held ones pass
+    the grouped gate/up/down products (`jax.lax.ragged_dot`) in blocks
+    of `row_block` rows: as many blocks as the held pairs fill. -> (y
+    with the layer's result added, `Routed.load`, `Routed.hit`)."""
+    t, top_k = local.shape
+    experts_held, d_ff = params.w_out.shape[:2]
     key = jnp.where(held, local, experts_held).reshape(-1)      # (T k,)
     pairs = t * top_k
     block = min(row_block, -(-pairs // 8) * 8)
@@ -273,7 +307,38 @@ def held_experts_ffn(params: HeldExperts, x: jax.Array, *, top_k: int,
         out = jnp.where(live, out * flat_weights[pair][:, None], 0.0)
         return y.at[token].add(out)
 
-    y = jax.lax.fori_loop(
-        0, (total + block - 1) // block, rows_of,
-        jnp.zeros(x.shape, jnp.float32) if onto is None else onto)
-    return y, Routed(held=jnp.sum(held, axis=-1, dtype=jnp.int32), load=load)
+    y = jax.lax.fori_loop(0, (total + block - 1) // block, rows_of, y)
+    return y, load, jnp.zeros((), jnp.int32)
+
+
+def _by_hit_expert(params: HeldExperts, x: jax.Array, local: jax.Array,
+                   weights: jax.Array, held: jax.Array, y: jax.Array
+                   ) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """A few rows: ALL of them through each held expert that at least
+    one row chose, and only through those (a loop over the list of hit
+    experts, its trip count their number), each row's result entering
+    with its weight for that expert, 0 where it did not choose it. No
+    sort, no gather, no scatter: the combine weights, the load and the
+    list come from comparisons and sums. -> as `_by_sorted_pair`."""
+    experts_held, d_ff = params.w_out.shape[:2]
+    each = jnp.arange(experts_held, dtype=jnp.int32)
+    chose = jnp.logical_and(local[:, :, None] == each, held[:, :, None])
+    combine = jnp.sum(jnp.where(chose, weights[:, :, None], 0.0),
+                      axis=1).T                                 # (held, T)
+    load = jnp.sum(chose, axis=(0, 1), dtype=jnp.int32)
+    hit = load > 0
+    place = jnp.cumsum(hit) - 1        # of a hit expert in the list
+    listed = jnp.sum(jnp.where(
+        jnp.logical_and(hit, place == each[:, None]), each, 0), axis=1)
+    rows = x.astype(params.w_in.dtype)
+
+    def one(i, y):
+        e = listed[i]
+        h = jnp.dot(rows, params.w_in[e], preferred_element_type=jnp.float32)
+        h = jax.nn.silu(h[:, :d_ff]) * h[:, d_ff:]
+        out = jnp.dot(h.astype(params.w_out.dtype), params.w_out[e],
+                      preferred_element_type=jnp.float32)
+        return y + combine[e][:, None] * out
+
+    trips = jnp.sum(hit, dtype=jnp.int32)
+    return jax.lax.fori_loop(0, trips, one, y), load, trips

@@ -20,6 +20,7 @@ from min_tfs_client_tpu.ops.attention import (
     attention_reference,
     flash_attention,
 )
+from min_tfs_client_tpu.parallel import moe
 from min_tfs_client_tpu.parallel.moe import HeldExperts, held_experts_ffn
 from perfbench import children
 
@@ -314,6 +315,81 @@ def test_the_expert_layer_drops_nothing_when_every_token_picks_one():
     want = ((jax.nn.silu(hidden[:, :16]) * hidden[:, 16:])
             @ case["w_out"][2]) * weight[:, None]
     np.testing.assert_allclose(np.asarray(y), np.asarray(want), atol=1e-4)
+
+
+def _on(*held):
+    """A selection bias that puts every row's two choices on `held`."""
+    return jnp.zeros((16,), jnp.float32).at[jnp.asarray(held)].set(10.0)
+
+
+FORM_CASES = {
+    "one_row": dict(tokens=1),
+    "four_rows": dict(tokens=4),
+    "the_decode_batch": dict(tokens=32),
+    "the_most_rows_the_walk_takes": dict(tokens=moe.DECODE_ROWS),
+    "no_pair_held_at_all": dict(tokens=32, bias=_on(0, 9), trips=0),
+    "every_row_on_one_expert": dict(tokens=32, bias=_on(6, 9), trips=1),
+    "some_rows_left_out": dict(tokens=32, valid=np.arange(32) % 3 > 0),
+    "added_onto_a_residual": dict(tokens=32, onto=True),
+}
+
+
+@pytest.mark.parametrize("case", FORM_CASES.values(), ids=FORM_CASES.keys())
+def test_the_walk_over_hit_experts_agrees_with_the_sorted_pairs(
+        monkeypatch, case):
+    """`held_experts_ffn` on the same inputs in both its forms (experts
+    4..7 held of 16, top-2): the rows' results, each row's held pairs
+    and each expert's load; the walk's trips are the experts hit."""
+    layer = _expert_layer(np.random.default_rng(3), tokens=case["tokens"])
+    x = layer["x"]
+    params = HeldExperts(layer["router"], case.get("bias", layer["bias"]),
+                         layer["w_in"][4:8], layer["w_out"][4:8])
+    routing = {}
+    if "valid" in case:
+        routing["valid"] = jnp.asarray(case["valid"])
+    if "onto" in case:
+        routing["onto"] = 3.0 * x
+
+    def run():
+        return jax.jit(lambda p, x: held_experts_ffn(
+            p, x, top_k=2, experts_held=4, expert_offset=4, **routing))(
+                params, x)
+
+    walked, counted = run()
+    monkeypatch.setattr(moe, "DECODE_ROWS", 0)
+    sorted_, want = run()
+    np.testing.assert_allclose(np.asarray(walked), np.asarray(sorted_),
+                               atol=1e-5)
+    assert np.asarray(counted.held).tolist() == np.asarray(want.held).tolist()
+    assert np.asarray(counted.load).tolist() == np.asarray(want.load).tolist()
+    assert int(counted.hit) == int(np.sum(np.asarray(want.load) > 0))
+    assert int(want.hit) == 0
+    if "valid" in case:
+        assert not np.asarray(counted.held)[~case["valid"]].any()
+    if "trips" in case:
+        assert int(counted.hit) == case["trips"]
+
+
+def test_which_form_a_row_count_takes_shows_in_the_lowered_text():
+    """At decode's rows the program holds no sort, no scatter and no
+    grouped product; one row more, or the prefill's `rows=`, and the
+    sorted form is there as it was."""
+    layer = _expert_layer(np.random.default_rng(4), tokens=moe.DECODE_ROWS + 1)
+    params = HeldExperts(layer["router"], layer["bias"], layer["w_in"][:4],
+                         layer["w_out"][:4])
+
+    def found(tokens, **routing):
+        text = jax.jit(lambda p, x: held_experts_ffn(
+            p, x, top_k=2, experts_held=4, expert_offset=0,
+            **routing)).trace(params, layer["x"][:tokens]).lower(
+                lowering_platforms=("tpu",)).as_text()
+        return {name: name in text for name in (
+            "stablehlo.sort", "stablehlo.scatter", "ragged_dot")}
+
+    assert not any(found(32).values())
+    assert not any(found(moe.DECODE_ROWS).values())
+    assert all(found(moe.DECODE_ROWS + 1).values())
+    assert all(found(32, rows=jnp.int32(20)).values())
 
 
 def test_serving_default_through_a_real_server_with_batching(tiny, tmp_path):
