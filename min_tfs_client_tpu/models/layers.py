@@ -19,7 +19,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from min_tfs_client_tpu.ops.attention import attention
+from min_tfs_client_tpu.ops.attention import attention, attention_rows
 
 COMPUTE_DTYPE = jnp.bfloat16
 
@@ -180,6 +180,69 @@ def mha(
     out = attention(q, k, v, causal=causal, lengths=lengths, bias=bias,
                     scale=scale, causal_offset=causal_offset)
     return dense(params["out"], _unheads(out)), cache
+
+
+def cross_rows(blocks: list[dict], kv: jax.Array) -> dict:
+    """K and V of `kv` (B, S, d_model) under each of a stack of attention
+    blocks' projections, made once and kept as the projections leave
+    them: {"key", "value"} of (L, B, S, H * D) rows, the heads side by
+    side on the lanes and never split (split into heads, XLA lays every
+    leaf out again), the layers in ONE array each, by one product (a
+    leaf a layer is small enough for XLA to stage whole in its fast
+    memory ahead of each read, every row of it, whatever the lengths)."""
+    def stacked(name):
+        rows = jnp.einsum(
+            "bsd,ldf->lbsf", kv.astype(COMPUTE_DTYPE),
+            jnp.stack([p[name]["kernel"].astype(COMPUTE_DTYPE)
+                       for p in blocks]))
+        if "bias" in blocks[0][name]:
+            rows = rows + jnp.stack(
+                [p[name]["bias"].astype(COMPUTE_DTYPE)
+                 for p in blocks])[:, None, None, :]
+        return rows
+
+    return {"key": stacked("key"), "value": stacked("value")}
+
+
+def mha_rows(params: dict, x: jax.Array, rows: dict, layer: int, *,
+             num_heads: int, lengths: Optional[jax.Array] = None,
+             bias: Optional[jax.Array] = None,
+             cache_index: Optional[jax.Array] = None,
+             scale: Optional[float] = None) -> tuple[jax.Array, dict]:
+    """`mha` for a loop that attends the same rows at every step (a whole
+    generation's decode steps), over layer `layer` of `rows`, read by
+    ops/attention.attention_rows: each example's keys once, in their own
+    dtype, by length. Cross-attention: `rows = cross_rows(blocks, kv)`
+    and `lengths`, as `mha(kv=, lengths=)`. Self-attention over a cache:
+    `rows = init_rows_cache(...)` and `cache_index`, as `mha(cache=,
+    cache_index=, causal=True)`: x's K and V rows are written at
+    cache_index and each query row sees the rows up to its own. Returns
+    (output, rows as they now are)."""
+    q_start = None
+    if cache_index is not None:
+        rows = {name: jax.lax.dynamic_update_slice(
+                    rows[name],
+                    dense(params[name], x)[None].astype(rows[name].dtype),
+                    (layer, 0, cache_index, 0))
+                for name in ("key", "value")}
+        lengths = jnp.full((x.shape[0],), cache_index + x.shape[1], jnp.int32)
+        q_start = cache_index
+    out = attention_rows(dense(params["query"], x), rows["key"],
+                         rows["value"], lengths, num_heads=num_heads,
+                         scale=scale, layer=layer, bias=bias, q_start=q_start)
+    return dense(params["out"], out), rows
+
+
+def init_rows_cache(layers: int, batch: int, max_len: int, width: int,
+                    dtype=COMPUTE_DTYPE) -> dict:
+    """An empty self-attention cache for `mha_rows`: every layer's K and
+    V rows in one array each, (L, B, max_len, H * D). One array, because
+    a step writes one row a layer into it where it lies; a dense cache a
+    layer that a loop carries and updates, XLA keeps in its fast memory
+    and writes back WHOLE at every step (3.3 ms of a 7.4 ms step on the
+    v5e: PERF.md section 6, PR 44)."""
+    return {name: jnp.zeros((layers, batch, max_len, width), dtype)
+            for name in ("key", "value")}
 
 
 def init_cache(batch: int, num_heads: int, max_len: int, d_head: int,

@@ -14,6 +14,7 @@ pre-RMSNorm, ReLU MLP, no biases in dense layers, tied softmax scaled by
 
 from __future__ import annotations
 
+import time
 from dataclasses import dataclass
 
 import jax
@@ -255,50 +256,80 @@ def pipelined_encode(pp_params: dict, config: T5Config,
 # -- decoder -----------------------------------------------------------------
 
 
+def _project_cross(params: dict, encoded: jax.Array) -> dict:
+    """Every decoder layer's cross-attention K and V of `encoded`, for a
+    loop that decodes against one `encoded` from start to end: projected
+    here ONCE, before the loop, and handed to every `_decoder_positions`
+    of it as `cross` (nn.cross_rows: rows, read by length)."""
+    return nn.cross_rows([layer["cross_attention"]
+                          for layer in params["decoder"]["layers"]], encoded)
+
+
 def _decoder_positions(params: dict, config: T5Config, tokens: jax.Array,
-                       step: jax.Array, caches: list[dict],
-                       encoded: jax.Array, enc_lengths: jax.Array
-                       ) -> tuple[jax.Array, list[dict]]:
+                       step: jax.Array, caches: list[dict] | dict,
+                       encoded: jax.Array | None, enc_lengths: jax.Array,
+                       cross: dict | None = None,
+                       ) -> tuple[jax.Array, list[dict] | dict]:
     """Decode a block of L positions: tokens (B, L) at absolute positions
     step .. step+L (causal within the block, attending the cache behind
     it). L=1 is the classic decode step; L=k+1 is a speculative verify
-    block. Returns (logits (B, L, vocab), updated caches)."""
+    block. Cross-attention reads `cross` (`_project_cross(params,
+    encoded)`, the whole-generation loops) where it is given, and
+    otherwise projects `encoded` itself (one step of a session).
+    `caches` is a layer's dense {"self": {"k", "v"}} each (the sessions'
+    layout; beams reorder it), or ONE rows cache for all layers
+    (`nn.init_rows_cache`: a whole generation that only appends).
+    Returns (logits (B, L, vocab), updated caches)."""
     dec = params["decoder"]
     x = nn.embed(params["shared_embedding"], tokens)
-    max_len = caches[0]["self"]["k"].shape[2]
+    in_rows = isinstance(caches, dict)
+    max_len = (caches["key"] if in_rows else caches[0]["self"]["k"]).shape[2]
     bias = relative_bias(dec["rel_bias"], config, tokens.shape[1], max_len,
                          bidirectional=False, q_offset=step)
     new_caches = []
-    for layer, cache in zip(dec["layers"], caches):
+    for i, layer in enumerate(dec["layers"]):
         h = nn.rms_norm(layer["self_norm"], x)
-        attn, self_cache = nn.mha(
-            layer["self_attention"], h, num_heads=config.num_heads,
-            causal=True, bias=bias, cache=cache["self"], cache_index=step,
-            scale=1.0)
+        if in_rows:
+            attn, caches = nn.mha_rows(
+                layer["self_attention"], h, caches, i,
+                num_heads=config.num_heads, bias=bias, cache_index=step,
+                scale=1.0)
+        else:
+            attn, self_cache = nn.mha(
+                layer["self_attention"], h, num_heads=config.num_heads,
+                causal=True, bias=bias, cache=caches[i]["self"],
+                cache_index=step, scale=1.0)
+            new_caches.append({"self": self_cache})
         x = x + attn
         h = nn.rms_norm(layer["cross_norm"], x)
-        cross, _ = nn.mha(
-            layer["cross_attention"], h, num_heads=config.num_heads,
-            kv=encoded, lengths=enc_lengths, scale=1.0)
-        x = x + cross
+        if cross is None:
+            attended, _ = nn.mha(
+                layer["cross_attention"], h, num_heads=config.num_heads,
+                kv=encoded, lengths=enc_lengths, scale=1.0)
+        else:
+            attended, _ = nn.mha_rows(
+                layer["cross_attention"], h, cross, i,
+                num_heads=config.num_heads, lengths=enc_lengths, scale=1.0)
+        x = x + attended
         h = nn.rms_norm(layer["mlp_norm"], x)
         x = x + nn.mlp(layer["mlp"], h, activation=jax.nn.relu)
-        new_caches.append({"self": self_cache})
     x = nn.rms_norm(dec["final_norm"], x)
     # Tied output embedding, T5-style 1/sqrt(d) rescale.
     logits = jnp.einsum(
         "bld,vd->blv", x.astype(jnp.float32) / np.sqrt(config.d_model),
         params["shared_embedding"]["embedding"])
-    return logits, new_caches
+    return logits, caches if in_rows else new_caches
 
 
 def _decoder_step(params: dict, config: T5Config, token: jax.Array,
-                  step: jax.Array, caches: list[dict], encoded: jax.Array,
-                  enc_lengths: jax.Array) -> tuple[jax.Array, list[dict]]:
+                  step: jax.Array, caches: list[dict] | dict,
+                  encoded: jax.Array | None, enc_lengths: jax.Array,
+                  cross: dict | None = None,
+                  ) -> tuple[jax.Array, list[dict] | dict]:
     """One decode position: token (B, 1) at absolute position `step`.
     Returns (logits (B, vocab), updated caches)."""
     logits, new_caches = _decoder_positions(
-        params, config, token, step, caches, encoded, enc_lengths)
+        params, config, token, step, caches, encoded, enc_lengths, cross)
     return logits[:, 0], new_caches
 
 
@@ -457,16 +488,15 @@ def greedy_decode(params: dict, config: T5Config, input_ids: jax.Array,
     b = input_ids.shape[0]
     if encoded is None:
         encoded = encode(params, config, input_ids, lengths)
-    d_head = config.d_kv
-    caches = [{"self": nn.init_cache(b, config.num_heads, max_decode_len,
-                                     d_head)}
-              for _ in range(config.num_decoder_layers)]
+    caches = nn.init_rows_cache(config.num_decoder_layers, b, max_decode_len,
+                                config.num_heads * config.d_kv)
     token0 = jnp.full((b, 1), config.decoder_start_id, jnp.int32)
+    cross = _project_cross(params, encoded)
 
     def step_fn(carry, step):
         token, caches, finished = carry
         logits, caches = _decoder_step(params, config, token, step, caches,
-                                       encoded, lengths)
+                                       None, lengths, cross)
         next_token = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         next_token = jnp.where(finished, config.pad_id, next_token)
         finished = jnp.logical_or(finished, next_token == config.eos_id)
@@ -540,16 +570,16 @@ def sample_decode(params: dict, config: T5Config, input_ids: jax.Array,
     b = input_ids.shape[0]
     if encoded is None:
         encoded = encode(params, config, input_ids, lengths)
-    caches = [{"self": nn.init_cache(b, config.num_heads, max_decode_len,
-                                     config.d_kv)}
-              for _ in range(config.num_decoder_layers)]
+    caches = nn.init_rows_cache(config.num_decoder_layers, b, max_decode_len,
+                                config.num_heads * config.d_kv)
     token0 = jnp.full((b, 1), config.decoder_start_id, jnp.int32)
     keys0 = _per_example_keys(seed)
+    cross = _project_cross(params, encoded)
 
     def step_fn(carry, step):
         token, caches, finished, keys = carry
         logits, caches = _decoder_step(params, config, token, step, caches,
-                                       encoded, lengths)
+                                       None, lengths, cross)
         keys, subs = _split_keys(keys)
         next_token = _sample_token(logits, subs, temperature, top_k,
                                    config.pad_id, top_p)
@@ -587,8 +617,11 @@ def beam_decode(params: dict, config: T5Config, input_ids: jax.Array,
 
     if encoded is None:
         encoded = encode(params, config, input_ids, lengths)
-    # Beams share the prompt: tile encoder state to (B*K, ...).
-    enc_k = jnp.repeat(encoded, k, axis=0)
+    # Beams share the prompt: project its K and V once, then tile them
+    # to (B*K, ...).
+    cross_k = jax.tree_util.tree_map(
+        lambda rows: jnp.repeat(rows, k, axis=1),
+        _project_cross(params, encoded))
     len_k = jnp.repeat(lengths, k, axis=0)
     caches = [{"self": nn.init_cache(b * k, config.num_heads,
                                      max_decode_len, config.d_kv)}
@@ -623,7 +656,7 @@ def beam_decode(params: dict, config: T5Config, input_ids: jax.Array,
     def step_fn(state, step):
         logits, caches = _decoder_step(
             params, config, state["cur"], step, state["caches"],
-            enc_k, len_k)
+            None, len_k, cross_k)
         logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
         v = logp.shape[-1]
         logp = logp.reshape(b, k, v)
@@ -734,7 +767,8 @@ def speculative_decode(
     """
     b = input_ids.shape[0]
     encoded_t = encode(params, config, input_ids, lengths)
-    encoded_d = encode(draft_params, draft_config, input_ids, lengths)
+    cross_d = _project_cross(
+        draft_params, encode(draft_params, draft_config, input_ids, lengths))
     cache_len = max_decode_len + k  # room for the last round's overshoot
     if kv_block_size:
         # Target caches as page arenas + per-example block tables (each
@@ -757,6 +791,7 @@ def speculative_decode(
         caches_t = [{"self": nn.init_cache(b, config.num_heads, cache_len,
                                            config.d_kv)}
                     for _ in range(config.num_decoder_layers)]
+        cross_t = _project_cross(params, encoded_t)
     caches_d = [{"self": nn.init_cache(b, draft_config.num_heads, cache_len,
                                        draft_config.d_kv)}
                 for _ in range(draft_config.num_decoder_layers)]
@@ -776,7 +811,7 @@ def speculative_decode(
             tok, caches_d = c
             logits, caches_d = _decoder_step(
                 draft_params, draft_config, tok, step + i, caches_d,
-                encoded_d, lengths)
+                None, lengths, cross_d)
             nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)[:, None]
             return (nxt, caches_d), nxt[:, 0]
 
@@ -795,7 +830,8 @@ def speculative_decode(
             caches_t = kv.arenas
         else:
             logits, caches_t = _decoder_positions(
-                params, config, block, step, caches_t, encoded_t, lengths)
+                params, config, block, step, caches_t, None, lengths,
+                cross_t)
         t_pred = jnp.argmax(logits, axis=-1).astype(jnp.int32)  # (B, k+1)
 
         # Acceptance: longest prefix where the draft matched the target's
@@ -867,6 +903,7 @@ def build_signatures(params: dict, config: T5Config, *, seq_len: int,
                      kv_num_blocks: int | None = None,
                      kv_evict_policy: str | None = None,
                      kv_prefill_chunk: int | None = None) -> dict:
+    from min_tfs_client_tpu.ops.attention import rows_block
     from min_tfs_client_tpu.servables import decode_signatures
     from min_tfs_client_tpu.servables.decode_sessions import Paging
     from min_tfs_client_tpu.servables.servable import Signature, TensorSpec
@@ -921,6 +958,28 @@ def build_signatures(params: dict, config: T5Config, *, seq_len: int,
         return {"encodings": run_encode(tree, ids,
                                         lengths).astype(jnp.float32)}
 
+    block = rows_block(seq_len)
+
+    def note_cross(signature, inputs):
+        """The `on_request` of the whole-generation signatures: what the
+        request's own example(s) make every decode step's
+        cross-attention read, as the span `generate/cross` on its trace
+        (`input_tokens`, its non-pad tokens; `blocks_read`, the
+        ceil(tokens / block) blocks of K and of V a layer reads for it;
+        `blocks_held`, the seq_len / block it holds), and into the
+        process's counters (`/monitoring/runtime`, `route`, under the
+        signature's label)."""
+        from min_tfs_client_tpu.observability import runtime, tracing
+
+        tokens = np.sum(np.asarray(inputs["input_ids"]) != config.pad_id,
+                        axis=-1).reshape(-1)
+        args = {"input_tokens": int(tokens.sum()),
+                "blocks_read": int(np.sum(-(-tokens // block))),
+                "blocks_held": int(tokens.size * (seq_len // block))}
+        now = time.perf_counter()
+        tracing.add_span("generate/cross", now, now, **args)
+        runtime.count_route(signature.telemetry_label or "unlabeled", args)
+
     decode_sig = Signature(
         fn=decode_fn,
         params=sig_params,
@@ -929,6 +988,10 @@ def build_signatures(params: dict, config: T5Config, *, seq_len: int,
                  "output_lengths": TensorSpec(np.int32, (None,))},
         # Decode compiles are expensive: serve a small bucket ladder.
         batch_buckets=(1, 4, 16, 32),
+        # a padding row is an input of length 0: its cross-attention
+        # reads nothing (a repeat of row 0 would read row 0's blocks)
+        batch_pad_values={"input_ids": config.pad_id},
+        on_request=note_cross,
     )
 
     encode_sig = Signature(
@@ -968,6 +1031,10 @@ def build_signatures(params: dict, config: T5Config, *, seq_len: int,
         outputs={"output_ids": TensorSpec(np.int32, (None, max_decode_len)),
                  "output_lengths": TensorSpec(np.int32, (None,))},
         batch_buckets=(1, 4, 16, 32),
+        # a padding row is an input of length 0: its cross-attention
+        # reads nothing (a repeat of row 0 would read row 0's blocks)
+        batch_pad_values={"input_ids": config.pad_id},
+        on_request=note_cross,
     )
 
     signatures = {"serving_default": decode_sig, "decode": decode_sig,

@@ -189,6 +189,11 @@ STAGES = (
     # arguments are the numbers (prompt_tokens, pairs_* and held_* for
     # prefill and decode, max_load, load_total).
     "generate/route",
+    # What a whole generation's cross-attention reads of the K and V it
+    # holds (models/t5.py), on the request's own trace before it runs:
+    # no duration, its arguments are the numbers (input_tokens,
+    # blocks_read, blocks_held).
+    "generate/cross",
     "serving/serialize",
 )
 

@@ -799,6 +799,280 @@ class PagedKV:
                                q_start=q_start)
 
 
+# -- a few query rows over dense K/V rows ------------------------------------
+#
+# A whole generation (models/t5.py) attends the same K and V at every decode
+# step: cross-attention the encoder's output projected ONCE, self-attention
+# the rows the steps before it wrote. Both are kept as the projection leaves
+# them, (B, S, H * D) rows with the heads side by side on the lanes, like an
+# arena's pages, every layer's in one array, (L, B, S, H * D): `layer=`.
+# One query row a step is no work for the MXU as `attention()` sees it
+# (M = 1: XLA widens K and V to float32 and multiplies on the VPU, over all
+# S rows whatever the lengths), so these rows get a read of their own:
+#
+#  * `rows_flash_attention` — Pallas kernel, one grid step an example. K
+#    and V stay in HBM; a step copies the example's ceil(length / block)
+#    blocks of each into VMEM itself, the NEXT example's while this one's
+#    are multiplied, so an example reads what its length needs, once, in
+#    its own dtype, and one of length 0 reads nothing. The heads never
+#    leave their lanes: the query rows go in block-diagonal, (H * Sq,
+#    H * D) with head h's query on head h's lanes and zeros elsewhere, so
+#    ONE matmul against a block gives every head's scores and one against
+#    V every head's output (a zero adds nothing, in any precision), and
+#    the MXU takes K and V as they are.
+#  * elsewhere `attention_reference` over the same rows split into heads.
+#
+# `attention_rows()` dispatches on the shapes it sees.
+
+_ROWS_BLOCK = 128  # key rows a copy moves and a product takes
+
+
+def rows_block(seq_len: int) -> int:
+    """Key rows `attention_rows` reads at a time at this sequence length
+    (what a model counts its reads in)."""
+    return min(_ROWS_BLOCK, seq_len)
+
+
+def _rows_kernel(len_ref, qstart_ref, layer_ref, *refs, scale: float,
+                 heads: int, block: int, has_bias: bool, causal: bool):
+    """One example a grid cell: online softmax over the blocks its length
+    needs, every head at once. Row h * Sq + r of the scratch is head h's
+    query row r. Refs: lengths (B,), q_start (1,) and the layer (1,) in
+    SMEM; bias (H * Sq, S) float32, the same for every example, where
+    there is one; q (Sq, H * D); K and V whole in HBM; o (Sq, H * D); two
+    slots of an example's K and V rows, their copies' semaphores, and the
+    softmax's running (max, denominator, weighted sum)."""
+    if has_bias:
+        bias_ref, *refs = refs
+    q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sem, m_ref, l_ref, acc_ref = refs
+    example, slot = pl.program_id(0), pl.program_id(0) % 2
+    rows, f = acc_ref.shape
+    sq_p = rows // heads
+    valid_len, layer = len_ref[example], layer_ref[0]
+
+    def copies(of, into, blk):
+        at = pl.ds(pl.multiple_of(blk * block, block), block)
+        return [pltpu.make_async_copy(hbm.at[layer, of, at],
+                                      buf.at[into, at], sem.at[i, into, blk])
+                for i, (hbm, buf) in enumerate(((k_hbm, kbuf),
+                                                (v_hbm, vbuf)))]
+
+    def start(of, into):
+        for blk in range(kbuf.shape[1] // block):
+            @pl.when(blk * block < len_ref[of])
+            def _():
+                for copy in copies(of, into, blk):
+                    copy.start()
+
+    @pl.when(example == 0)
+    def _first():
+        start(0, 0)
+
+    @pl.when(example + 1 < pl.num_programs(0))
+    def _ahead():
+        start(example + 1, 1 - slot)
+
+    row = jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
+    # Whether a lane belongs to the head of a query row.
+    first = (row // sq_p) * (f // heads)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (rows, f), 1)
+    own = jnp.logical_and(lane >= first, lane < first + f // heads)
+    # The keys a query row may see: the example's, and with `causal` none
+    # past its own position (row r of a block sits at q_start + r).
+    limit = (jnp.minimum(valid_len, qstart_ref[0] + row % sq_p + 1)
+             if causal else valid_len)
+    q = q_ref[...].astype(jnp.float32)                   # (Sq, H * D)
+    q = (jnp.broadcast_to(q, (rows, f)) if sq_p == 1
+         else jnp.concatenate([q] * heads, axis=0))
+    q = jnp.where(own, q, 0.0).astype(kbuf.dtype)        # block-diagonal
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    def accumulate(blk, _):
+        for copy in copies(example, slot, blk):
+            copy.wait()  # servelint: blocks a DMA's semaphore on the device
+        at = pl.ds(pl.multiple_of(blk * block, block), block)
+        s = jax.lax.dot_general(
+            q, kbuf[slot, at, :], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale  # (rows, block)
+        if has_bias:
+            s = s + bias_ref[:, at]
+        ki = blk * block + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where(ki < limit, s, NEG_INF)
+        m_prev = m_ref[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        correction = jnp.exp(m_prev - m_new)
+        l_ref[...] = correction * l_ref[...] + jnp.sum(
+            p, axis=1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * correction + jax.lax.dot_general(
+            p.astype(vbuf.dtype), vbuf[slot, at, :], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)          # (rows, H * D)
+        m_ref[...] = m_new
+
+    jax.lax.fori_loop(0, (valid_len + block - 1) // block, accumulate, None)
+    # A row that met no key (length 0) never left NEG_INF: zeros. Each
+    # lane keeps its own head's row.
+    l = l_ref[...]
+    out = jnp.where(jnp.logical_and(own, m_ref[...] > NEG_INF * 0.5),
+                    acc_ref[...] / jnp.where(l == 0.0, 1.0, l), 0.0)
+    out = (jnp.sum(out, axis=0, keepdims=True) if sq_p == 1
+           else jnp.sum(out.reshape(heads, sq_p, f), axis=0))
+    o_ref[...] = out.astype(o_ref.dtype)
+
+
+def _rows_query_rows(sq: int) -> int:
+    """Query rows as `_rows_kernel` sees them: one, or whole sublane
+    tiles."""
+    return 1 if sq == 1 else -(-sq // 8) * 8
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "num_heads", "scale", "block", "interpret"))
+def rows_flash_attention(
+    q: jax.Array,
+    k: jax.Array,
+    v: jax.Array,
+    lengths: jax.Array,
+    *,
+    num_heads: int,
+    scale: Optional[float] = None,
+    layer: Optional[jax.Array] = None,
+    bias: Optional[jax.Array] = None,
+    q_start: Optional[jax.Array] = None,
+    block: Optional[int] = None,
+    interpret: bool = False,
+) -> jax.Array:
+    """Pallas attention of a few query rows over dense rows: q (B, Sq,
+    H * D), k and v (B, S, H * D) — or, with `layer`, that layer's (B, S,
+    H * D) of a stack (L, B, S, H * D), picked by the kernel's own copies
+    and never sliced out (a traced scalar, so that a model's layers share
+    ONE traced and lowered kernel) — with S a multiple of `block` (default
+    `rows_block(S)`), lengths (B,) valid key counts. `bias` (1, H, Sq, S)
+    is added after scaling, the same for every example (T5's relative
+    position bias). Without `q_start` every query row sees the example's
+    `length` keys (cross-attention); with it, a scalar, row r sits at
+    position q_start + r and sees keys < min(length, q_start + r + 1)
+    (a decode step or a verify block over the cache behind it). Returns
+    (B, Sq, H * D) in q.dtype; an example of length 0 gives zeros."""
+    b, sq, f = q.shape
+    if layer is None:
+        k, v, layer = k[None], v[None], 0
+    s = k.shape[2]
+    if scale is None:
+        scale = 1.0 / float(np.sqrt(f // num_heads))
+    if block is None:
+        block = rows_block(s)
+    sq_p = _rows_query_rows(sq)
+    rows = num_heads * sq_p
+
+    def q_index(example, lens, start, of_layer):
+        return (example, 0, 0)
+
+    in_specs = [pl.BlockSpec((None, sq_p, f), q_index),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY)]
+    operands = [_pad_to(q, 1, sq_p), k, v]
+    if bias is not None:
+        # Rows in the scratch's order, (head, query row); whole in VMEM
+        # at every step, so it is fetched once.
+        bias_f = _pad_to(bias.astype(jnp.float32).reshape(num_heads, sq, s),
+                         1, sq_p).reshape(rows, s)
+        in_specs.insert(0, pl.BlockSpec((rows, s),
+                                        lambda example, *scalars: (0, 0)))
+        operands.insert(0, bias_f)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,  # lengths, q_start, layer
+        grid=(b,),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((None, sq_p, f), q_index),
+        scratch_shapes=[
+            pltpu.VMEM((2, s, f), k.dtype),
+            pltpu.VMEM((2, s, f), v.dtype),
+            pltpu.SemaphoreType.DMA((2, 2, s // block)),
+            pltpu.VMEM((rows, 1), jnp.float32),
+            pltpu.VMEM((rows, 1), jnp.float32),
+            pltpu.VMEM((rows, f), jnp.float32),
+        ],
+    )
+    out = pl.pallas_call(
+        functools.partial(
+            _rows_kernel, scale=scale, heads=num_heads, block=block,
+            has_bias=bias is not None, causal=q_start is not None),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, sq_p, f), q.dtype),
+        # in order: a step starts the copies the next one waits for
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+        name="_rows_kernel",  # the device-trace reduction finds it by name
+    )(lengths.astype(jnp.int32),
+      jnp.reshape(0 if q_start is None else q_start, (1,)).astype(jnp.int32),
+      jnp.reshape(layer, (1,)).astype(jnp.int32), *operands)
+    return out[:, :sq, :]
+
+
+def _rows_kernel_applies(q: jax.Array, k: jax.Array, num_heads: int,
+                         bias: Optional[jax.Array] = None) -> bool:
+    """The shapes `_rows_kernel` compiles for: rows of whole 128-lane
+    tiles, key rows in whole blocks of whole sublane tiles, a bias (if
+    any) that every example shares, and a step inside VMEM: two slots of
+    an example's K and V rows, the bias, the float32 scratch and the
+    body's temporaries, each as wide as the block-diagonal query rows.
+    One device only, as the paged read."""
+    _, sq, f = q.shape
+    s = k.shape[-2]
+    block = rows_block(s)
+    rows = num_heads * _rows_query_rows(sq)
+    step_bytes = (4 * s * f * k.dtype.itemsize
+                  + 4 * rows * (4 * f + 3 * max(block, 128) + 2 * 128)
+                  + (0 if bias is None else 2 * 4 * rows * s))
+    return (f % 128 == 0
+            and block % 16 == 0
+            and s % block == 0
+            and (bias is None or bias.shape[0] == 1)
+            and step_bytes <= _PAGED_STEP_VMEM_BYTES
+            and not _auto_mesh_axes())
+
+
+def attention_rows(
+    q: jax.Array,
+    k: jax.Array,
+    v: jax.Array,
+    lengths: jax.Array,
+    *,
+    num_heads: int,
+    scale: Optional[float] = None,
+    layer: Optional[jax.Array] = None,
+    bias: Optional[jax.Array] = None,
+    q_start: Optional[jax.Array] = None,
+) -> jax.Array:
+    """Attention of q's few query rows (B, Sq, H * D) over K and V kept
+    as rows (B, S, H * D) — with `layer`, that layer's of a stack (L, B,
+    S, H * D) — each example over its first `lengths` keys, with `q_start`
+    causally from there (`rows_flash_attention` says how): the Pallas read
+    on a TPU for every shape it is written for (`_rows_kernel_applies`),
+    `attention_reference` over the same rows split into heads otherwise.
+    Returns (B, Sq, H * D)."""
+    if _on_tpu() and _rows_kernel_applies(q, k, num_heads, bias):
+        return rows_flash_attention(
+            q, k, v, lengths, num_heads=num_heads, scale=scale, layer=layer,
+            bias=bias, q_start=q_start)
+    if layer is not None:
+        k, v = k[layer], v[layer]
+
+    def heads(x):
+        b, s, f = x.shape
+        return x.reshape(b, s, num_heads, f // num_heads).transpose(
+            0, 2, 1, 3)
+
+    out = attention_reference(
+        heads(q), heads(k), heads(v), lengths=lengths, bias=bias,
+        scale=scale, causal=q_start is not None, causal_offset=q_start)
+    return out.transpose(0, 2, 1, 3).reshape(q.shape)
+
+
 def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 

@@ -233,6 +233,16 @@ class Signature:
                                  None]] = \
         dc_field(default=None, repr=False, compare=False)
 
+    # Optional `on_request(signature, inputs)`: `on_answer`'s twin for
+    # what a model can count from the request alone: called by the
+    # Predict handlers with a request's OWN decoded inputs, before it
+    # runs (models/t5.py: `generate/cross`, the blocks of K and V its
+    # input's length will make every decode step read). Logged and
+    # answered all the same where it raises.
+    on_request: Optional[Callable[["Signature", Mapping[str, np.ndarray]],
+                                  None]] = \
+        dc_field(default=None, repr=False, compare=False)
+
     # "model:version:signature", stamped by Servable.__init__ — keys the
     # compile-event ledger (observability/runtime.py).
     telemetry_label: str = ""

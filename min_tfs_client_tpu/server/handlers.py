@@ -204,15 +204,23 @@ class Handlers:
     @staticmethod
     def _answered(signature, outputs) -> None:
         """The signature's `on_answer`, where it has one, on a Predict's
-        own outputs. What it notes is telemetry: if it raises, the answer
-        still goes out, and the log says what was lost."""
-        if signature.on_answer is None:
+        own outputs."""
+        Handlers._noted("on_answer", signature, outputs)
+
+    @staticmethod
+    def _noted(hook: str, signature, tensors) -> None:
+        """A signature's `on_request` or `on_answer`, where it has one,
+        on a Predict's own inputs or outputs. What it notes is telemetry:
+        if it raises, the answer still goes out, and the log says what
+        was lost."""
+        note = getattr(signature, hook)
+        if note is None:
             return
         try:
-            signature.on_answer(signature, outputs)
+            note(signature, tensors)
         except Exception:  # servelint: fallback-ok the answer is sound
             logging.getLogger(__name__).exception(
-                "on_answer of %s raised; its note is lost",
+                "%s of %s raised; its note is lost", hook,
                 signature.telemetry_label or "a signature")
 
     def _predict_inputs(self, handle, request: apis.PredictRequest):
@@ -224,6 +232,7 @@ class Handlers:
         signature = handle.servable.signature(
             request.model_spec.signature_name)
         inputs = tensor_protos_to_dict(request.inputs, writable=False)
+        self._noted("on_request", signature, inputs)
         sid = inputs.get("session_id")
         if sid is not None:
             # Sessioned decode surface: the session id on the trace

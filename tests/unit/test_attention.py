@@ -13,6 +13,7 @@ from min_tfs_client_tpu.ops.attention import (
     _flash_kernel_applies,
     _paged_head_group,
     _paged_kernel_applies,
+    _rows_kernel_applies,
     attention,
     attention_reference,
     flash_attention,
@@ -20,6 +21,8 @@ from min_tfs_client_tpu.ops.attention import (
     paged_attention_reference,
     paged_flash_attention,
     paged_prefill_attention,
+    rows_block,
+    rows_flash_attention,
 )
 
 
@@ -482,6 +485,123 @@ def test_fully_masked_rows_are_zero_in_both_paths():
     np.testing.assert_allclose(fl[1], ref[1], atol=2e-5, rtol=2e-5)
 
 
+# -- a few query rows over dense rows (a whole generation's cross-attention) ---
+
+_ROWS_SEQ = 512
+# Every example of a batch of two at one length, then the lengths mixed
+# in one batch with two rows that pad it.
+_ROWS_LENGTHS = [(n, n) for n in (0, 1, 127, 128, 129, 512)] + [
+    (129, 0, 512, 1, 128, 0, 127, 300)]
+
+
+@pytest.mark.parametrize("d", [64, 128])
+@pytest.mark.parametrize("sq", [1, 5])
+@pytest.mark.parametrize("lengths", _ROWS_LENGTHS,
+                         ids=lambda n: "-".join(map(str, n)))
+def test_rows_kernel_matches_reference_and_reads_by_length(lengths, sq, d):
+    """`_rows_kernel` (interpret mode) against `attention_reference` over
+    the same rows split into heads, at float32 rounding; an example of
+    length 0 gives zeros; and no block past ceil(length / block) is
+    read: the kernel's K and V hold NaN there."""
+    h, f, b = 2, 2 * d, len(lengths)
+    q = _rand((b, sq, f), 0)
+    k, v = _rand((b, _ROWS_SEQ, f), 1), _rand((b, _ROWS_SEQ, f), 2)
+    lengths = jnp.asarray(lengths, jnp.int32)
+    block = rows_block(_ROWS_SEQ)
+    assert block == 128
+    unread = (jnp.arange(_ROWS_SEQ)[None, :]
+              >= (-(-lengths // block) * block)[:, None])[:, :, None]
+    got = rows_flash_attention(
+        q, jnp.where(unread, jnp.nan, k), jnp.where(unread, jnp.nan, v),
+        lengths, num_heads=h, interpret=True)
+
+    def heads(x):
+        return x.reshape(b, -1, h, d).transpose(0, 2, 1, 3)
+
+    want = attention_reference(heads(q), heads(k), heads(v), lengths=lengths)
+    want = want.transpose(0, 2, 1, 3).reshape(b, sq, f)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=2e-6, rtol=2e-6)
+    np.testing.assert_array_equal(
+        np.asarray(got)[np.asarray(lengths) == 0], 0.0)
+    # and the dispatcher, on this backend, is the reference over the rows
+    np.testing.assert_array_equal(
+        np.asarray(attention_module.attention_rows(
+            q, k, v, lengths, num_heads=h)), np.asarray(want))
+
+
+def test_rows_kernel_rounds_its_weights_as_the_reference_does():
+    """bfloat16 rows: products into float32, the softmax weights rounded
+    to the operands' dtype before they meet V, on both paths."""
+    b, h, d = 3, 4, 64
+    q = _rand((b, 1, h * d), 0, jnp.bfloat16)
+    k = _rand((b, _ROWS_SEQ, h * d), 1, jnp.bfloat16)
+    v = _rand((b, _ROWS_SEQ, h * d), 2, jnp.bfloat16)
+    lengths = jnp.asarray([300, 0, 512], jnp.int32)
+    got = rows_flash_attention(q, k, v, lengths, num_heads=h, scale=1.0,
+                               interpret=True)
+    want = attention_module.attention_rows(q, k, v, lengths, num_heads=h,
+                                           scale=1.0)
+    assert got.dtype == jnp.bfloat16
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32), atol=2 ** -7)
+
+
+@pytest.mark.parametrize("sq, q_start", [(1, 0), (1, 37), (1, 255),
+                                         (5, 0), (5, 126), (5, 251)])
+def test_rows_kernel_over_a_cache_with_bias_sees_no_key_past_its_row(
+        sq, q_start):
+    """`q_start`: row r of the block sits at q_start + r and sees the
+    rows up to its own, under a bias every example shares (a decode step
+    or a verify block of T5's self-attention): the kernel against
+    `attention_reference` with the same bias and a causal offset; the
+    rows past the block hold NaN where whole blocks lie past it."""
+    b, h, d, s = 3, 4, 64, 256
+    q = _rand((b, sq, h * d), 0)
+    k, v = _rand((2, b, s, h * d), 1), _rand((2, b, s, h * d), 2)
+    bias = _rand((1, h, sq, s), 3)
+    lengths = jnp.full((b,), q_start + sq, jnp.int32)
+    unread = (jnp.arange(s) >= -(-(q_start + sq) // 128) * 128)[:, None]
+    got = rows_flash_attention(
+        q, jnp.where(unread, jnp.nan, k), jnp.where(unread, jnp.nan, v),
+        lengths, num_heads=h, layer=1, bias=bias,
+        q_start=jnp.int32(q_start), interpret=True)
+
+    def heads(x):
+        return x.reshape(b, -1, h, d).transpose(0, 2, 1, 3)
+
+    want = attention_reference(
+        heads(q), heads(k[1]), heads(v[1]), lengths=lengths, bias=bias,
+        causal=True, causal_offset=q_start)
+    np.testing.assert_allclose(
+        np.asarray(got), np.asarray(want.transpose(0, 2, 1, 3).reshape(
+            b, sq, h * d)), atol=2e-6, rtol=2e-6)
+    np.testing.assert_allclose(
+        np.asarray(got), np.asarray(attention_module.attention_rows(
+            q, k, v, lengths, num_heads=h, layer=1, bias=bias,
+            q_start=jnp.int32(q_start))), atol=2e-6, rtol=2e-6)
+
+
+def test_rows_kernel_reads_a_layer_of_a_stack_where_it_lies():
+    """`layer=`: K and V of every layer in one array each; the kernel's
+    fetches pick the layer, and the dispatcher's reference slices it."""
+    b, h, d, layers = 2, 2, 64, 3
+    q = _rand((b, 1, h * d), 0)
+    k = _rand((layers, b, _ROWS_SEQ, h * d), 1)
+    v = _rand((layers, b, _ROWS_SEQ, h * d), 2)
+    lengths = jnp.asarray([200, 512], jnp.int32)
+    for layer in range(layers):
+        got = rows_flash_attention(q, k, v, lengths, num_heads=h,
+                                   layer=layer, interpret=True)
+        alone = rows_flash_attention(q, k[layer], v[layer], lengths,
+                                     num_heads=h, interpret=True)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(alone))
+        np.testing.assert_allclose(
+            np.asarray(got), np.asarray(attention_module.attention_rows(
+                q, k, v, lengths, num_heads=h, layer=layer)),
+            atol=2e-6, rtol=2e-6)
+
+
 # -- the kernels as the TPU compiler sees them --------------------------------
 #
 # Interpret mode never meets Mosaic's block-shape rules, and the dispatcher
@@ -508,6 +628,51 @@ def test_flash_lowers_for_tpu_at_bert_base_shapes():
         lambda q, k, v, n: flash_attention(q, k, v, lengths=n),
         qkv, qkv, qkv, lengths).as_text()
     assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("sq", [1, 5])
+@pytest.mark.parametrize("s, cached", [(512, False), (256, True)])
+def test_rows_read_lowers_for_tpu_at_t5_large_shapes(s, cached, sq):
+    """The two reads of `t5-large.generate`'s decode step (32 examples of
+    16 heads x 64): cross-attention over 512 rows, self-attention over a
+    cache of 256 under the relative position bias; and a verify block of
+    5 rows over each."""
+    b, h, d = 32, 16, 64
+    q = jax.ShapeDtypeStruct((b, sq, h * d), jnp.bfloat16)
+    rows = jax.ShapeDtypeStruct((24, b, s, h * d), jnp.bfloat16)
+    lengths = jax.ShapeDtypeStruct((b,), jnp.int32)
+    bias = jax.ShapeDtypeStruct((1, h, sq, s), jnp.float32)
+    assert _rows_kernel_applies(q, rows, h, bias if cached else None)
+
+    def read(q, k, v, n, bias, q_start):
+        return rows_flash_attention(
+            q, k, v, n, num_heads=h, layer=23,
+            **({"bias": bias, "q_start": q_start} if cached else {}))
+
+    text = _lower_for_tpu(read, q, rows, rows, lengths, bias,
+                          jax.ShapeDtypeStruct((), jnp.int32)).as_text()
+    assert "tpu_custom_call" in text and "_rows_kernel" in text
+
+
+def test_rows_gate_refuses_what_the_kernel_cannot_hold():
+    def applies(sq, s, f, h=16):
+        return _rows_kernel_applies(
+            jax.ShapeDtypeStruct((2, sq, f), jnp.bfloat16),
+            jax.ShapeDtypeStruct((2, s, f), jnp.bfloat16), h)
+
+    assert applies(1, 512, 1024) and applies(5, 512, 1024)
+    assert applies(1, 64, 128, h=2)           # one block of the whole 64
+    assert not applies(1, 512, 64, h=2)       # rows under a lane tile
+    assert not applies(1, 200, 1024)          # key rows not whole blocks
+    assert not applies(1, 8, 128, h=2)        # a block under a sublane tile
+    assert not applies(64, 512, 1024)         # 1,024 query rows: past VMEM
+    assert not applies(1, 4096, 1024)         # two slots of K and V rows: too
+    q = jax.ShapeDtypeStruct((2, 1, 1024), jnp.bfloat16)
+    rows = jax.ShapeDtypeStruct((2, 256, 1024), jnp.bfloat16)
+    assert _rows_kernel_applies(
+        q, rows, 16, jax.ShapeDtypeStruct((1, 16, 1, 256), jnp.float32))
+    assert not _rows_kernel_applies(      # a bias of its own an example
+        q, rows, 16, jax.ShapeDtypeStruct((2, 16, 1, 256), jnp.float32))
 
 
 # (b, h, d, block_size, width, sq): the page sizes the gate admits at

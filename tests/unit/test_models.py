@@ -104,6 +104,165 @@ def test_t5_cached_decode_matches_uncached_teacher_forcing():
                                    atol=1e-4, rtol=1e-4)
 
 
+def _parent_decoder_step(params, config, token, step, caches, encoded,
+                         enc_lengths):
+    """The decoder step as it was before the cross K and V were projected
+    once (PR 44): every layer's cross-attention through
+    `nn.mha(kv=encoded)`, which projects `encoded` again at every call."""
+    dec = params["decoder"]
+    x = nn.embed(params["shared_embedding"], token)
+    bias = t5.relative_bias(
+        dec["rel_bias"], config, 1, caches[0]["self"]["k"].shape[2],
+        bidirectional=False, q_offset=step)
+    new_caches = []
+    for layer, cache in zip(dec["layers"], caches):
+        h = nn.rms_norm(layer["self_norm"], x)
+        attn, self_cache = nn.mha(
+            layer["self_attention"], h, num_heads=config.num_heads,
+            causal=True, bias=bias, cache=cache["self"], cache_index=step,
+            scale=1.0)
+        x = x + attn
+        h = nn.rms_norm(layer["cross_norm"], x)
+        cross, _ = nn.mha(
+            layer["cross_attention"], h, num_heads=config.num_heads,
+            kv=encoded, lengths=enc_lengths, scale=1.0)
+        x = x + cross
+        h = nn.rms_norm(layer["mlp_norm"], x)
+        x = x + nn.mlp(layer["mlp"], h, activation=jax.nn.relu)
+        new_caches.append({"self": self_cache})
+    x = nn.rms_norm(dec["final_norm"], x)
+    logits = jnp.einsum(
+        "bld,vd->blv", x.astype(jnp.float32) / np.sqrt(config.d_model),
+        params["shared_embedding"]["embedding"])
+    return logits[:, 0], new_caches
+
+
+def test_t5_whole_generation_over_rows_projected_once_is_the_parents():
+    """`greedy_decode` projects each layer's cross K and V once and reads
+    the rows by length, and keeps its self-attention cache as rows too;
+    the parent projected `encoded` inside every step and kept a cache of
+    heads a layer. Same tokens, and along the way logits within
+    bfloat16's rounding, on inputs of mixed lengths and a row that pads
+    the batch."""
+    config = t5.T5Config.tiny()
+    params = t5.init_params(jax.random.PRNGKey(3), config)
+    rng = np.random.default_rng(0)
+    lengths = np.array([16, 5, 0, 11, 1], np.int32)
+    ids = np.zeros((5, 16), np.int32)
+    for row, n in enumerate(lengths):
+        ids[row, :n] = rng.integers(2, config.vocab_size, n)
+    steps = 12
+    served, _ = t5.greedy_decode(params, config, ids, lengths,
+                                 max_decode_len=steps)
+
+    encoded = t5.encode(params, config, ids, lengths)
+    cross = t5._project_cross(params, encoded)
+    theirs = [{"self": nn.init_cache(5, config.num_heads, steps,
+                                     config.d_kv)}
+              for _ in range(config.num_decoder_layers)]
+    ours = nn.init_rows_cache(config.num_decoder_layers, 5, steps,
+                              config.num_heads * config.d_kv)
+    token = jnp.full((5, 1), config.decoder_start_id, jnp.int32)
+    finished = np.zeros((5,), bool)
+    for step in range(steps):
+        want, theirs = _parent_decoder_step(
+            params, config, token, jnp.int32(step), theirs, encoded,
+            jnp.asarray(lengths))
+        got, ours = t5._decoder_step(
+            params, config, token, jnp.int32(step), ours, None,
+            jnp.asarray(lengths), cross)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   atol=2 ** -6)
+        chosen = np.where(finished, config.pad_id,
+                          np.argmax(np.asarray(want), -1))
+        np.testing.assert_array_equal(np.asarray(served)[:, step], chosen)
+        finished |= chosen == config.eos_id
+        token = jnp.asarray(chosen[:, None], jnp.int32)
+
+
+def test_t5_paged_tick_names_nothing_of_the_rows_read(monkeypatch):
+    """The session tick (`paged_decoder_positions`, `_T5PagedStep`) keeps
+    `nn.mha(kv=encoded)`: tracing it calls none of what the whole
+    generations' cross-attention brought."""
+    import importlib
+
+    from min_tfs_client_tpu.ops.attention import PagedKV
+
+    def never(*args, **kwargs):
+        raise AssertionError("the tick reached the rows read")
+
+    attention = importlib.import_module("min_tfs_client_tpu.ops.attention")
+    for module, name in ((nn, "cross_rows"), (nn, "mha_rows"),
+                         (nn, "init_rows_cache"), (nn, "attention_rows"),
+                         (t5, "_project_cross"),
+                         (attention, "attention_rows"),
+                         (attention, "rows_flash_attention")):
+        monkeypatch.setattr(module, name, never)
+    config = t5.T5Config.tiny()
+    params = t5.init_params(jax.random.PRNGKey(0), config)
+    b, bs, pages = 2, 4, 3
+    arenas = {t5._cache_key(i, name): PagedKV.arena(
+                  b * pages, bs, (config.num_heads, config.d_kv),
+                  nn.COMPUTE_DTYPE)
+              for i in range(config.num_decoder_layers) for name in "kv"}
+    tables = jnp.arange(b * pages, dtype=jnp.int32).reshape(b, pages)
+
+    def tick(params, arenas, tokens, q_start, encoded, enc_lengths):
+        kv = PagedKV(arenas, tables, q_start, block_size=bs,
+                     trash=b * pages)
+        logits, kv = t5.paged_decoder_positions(
+            params, config, tokens, q_start, kv, encoded, enc_lengths)
+        return logits, kv.arenas
+
+    text = str(jax.make_jaxpr(tick)(
+        params, arenas, jnp.zeros((b, 1), jnp.int32),
+        jnp.asarray([3, 0], jnp.int32),
+        jnp.zeros((b, 8, config.d_model), nn.COMPUTE_DTYPE),
+        jnp.asarray([8, 5], jnp.int32)))
+    assert "pallas_call" not in text and "dot_general" in text
+
+
+def test_t5_whole_generation_notes_what_its_cross_attention_reads():
+    """`serving_default` keeps its two outputs; its `on_request` puts
+    `generate/cross` on the request's trace and into the counters."""
+    from min_tfs_client_tpu.observability import runtime, tracing
+    from min_tfs_client_tpu.ops.attention import rows_block
+    from min_tfs_client_tpu.server.handlers import Handlers
+
+    config = t5.T5Config.tiny()
+    params = t5.init_params(jax.random.PRNGKey(0), config)
+    seq_len = 512
+    sigs = t5.build_signatures(params, config, seq_len=seq_len,
+                               max_decode_len=4)
+    sig = sigs["serving_default"]
+    assert set(sig.outputs) == {"output_ids", "output_lengths"}
+    assert sigs["decode_sampled"].on_request is sig.on_request
+    # the rows that pad a batch are inputs of length 0, not row 0 again
+    padding = sig._padding_rows("input_ids", np.full((1, seq_len), 7,
+                                                     np.int32), 3)
+    assert padding.shape == (3, seq_len) and not padding.any()
+    assert sigs["encode"].on_request is None
+    block = rows_block(seq_len)
+    ids = np.zeros((3, seq_len), np.int32)
+    for row, n in enumerate((129, 0, 512)):
+        ids[row, :n] = 7
+    label = sig.telemetry_label or "unlabeled"
+    before = dict(runtime.route_totals().get(label, {}))
+    with tracing.request_trace("predict") as trace:
+        Handlers._noted("on_request", sig, {"input_ids": ids})
+    (args,) = [a for name, _, _, a in trace.spans
+               if name == "generate/cross"]
+    assert args == {"input_tokens": 641,
+                    "blocks_read": -(-129 // block) + seq_len // block,
+                    "blocks_held": 3 * (seq_len // block)}
+    after = runtime.route_totals()[label]
+    assert after["requests"] - before.get("requests", 0) == 1
+    assert after["blocks_read"] - before.get("blocks_read", 0) == \
+        args["blocks_read"]
+    # a request it cannot read loses its note, not its answer
+    Handlers._noted("on_request", sig, {})
+
+
 def test_resnet_tiny_forward():
     config = resnet.ResNetConfig.tiny()
     params = resnet.init_params(jax.random.PRNGKey(0), config)
