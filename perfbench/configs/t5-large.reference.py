@@ -9,7 +9,8 @@ shared by all, ReLU MLP, no biases) and the decoder over a whole given
 sequence at once (causal self-attention, then attention to the encoder's
 output; logits from the tied embedding after the 1/sqrt(d_model)
 rescale). Given only the start token that is the first step; given the
-tokens the served model generated it is every step, each on the served
+tokens the served model generated, as one whole generation or step by
+step through a decode session, it is every step, each on the served
 prefix (`verify`). The parameter tree is the program's
 (models/t5.py:init_params), because the weights are. Written for this
 directory, independent of models/t5.py's code.
@@ -210,7 +211,8 @@ def check(ctx) -> dict:
     against the same server's whole generation. Runs in the benchmark's
     parent (numpy only), outside the timed window; the same requests warm
     the programs they use (the 136-step session every table width). The
-    whole generations themselves are held to the reference in `verify`."""
+    whole generations and the session streams themselves are held to the
+    reference in `verify`."""
     bar = ctx.config["correctness"]
     prompts, lengths = ctx.expected["prompts"], ctx.expected["lengths"]
     out: dict = {"ok": True, "seconds": {}}
@@ -252,13 +254,19 @@ def check(ctx) -> dict:
                 for i in range(n)]]
         streams.append(_run_session(ctx, f"check-{n}", prompts[n:n + 1],
                                     min(LONG_STEPS, limit)))
-        same = [[a == int(b) for a, b in zip(stream, whole[i])]
-                for i, stream in enumerate(streams)]
-        total = sum(len(s) for s in same)
-        out["streams_identical"] = sum(all(s) for s in same) / len(same)
-        out["tokens_equal"] = sum(sum(s) for s in same) / total
-        out["ok"] &= (out["streams_identical"] >= bar["min_identical_streams"]
-                      and out["tokens_equal"] >= bar["min_equal_tokens"])
+        # Two programs' greedy streams: one that parts from the whole
+        # generation at a near-tie differs to its end and says nothing of
+        # the steps after, so only whole streams are counted here; every
+        # step of every stream is the reference's to judge (`verify`):
+        # row i is prompt i's, -1 past its end.
+        out["streams_identical"] = float(np.mean([
+            all(a == int(b) for a, b in zip(stream, whole[i]))
+            for i, stream in enumerate(streams)]))
+        out["ok"] &= out["streams_identical"] >= bar["min_identical_streams"]
+        held = np.full((len(streams), max(map(len, streams))), -1, np.int32)
+        for i, stream in enumerate(streams):
+            held[i, :len(stream)] = stream
+        ctx.deferred["session_tokens"] = held
         lap("sessions")
     out["first_tokens_equal"] = float(np.mean(
         first == ctx.expected["first_tokens"]))
@@ -267,47 +275,83 @@ def check(ctx) -> dict:
     return out
 
 
+def _steps_on_the_served_prefix(tree, config: dict, encoded, key_mask,
+                                 served):
+    """One served stream (T,) against the reference's decoder run once
+    over it: for each step up to the first end-of-sequence token (after
+    it the program pads), whether the reference's largest logit is the
+    served token, and how far below the largest the served token's
+    lies."""
+    start = np.full((1, 1), config["decoder_start_token_id"], np.int32)
+    given = np.concatenate([start, served[None, :-1]], axis=1)
+    logits = np.asarray(_decode(tree, config, encoded, key_mask, given)[0])
+    ended = np.flatnonzero(served == config["eos_token_id"])
+    n = int(ended[0]) + 1 if ended.size else len(served)
+    took = logits[np.arange(n), served[:n]]
+    return (np.argmax(logits[:n], -1) == served[:n],
+            np.max(logits[:n], -1) - took)
+
+
 def verify(weights, config: dict, expected: dict, deferred: dict) -> dict:
-    """Every step of the served whole generations against the reference
-    (a CPU child, after the window). The decoder runs once over each
-    served sequence, so step t sees the served tokens before t, and its
-    largest logit should be the served token t: a greedy stream judged
-    step by step, where one flipped near-tie costs one token and not the
-    rest of the stream, and where a token that is not the reference's
-    choice must at least be a near-tie in the reference's logits. Counted
-    up to the first end-of-sequence token; after it the program pads. The
-    encoder's output is the reference's own, kept at export."""
+    """Every step of what the served model generated against the
+    reference (a CPU child, after the window): the whole generations
+    (`output_ids`) and, from a cell of sessions, the tokens the decode
+    sessions answered step by step (`session_tokens`, row i prompt i's,
+    -1 past a stream's end). The decoder runs once over each served
+    sequence, so step t sees the served tokens before t, and its largest
+    logit should be the served token t: a greedy stream judged step by
+    step, where one flipped near-tie costs one token and not the rest of
+    the stream, and where a token that is not the reference's choice must
+    at least be a near-tie in the reference's logits. The two surfaces
+    are two programs, so each has its pair of numbers, under the same
+    two limits. The encoder's output is the reference's own: kept at
+    export for the whole generations' prompts, computed here for the
+    sessions'."""
     import jax
     import jax.numpy as jnp
 
-    served = np.asarray(deferred["output_ids"], np.int32)
-    rows, steps = served.shape
+    bar = config["correctness"]
     tree = _float32({"decoder": weights("decoder"),
                      "shared_embedding": weights("shared_embedding")})
-    encoded = jnp.asarray(expected["encoded"][:rows], jnp.float32)
-    lengths = np.asarray(expected["lengths"][:rows])
-    key_mask = jnp.arange(encoded.shape[1])[None, :] < lengths[:, None]
-    start = np.full((rows, 1), config["decoder_start_token_id"], np.int32)
-    given = np.concatenate([start, served[:, :-1]], axis=1)
-    equal, gaps = [], []
+    lengths = np.asarray(expected["lengths"])
+    found: dict = {"ok": True}
+
+    def hold(surface: str, rows) -> None:
+        """`rows`: (encoder output (1, S, d), key mask (1, S), served
+        tokens (T,)) a stream."""
+        equal, gaps = (np.concatenate(column) for column in zip(*(
+            _steps_on_the_served_prefix(tree, config, *row)
+            for row in rows)))
+        share, gap = float(np.mean(equal)), float(np.max(gaps))
+        found["ok"] &= bool(share >= bar["min_equal_generated_tokens"]
+                            and gap <= bar["generated_logit_atol"])
+        found.update({
+            f"{surface}_tokens_equal": share,
+            f"{surface}_tokens_compared": int(equal.size),
+            # where the served token is not the reference's choice: how
+            # far below the reference's largest logit its own lies (0 if
+            # all agree). Rounding flips near-ties; a fault lands anywhere.
+            f"{surface}_logit_gap_max": gap})
+
     with jax.default_matmul_precision("highest"):
-        for row in range(rows):
-            logits = np.asarray(_decode(
-                tree, config, encoded[row:row + 1], key_mask[row:row + 1],
-                given[row:row + 1])[0])
-            ended = np.flatnonzero(served[row] == config["eos_token_id"])
-            n = int(ended[0]) + 1 if ended.size else steps
-            took = logits[np.arange(n), served[row, :n]]
-            equal.append(np.argmax(logits[:n], -1) == served[row, :n])
-            gaps.append(np.max(logits[:n], -1) - took)
-    equal, gaps = np.concatenate(equal), np.concatenate(gaps)
-    bar = config["correctness"]
-    share, gap = float(np.mean(equal)), float(np.max(gaps))
-    return {"ok": bool(share >= bar["min_equal_generated_tokens"]
-                       and gap <= bar["generated_logit_atol"]),
-            "generated_tokens_equal": share,
-            "generated_tokens_compared": int(equal.size),
-            # where the served token is not the reference's choice: how far
-            # below the reference's largest logit its own lies (0 if all
-            # agree). Rounding flips near-ties; a fault lands anywhere.
-            "generated_logit_gap_max": gap}
+        served = np.asarray(deferred["output_ids"], np.int32)
+        encoded = jnp.asarray(expected["encoded"][:len(served)], jnp.float32)
+        key_mask = jnp.arange(encoded.shape[1])[None, :] \
+            < lengths[:len(served), None]
+        hold("generated", [(encoded[row:row + 1], key_mask[row:row + 1],
+                            served[row]) for row in range(len(served))])
+        if "session_tokens" in deferred:
+            streams = np.asarray(deferred["session_tokens"], np.int32)
+            encoder = {"encoder": _float32(weights("encoder")),
+                       "shared_embedding": tree["shared_embedding"]}
+            ids = jnp.asarray(expected["prompts"][:len(streams)])
+            key_mask = jnp.arange(ids.shape[1])[None, :] \
+                < lengths[:len(streams), None]
+            # One program for the five prompts: op by op, each prompt's
+            # own length would compile every operation anew.
+            encoded = jax.jit(lambda tree, ids, mask: _encode(
+                tree, config, ids, mask))(encoder, ids, key_mask)
+            hold("session", [(encoded[row:row + 1], key_mask[row:row + 1],
+                              stream[stream >= 0])
+                             for row, stream in enumerate(streams)])
+    return found

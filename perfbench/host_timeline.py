@@ -41,7 +41,7 @@ PHASE_PREFIXES = ("decode/", "device/")
 WAITS = ("decode/wait", "batching/queue_wait")
 LAUNCHES = ("decode/tick", "device/execute")
 NO_REQUEST = "no request in flight"
-UNATTRIBUTED = "unattributed"
+UNATTRIBUTED = trace_reduce.UNATTRIBUTED
 # A launch is looked for from this long before its span, which is more
 # than the device plane has been seen to run early.
 SLACK_NS = 5_000_000

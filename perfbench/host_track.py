@@ -185,7 +185,13 @@ def timeline(payload: dict, events: dict, host_clock: dict,
     between programs by name, longest first, as (name, start s,
     seconds); the idle seconds between programs and those of them with
     a name; each round's fetch tail in ns."""
-    track = load(payload)
+    return timeline_of(load(payload), events, host_clock, main_program)
+
+
+def timeline_of(track: dict, events: dict, host_clock: dict,
+                main_program: str) -> dict:
+    """`timeline` of a track that is loaded already (`requests`, `spans`
+    and `process`, as `load` gives them)."""
     # The device plane's offset, by host_timeline's rule: the recorded
     # one, else the capture's quickest launch takes no time.
     waits = host_timeline.launch_to_device(

@@ -15,6 +15,7 @@ from __future__ import annotations
 
 import concurrent.futures as cf
 import json
+import os
 import pathlib
 import sys
 import threading
@@ -78,6 +79,49 @@ class Sender:
         return rec
 
 
+class Probe(threading.Thread):
+    """What the generator cost and how late its threads ran, over the
+    window alone: the process's CPU (`os.times()` at the opening and at
+    the close, in cores) and the overshoot of a thread that asks to sleep
+    TICK_S again and again: a thread of this process that is ready to
+    run waits that long for the interpreter, or for the host."""
+
+    TICK_S = 0.01
+
+    def __init__(self, sender: Sender, seconds: float):
+        super().__init__(name="loadgen-probe", daemon=True)
+        self.sender, self.seconds = sender, seconds
+        self.found: dict = {}
+        self.start()
+
+    @staticmethod
+    def _cpu_s() -> float:
+        t = os.times()
+        return t.user + t.system
+
+    def run(self) -> None:
+        from perfbench import stats
+
+        time.sleep(max(0.0, -self.sender.now()))
+        opened, cpu0, late = self.sender.now(), self._cpu_s(), []
+        while (now := self.sender.now()) < self.seconds:
+            time.sleep(self.TICK_S)
+            late.append((self.sender.now() - now - self.TICK_S) * 1e3)
+        span = self.sender.now() - opened
+        self.found = {
+            "span_s": span,
+            "cpu_cores": (self._cpu_s() - cpu0) / span if span > 0 else None,
+            "thread_late_ms": {
+                "samples": len(late), "mean": sum(late) / len(late),
+                "p50": stats.percentile(late, 50),
+                "p99": stats.percentile(late, 99),
+                "max": max(late)} if late else None}
+
+    def result(self) -> dict:
+        self.join(timeout=5.0)
+        return self.found
+
+
 def run_open_loop(sender: Sender, plan: dict) -> dict:
     """Arrivals on the schedule, whether or not earlier ones ended. A
     request waits for a pool thread only when more are in flight than
@@ -85,6 +129,7 @@ def run_open_loop(sender: Sender, plan: dict) -> dict:
     requests = sorted(plan["requests"], key=lambda r: r["due"])
     prepared = [sender.inputs(r) for r in requests]
     sender.wait_go()
+    probe = Probe(sender, float(plan["seconds"]))
     records = []
     with cf.ThreadPoolExecutor(int(plan["threads"])) as pool:
         futures = []
@@ -95,7 +140,7 @@ def run_open_loop(sender: Sender, plan: dict) -> dict:
             futures.append(pool.submit(sender.timed, item, inputs,
                                        item["due"]))
         records = [f.result() for f in futures]
-    return {"requests": records}
+    return {"requests": records, "generator": probe.result()}
 
 
 def run_sessions(sender: Sender, plan: dict) -> dict:
@@ -108,6 +153,7 @@ def run_sessions(sender: Sender, plan: dict) -> dict:
     np = sender.np
     end = float(plan["seconds"])
     sender.wait_go()
+    probe = Probe(sender, end)
     lock = threading.Lock()   # one generator of inputs, several clients
 
     def one(start: float, sessions) -> list[dict]:
@@ -154,7 +200,8 @@ def run_sessions(sender: Sender, plan: dict) -> dict:
     with cf.ThreadPoolExecutor(len(plan["clients"])) as pool:
         futures = [pool.submit(one, c["start"], c["sessions"])
                    for c in plan["clients"]]
-        return {"sessions": [r for f in futures for r in f.result()]}
+        return {"sessions": [r for f in futures for r in f.result()],
+                "generator": probe.result()}
 
 
 LOOPS = {"open_loop": run_open_loop, "sessions": run_sessions}

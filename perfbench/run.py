@@ -18,6 +18,7 @@ from __future__ import annotations
 
 import argparse
 import json
+import math
 import os
 import pathlib
 import re
@@ -32,13 +33,15 @@ REPO = pathlib.Path(__file__).resolve().parents[1]
 if str(REPO) not in sys.path:
     sys.path.insert(0, str(REPO))
 
-from perfbench import metrics, server as srv, spans, stats, trace_reduce  # noqa: E402
+from perfbench import (host_track, metrics, server as srv, spans,  # noqa: E402
+                       stats, trace_reduce)
 from perfbench.traffic import build_plan  # noqa: E402
 
 PLATFORM = "tpu"
 WORK = REPO / ".perfbench"    # fixed: exports, compile cache, run files
 DEADLINE_S = 1150.0           # a first run may take 1200 s, compile included
-TRACE_RING = 65536            # traced runs keep every request's spans
+TRACE_RING = 65536            # traced runs keep every request's spans,
+                              # times the mix's `window_scale`
 
 
 def fail(message: str, code: int = 2) -> "NoReturn":
@@ -105,28 +108,66 @@ def ensure_export(config: dict, config_file: pathlib.Path) -> pathlib.Path:
     return out
 
 
-def boot(spec: dict, trace_ring: int):
-    """Export (or find it), boot the server, refuse any device but the
-    cell's; returns (server, export directory, device, seconds spent on
-    export and on boot)."""
-    config = spec["config"]
-    run_dir = WORK / "run"
-    shutil.rmtree(run_dir, ignore_errors=True)
-    (run_dir / "profile").mkdir(parents=True)
-    clock = time.monotonic()
-    export_dir = ensure_export(config, spec["config_file"])
-    export_s = time.monotonic() - clock
-    server = srv.Server(run_dir, export_dir, config["serve"],
-                        platform=PLATFORM, cache_dir=WORK / "jax_cache",
+def cache_dir() -> pathlib.Path:
+    """Where the server keeps JAX's persistent compilation cache: where
+    the environment says, else the benchmark's own fixed directory."""
+    return pathlib.Path(os.environ.get("JAX_COMPILATION_CACHE_DIR",
+                                       WORK / "jax_cache"))
+
+
+def cached_programs() -> set[str]:
+    """The cache's entries by name: one `*-cache` file a compiled
+    program. Names and not a count: a cache held to a size drops an old
+    entry for a new one."""
+    return {p.name for p in cache_dir().glob("*-cache")}
+
+
+def start_server(spec: dict, export_dir, trace_ring: int):
+    """Boot the server on the export and refuse any device but the
+    cell's; returns (server, device)."""
+    server = srv.Server(WORK / "run", export_dir, spec["config"]["serve"],
+                        platform=PLATFORM, cache_dir=cache_dir(),
                         trace_ring=trace_ring)
     device = server.device()
     if (device["platform"] != PLATFORM
             or device["count"] < spec["cell"]["chips"]):
         fail(f"the cell asks for {spec['cell']['chips']} {PLATFORM} chip(s); "
              f"JAX reports {device}")
-    return (server, export_dir, device,
-            {"export_s": export_s,
-             "boot_s": time.monotonic() - clock - export_s})
+    return server, device
+
+
+def boot_check_and_warm(spec: dict, trace_ring: int):
+    """Export (or find it), boot, check the answers and warm the cell's
+    shapes; returns (server, export directory, device, verdict, seconds
+    by phase).
+
+    The server that meets the window has LOADED every program from the
+    persistent cache and compiled none: a process that compiled its
+    programs itself answers 10-13% fewer decode steps a second in the
+    same window (PERF.md section 6, PR 45: 872.5 and 899.1 against
+    989-1,025 in the same checkouts' next runs; only `setup_s` told such
+    a run from the others). So where set-up added an entry to the cache,
+    the server is stopped and booted once more, and the check and the
+    warm-up run again on the one that stays. Only a checkout's first run
+    pays that."""
+    run_dir = WORK / "run"
+    shutil.rmtree(run_dir, ignore_errors=True)
+    (run_dir / "profile").mkdir(parents=True)
+    clock = time.monotonic()
+    export_dir = ensure_export(spec["config"], spec["config_file"])
+    spent = {"export_s": time.monotonic() - clock, "boot_s": 0.0,
+             "check_and_warm_s": 0.0, "programs_compiled": []}
+    for last in (False, True):
+        known, clock = cached_programs(), time.monotonic()
+        server, device = start_server(spec, export_dir, trace_ring)
+        booted = time.monotonic()
+        verdict = check_and_warm(server, spec, export_dir, run_dir)
+        spent["boot_s"] += booted - clock
+        spent["check_and_warm_s"] += time.monotonic() - booted
+        spent["programs_compiled"].append(len(cached_programs() - known))
+        if last or not spent["programs_compiled"][-1]:
+            return server, export_dir, device, verdict, spent
+        server.terminate()
 
 
 def alias_workaround(server) -> dict | None:
@@ -204,13 +245,16 @@ def verify_deferred(spec, export_dir, run_dir, verdict: dict) -> None:
         dict(os.environ, JAX_PLATFORMS="cpu"))
     later = (json.loads(found.read_text()) if child.wait() == 0
              else {"ok": False, "verify_child_rc": child.returncode})
-    verdict["ok"] = bool(verdict["ok"] and later.pop("ok"))
+    # Popped first, whatever the check said: the child's `ok` must never
+    # reach `update` and stand in for a check that failed.
+    later_ok = later.pop("ok")
+    verdict["ok"] = bool(verdict["ok"] and later_ok)
     verdict.update(later)
     verdict.setdefault("seconds", {})["verify"] = time.monotonic() - clock
 
 
 # ---------------------------------------------------------------------------
-# The window: one load-generator worker
+# The window: one load-generator worker, and what the host did meanwhile
 
 
 def run_worker(server, spec, plan, seed: int, seconds: float, run_dir,
@@ -242,10 +286,70 @@ def run_worker(server, spec, plan, seed: int, seconds: float, run_dir,
     def collect() -> dict:
         if proc.wait(timeout=seconds + lead_s + 300) != 0:
             fail(f"the load-generator worker exited rc={proc.returncode}")
-        return {"requests": [], "sessions": [],
+        return {"requests": [], "sessions": [], "generator": None,
                 **json.loads(out_file.read_text())}
 
     return t0, collect
+
+
+def process_cpu_s(pid: int) -> float | None:
+    """User + system CPU seconds of a process and its threads so far
+    (`/proc/<pid>/stat`); None where the host does not say."""
+    try:
+        fields = pathlib.Path(f"/proc/{pid}/stat").read_text() \
+            .rsplit(")", 1)[1].split()
+        return (int(fields[11]) + int(fields[12])) / os.sysconf("SC_CLK_TCK")
+    except (OSError, ValueError, IndexError):
+        return None
+
+
+def host_snapshot(server) -> dict:
+    """One reading of what the window's whole length is judged by: the
+    server's own counters (`/monitoring/runtime`), its CPU seconds as
+    the host books them, the load average."""
+    runtime = server.runtime()
+    return {"at": time.monotonic(), "runtime": runtime,
+            "server_cpu_s": process_cpu_s(server.proc.pid),
+            "loadavg": list(os.getloadavg())}
+
+
+def whole_window(opened: dict, closed: dict, records: dict,
+                 seconds: float) -> dict:
+    """The host over the WHOLE window, traced or not (a capture sees 4 s
+    of a profiled server): the collector's pauses by generation, the
+    event loop's CPU share (the server's own figure: its last minute at
+    the close) and its stalls, the server's CPU in cores, the load
+    generator's own account (loadgen.py's Probe), the load average, and
+    the rate in each eighth of the window."""
+    span = closed["at"] - opened["at"]
+    gc0 = opened["runtime"].get("gc_pause_seconds", {})
+    gc1 = closed["runtime"].get("gc_pause_seconds", {})
+    grpc0 = opened["runtime"].get("grpc", {})
+    grpc1 = closed["runtime"].get("grpc", {})
+    cpu0, cpu1 = opened["server_cpu_s"], closed["server_cpu_s"]
+    done = ([(t, 1) for s in records["sessions"] for t in s["steps"]]
+            + [(r["done"], r["outputs"]) for r in records["requests"]
+               if r["ok"]])
+    eighth = seconds / 8
+    return {
+        "span_s": span,
+        "gc_pause_s": {gen: gc1[gen] - gc0.get(gen, 0.0) for gen in gc1},
+        "event_loop_cpu_share_last_minute": grpc1.get("event_loop_cpu_share"),
+        "event_loop_stalls": (grpc1.get("lag_over_threshold", 0)
+                              - grpc0.get("lag_over_threshold", 0)),
+        # The server's longest stall since its boot, at the opening and
+        # at the close: where the generator's probe ran seconds late and
+        # this did not move, the stall was the generator's alone.
+        "event_loop_lag_max_ms": [grpc0.get("event_loop_lag_max_ms"),
+                                  grpc1.get("event_loop_lag_max_ms")],
+        "server_cpu_cores": None if cpu0 is None or cpu1 is None
+        else (cpu1 - cpu0) / span,
+        "generator": records["generator"],
+        "loadavg": closed["loadavg"],
+        "cpu_count": len(os.sched_getaffinity(0)),
+        "outputs_per_s_by_eighth": [
+            stats.rate_per_s(done, k * eighth, (k + 1) * eighth)
+            for k in range(8)]}
 
 
 def capture_trace(server, t0: float, seconds: float, trace_seconds: float):
@@ -324,19 +428,37 @@ def steadiness(records: dict, seconds: float, page_tokens: int) -> dict:
             "width_share": {str(w): n / 200 for w, n in sorted(widths.items())}}
 
 
+def checked(verdict: dict, config: dict, compiled: int, rc: int) -> dict:
+    """Each number `correct` was decided from, beside the limits the
+    configuration's file sets for them (short plain names, no lists):
+    what the driver keeps of a run that was not correct."""
+    numbers = {k: v for k, v in verdict.items()
+               if isinstance(v, (int, float, bool)) and k != "ok"}
+    limits = {k: v for k, v in config.get("correctness", {}).items()
+              if isinstance(v, (int, float))}
+    return {"read": {**numbers, "compiles_in_window": compiled,
+                     "server_exit_code": rc},
+            "limits": {**limits, "compiles_in_window": 0,
+                       "server_exit_code": 0}}
+
+
 def result_line(*, correct: bool, attempted: int, failed: int,
                 metric_values: dict, device: dict,
-                reduced: dict | None = None) -> dict:
+                reduced: dict | None = None, named_gaps=None,
+                compared: dict | None = None) -> dict:
     """The one object the driver reads, printed as the last line. A
     traced run (`reduced` given) adds the device's busy and window
-    seconds and the breakdown."""
+    seconds and the breakdown, its gaps named where the capture has a
+    host track (`named_gaps`)."""
     result = {"correct": bool(correct), "attempted": int(attempted),
               "failed": int(failed), "metrics": metric_values,
               "device": dict(device)}
     if reduced is not None:
         result["device"].update(busy_s=reduced["busy_s"],
                                 window_s=reduced["window_s"])
-        result["breakdown"] = trace_reduce.breakdown(reduced)
+        result["breakdown"] = trace_reduce.breakdown(reduced, named_gaps)
+    if compared is not None:
+        result["checked"] = compared   # the line's last key
     return result
 
 
@@ -344,15 +466,17 @@ def run_cell(args) -> int:
     t_start = time.monotonic()
     spec = load_cell(args.workload)
     config, traffic = spec["config"], spec["traffic"]
-    seconds = float(args.seconds)
+    # A mix may ask for a window of `window_scale` times --seconds: a
+    # closed loop judged on a rate wants the length (the collector's
+    # pauses, a stall of the host), and pays for it in this cell alone.
+    scale = float(traffic.get("window_scale", 1))
+    seconds = float(args.seconds) * scale
     run_dir, bench = WORK / "run", spec["bench"]
-    server, export_dir, device, spent = boot(
-        spec, TRACE_RING if args.trace else 0)
-    t_boot = time.monotonic()
+    server, export_dir, device, verdict, spent = boot_check_and_warm(
+        spec, math.ceil(TRACE_RING * scale) if args.trace else 0)
+    t_warm = time.monotonic()
     peak = peak_for(device["kind"])
     aliases = alias_workaround(server)
-    verdict = check_and_warm(server, spec, export_dir, run_dir)
-    t_warm = time.monotonic()
 
     lead = float(traffic.get("ramp_s", traffic.get("lead_in_s", 0.0)))
     plan = build_plan(traffic, args.seed, seconds)
@@ -362,18 +486,22 @@ def run_cell(args) -> int:
     t0, collect = run_worker(server, spec, plan, args.seed, seconds,
                              run_dir, lead)
     setup_s = t0 - t_start
+    time.sleep(max(0.0, t0 - time.monotonic()))
+    opened = host_snapshot(server)
     capture = None
     if args.trace:
         capture = capture_trace(server, t0, seconds,
                                 float(traffic["trace_seconds"]))
+    time.sleep(max(0.0, t0 + seconds - time.monotonic()))
+    closed = host_snapshot(server)
     records = collect()
     runtime_after = server.runtime()
     compiled = runtime_after["compile"]["total_compiles"] - compiles_before
     device = server.device(runtime_after)
     requests, reduced = [], None
     if args.trace:
-        first_sent = min(r["sent"] for rows in records.values()
-                         for r in rows)
+        first_sent = min(r["sent"] for r in records["requests"]
+                         + records["sessions"])
         requests = spans.in_window(
             spans.requests_from_chrome(server.rest("/monitoring/traces")),
             traced_before, first_sent, seconds)
@@ -389,11 +517,11 @@ def run_cell(args) -> int:
               trace=reduced, capture=capture, peak=peak,
               memory_peak_bytes=device["memory_peak_bytes"])
     attempted, failed = attempted_failed(records, seconds)
-    info = {"setup": {**spent, "check_and_warm_s": t_warm - t_boot,
-                      "workers_and_lead_in_s": t0 - t_warm},
+    info = {"setup": {**spent, "workers_and_lead_in_s": t0 - t_warm},
             "check": verdict, "compiles_in_window": compiled,
             "server_exit_code": rc, "cache_dir": server.cache_dir,
-            "alias_workaround": aliases}
+            "alias_workaround": aliases,
+            "whole_window": whole_window(opened, closed, records, seconds)}
     if records["sessions"]:
         info["steadiness"] = steadiness(
             records, seconds,
@@ -414,10 +542,16 @@ def run_cell(args) -> int:
     result = result_line(
         correct=bool(verdict["ok"]) and compiled == 0 and rc == 0,
         attempted=attempted, failed=failed, device=device, reduced=reduced,
+        named_gaps=(host_track.of_run(run) or {}).get("gaps"),
+        compared=checked(verdict, config, compiled, rc),
         metric_values=metrics.read_all(
             spec["per_layer"] if args.trace else spec["end_to_end"],
             run, bench))
     srv.stop_all()
+    print("perfbench: compared "
+          + json.dumps(result["checked"]["read"]) + "\nperfbench: limits "
+          + json.dumps(result["checked"]["limits"]), file=sys.stderr,
+          flush=True)
     print(json.dumps(result), flush=True)
     return 0
 
@@ -433,8 +567,7 @@ def run_sweep(args) -> int:
         fail("only an open-loop cell has a knee to sweep for")
     step_s = float(args.seconds)
     run_dir = WORK / "run"
-    server, export_dir, device, _ = boot(spec, TRACE_RING)
-    verdict = check_and_warm(server, spec, export_dir, run_dir)
+    server, _, device, verdict, _ = boot_check_and_warm(spec, TRACE_RING)
     table = []
     lead = float(traffic.get("lead_in_s", 0.0))
     for k, rate in enumerate(float(r) for r in args.rates.split(",")):

@@ -16,6 +16,7 @@ import statistics
 MODULES_LINE = "XLA Modules"
 OPS_LINE = "XLA Ops"
 _SUFFIX = re.compile(r"\.\d+$")
+UNATTRIBUTED = "unattributed"
 
 
 def instruction(name: str) -> str:
@@ -91,8 +92,8 @@ def reduce(trace: dict, window_s: float | None = None) -> dict | None:
         "window_s": window,
         "modules": modules,
         "ops": ops,
-        # The host's spans and this trace are not on one clock, so no gap
-        # can be given the name of what the host was doing in it.
+        # Gaps in the union of operations, inside programs too; they get
+        # names only through host_track.py, which joins the clocks.
         "idle_gaps": sorted(gaps, reverse=True)[:10],
     }
 
@@ -139,12 +140,20 @@ def roofline_share(seconds_per_call: float, flops: float, bytes_moved: float,
     return least / seconds_per_call
 
 
-def breakdown(reduced: dict) -> dict:
+def breakdown(reduced: dict, named_gaps=None) -> dict:
     """What the next issue's writer sees: the device operations that
-    took most time (instances of one name summed) and the longest gaps."""
+    took most time (instances of one name summed) and the longest gaps.
+    `named_gaps` are perfbench/host_track.py's `timeline(...)["gaps"]`,
+    (name, start s, seconds) longest first: the gaps between two
+    programs, each with the name of what the host was doing in it. Where
+    the program wrote no host track the gaps of this trace alone stand,
+    and those have no name."""
     totals = sorted(((name, sum(times))
                      for name, times in reduced["ops"].items()),
                     key=lambda kv: kv[1], reverse=True)[:10]
+    if named_gaps:
+        gaps = [[name, seconds] for name, _, seconds in named_gaps[:10]]
+    else:
+        gaps = [[UNATTRIBUTED, g] for g in reduced["idle_gaps"]]
     return {"device_ops": [[name, t] for name, t in totals],
-            "idle_gaps": [["unattributed", g]
-                          for g in reduced["idle_gaps"]]}
+            "idle_gaps": gaps}

@@ -4,7 +4,8 @@ parameters (perfbench/traffic/<name>.json); this code reads it.
 What makes runs comparable: a seed ORDERS the work and never DRAWS it.
 Each length law is a fixed grid of quantiles. The seed permutes the grid
 (a fresh permutation per pass, so any 64 consecutive requests hold the
-whole law, heavy tail included) and shapes the gaps of an open loop; the
+whole law, heavy tail included) and places each arrival of an open loop
+inside its own slot of 1 / rate (no clumps: these are steady mixes); the
 number of arrivals in the window is fixed by the rate. Two seeds offer
 the same multiset of sizes and the same count, in another order.
 
@@ -44,13 +45,14 @@ def ordered(grid, n: int, rng: np.random.Generator) -> list[int]:
 
 
 def arrivals(n: int, seconds: float, rng: np.random.Generator) -> list[float]:
-    """`n` due times in [0, seconds): exponential gaps, scaled so that
-    exactly n fall in the window. The seed shapes the gaps, not the count."""
+    """`n` due times in [0, seconds), one inside each slot of
+    `seconds / n`: the i-th lies in [i, i + 1) slots, and the seed places
+    it there. The count and the spacing are the rate's; the seed moves
+    every arrival by up to one slot and never clumps them."""
     if n <= 0:
         return []
-    gaps = rng.exponential(1.0, n + 1)
-    times = np.cumsum(gaps)[:-1] / float(np.sum(gaps)) * seconds
-    return [float(t) for t in times]
+    slot = seconds / n
+    return [float((i + u) * slot) for i, u in enumerate(rng.random(n))]
 
 
 def build_plan(traffic: dict, seed: int, seconds: float,

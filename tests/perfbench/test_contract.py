@@ -135,6 +135,25 @@ def test_the_result_line_has_the_keys_the_driver_reads():
     json.dumps(traced)
 
 
+def test_the_line_ends_with_each_number_compared_beside_its_limit():
+    verdict = {"ok": True, "seconds": {"verify": 13.0},
+               "encoding_max_abs_diff": 0.105, "tokens_equal": 1.0,
+               "first_logits_diff_by_row": [0.02, 0.05]}
+    config = {"correctness": {"encoding_atol": 0.25, "why": "words"}}
+    compared = run.checked(verdict, config, compiled=0, rc=0)
+    assert compared == {
+        "read": {"encoding_max_abs_diff": 0.105, "tokens_equal": 1.0,
+                 "compiles_in_window": 0, "server_exit_code": 0},
+        "limits": {"encoding_atol": 0.25, "compiles_in_window": 0,
+                   "server_exit_code": 0}}
+    line = run.result_line(
+        correct=True, attempted=4, failed=0, metric_values={},
+        device={"platform": "tpu", "kind": "TPU v5 lite", "count": 1,
+                "memory_peak_bytes": 1}, compared=compared)
+    assert list(line)[-1] == "checked"
+    json.dumps(line)
+
+
 def test_the_command_refuses_to_run_without_a_tpu():
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     proc = subprocess.run(

@@ -62,7 +62,9 @@ def test_the_benchmark_lists_it_for_the_sessions_cell_only():
                      "better": "higher", "source": "program_span",
                      "layer": "decode pool", "moves": "outputs_per_s",
                      "workloads": ["t5-large.sessions"]}
-    assert BENCH["per_layer"][-1] is entry  # appended, nothing moved
+    names = [m["name"] for m in BENCH["per_layer"]]
+    # appended behind what was there when it came, nothing moved
+    assert names.index("tick_ahead_share") == names.index("idle_named") + 1
 
 
 def test_a_line_leaves_it_out_where_there_is_nothing_to_read():

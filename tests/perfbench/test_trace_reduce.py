@@ -93,6 +93,37 @@ def test_breakdown_sums_the_instances_of_a_name_and_names_no_gap():
     assert len(got["device_ops"]) <= 10 and len(got["idle_gaps"]) <= 10
 
 
+def test_breakdown_takes_the_names_of_the_gaps_it_is_given():
+    gaps = [("observe/drain", 1.5, 0.126), ("host/gc", 0.2, 0.051)] \
+        + [("decode/fetch", float(k), 0.01) for k in range(12)]
+    got = trace_reduce.breakdown(trace_reduce.reduce(HAND), gaps)
+    assert got["idle_gaps"][:2] == [["observe/drain", 0.126],
+                                    ["host/gc", 0.051]]
+    assert len(got["idle_gaps"]) == 10
+
+
+def test_breakdown_names_the_gaps_of_the_ticks_recorded_on_the_v5e():
+    """The recorded capture (data/README_timeline.txt) through
+    host_track's rule, as run.py feeds it: each of the longest gaps
+    between two programs has the name that was worked out by hand."""
+    from perfbench import host_timeline, host_track
+
+    cut = json.loads(gzip.decompress(
+        (DATA / "v5e_sessions_timeline.json.gz").read_bytes()))
+    want = json.loads(
+        (DATA / "v5e_sessions_timeline.expected.json").read_text())
+    track = {"requests": cut["requests"], "process": [],
+             "spans": host_timeline.distinct(cut["requests"])}
+    found = host_track.timeline_of(track, cut["events"], cut["host_clock"],
+                                   "jit_direct_tick_fn")
+    got = trace_reduce.breakdown(trace_reduce.reduce(cut["events"]),
+                                 found["gaps"])
+    longest = want["longest_between"]
+    assert got["idle_gaps"][:len(longest)] == [
+        [name, pytest.approx(seconds)] for name, _, seconds in longest]
+    assert all(name != "unattributed" for name, _ in got["idle_gaps"])
+
+
 def test_no_operation_on_a_device_reduces_to_nothing():
     assert trace_reduce.reduce(trace([], [])) is None
     host_only = {"names": ["x"], "planes": [
