@@ -21,7 +21,7 @@ from typing import Mapping
 import jax
 import numpy as np
 
-FAMILIES = ("bert", "t5", "resnet", "use", "mimo")
+FAMILIES = ("bert", "t5", "resnet", "use", "mimo", "granite_hybrid")
 
 _SERVABLE_STUB = """\
 # Auto-generated by min_tfs_client_tpu.models.export — loads a native
@@ -390,4 +390,13 @@ def build_family_signatures(family, config_kwargs, sig_kwargs, params, *,
         config = mimo.MimoConfig(**config_kwargs)
         return _attach_mesh(
             mimo.build_signatures(params, config, **sig_kwargs), mesh)
+    if family == "granite_hybrid":
+        from min_tfs_client_tpu.models import granite_hybrid
+
+        if "batch_buckets" in sig_kwargs:
+            sig_kwargs["batch_buckets"] = tuple(sig_kwargs["batch_buckets"])
+        config = granite_hybrid.GraniteHybridConfig(**config_kwargs)
+        return _attach_mesh(
+            granite_hybrid.build_signatures(params, config, **sig_kwargs),
+            mesh)
     raise ValueError(f"unknown family {family!r}")  # pragma: no cover

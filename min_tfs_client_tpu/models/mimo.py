@@ -383,15 +383,19 @@ def prefill(params: dict, config: MimoConfig, input_ids: jax.Array, *,
 
 
 def _attend_cache(q: jax.Array, cache: dict, seen: jax.Array,
-                  sink: jax.Array | None) -> jax.Array:
+                  sink: jax.Array | None,
+                  scale: float | None = None) -> jax.Array:
     """One query row a head over a cache, in plain jnp: q (B, H, dk),
     cache k (B, kv, S, dk) / v (B, kv, S, dv), `seen` (B, S) bool the
-    rows this example's query may read. -> (B, H * dv) in q's dtype."""
+    rows this example's query may read; scores times `scale` (dk ** -0.5
+    where none is given). -> (B, H * dv) in q's dtype."""
     b, h, dk = q.shape
+    if scale is None:
+        scale = dk ** -0.5
     kv = cache["k"].shape[1]
     scores = jnp.einsum("bngd,bnsd->bngs", q.reshape(b, kv, h // kv, dk),
                         cache["k"], preferred_element_type=jnp.float32)
-    scores = jnp.where(seen[:, None, None, :], scores * dk ** -0.5, NEG_INF)
+    scores = jnp.where(seen[:, None, None, :], scores * scale, NEG_INF)
     top = jnp.max(scores, axis=-1, keepdims=True)
     if sink is not None:
         sink = sink.astype(jnp.float32).reshape(1, kv, h // kv, 1)

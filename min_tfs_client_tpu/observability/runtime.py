@@ -335,6 +335,27 @@ def route_totals() -> dict:
                 for label, totals in _route_counts.items()}
 
 
+_state_counts: dict[str, dict[str, int]] = {}   # guarded_by: _lock
+
+
+def count_state(label: str, counts: Mapping[str, int]) -> None:
+    """Accumulate what a model with a recurrent state counted for one
+    answered request (prompt tokens, rows its chunked scan ran, bytes of
+    state held through the loop, decode steps), under the model's label:
+    `state` in /monitoring/runtime."""
+    with _lock:
+        totals = _state_counts.setdefault(label, {"requests": 0})
+        totals["requests"] += 1
+        for name, value in counts.items():
+            totals[name] = totals.get(name, 0) + int(value)
+
+
+def state_totals() -> dict:
+    with _lock:
+        return {label: dict(totals)
+                for label, totals in _state_counts.items()}
+
+
 def transfer_totals() -> dict:
     try:
         from min_tfs_client_tpu.server import metrics
@@ -401,6 +422,7 @@ def snapshot(include_live_arrays: bool = False) -> dict:
         "pipeline": pipeline_stats(),
         "kv_pool": kv_pool_stats(),
         "route": route_totals(),
+        "state": state_totals(),
         # The gRPC front end: requests answered on the event loop and on
         # the worker pool, and the loop's sampled lag (utils/aio_loop.py).
         "grpc": aio_loop.stats(),

@@ -194,6 +194,11 @@ STAGES = (
     # no duration, its arguments are the numbers (input_tokens,
     # blocks_read, blocks_held).
     "generate/cross",
+    # A whole generation's recurrent state (models/granite_hybrid.py), on
+    # the request's own trace after its batch was split: no duration, its
+    # arguments are the numbers (prompt_tokens, scan_rows, state_bytes,
+    # steps).
+    "generate/state",
     "serving/serialize",
 )
 

@@ -163,7 +163,7 @@ def moe_ffn_reference(params: MoeParams, x: jax.Array) -> jax.Array:
 
 class HeldExperts(NamedTuple):
     router: jax.Array  # (D, E) over ALL experts
-    bias: jax.Array    # (E,) the selection's correction bias (noaux_tc)
+    bias: jax.Array | None  # (E,) the sigmoid selection's correction bias
     w_in: jax.Array    # (held, D, 2 F): gate and up, side by side
     w_out: jax.Array   # (held, F, D)
 
@@ -206,16 +206,38 @@ def sigmoid_top_k(x: jax.Array, router: jax.Array, bias: jax.Array,
             chosen / jnp.sum(chosen, axis=-1, keepdims=True))
 
 
+def softmax_top_k(x: jax.Array, router: jax.Array,
+                  top_k: int) -> tuple[jax.Array, jax.Array]:
+    """Logits x W over all experts in float32 at full matmul precision
+    (as `sigmoid_top_k`); the top_k logits are chosen, and the weights
+    are the softmax of the chosen logits alone. -> (experts (T, k) int32,
+    weights (T, k) float32)."""
+    logits = jnp.dot(x.astype(jnp.float32), router.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    chosen, experts = jax.lax.top_k(logits, top_k)
+    return experts.astype(jnp.int32), jax.nn.softmax(chosen, axis=-1)
+
+
+ROUTINGS = ("sigmoid", "softmax_top_k")
+
+
 def held_experts_ffn(params: HeldExperts, x: jax.Array, *, top_k: int,
                      experts_held: int, expert_offset: int,
                      valid: jax.Array | None = None,
                      rows: jax.Array | None = None,
                      onto: jax.Array | None = None,
-                     row_block: int = ROW_BLOCK
+                     row_block: int = ROW_BLOCK,
+                     routing: str = "sigmoid",
+                     scale: float | None = None
                      ) -> tuple[jax.Array, Routed]:
     """x (T, D) -> (y (T, D) float32, Routed): y = sum over a token's
     chosen experts e in [expert_offset, expert_offset + experts_held) of
     w_e * SwiGLU_e(x). A token none of whose choices is held gets 0.
+    `routing` (static, the model's to say from its published config) is
+    the rule that gives the choices and the w_e: `sigmoid`
+    (`sigmoid_top_k`, with the selection bias) or `softmax_top_k`.
+    `scale` multiplies every w_e (a model's residual multiplier, so that
+    `onto` can be its residual stream).
     `valid` (T,) bool leaves padding rows out of the routing altogether.
     `rows` (a traced count) says that only the leading `rows` rows are
     real: the router then runs in blocks of `row_block` rows, as many as
@@ -234,17 +256,22 @@ def held_experts_ffn(params: HeldExperts, x: jax.Array, *, top_k: int,
     t, _ = x.shape
     if params.w_in.shape[0] != experts_held:
         raise ValueError("w_in holds another number of experts than held")
+    if routing not in ROUTINGS:
+        raise ValueError(f"unknown routing {routing!r}; known: {ROUTINGS}")
+
+    def choose(some):
+        if routing == "sigmoid":
+            return sigmoid_top_k(some, params.router, params.bias, top_k)
+        return softmax_top_k(some, params.router, top_k)
+
     if rows is None:
-        experts, weights = sigmoid_top_k(x, params.router, params.bias,
-                                         top_k)
+        experts, weights = choose(x)
     else:
         step = min(row_block, t)
 
         def route(i, found):
             lo = jnp.minimum(i * step, t - step)   # the last block may lap
-            some = sigmoid_top_k(
-                jax.lax.dynamic_slice_in_dim(x, lo, step), params.router,
-                params.bias, top_k)
+            some = choose(jax.lax.dynamic_slice_in_dim(x, lo, step))
             return tuple(jax.lax.dynamic_update_slice_in_dim(all_, part, lo, 0)
                          for all_, part in zip(found, some))
 
@@ -254,6 +281,8 @@ def held_experts_ffn(params: HeldExperts, x: jax.Array, *, top_k: int,
              jnp.zeros((t, top_k), jnp.float32)))
         real = jnp.arange(t) < rows
         valid = real if valid is None else jnp.logical_and(valid, real)
+    if scale is not None:
+        weights = weights * scale
     local = experts - expert_offset
     held = jnp.logical_and(local >= 0, local < experts_held)
     if valid is not None:

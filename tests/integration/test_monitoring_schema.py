@@ -24,8 +24,8 @@ pytestmark = pytest.mark.integration
 SERVER_SCHEMAS = {
     "/monitoring/slo": {"default_objective", "dropped_keys", "entries"},
     "/monitoring/runtime": {"compile", "devices", "transfer", "profiler",
-                            "pipeline", "kv_pool", "route", "grpc",
-                            "gc_pause_seconds"},
+                            "pipeline", "kv_pool", "route", "state",
+                            "grpc", "gc_pause_seconds"},
     "/monitoring/sessions": {"pools"},
     "/monitoring/costs": {"schema", "window_s", "context", "dropped_keys",
                           "entries", "tick_utilization", "log"},

@@ -110,3 +110,53 @@ def test_program_copies_no_arena(which, pool, v5e, monkeypatch):
     copies, unaliased = _arena_faults(pool, compiled)
     assert not copies, copies
     assert not unaliased, unaliased
+
+
+# -- the state-space kernels at the published widths --------------------------
+#
+# In this file because it is the one that describes the v5e (one worker
+# loads libtpu; a second file's fixture would skip in silence).
+
+
+def _ssm_shapes(v5e, batch, seq):
+    """granite-4.0-h-small's state-space layer: 128 heads x 64, state 128."""
+    import jax.numpy as jnp
+
+    def struct(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    heads, channels, state = 128, 8192, 128
+    lead = (batch,) if seq is None else (batch, seq)
+    return {"x": struct(lead + (channels,), jnp.bfloat16),
+            "dt": struct(lead + (heads,), jnp.float32),
+            "a": struct((heads,), jnp.float32),
+            "bm": struct(lead + (state,), jnp.bfloat16),
+            "cm": struct(lead + (state,), jnp.bfloat16),
+            "d": struct((heads,), jnp.float32),
+            "lengths": struct((batch,), jnp.int32),
+            "state": struct((batch, state, channels), jnp.float32)}
+
+
+def test_the_chunked_scan_compiles_for_the_v5e_at_the_published_widths(v5e):
+    from min_tfs_client_tpu.ops import ssm
+
+    s = _ssm_shapes(v5e, 4, 2048)
+    compiled = jax.jit(lambda *a: ssm.ssd_scan(*a, chunk=256)).lower(
+        s["x"], s["dt"], s["a"], s["bm"], s["cm"], s["d"],
+        s["lengths"]).compile()
+    assert "_ssd_kernel" in compiled.as_text()
+
+
+def test_the_step_compiles_for_the_v5e_and_writes_the_state_in_place(v5e):
+    from min_tfs_client_tpu.ops import ssm
+
+    s = _ssm_shapes(v5e, 32, None)
+    compiled = jax.jit(ssm.ssm_step_kernel, donate_argnums=(0,)).lower(
+        s["state"], s["x"], s["dt"], s["a"], s["bm"], s["cm"],
+        s["d"]).compile()
+    text = compiled.as_text()
+    assert "_ssm_step_kernel" in text
+    # the donated state is the output's buffer, and no copy of it is made
+    assert "may-alias" in text[:text.index("\n")] \
+        or "must-alias" in text[:text.index("\n")]
+    assert not re.search(r"= f32\[32,128,8192\]\S* copy\(", text)
