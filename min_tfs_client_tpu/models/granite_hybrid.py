@@ -48,8 +48,12 @@ from min_tfs_client_tpu.parallel.moe import HeldExperts, held_experts_ffn
 
 # What `generate/state` carries, one row an example: its prompt tokens,
 # the rows the chunked scan ran for it in one state-space layer, the
-# bytes of state it holds through the loop, its decode steps.
-STATE_COUNTS = ("prompt_tokens", "scan_rows", "state_bytes", "steps")
+# bytes of state it holds through the loop, its decode steps; and of its
+# BATCH, on every row: the (row, state-space layer, step) recurrent states
+# the decode steps held and those they moved (the rows the batch owns).
+STATE_COUNTS = ("prompt_tokens", "scan_rows", "state_bytes", "steps",
+                "state_rows_held", "state_rows_moved")
+STATE_BATCH_COUNTS = ("state_rows_held", "state_rows_moved")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -465,7 +469,9 @@ def prefill(params: dict, config: GraniteHybridConfig, input_ids: jax.Array,
                    "load_total": jnp.sum(load),
                    "prefill_rows": jnp.sum(ran),
                    "hit_decode": jnp.zeros((), jnp.int32),
-                   "scan_rows": merge(scanned).astype(jnp.int32)},
+                   "scan_rows": merge(scanned).astype(jnp.int32),
+                   "state_rows_held": jnp.zeros((), jnp.int32),
+                   "state_rows_moved": jnp.zeros((), jnp.int32)},
     }
 
 
@@ -479,7 +485,8 @@ def step(params: dict, config: GraniteHybridConfig, state: dict):
     the token's row and moves its recurrent state one step, where it
     lies; the attention layer writes its cache at the example's own
     position. A prompt of length 0 (a row that pads the batch) is routed
-    to no expert."""
+    to no expert, and its recurrent states are neither read nor
+    written."""
     token = jnp.argmax(state["logits"], axis=-1).astype(jnp.int32)
     token = jnp.where(state["finished"], config.pad_id, token)
     finished = jnp.logical_or(state["finished"], token == config.eos_id)
@@ -490,6 +497,7 @@ def step(params: dict, config: GraniteHybridConfig, state: dict):
     caches, held = [], jnp.zeros((b,), jnp.int32)
     hit = jnp.zeros((), jnp.int32)
     owned = state["counts"]["prompt_tokens"] > 0
+    states_held = states_moved = jnp.zeros((), jnp.int32)
     for kind, layer, cache in zip(config.layer_types, params["layers"],
                                   state["caches"]):
         x = _norm(layer["norm"], h, config)
@@ -503,7 +511,9 @@ def step(params: dict, config: GraniteHybridConfig, state: dict):
                 + p["conv_bias"]).astype(pre.dtype)
             moved, y = ssm.ssm_step(
                 cache["ssm"], mixed[:, :di], dt, -jnp.exp(p["a_log"]),
-                mixed[:, di:di + n], mixed[:, di + n:], p["d"])
+                mixed[:, di:di + n], mixed[:, di + n:], p["d"], owned=owned)
+            states_held = states_held + b
+            states_moved = states_moved + jnp.sum(owned, dtype=jnp.int32)
             caches.append({"conv": seen[:, 1:], "ssm": moved})
             h = h + _gated_out(config, p, y, z)
         else:
@@ -525,6 +535,8 @@ def step(params: dict, config: GraniteHybridConfig, state: dict):
     counts["held_decode"] = counts["held_decode"] + held
     counts["hit_decode"] = counts["hit_decode"] + hit
     counts["steps"] = counts["steps"] + 1
+    counts["state_rows_held"] = counts["state_rows_held"] + states_held
+    counts["state_rows_moved"] = counts["state_rows_moved"] + states_moved
     return {"caches": caches, "length": position + 1,
             "logits": _logits(params, config, h), "token": token[:, None],
             "finished": finished, "counts": counts}, token
@@ -532,13 +544,16 @@ def step(params: dict, config: GraniteHybridConfig, state: dict):
 
 def state_counts(config: GraniteHybridConfig, state: dict) -> jax.Array:
     """(B, len(STATE_COUNTS)) int32, one row an example: what
-    `generate/state` carries."""
+    `generate/state` carries (the batch's figures on every row)."""
     counts = state["counts"]
     columns = {"prompt_tokens": counts["prompt_tokens"],
                "scan_rows": counts["scan_rows"],
                "state_bytes": jnp.full_like(counts["steps"],
                                             config.state_bytes),
-               "steps": counts["steps"]}
+               "steps": counts["steps"],
+               **{name: jnp.broadcast_to(counts[name],
+                                         counts["steps"].shape)
+                  for name in STATE_BATCH_COUNTS}}
     return jnp.stack([columns[name].astype(jnp.int32)
                       for name in STATE_COUNTS], axis=-1)
 
@@ -551,15 +566,20 @@ def note_answer(signature, outputs) -> None:
     models/mimo.py notes it, and a request's own rows of `state_counts`
     as the span `generate/state` on its trace and into the process's
     counters (`/monitoring/runtime`, `state`, under the signature's
-    label)."""
+    label). The batch's figures go as they are, on every rider's span
+    and into every rider's counters: summed over the riders both grow
+    alike, and `state_rows_moved` over `state_rows_held` is what is
+    read."""
     from min_tfs_client_tpu.observability import runtime, tracing
 
     note_route(signature, outputs)
     rows = outputs.get("state_counts")
     if rows is None:
         return
-    sums = np.asarray(rows).reshape(-1, len(STATE_COUNTS)).sum(axis=0)
-    args = {name: int(sums[i]) for i, name in enumerate(STATE_COUNTS)}
+    rows = np.asarray(rows).reshape(-1, len(STATE_COUNTS))
+    args = {name: int(rows[:, i].max() if name in STATE_BATCH_COUNTS
+                      else rows[:, i].sum())
+            for i, name in enumerate(STATE_COUNTS)}
     now = time.perf_counter()
     tracing.add_span("generate/state", now, now, **args)
     runtime.count_state(signature.telemetry_label or "unlabeled", args)

@@ -324,58 +324,106 @@ def _step_operands(x, dt, a, d):
             _per_channel(d.astype(jnp.float32), ch) * xf)
 
 
-def ssm_step_reference(state, x, dt, a, bm, cm, d):
+def ssm_step_reference(state, x, dt, a, bm, cm, d, owned=None):
     """One token: state (B, N, H * P) float32, x (B, H * P), dt (B, H),
-    B and C (B, N). -> (state', y (B, H * P) float32)."""
+    B and C (B, N); `owned` (B,) bool, None for every row: a row that is
+    not owned keeps its state and gives y = 0.
+    -> (state', y (B, H * P) float32)."""
     decay, dtx, dx = _step_operands(x, dt, a, d)
-    state = (decay[:, None, :] * state
+    moved = (decay[:, None, :] * state
              + bm.astype(jnp.float32)[:, :, None] * dtx[:, None, :])
-    return state, jnp.sum(state * cm.astype(jnp.float32)[:, :, None],
-                          axis=1) + dx
+    y = jnp.sum(moved * cm.astype(jnp.float32)[:, :, None], axis=1) + dx
+    if owned is None:
+        return moved, y
+    return (jnp.where(owned[:, None, None], moved, state),
+            jnp.where(owned[:, None], y, 0.0))
 
 
-def _ssm_step_kernel(h_ref, rows_ref, b_ref, c_ref, ho_ref, y_ref):
-    """One (example, channel block) grid cell: h (N, C) read, updated and
-    written where it lies; rows (8, C): the decay, dt x and D x on the
-    first three; B and C as columns (N, 1); y (1, C)."""
-    h = (rows_ref[0:1, :] * h_ref[...] + b_ref[...] * rows_ref[1:2, :])
-    ho_ref[...] = h
-    y_ref[...] = (jnp.sum(h * c_ref[...], axis=0, keepdims=True)
-                  + rows_ref[2:3, :])
+def _ssm_step_kernel(row_ref, count_ref, h_ref, rows_ref, b_ref, c_ref,
+                     ho_ref, y_ref):
+    """One (owned row, channel block) grid cell: h (N, C) read, updated
+    and written where it lies; rows (8, C): the decay, dt x and D x on
+    the first three; B and C as columns (N, 1); y (1, C). row_ref (B,)
+    and count_ref (1,) in SMEM: the owned rows' indices first, and how
+    many they are. A cell past the count names the last real cell's
+    blocks (`ssm_step_kernel`): nothing was fetched for it, it does
+    nothing, and nothing is written after it."""
+    count = count_ref[0]
+
+    @pl.when(pl.program_id(0) < count)
+    def _():
+        h = (rows_ref[0:1, :] * h_ref[...] + b_ref[...] * rows_ref[1:2, :])
+        ho_ref[...] = h
+        y_ref[...] = (jnp.sum(h * c_ref[...], axis=0, keepdims=True)
+                      + rows_ref[2:3, :])
+
+    # no row owned: every cell names ONE block, which the pipeline still
+    # fetches and writes back: it goes back as it came
+    @pl.when(count == 0)
+    def _():
+        ho_ref[...] = h_ref[...]
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def ssm_step_kernel(state, x, dt, a, bm, cm, d, *, interpret: bool = False):
+def ssm_step_kernel(state, x, dt, a, bm, cm, d, owned=None, *,
+                    interpret: bool = False):
     """`ssm_step_reference` as the Pallas kernel, the state written in
-    place (the input's buffer is the output's)."""
+    place (the input's buffer is the output's). The grid walks the OWNED
+    rows, compacted to the front of a prefetched list; the cells behind
+    them name the block the last real cell named, so the pipeline moves
+    nothing for them: a row that is not owned is neither fetched nor
+    written, and keeps its bytes."""
     b, n, ch = state.shape
     block = min(_STEP_CHANNELS, ch)
+    blocks = ch // block
+    if owned is None:
+        owned = jnp.ones((b,), jnp.bool_)
+    row_of = jnp.argsort(jnp.logical_not(owned), stable=True).astype(
+        jnp.int32)
+    count = jnp.sum(owned, dtype=jnp.int32)[None]
     decay, dtx, dx = _step_operands(x, dt, a, d)
     rows = jnp.stack([decay, dtx, dx], axis=1)
     rows = jnp.pad(rows, ((0, 0), (0, 8 - rows.shape[1]), (0, 0)))
     column = lambda v: v.astype(jnp.float32)[:, :, None]  # noqa: E731
+
+    def row(i, row_ref, count_ref):
+        return row_ref[jnp.maximum(jnp.minimum(i, count_ref[0] - 1), 0)]
+
+    def by_channels(i, c, row_ref, count_ref):
+        return (row(i, row_ref, count_ref), 0,
+                jnp.where(i < count_ref[0], c, blocks - 1))
+
+    def whole(i, c, row_ref, count_ref):
+        return (row(i, row_ref, count_ref), 0, 0)
+
     state, y = pl.pallas_call(
         _ssm_step_kernel,
-        grid=(b, ch // block),
-        in_specs=[
-            pl.BlockSpec((None, n, block), lambda e, c: (e, 0, c)),
-            pl.BlockSpec((None, 8, block), lambda e, c: (e, 0, c)),
-            pl.BlockSpec((None, n, 1), lambda e, c: (e, 0, 0)),
-            pl.BlockSpec((None, n, 1), lambda e, c: (e, 0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((None, n, block), lambda e, c: (e, 0, c)),
-            pl.BlockSpec((None, 1, block), lambda e, c: (e, 0, c)),
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(b, blocks),
+            in_specs=[
+                pl.BlockSpec((None, n, block), by_channels),
+                pl.BlockSpec((None, 8, block), by_channels),
+                pl.BlockSpec((None, n, 1), whole),
+                pl.BlockSpec((None, n, 1), whole),
+            ],
+            out_specs=[
+                pl.BlockSpec((None, n, block), by_channels),
+                pl.BlockSpec((None, 1, block), by_channels),
+            ],
+        ),
         out_shape=[jax.ShapeDtypeStruct(state.shape, jnp.float32),
                    jax.ShapeDtypeStruct((b, 1, ch), jnp.float32)],
-        input_output_aliases={0: 0},
+        # operand 2: the two prefetched scalars come first
+        input_output_aliases={2: 0},
+        # a cell past the count revisits a block: no dimension is parallel
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel")),
+            dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
         name="_ssm_step_kernel",  # the device-trace reduction finds it
-    )(state, rows, column(bm), column(cm))
-    return state, y[:, 0, :]
+    )(row_of, count, state, rows, column(bm), column(cm))
+    # a row no cell wrote holds whatever the buffer held
+    return state, jnp.where(owned[:, None], y[:, 0, :], 0.0)
 
 
 def _step_kernel_applies(state: jax.Array) -> bool:
@@ -400,8 +448,10 @@ def ssd(x, dt, a, bm, cm, d, lengths=None, *, chunk: int = 256):
     return ssd_chunked(x, dt, a, bm, cm, d, lengths, chunk=chunk)
 
 
-def ssm_step(state, x, dt, a, bm, cm, d):
-    """One token through the state: -> (state', y (B, H * P) float32)."""
+def ssm_step(state, x, dt, a, bm, cm, d, owned=None):
+    """One token through the state of the rows that are `owned` ((B,)
+    bool; None: every row): -> (state', y (B, H * P) float32). A row that
+    is not owned keeps its state, untouched, and gives y = 0."""
     if _on_tpu() and _step_kernel_applies(state):
-        return ssm_step_kernel(state, x, dt, a, bm, cm, d)
-    return ssm_step_reference(state, x, dt, a, bm, cm, d)
+        return ssm_step_kernel(state, x, dt, a, bm, cm, d, owned)
+    return ssm_step_reference(state, x, dt, a, bm, cm, d, owned)

@@ -103,6 +103,59 @@ def test_a_row_of_length_0_touches_nothing(tiny, generated):
     assert np.asarray(counts["held_decode"])[empty].tolist() == [0, 0, 0]
 
 
+@pytest.mark.parametrize("form", ["jnp", "pallas"])
+def test_padding_rows_change_nothing_for_the_real_rows(tiny, form,
+                                                       monkeypatch):
+    """A whole generation of the batch with its rows of length 0 against
+    the same prompts in a batch without them: tokens, first and last
+    logits; and the states the steps held and moved. `pallas`: the step
+    through `_ssm_step_kernel` (interpret mode) as on the chip."""
+    from min_tfs_client_tpu.servables.decode_signatures import (
+        whole_generation,
+    )
+
+    if form == "pallas":
+        monkeypatch.setattr(gh.ssm, "ssm_step", lambda *a, **kw:
+                            gh.ssm.ssm_step_kernel(*a, **kw, interpret=True))
+    pc, steps = tiny["program_config"], 12
+
+    def generate(ids):
+        found = jax.jit(lambda p, ids: whole_generation(
+            lambda p, ids: gh.prefill(p, pc, ids, max_decode_len=steps,
+                                      row_block=32),
+            lambda p, state: gh.step(p, pc, state), p, ids,
+            max_decode_len=steps, pad_id=pc.pad_id))(tiny["params"], ids)
+        return jax.tree_util.tree_map(np.asarray, {
+            "tokens": found["output_ids"],
+            "first": found["first"]["logits"],
+            "last": found["before_last"]["logits"],
+            "counts": found["final"]["counts"],
+            "states": [c["ssm"] for c in found["final"]["caches"]
+                       if "ssm" in c]})
+
+    real = np.nonzero(LENGTHS)[0]
+    padded, packed = generate(tiny["ids"]), generate(tiny["ids"][real])
+    assert np.array_equal(padded["tokens"][real], packed["tokens"])
+    np.testing.assert_allclose(padded["first"][real], packed["first"],
+                               atol=2e-5)
+    np.testing.assert_allclose(padded["last"][real], packed["last"],
+                               atol=2e-5)
+    layers = pc.layer_types.count("mamba")
+    assert int(padded["counts"]["state_rows_held"]) == (
+        len(LENGTHS) * layers * steps)
+    assert int(padded["counts"]["state_rows_moved"]) == (
+        len(real) * layers * steps)
+    assert (int(packed["counts"]["state_rows_held"])
+            == int(packed["counts"]["state_rows_moved"])
+            == len(real) * layers * steps)
+    # a padding row's states are the zeros the prefill left, the real
+    # rows' moved
+    empty = np.asarray(LENGTHS) == 0
+    for state in padded["states"]:
+        assert not np.any(state[empty]) and np.all(
+            np.any(state[real], axis=(1, 2)))
+
+
 def test_the_prefill_does_not_pay_for_the_padding(tiny):
     """Per-token work runs in blocks of the real tokens, and the scan in
     whole chunks (on the CPU: every example of a group to the group's
@@ -264,14 +317,31 @@ def test_an_answer_carries_its_route_and_its_state(tiny):
     assert per_sequence == 2 * (4 * 16 * 64 + 4 * 3 * (64 + 32))
     assert set(rows[:, 2].tolist()) == {per_sequence}
     assert set(rows[:, 3].tolist()) == {8}
+    # the batch's figures on every row: 12 rows, 9 of them real, through
+    # 2 state-space layers and 8 steps
+    assert set(rows[:, 4].tolist()) == {12 * 2 * 8}
+    assert set(rows[:, 5].tolist()) == {9 * 2 * 8}
     spans = {name: args for name, _, _, args in trace.spans}
     assert spans["generate/state"] == {
         "prompt_tokens": sum(LENGTHS), "scan_rows": int(rows[:, 1].sum()),
-        "state_bytes": 12 * per_sequence, "steps": 96}
+        "state_bytes": 12 * per_sequence, "steps": 96,
+        "state_rows_held": 12 * 2 * 8, "state_rows_moved": 9 * 2 * 8}
     assert spans["generate/route"]["prompt_tokens"] == sum(LENGTHS)
     assert spans["generate/route"]["pairs_decode"] == 12 * 8 * 3 * 3
     snapshot = runtime.snapshot()
-    assert snapshot["state"]["granite:1:serving_default"]["steps"] >= 96
+    counted = snapshot["state"]["granite:1:serving_default"]
+    assert counted["steps"] >= 96
+    assert counted["state_rows_moved"] * 12 == counted["state_rows_held"] * 9
+    # ... and a batch with no padding row moved every state it held
+    full = np.nonzero(LENGTHS)[0][:4]
+    signature.on_answer(signature, gh.build_signatures(
+        tiny["params"], tiny["program_config"], seq_len=SEQ,
+        max_decode_len=8, batch_buckets=(4,))["serving_default"].run(
+            {"input_ids": tiny["ids"][full]}))
+    after = runtime.snapshot()["state"]["granite:1:serving_default"]
+    assert (after["state_rows_held"] - counted["state_rows_held"]
+            == after["state_rows_moved"] - counted["state_rows_moved"]
+            == 4 * 2 * 8)
     assert snapshot["route"]["granite:1:serving_default"]["requests"] >= 1
 
 

@@ -105,6 +105,84 @@ def test_a_step_is_one_more_token_of_the_recurrence(interpret):
     np.testing.assert_allclose(y, want_y[:, 40], atol=2e-5)
 
 
+# -- the step moves the states of the rows a batch owns -----------------------
+
+# (batch, heads, head size, state): a small shape of one channel block, and
+# the published state (128 columns) over two blocks of 2,048 channels
+STEP_SHAPES = {"small": (5, 4, 64, 32), "published_blocks": (5, 64, 64, 128)}
+MASKS = {"all": (1, 1, 1, 1, 1), "none": (0, 0, 0, 0, 0),
+         "prefix": (1, 1, 1, 0, 0), "scattered": (0, 1, 0, 1, 1),
+         "one_row": (0, 0, 0, 1, 0)}
+
+
+STEP_FORMS = {"jnp": ssm.ssm_step_reference,
+              "pallas": lambda *args: ssm.ssm_step_kernel(*args,
+                                                          interpret=True)}
+
+
+@pytest.fixture(scope="module")
+def step_cases():
+    """shape name -> (operands, {form: its unmasked step})."""
+    found = {}
+    for name, (batch, heads, p, n) in STEP_SHAPES.items():
+        k = jax.random.split(jax.random.PRNGKey(11), 7)
+        args = (jax.random.normal(k[0], (batch, n, heads * p)),
+                jax.random.normal(k[1], (batch, heads * p)),
+                jax.nn.softplus(jax.random.normal(k[2], (batch, heads)) - 2),
+                -jnp.exp(jax.random.uniform(k[3], (heads,), maxval=2.7)),
+                jax.random.normal(k[4], (batch, n)) * 0.3,
+                jax.random.normal(k[5], (batch, n)) * 0.3,
+                jax.random.normal(k[6], (heads,)))
+        found[name] = (args, {form: jax.tree_util.tree_map(
+            np.asarray, step(*args)) for form, step in STEP_FORMS.items()})
+    return found
+
+
+@pytest.mark.parametrize("form", ["jnp", "pallas"])
+@pytest.mark.parametrize("mask", list(MASKS))
+@pytest.mark.parametrize("shape", list(STEP_SHAPES))
+def test_a_step_moves_the_owned_rows_and_no_other(step_cases, shape, mask,
+                                                  form):
+    args, unmasked = step_cases[shape]
+    want_state, want_y = unmasked[form]
+    owned = np.asarray(MASKS[mask], bool)
+    state, y = STEP_FORMS[form](*args, jnp.asarray(owned))
+    state, y = np.asarray(state), np.asarray(y)
+    # an owned row: the unmasked step's, to the bit
+    assert np.array_equal(state[owned], want_state[owned])
+    assert np.array_equal(y[owned], want_y[owned])
+    # any other: its state as it came, to the bit, and y = 0
+    assert np.array_equal(state[~owned], np.asarray(args[0])[~owned])
+    assert not np.any(y[~owned])
+
+
+@pytest.mark.parametrize("form", ["jnp", "pallas"])
+def test_no_mask_is_every_row_owned(step_cases, form):
+    args, unmasked = step_cases["small"]
+    state, y = STEP_FORMS[form](*args, None)
+    assert np.array_equal(state, unmasked[form][0])
+    assert np.array_equal(y, unmasked[form][1])
+    # the two forms are one arithmetic
+    np.testing.assert_allclose(unmasked["pallas"][0], unmasked["jnp"][0],
+                               atol=2e-6, rtol=1e-5)
+    np.testing.assert_allclose(unmasked["pallas"][1], unmasked["jnp"][1],
+                               atol=2e-5)
+
+
+def test_the_kernel_updates_a_donated_state_where_it_lies(step_cases):
+    """The state's buffer is the output's: a donated state comes back
+    with the owned rows moved and the other rows' bytes as they were."""
+    args, unmasked = step_cases["small"]
+    owned = np.asarray(MASKS["scattered"], bool)
+    kept = np.asarray(args[0])
+    step = jax.jit(lambda *a: ssm.ssm_step_kernel(*a, interpret=True),
+                   donate_argnums=(0,))
+    state, _ = step(jnp.array(kept), *args[1:], jnp.asarray(owned))
+    assert np.array_equal(np.asarray(state)[owned],
+                          unmasked["pallas"][0][owned])
+    assert np.array_equal(np.asarray(state)[~owned], kept[~owned])
+
+
 def test_off_the_tpu_the_dispatch_takes_the_plain_forms():
     args = operands(2, 64)
     y, h, ran = ssm.ssd(*args, jnp.asarray([64, 5], jnp.int32), chunk=32)

@@ -147,13 +147,18 @@ def test_the_chunked_scan_compiles_for_the_v5e_at_the_published_widths(v5e):
     assert "_ssd_kernel" in compiled.as_text()
 
 
-def test_the_step_compiles_for_the_v5e_and_writes_the_state_in_place(v5e):
+@pytest.mark.parametrize("masked", [False, True],
+                         ids=["every_row", "owned_rows"])
+def test_the_step_compiles_for_the_v5e_and_writes_the_state_in_place(
+        v5e, masked):
     from min_tfs_client_tpu.ops import ssm
 
     s = _ssm_shapes(v5e, 32, None)
+    owned = ([jax.ShapeDtypeStruct((32,), np.bool_, sharding=v5e)]
+             if masked else [])
     compiled = jax.jit(ssm.ssm_step_kernel, donate_argnums=(0,)).lower(
         s["state"], s["x"], s["dt"], s["a"], s["bm"], s["cm"],
-        s["d"]).compile()
+        s["d"], *owned).compile()
     text = compiled.as_text()
     assert "_ssm_step_kernel" in text
     # the donated state is the output's buffer, and no copy of it is made
