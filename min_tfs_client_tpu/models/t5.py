@@ -903,7 +903,7 @@ def build_signatures(params: dict, config: T5Config, *, seq_len: int,
                      kv_num_blocks: int | None = None,
                      kv_evict_policy: str | None = None,
                      kv_prefill_chunk: int | None = None) -> dict:
-    from min_tfs_client_tpu.ops.attention import rows_block
+    from min_tfs_client_tpu.ops.attention import rows_block, rows_copied
     from min_tfs_client_tpu.servables import decode_signatures
     from min_tfs_client_tpu.servables.decode_sessions import Paging
     from min_tfs_client_tpu.servables.servable import Signature, TensorSpec
@@ -959,26 +959,38 @@ def build_signatures(params: dict, config: T5Config, *, seq_len: int,
                                         lengths).astype(jnp.float32)}
 
     block = rows_block(seq_len)
+    # What ONE layer's self-attention copies of an example's cache over
+    # the steps of a generation: step t sees t + 1 keys.
+    self_rows_read = int(np.sum(rows_copied(
+        np.arange(max_decode_len) + 1, max_decode_len)))
 
-    def note_cross(signature, inputs):
+    def note_reads(signature, inputs):
         """The `on_request` of the whole-generation signatures: what the
-        request's own example(s) make every decode step's
-        cross-attention read, as the span `generate/cross` on its trace
-        (`input_tokens`, its non-pad tokens; `blocks_read`, the
-        ceil(tokens / block) blocks of K and of V a layer reads for it;
-        `blocks_held`, the seq_len / block it holds), and into the
-        process's counters (`/monitoring/runtime`, `route`, under the
-        signature's label)."""
+        request's own example(s) make the decode steps' attention read,
+        as two spans of no duration on its trace. `generate/cross`, every
+        step's cross-attention (`input_tokens`, its non-pad tokens;
+        `blocks_read`, the ceil(tokens / block) blocks of K and of V a
+        layer reads for it; `blocks_held`, the seq_len / block it holds).
+        `generate/self`, the steps' self-attention over the cache
+        (`rows_read`, the rows of K and of V ONE layer copies for it,
+        summed over the generation's steps: each step its keys so far to
+        the tile; `rows_held`, steps x the cache's max_decode_len rows).
+        All five also go into the process's counters
+        (`/monitoring/runtime`, `route`, under the signature's label)."""
         from min_tfs_client_tpu.observability import runtime, tracing
 
         tokens = np.sum(np.asarray(inputs["input_ids"]) != config.pad_id,
                         axis=-1).reshape(-1)
-        args = {"input_tokens": int(tokens.sum()),
-                "blocks_read": int(np.sum(-(-tokens // block))),
-                "blocks_held": int(tokens.size * (seq_len // block))}
+        cross = {"input_tokens": int(tokens.sum()),
+                 "blocks_read": int(np.sum(-(-tokens // block))),
+                 "blocks_held": int(tokens.size * (seq_len // block))}
+        cache = {"rows_read": int(tokens.size * self_rows_read),
+                 "rows_held": int(tokens.size * max_decode_len ** 2)}
         now = time.perf_counter()
-        tracing.add_span("generate/cross", now, now, **args)
-        runtime.count_route(signature.telemetry_label or "unlabeled", args)
+        tracing.add_span("generate/cross", now, now, **cross)
+        tracing.add_span("generate/self", now, now, **cache)
+        runtime.count_route(signature.telemetry_label or "unlabeled",
+                            {**cross, **cache})
 
     decode_sig = Signature(
         fn=decode_fn,
@@ -991,7 +1003,7 @@ def build_signatures(params: dict, config: T5Config, *, seq_len: int,
         # a padding row is an input of length 0: its cross-attention
         # reads nothing (a repeat of row 0 would read row 0's blocks)
         batch_pad_values={"input_ids": config.pad_id},
-        on_request=note_cross,
+        on_request=note_reads,
     )
 
     encode_sig = Signature(
@@ -1034,7 +1046,7 @@ def build_signatures(params: dict, config: T5Config, *, seq_len: int,
         # a padding row is an input of length 0: its cross-attention
         # reads nothing (a repeat of row 0 would read row 0's blocks)
         batch_pad_values={"input_ids": config.pad_id},
-        on_request=note_cross,
+        on_request=note_reads,
     )
 
     signatures = {"serving_default": decode_sig, "decode": decode_sig,

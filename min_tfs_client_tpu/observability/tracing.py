@@ -194,6 +194,10 @@ STAGES = (
     # no duration, its arguments are the numbers (input_tokens,
     # blocks_read, blocks_held).
     "generate/cross",
+    # What a whole generation's self-attention copies of the cache it
+    # holds, over all its steps (models/t5.py), beside `generate/cross`:
+    # no duration, its arguments are the numbers (rows_read, rows_held).
+    "generate/self",
     # A whole generation's recurrent state (models/granite_hybrid.py), on
     # the request's own trace after its batch was split: no duration, its
     # arguments are the numbers (prompt_tokens, scan_rows, state_bytes,

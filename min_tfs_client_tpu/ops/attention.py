@@ -810,122 +810,269 @@ class PagedKV:
 # (M = 1: XLA widens K and V to float32 and multiplies on the VPU, over all
 # S rows whatever the lengths), so these rows get a read of their own:
 #
-#  * `rows_flash_attention` — Pallas kernel, one grid step an example. K
-#    and V stay in HBM; a step copies the example's ceil(length / block)
-#    blocks of each into VMEM itself, the NEXT example's while this one's
-#    are multiplied, so an example reads what its length needs, once, in
-#    its own dtype, and one of length 0 reads nothing. The heads never
-#    leave their lanes: the query rows go in block-diagonal, (H * Sq,
-#    H * D) with head h's query on head h's lanes and zeros elsewhere, so
-#    ONE matmul against a block gives every head's scores and one against
-#    V every head's output (a zero adds nothing, in any precision), and
-#    the MXU takes K and V as they are.
+#  * `rows_flash_attention` — Pallas kernel. K and V stay in HBM; a grid
+#    step copies into VMEM itself what its examples' lengths need, the NEXT
+#    step's while this one's are multiplied, once, in their own dtype, and
+#    an example of length 0 reads nothing. What a step copies follows what
+#    the call says of its lengths:
+#      - each example a length of its own (no `q_start`: cross-attention):
+#        one example a grid step, its ceil(length / block) blocks of
+#        `_ROWS_BLOCK` rows of K and of V, a copy a block;
+#      - one length for all (`q_start`: the cache behind a decode step or
+#        a verify block, where no row sees a key at or past q_start + Sq):
+#        `_rows_group` examples a grid step, and of each the first
+#        ceil((q_start + Sq) / 16) tiles of `_ROWS_TILE` rows and no more.
+#        A copy's size is static, so the tiles go as whole blocks and then
+#        one copy of 64, 32 and 16 rows for each bit of what is left; a
+#        copy takes its rows of ALL the group's examples (one strided
+#        copy: the cache is (L, B, S, F)), so a group costs the copies one
+#        example would, all started before the first is waited for.
+#    The online softmax advances by blocks of `_ROWS_BLOCK` rows under
+#    the mask either way, block by block, the group's examples side by
+#    side in one batched product a side (each example's own arithmetic;
+#    the MXU's passes of one example behind the other's, not each
+#    waiting for its own result). Over a cache the last block's two
+#    products take the copied tiles alone (a static size a case, 16 to
+#    112 rows): a key past them would weigh zero, so the result is the
+#    whole block's to the bit, and the MXU, which takes a block as its
+#    weights whatever the rows that count (16 passes an example a block:
+#    as long as the block's bytes take at 819 GB/s), is not handed the
+#    rows nobody wrote. The heads never leave their lanes: the query rows
+#    go in block-diagonal, (H * Sq, H * D) with head h's query on head
+#    h's lanes and zeros elsewhere, so ONE matmul against a block gives
+#    every head's scores and one against V every head's output (a zero
+#    adds nothing, in any precision), and the MXU takes K and V as they
+#    are.
 #  * elsewhere `attention_reference` over the same rows split into heads.
 #
 # `attention_rows()` dispatches on the shapes it sees.
 
-_ROWS_BLOCK = 128  # key rows a copy moves and a product takes
+_ROWS_BLOCK = 128  # key rows a product takes, and the most a copy moves
+_ROWS_TILE = 16    # the fewest a copy moves: a tile of bfloat16 rows
+_ROWS_GROUP = 4    # the most examples a grid step over a cache holds
 
 
 def rows_block(seq_len: int) -> int:
     """Key rows `attention_rows` reads at a time at this sequence length
-    (what a model counts its reads in)."""
+    (what a model counts its cross-attention's reads in)."""
     return min(_ROWS_BLOCK, seq_len)
 
 
+def rows_copied(length, seq_len: int):
+    """Key rows `attention_rows` copies of an example's `seq_len` for a
+    read over a cache (`q_start`) whose last query row sees `length` keys:
+    whole tiles (what a model counts its self-attention's reads in).
+    `length` an int or an array of them."""
+    return np.minimum(-(-length // _ROWS_TILE) * _ROWS_TILE, seq_len)
+
+
+def _rows_tail_sizes(block: int) -> list[int]:
+    """The copy sizes that make up any whole number of tiles under a
+    block, largest first: 64, 32, 16 rows at a block of 128."""
+    return [_ROWS_TILE << bit for bit in reversed(range(
+        (block // _ROWS_TILE - 1).bit_length()))]
+
+
 def _rows_kernel(len_ref, qstart_ref, layer_ref, *refs, scale: float,
-                 heads: int, block: int, has_bias: bool, causal: bool):
-    """One example a grid cell: online softmax over the blocks its length
-    needs, every head at once. Row h * Sq + r of the scratch is head h's
-    query row r. Refs: lengths (B,), q_start (1,) and the layer (1,) in
-    SMEM; bias (H * Sq, S) float32, the same for every example, where
-    there is one; q (Sq, H * D); K and V whole in HBM; o (Sq, H * D); two
-    slots of an example's K and V rows, their copies' semaphores, and the
-    softmax's running (max, denominator, weighted sum)."""
+                 heads: int, block: int, sq: int, batch: int,
+                 has_bias: bool, causal: bool):
+    """A group of examples a grid cell (one without `causal`): online
+    softmax over the blocks their lengths need, every head and every
+    example of the group at once, block by block. Row h * Sq + r of the
+    scratch is head h's query row r. Refs: lengths (B,), q_start (1,) and
+    the layer (1,) in SMEM; bias (H * Sq, S) float32, the same for every
+    example, where there is one; q (G, Sq, H * D); K and V whole in HBM;
+    o (G, Sq, H * D); two slots of a group's K and V rows, their copies'
+    semaphores, and for each example of the group its block-diagonal
+    query rows and the softmax's running (max, denominator, weighted
+    sum)."""
     if has_bias:
         bias_ref, *refs = refs
-    q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sem, m_ref, l_ref, acc_ref = refs
-    example, slot = pl.program_id(0), pl.program_id(0) % 2
-    rows, f = acc_ref.shape
+    (q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sem, qd_ref, m_ref, l_ref,
+     acc_ref) = refs
+    step, slot = pl.program_id(0), pl.program_id(0) % 2
+    _, group, s, _ = kbuf.shape
+    _, rows, f = acc_ref.shape
     sq_p = rows // heads
-    valid_len, layer = len_ref[example], layer_ref[0]
+    layer = layer_ref[0]
+    tail_sizes = _rows_tail_sizes(block)
+    # With `causal` no query row sees a key at or past q_start + Sq,
+    # whatever the lengths say: the rows a copy need bring and a product
+    # take, in tiles: `whole` blocks and `rest` rows of the next.
+    seen = jnp.minimum(qstart_ref[0] + sq, s)
+    tiles = (seen + _ROWS_TILE - 1) // _ROWS_TILE * _ROWS_TILE
+    whole, rest = tiles // block, tiles % block
 
-    def copies(of, into, blk):
-        at = pl.ds(pl.multiple_of(blk * block, block), block)
-        return [pltpu.make_async_copy(hbm.at[layer, of, at],
-                                      buf.at[into, at], sem.at[i, into, blk])
-                for i, (hbm, buf) in enumerate(((k_hbm, kbuf),
-                                                (v_hbm, vbuf)))]
+    def copies(of, into, at, piece):
+        """[(whether group `of` is that, K's and V's copy)] of rows `at`
+        of every example of the group, one strided copy a side: the
+        group is whole, or the batch's last few."""
+        return [(ours, [pltpu.make_async_copy(
+                            hbm.at[layer, pl.ds(of * group, count), at],
+                            buf.at[into, pl.ds(0, count), at],
+                            sem.at[side, into, piece])
+                        for side, (hbm, buf) in enumerate(
+                            ((k_hbm, kbuf), (v_hbm, vbuf)))])
+                for count, ours in ((group, of < batch // group),
+                                    (batch % group, of == batch // group))
+                if count]
+
+    def block_copies(of, into, blk):
+        return copies(of, into, pl.ds(pl.multiple_of(blk * block, block),
+                                      block), blk)
+
+    def tail_copies(of, into, size):
+        """The `size` rows that are one bit of `rest`."""
+        return copies(of, into, pl.ds(pl.multiple_of(
+            whole * block + rest // (2 * size) * (2 * size), size), size),
+            s // block + tail_sizes.index(size))
+
+    def each(pairs, do, needed=True):
+        for ours, pair in pairs:
+            @pl.when(jnp.logical_and(ours, needed))
+            def _():
+                for copy in pair:
+                    do(copy)
+
+    def begin(copy):
+        copy.start()
+
+    def wait(copy):
+        copy.wait()  # servelint: blocks a DMA's semaphore on the device
 
     def start(of, into):
-        for blk in range(kbuf.shape[1] // block):
-            @pl.when(blk * block < len_ref[of])
-            def _():
-                for copy in copies(of, into, blk):
-                    copy.start()
+        """What group `of` needs: with `causal` the first `tiles` rows of
+        each example, else (a group of one) the example's own blocks."""
+        for blk in range(s // block):
+            each(block_copies(of, into, blk), begin,
+                 blk < whole if causal else blk * block < len_ref[of])
+        if causal:
+            for size in tail_sizes:
+                each(tail_copies(of, into, size), begin,
+                     (rest & size) != 0)
 
-    @pl.when(example == 0)
+    @pl.when(step == 0)
     def _first():
         start(0, 0)
 
-    @pl.when(example + 1 < pl.num_programs(0))
+    @pl.when(step + 1 < pl.num_programs(0))
     def _ahead():
-        start(example + 1, 1 - slot)
+        start(step + 1, 1 - slot)
 
-    row = jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
+    row = jax.lax.broadcasted_iota(jnp.int32, (1, rows, 1), 1)
     # Whether a lane belongs to the head of a query row.
     first = (row // sq_p) * (f // heads)
-    lane = jax.lax.broadcasted_iota(jnp.int32, (rows, f), 1)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, rows, f), 2)
     own = jnp.logical_and(lane >= first, lane < first + f // heads)
-    # The keys a query row may see: the example's, and with `causal` none
+    # The keys a query row may see: its example's, and with `causal` none
     # past its own position (row r of a block sits at q_start + r).
-    limit = (jnp.minimum(valid_len, qstart_ref[0] + row % sq_p + 1)
-             if causal else valid_len)
-    q = q_ref[...].astype(jnp.float32)                   # (Sq, H * D)
-    q = (jnp.broadcast_to(q, (rows, f)) if sq_p == 1
-         else jnp.concatenate([q] * heads, axis=0))
-    q = jnp.where(own, q, 0.0).astype(kbuf.dtype)        # block-diagonal
+    held = jax.lax.broadcasted_iota(jnp.int32, (group, 1, 1), 0)
+    limit = sum(jnp.where(held == g, len_ref[step * group + g], 0)
+                for g in range(group))
+    if causal:
+        limit = jnp.minimum(limit, qstart_ref[0] + row % sq_p + 1)
+
+    # Every example of the group at once from here on, (G, ...): a
+    # product a side for each, one after the other on the MXU.
+    q = q_ref[...].astype(jnp.float32)                       # (G, Sq, H * D)
+    q = (jnp.broadcast_to(q, (group, rows, f)) if sq_p == 1
+         else jnp.concatenate([q] * heads, axis=1))
+    qd_ref[...] = jnp.where(own, q, 0.0).astype(qd_ref.dtype)  # block-diagonal
     m_ref[...] = jnp.full_like(m_ref, NEG_INF)
     l_ref[...] = jnp.zeros_like(l_ref)
     acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    def accumulate(blk, _):
-        for copy in copies(example, slot, blk):
-            copy.wait()  # servelint: blocks a DMA's semaphore on the device
-        at = pl.ds(pl.multiple_of(blk * block, block), block)
-        s = jax.lax.dot_general(
-            q, kbuf[slot, at, :], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale  # (rows, block)
+    def accumulate(blk, size, last=False):
+        """One step of the online softmax, over the first `size` rows of
+        block `blk`; the `last` one writes the output."""
+        at = pl.ds(pl.multiple_of(blk * block, block), size)
+        scores = jax.lax.dot_general(
+            qd_ref[...], kbuf[slot, :, at, :], (((2,), (2,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32) * scale   # (G, rows, size)
         if has_bias:
-            s = s + bias_ref[:, at]
-        ki = blk * block + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(ki < limit, s, NEG_INF)
+            scores = scores + bias_ref[:, at]
+        ki = blk * block + jax.lax.broadcasted_iota(
+            jnp.int32, (1, 1, size), 2)
+        scores = jnp.where(ki < limit, scores, NEG_INF)
         m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)
+        m_new = jnp.maximum(m_prev, jnp.max(scores, axis=2, keepdims=True))
+        p = jnp.exp(scores - m_new)
         correction = jnp.exp(m_prev - m_new)
-        l_ref[...] = correction * l_ref[...] + jnp.sum(
-            p, axis=1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * correction + jax.lax.dot_general(
-            p.astype(vbuf.dtype), vbuf[slot, at, :], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)          # (rows, H * D)
-        m_ref[...] = m_new
+        l = correction * l_ref[...] + jnp.sum(p, axis=2, keepdims=True)
+        acc = acc_ref[...] * correction + jax.lax.dot_general(
+            p.astype(vbuf.dtype), vbuf[slot, :, at, :],
+            (((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32)           # (G, rows, H * D)
+        if last:
+            finish(m_new, l, acc)
+        else:
+            m_ref[...], l_ref[...], acc_ref[...] = m_new, l, acc
 
-    jax.lax.fori_loop(0, (valid_len + block - 1) // block, accumulate, None)
-    # A row that met no key (length 0) never left NEG_INF: zeros. Each
-    # lane keeps its own head's row.
-    l = l_ref[...]
-    out = jnp.where(jnp.logical_and(own, m_ref[...] > NEG_INF * 0.5),
-                    acc_ref[...] / jnp.where(l == 0.0, 1.0, l), 0.0)
-    out = (jnp.sum(out, axis=0, keepdims=True) if sq_p == 1
-           else jnp.sum(out.reshape(heads, sq_p, f), axis=0))
-    o_ref[...] = out.astype(o_ref.dtype)
+    def finish(m, l, acc):
+        # A row that met no key (length 0) never left NEG_INF: zeros. Each
+        # lane keeps its own head's row.
+        out = jnp.where(jnp.logical_and(own, m > NEG_INF * 0.5),
+                        acc / jnp.where(l == 0.0, 1.0, l), 0.0)
+        out = (jnp.sum(out, axis=1, keepdims=True) if sq_p == 1
+               else jnp.sum(out.reshape(group, heads, sq_p, f), axis=1))
+        o_ref[...] = out.astype(o_ref.dtype)
+
+    def from_scratch():
+        finish(m_ref[...], l_ref[...], acc_ref[...])
+
+    def whole_block(blk, _):
+        each(block_copies(step, slot, blk), wait)
+        accumulate(blk, block)
+
+    if not causal:
+        jax.lax.fori_loop(0, (len_ref[step] + block - 1) // block,
+                          whole_block, None)
+        from_scratch()
+        return
+    # Every example's keys end at `seen`: the blocks that are whole, then
+    # the `rest` rows of the last, a product of just those rows (a masked
+    # key weighs zero, in any precision: what the whole block would give,
+    # to the bit) that goes on to the output.
+    jax.lax.fori_loop(0, whole, whole_block, None)
+    for tail in range(_ROWS_TILE, block, _ROWS_TILE):
+        @pl.when(rest == tail)
+        def _(tail=tail):
+            for size in tail_sizes:
+                if tail & size:
+                    each(tail_copies(step, slot, size), wait)
+            accumulate(whole, tail, last=True)
+
+    pl.when(rest == 0)(from_scratch)
 
 
 def _rows_query_rows(sq: int) -> int:
     """Query rows as `_rows_kernel` sees them: one, or whole sublane
     tiles."""
     return 1 if sq == 1 else -(-sq // 8) * 8
+
+
+def _rows_step_bytes(group: int, sq: int, s: int, f: int, itemsize: int,
+                     num_heads: int, has_bias: bool) -> int:
+    """VMEM a grid step of `_rows_kernel` takes with `group` examples:
+    two slots of their K and V rows; for each example its block-diagonal
+    query rows, the float32 (max, denominator, weighted sum) and the
+    body's temporaries, each as wide as those query rows; the bias."""
+    rows = num_heads * _rows_query_rows(sq)
+    return (4 * group * s * f * itemsize
+            + group * rows * (f * (itemsize + 4 * 4)
+                              + 4 * (3 * max(rows_block(s), 128) + 2 * 128))
+            + (2 * 4 * rows * s if has_bias else 0))
+
+
+def _rows_group(b: int, sq: int, s: int, f: int, itemsize: int,
+                num_heads: int, has_bias: bool) -> int:
+    """Examples a grid step of a read over a cache holds: as many as
+    `_ROWS_GROUP`, the batch and the step's VMEM allow, and one at the
+    least (what `_rows_kernel_applies` admits)."""
+    return max([1] + [
+        group for group in range(2, min(_ROWS_GROUP, b) + 1)
+        if _rows_step_bytes(group, sq, s, f, itemsize, num_heads, has_bias)
+        <= _ROWS_GROUP_VMEM_BYTES])
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -954,8 +1101,10 @@ def rows_flash_attention(
     position bias). Without `q_start` every query row sees the example's
     `length` keys (cross-attention); with it, a scalar, row r sits at
     position q_start + r and sees keys < min(length, q_start + r + 1)
-    (a decode step or a verify block over the cache behind it). Returns
-    (B, Sq, H * D) in q.dtype; an example of length 0 gives zeros."""
+    (a decode step or a verify block over the cache behind it), and no
+    row of K or V at or past the tile that holds q_start + Sq - 1 is
+    read. Returns (B, Sq, H * D) in q.dtype; an example of length 0 gives
+    zeros."""
     b, sq, f = q.shape
     if layer is None:
         k, v, layer = k[None], v[None], 0
@@ -966,73 +1115,78 @@ def rows_flash_attention(
         block = rows_block(s)
     sq_p = _rows_query_rows(sq)
     rows = num_heads * sq_p
+    group = 1 if q_start is None else _rows_group(
+        b, sq, s, f, k.dtype.itemsize, num_heads, bias is not None)
+    steps = -(-b // group)
 
-    def q_index(example, lens, start, of_layer):
-        return (example, 0, 0)
+    def q_index(step, lens, start, of_layer):
+        return (step, 0, 0)
 
-    in_specs = [pl.BlockSpec((None, sq_p, f), q_index),
+    in_specs = [pl.BlockSpec((group, sq_p, f), q_index),
                 pl.BlockSpec(memory_space=pl.ANY),
                 pl.BlockSpec(memory_space=pl.ANY)]
-    operands = [_pad_to(q, 1, sq_p), k, v]
+    # (query rows and lengths of whole groups: what the last group's
+    # spare examples give is cut off below)
+    operands = [_pad_to(_pad_to(q, 1, sq_p), 0, steps * group), k, v]
     if bias is not None:
         # Rows in the scratch's order, (head, query row); whole in VMEM
         # at every step, so it is fetched once.
         bias_f = _pad_to(bias.astype(jnp.float32).reshape(num_heads, sq, s),
                          1, sq_p).reshape(rows, s)
         in_specs.insert(0, pl.BlockSpec((rows, s),
-                                        lambda example, *scalars: (0, 0)))
+                                        lambda step, *scalars: (0, 0)))
         operands.insert(0, bias_f)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,  # lengths, q_start, layer
-        grid=(b,),
+        grid=(steps,),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((None, sq_p, f), q_index),
+        out_specs=pl.BlockSpec((group, sq_p, f), q_index),
         scratch_shapes=[
-            pltpu.VMEM((2, s, f), k.dtype),
-            pltpu.VMEM((2, s, f), v.dtype),
-            pltpu.SemaphoreType.DMA((2, 2, s // block)),
-            pltpu.VMEM((rows, 1), jnp.float32),
-            pltpu.VMEM((rows, 1), jnp.float32),
-            pltpu.VMEM((rows, f), jnp.float32),
+            pltpu.VMEM((2, group, s, f), k.dtype),
+            pltpu.VMEM((2, group, s, f), v.dtype),
+            pltpu.SemaphoreType.DMA(
+                (2, 2, s // block + len(_rows_tail_sizes(block)))),
+            pltpu.VMEM((group, rows, f), k.dtype),
+            pltpu.VMEM((group, rows, 1), jnp.float32),
+            pltpu.VMEM((group, rows, 1), jnp.float32),
+            pltpu.VMEM((group, rows, f), jnp.float32),
         ],
     )
     out = pl.pallas_call(
         functools.partial(
-            _rows_kernel, scale=scale, heads=num_heads, block=block,
-            has_bias=bias is not None, causal=q_start is not None),
+            _rows_kernel, scale=scale, heads=num_heads, block=block, sq=sq,
+            batch=b, has_bias=bias is not None, causal=q_start is not None),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, sq_p, f), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((steps * group, sq_p, f), q.dtype),
         # in order: a step starts the copies the next one waits for
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",)),
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_ROWS_VMEM_LIMIT_BYTES if group > 1 else None),
         interpret=interpret,
         name="_rows_kernel",  # the device-trace reduction finds it by name
-    )(lengths.astype(jnp.int32),
+    )(_pad_to(lengths.astype(jnp.int32), 0, steps * group),
       jnp.reshape(0 if q_start is None else q_start, (1,)).astype(jnp.int32),
       jnp.reshape(layer, (1,)).astype(jnp.int32), *operands)
-    return out[:, :sq, :]
+    return out[:b, :sq, :]
 
 
 def _rows_kernel_applies(q: jax.Array, k: jax.Array, num_heads: int,
                          bias: Optional[jax.Array] = None) -> bool:
     """The shapes `_rows_kernel` compiles for: rows of whole 128-lane
-    tiles, key rows in whole blocks of whole sublane tiles, a bias (if
-    any) that every example shares, and a step inside VMEM: two slots of
-    an example's K and V rows, the bias, the float32 scratch and the
-    body's temporaries, each as wide as the block-diagonal query rows.
-    One device only, as the paged read."""
+    tiles, key rows in whole blocks of whole tiles, a bias (if any) that
+    every example shares, and a step of one example inside VMEM
+    (`_rows_step_bytes`; a read over a cache then takes as many examples
+    a step as `_rows_group` finds room for). One device only, as the
+    paged read."""
     _, sq, f = q.shape
     s = k.shape[-2]
     block = rows_block(s)
-    rows = num_heads * _rows_query_rows(sq)
-    step_bytes = (4 * s * f * k.dtype.itemsize
-                  + 4 * rows * (4 * f + 3 * max(block, 128) + 2 * 128)
-                  + (0 if bias is None else 2 * 4 * rows * s))
     return (f % 128 == 0
-            and block % 16 == 0
+            and block % _ROWS_TILE == 0
             and s % block == 0
             and (bias is None or bias.shape[0] == 1)
-            and step_bytes <= _PAGED_STEP_VMEM_BYTES
+            and _rows_step_bytes(1, sq, s, f, k.dtype.itemsize, num_heads,
+                                 bias is not None) <= _PAGED_STEP_VMEM_BYTES
             and not _auto_mesh_axes())
 
 
@@ -1085,6 +1239,10 @@ def _on_tpu() -> bool:
 _PAGED_SMEM_BYTES = (1 << 20) - (16 << 10)
 _FLASH_KV_VMEM_BYTES = 8 << 20
 _PAGED_STEP_VMEM_BYTES = 8 << 20
+# A read over a cache (`_rows_kernel` with `q_start`) holds several
+# examples a step: an account of its own, half of the limit its call names.
+_ROWS_GROUP_VMEM_BYTES = 12 << 20
+_ROWS_VMEM_LIMIT_BYTES = 2 * _ROWS_GROUP_VMEM_BYTES
 
 
 def _paged_kernel_applies(q: jax.Array, k_pages: jax.Array,

@@ -1,15 +1,24 @@
-"""Device times of a T5 whole generation's decode loop and of its
-cross-attention read alone on the chip, at the widths of
+"""Device times of a T5 whole generation's decode loop and of its two
+attention reads alone on the chip, at the widths of
 perfbench/configs/t5-large.json (PERF.md section 5 quotes them). Not a
 test and not part of the benchmark: run it on a machine with the chip,
 
-    python tests/tpu/t5_pieces.py [--pieces decode,cross] [--out FILE]
+    python tests/tpu/t5_pieces.py [--pieces decode,cross,self]
+        [--out FILE] [--parent DIR]
 
 and read chiprun_out/t5_pieces.json (or FILE). `decode`: the decode step
 as `greedy_decode` runs it, a `lax.scan` of 16 steps with the caches
-donated, timed over 5 calls and captured once for its device time by
-operation (a `while` spans its body's operations: what the loop costs a
-step without what XLA hoists out of it). `cross`: a step's 24
+donated, from position 0 and from position 128 (where a step's
+self-attention reads a second block), timed over 5 calls and captured
+once for its device time by operation (a `while` spans its body's
+operations: what the loop costs a step without what XLA hoists out of
+it). `self`: a step's 24 self-attention reads alone at 32 rows, chained,
+over a cache of 256 positions under a shared bias, scans of 16 steps from
+positions 0, 128 and 240: microseconds a call from the capture and the
+share of 819 GB/s by the rows a call needs (its positions' keys to the
+16-row tile); with `--parent DIR` (a `git archive` checkout of the parent
+commit) the tree's kernel against the parent's, to the bit, at every one
+of those positions. `cross`: a step's 24
 cross-attention reads alone, chained (each layer's output is the next
 one's query) over 24 distinct K and V, 16 steps a call: as the parent
 formulates them (`attention_reference` over rows split into heads: the
@@ -27,6 +36,7 @@ the parent's checkout; what a tree lacks is left out).
 
 import argparse
 import importlib
+import importlib.util
 import json
 import pathlib
 import sys
@@ -77,8 +87,9 @@ def timed_and_captured(out: dict, name: str, steps, carried, *fixed) -> None:
         out[f"{name}_ops"] = ops_a_step(capture)
 
 
-def decode(out: dict, name: str, params, config, encoded, lengths) -> None:
-    """The loop of `greedy_decode`: SCAN steps from position 0 on, the
+def decode(out: dict, name: str, params, config, encoded, lengths,
+           position: int = 0) -> None:
+    """The loop of `greedy_decode`: SCAN steps from `position` on, the
     caches donated (the tree's own: a rows cache where it has one). A
     tree that projects the cross K and V before the loop gets them made
     outside the timed program, as its whole generation makes them once;
@@ -111,10 +122,12 @@ def decode(out: dict, name: str, params, config, encoded, lengths) -> None:
             return (jnp.argmax(logits, -1).astype(jnp.int32)[:, None],
                     caches), None
 
-        return jax.lax.scan(step_fn, carried, jnp.arange(SCAN))[0]
+        return jax.lax.scan(step_fn, carried,
+                            position + jnp.arange(SCAN))[0]
 
     timed_and_captured(
-        out, f"decode_{name}", jax.jit(steps, donate_argnums=(0,)),
+        out, f"decode_{name}" + (f"_from_{position}" if position else ""),
+        jax.jit(steps, donate_argnums=(0,)),
         (jnp.zeros((BATCH, 1), jnp.int32), caches), params, source)
 
 
@@ -172,11 +185,75 @@ def cross(out: dict, name: str, config, rows, lengths) -> None:
                            jax.jit(steps), q0, held, jnp.asarray(lengths))
 
 
+SELF_POSITIONS = (0, 128, 240)
+
+
+def self_reads(out: dict, config, parent: str | None) -> None:
+    """A step's self reads alone: layer i's output is layer i + 1's
+    query, SCAN steps a call from each of SELF_POSITIONS, every row of
+    the batch real. The rows a call needs are its position's keys to the
+    16-row tile, of K and of V."""
+    h, f = config.num_heads, config.num_heads * config.d_kv
+    layers = config.num_decoder_layers
+    keys = jax.random.split(jax.random.PRNGKey(5), 4)
+    rows = {name: jax.random.normal(
+                key, (layers, BATCH, MAX_DECODE_LEN, f), jnp.bfloat16)
+            for name, key in zip(("key", "value"), keys)}
+    bias = jax.random.normal(keys[2], (1, h, 1, MAX_DECODE_LEN), jnp.float32)
+    q0 = jax.random.normal(keys[3], (BATCH, 1, f), jnp.bfloat16)
+    peak = json.loads((ROOT / "perfbench/peaks.json").read_text())[
+        out["device"]]["hbm_bytes_per_s"]
+
+    def read(kernel, q, rows, bias, i, position):
+        return kernel(
+            q, rows["key"], rows["value"],
+            jnp.full((BATCH,), position + 1, jnp.int32), num_heads=h,
+            scale=1.0, layer=i, bias=bias, q_start=position)
+
+    def steps(q, rows, bias, first):
+        def step_fn(q, position):
+            for i in range(layers):
+                q = read(attention.rows_flash_attention, q, rows, bias, i,
+                         position)
+            return q, None
+
+        return jax.lax.scan(step_fn, q, first + jnp.arange(SCAN))[0]
+
+    for first in SELF_POSITIONS:
+        name = f"self_24_reads_from_{first}"
+        timed_and_captured(out, name, jax.jit(steps), q0, rows, bias,
+                           jnp.int32(first))
+        us = (out[f"{name}_ops"]["ops"]["_rows_kernel"]["ms_a_step"]
+              * 1e3 / layers)
+        needed = sum(2 * BATCH * f * 2 * (-(-(first + i + 1) // 16) * 16)
+                     for i in range(SCAN)) / SCAN
+        out[f"{name}_us_a_call"] = us
+        out[f"{name}_share_of_peak_bytes"] = needed / peak / (
+            us * 1e-6)
+    if parent is None:
+        return
+    spec = importlib.util.spec_from_file_location(
+        "parent_attention",
+        pathlib.Path(parent) / "min_tfs_client_tpu/ops/attention.py")
+    before = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(before)
+    both = jax.jit(lambda q, rows, bias, i, position: tuple(
+        read(module.rows_flash_attention, q, rows, bias, i, position)
+        for module in (attention, before)))
+    differing = [
+        first + i for first in SELF_POSITIONS for i in range(SCAN)
+        if not np.array_equal(*map(np.asarray, both(
+            q0, rows, bias, (first + i) % layers, jnp.int32(first + i))))]
+    out["self_positions_where_the_kernel_differs_from_the_parents"] = (
+        differing)
+
+
 def main() -> None:
     parser = argparse.ArgumentParser()
-    parser.add_argument("--pieces", default="decode,cross")
+    parser.add_argument("--pieces", default="decode,cross,self")
     parser.add_argument("--out", default=str(
         ROOT / "chiprun_out/t5_pieces.json"))
+    parser.add_argument("--parent", default=None)
     args = parser.parse_args()
     pieces = set(args.pieces.split(","))
     out = {"device": str(jax.devices()[0].device_kind)}
@@ -198,9 +275,13 @@ def main() -> None:
     for name, lengths in batches(grid).items():
         out[f"lengths_{name}"] = lengths.tolist()
         if "decode" in pieces:
-            decode(out, name, params, config, encoded, jnp.asarray(lengths))
+            for position in (0, 128):
+                decode(out, name, params, config, encoded,
+                       jnp.asarray(lengths), position)
         if "cross" in pieces:
             cross(out, name, config, rows, lengths)
+    if "self" in pieces and hasattr(attention, "rows_flash_attention"):
+        self_reads(out, config, args.parent)
     print(json.dumps(out, indent=1))
     pathlib.Path(args.out).parent.mkdir(parents=True, exist_ok=True)
     pathlib.Path(args.out).write_text(json.dumps(out, indent=1))

@@ -22,6 +22,7 @@ from min_tfs_client_tpu.ops.attention import (
     paged_flash_attention,
     paged_prefill_attention,
     rows_block,
+    rows_copied,
     rows_flash_attention,
 )
 
@@ -547,21 +548,41 @@ def test_rows_kernel_rounds_its_weights_as_the_reference_does():
                                np.asarray(want, np.float32), atol=2 ** -7)
 
 
-@pytest.mark.parametrize("sq, q_start", [(1, 0), (1, 37), (1, 255),
-                                         (5, 0), (5, 126), (5, 251)])
+# A read over a cache: the keys its last query row sees, on both sides of
+# every edge of a 16-row tile and of a 128-row block.
+_CACHE_KEYS = (1, 15, 16, 17, 38, 127, 128, 129, 255, 256)
+
+
+# (sq, q_start, b): every edge in a batch of 3; the edges where the copies
+# change in batches of 1, 4, 5 and 32 (a grid step holds up to
+# `_ROWS_GROUP` examples: batches it divides and does not).
+_CACHE_CASES = (
+    [(1, keys - 1, 3) for keys in _CACHE_KEYS]
+    + [(5, keys - 5, 3) for keys in (5,) + _CACHE_KEYS[1:] if keys != 38]
+    + [(5, 126, 3)]
+    + [(1, keys - 1, b) for b in (1, 4, 5, 32)
+       for keys in (1, 16, 17, 128, 129, 256)]
+    + [(5, keys - 5, b) for b in (4, 5) for keys in (16, 129, 256)])
+
+
+@pytest.mark.parametrize("sq, q_start, b", _CACHE_CASES)
 def test_rows_kernel_over_a_cache_with_bias_sees_no_key_past_its_row(
-        sq, q_start):
+        sq, q_start, b):
     """`q_start`: row r of the block sits at q_start + r and sees the
     rows up to its own, under a bias every example shares (a decode step
     or a verify block of T5's self-attention): the kernel against
-    `attention_reference` with the same bias and a causal offset; the
-    rows past the block hold NaN where whole blocks lie past it."""
-    b, h, d, s = 3, 4, 64, 256
+    `attention_reference` with the same bias and a causal offset, a
+    layer of a stack, in batches that the examples of a grid step divide
+    and do not; the rows past the 16-row tile that holds the block's last
+    row hold NaN: the kernel copies the tiles its keys lie in and no
+    row more."""
+    h, d, s = 4, 64, 256
+    assert rows_copied(q_start + sq, s) - (q_start + sq) < 16
     q = _rand((b, sq, h * d), 0)
     k, v = _rand((2, b, s, h * d), 1), _rand((2, b, s, h * d), 2)
     bias = _rand((1, h, sq, s), 3)
     lengths = jnp.full((b,), q_start + sq, jnp.int32)
-    unread = (jnp.arange(s) >= -(-(q_start + sq) // 128) * 128)[:, None]
+    unread = (jnp.arange(s) >= rows_copied(q_start + sq, s))[:, None]
     got = rows_flash_attention(
         q, jnp.where(unread, jnp.nan, k), jnp.where(unread, jnp.nan, v),
         lengths, num_heads=h, layer=1, bias=bias,
@@ -630,14 +651,16 @@ def test_flash_lowers_for_tpu_at_bert_base_shapes():
     assert "tpu_custom_call" in text
 
 
+@pytest.mark.parametrize("b", [32, 5, 1])
 @pytest.mark.parametrize("sq", [1, 5])
 @pytest.mark.parametrize("s, cached", [(512, False), (256, True)])
-def test_rows_read_lowers_for_tpu_at_t5_large_shapes(s, cached, sq):
+def test_rows_read_lowers_for_tpu_at_t5_large_shapes(s, cached, sq, b):
     """The two reads of `t5-large.generate`'s decode step (32 examples of
     16 heads x 64): cross-attention over 512 rows, self-attention over a
-    cache of 256 under the relative position bias; and a verify block of
-    5 rows over each."""
-    b, h, d = 32, 16, 64
+    cache of 256 under the relative position bias; a verify block of 5
+    rows over each; and batches that the cache read's group of examples
+    does not divide."""
+    h, d = 16, 64
     q = jax.ShapeDtypeStruct((b, sq, h * d), jnp.bfloat16)
     rows = jax.ShapeDtypeStruct((24, b, s, h * d), jnp.bfloat16)
     lengths = jax.ShapeDtypeStruct((b,), jnp.int32)
@@ -652,6 +675,30 @@ def test_rows_read_lowers_for_tpu_at_t5_large_shapes(s, cached, sq):
     text = _lower_for_tpu(read, q, rows, rows, lengths, bias,
                           jax.ShapeDtypeStruct((), jnp.int32)).as_text()
     assert "tpu_custom_call" in text and "_rows_kernel" in text
+
+
+def test_a_cache_read_holds_the_examples_a_step_has_room_for():
+    """`_rows_group`: `_ROWS_GROUP` examples a grid step at the cell's
+    shapes, the batch where it is smaller, fewer where the step's VMEM
+    account is short, one where only one fits; and what a generation of
+    256 steps copies of one layer's 256 x 256 rows, to the tile."""
+    group = attention_module._rows_group
+    most = attention_module._ROWS_GROUP
+    assert 2 <= most <= 8
+    assert group(32, 1, 256, 1024, 2, 16, True) == most
+    assert group(5, 1, 256, 1024, 2, 16, True) == min(5, most)
+    assert group(1, 1, 256, 1024, 2, 16, True) == 1
+    assert 2 <= group(32, 5, 256, 1024, 2, 16, True) <= most
+    assert 1 <= group(32, 1, 1024, 1024, 2, 16, True) < most
+    assert group(32, 1, 2048, 1024, 2, 16, True) == 1
+    assert attention_module._rows_step_bytes(
+        most, 1, 256, 1024, 2, 16, True) \
+        <= attention_module._ROWS_GROUP_VMEM_BYTES \
+        < attention_module._ROWS_VMEM_LIMIT_BYTES
+    steps = np.arange(256)
+    assert int(rows_copied(steps + 1, 256).sum()) == 34_816
+    assert int((-(-(steps + 1) // 128) * 128).sum()) == 49_152
+    assert rows_copied(250, 64) == 64
 
 
 def test_rows_gate_refuses_what_the_kernel_cannot_hold():

@@ -263,6 +263,38 @@ def test_t5_whole_generation_notes_what_its_cross_attention_reads():
     Handlers._noted("on_request", sig, {})
 
 
+@pytest.mark.parametrize("signature", ["serving_default", "decode_sampled"])
+@pytest.mark.parametrize("examples", [1, 3])
+def test_t5_whole_generation_notes_what_its_self_attention_copies(
+        examples, signature):
+    """`generate/self` beside `generate/cross`: the rows of K and of V
+    ONE layer's self-attention copies for the request's example(s) over
+    the steps of a generation (step t its t + 1 keys, to the 16-row
+    tile) and the rows the cache holds for them, on the trace and in the
+    counters; 34,816 of 65,536 at the cell's 256 steps."""
+    from min_tfs_client_tpu.observability import runtime, tracing
+    from min_tfs_client_tpu.server.handlers import Handlers
+
+    config = t5.T5Config.tiny()
+    params = t5.init_params(jax.random.PRNGKey(0), config)
+    for steps, read in ((256, 34_816), (40, 16 * 16 + 16 * 32 + 8 * 40)):
+        sig = t5.build_signatures(params, config, seq_len=128,
+                                  max_decode_len=steps)[signature]
+        label = sig.telemetry_label or "unlabeled"
+        before = dict(runtime.route_totals().get(label, {}))
+        with tracing.request_trace("predict") as trace:
+            Handlers._noted("on_request", sig, {
+                "input_ids": np.full((examples, 128), 7, np.int32)})
+        assert [name for name, _, _, _ in trace.spans] == [
+            "generate/cross", "generate/self"]
+        assert trace.spans[1][3] == {"rows_read": examples * read,
+                                     "rows_held": examples * steps * steps}
+        after = runtime.route_totals()[label]
+        assert after["requests"] - before.get("requests", 0) == 1
+        for name, value in trace.spans[1][3].items():
+            assert after[name] - before.get(name, 0) == value
+
+
 def test_resnet_tiny_forward():
     config = resnet.ResNetConfig.tiny()
     params = resnet.init_params(jax.random.PRNGKey(0), config)
