@@ -14,6 +14,7 @@ so native models hot-load/reload/version exactly like imported GraphDefs.
 
 from __future__ import annotations
 
+import importlib
 import json
 import pathlib
 from typing import Mapping
@@ -21,7 +22,16 @@ from typing import Mapping
 import jax
 import numpy as np
 
-FAMILIES = ("bert", "t5", "resnet", "use", "mimo", "granite_hybrid")
+# A family whose module (models/<family>.py) needs no more of the loader
+# than its config class and which JSON lists are tuples to it: {family:
+# (config class, config keywords, signature keywords)}, a line a family.
+_PLAIN_FAMILIES = {
+    "resnet": ("ResNetConfig", ("stage_sizes",), ()),
+    "use": ("USEConfig", ("seq_buckets",), ("batch_buckets",)),
+    "mimo": ("MimoConfig", (), ("batch_buckets",)),
+    "granite_hybrid": ("GraniteHybridConfig", (), ("batch_buckets",)),
+}
+FAMILIES = ("bert", "t5", *_PLAIN_FAMILIES)
 
 _SERVABLE_STUB = """\
 # Auto-generated by min_tfs_client_tpu.models.export — loads a native
@@ -284,10 +294,24 @@ def _maybe_shard_params(params, sharding_cfg: dict):
     return shard_params(params, specs, mesh), mesh
 
 
-def _attach_mesh(signatures: dict, mesh) -> dict:
-    from min_tfs_client_tpu.servables.servable import attach_mesh
+def _stage_mesh(meta: dict, mesh, sig_kwargs: dict):
+    """An export with a `pipeline` block is served over a stage mesh
+    where the host has the devices: the mesh goes into the signatures'
+    keywords and is the one they are attached to."""
+    pipe = meta.get("pipeline")
+    if not pipe or mesh is not None:
+        return mesh
+    from min_tfs_client_tpu.parallel.mesh import make_mesh
+    from min_tfs_client_tpu.parallel.pipeline import STAGE_AXIS
 
-    return attach_mesh(signatures, mesh)
+    try:
+        stages = make_mesh({STAGE_AXIS: int(pipe["stages"])})
+    except ValueError:
+        return None  # too few devices: serve sequential
+    sig_kwargs["pipeline_mesh"] = stages
+    if pipe.get("n_micro"):
+        sig_kwargs["pipeline_n_micro"] = int(pipe["n_micro"])
+    return stages
 
 
 def load_signatures(path) -> dict:
@@ -308,6 +332,8 @@ def build_family_signatures(family, config_kwargs, sig_kwargs, params, *,
                             mesh=None, meta=None, vdir=None) -> dict:
     """Family dispatch shared by load_signatures and the export-time
     quantization guard (which builds fp and int8 variants in-memory)."""
+    from min_tfs_client_tpu.servables.servable import attach_mesh
+
     meta = meta or {}
     sig_kwargs = dict(sig_kwargs)
     config_kwargs = dict(config_kwargs)
@@ -319,40 +345,14 @@ def build_family_signatures(family, config_kwargs, sig_kwargs, params, *,
             sig_kwargs["class_labels"] = [
                 label.encode() for label in sig_kwargs["class_labels"]]
         config = bert.BertConfig(**config_kwargs)
-        pipe = meta.get("pipeline")
-        if pipe and mesh is None:
-            from min_tfs_client_tpu.parallel.mesh import make_mesh
-            from min_tfs_client_tpu.parallel.pipeline import STAGE_AXIS
-
-            try:
-                pipeline_mesh = make_mesh({STAGE_AXIS: int(pipe["stages"])})
-            except ValueError:
-                pipeline_mesh = None  # too few devices: serve sequential
-            if pipeline_mesh is not None:
-                sig_kwargs["pipeline_mesh"] = pipeline_mesh
-                if pipe.get("n_micro"):
-                    sig_kwargs["pipeline_n_micro"] = int(pipe["n_micro"])
-                mesh = pipeline_mesh
-        return _attach_mesh(
+        mesh = _stage_mesh(meta, mesh, sig_kwargs)
+        return attach_mesh(
             bert.build_signatures(params, config, **sig_kwargs), mesh)
     if family == "t5":
         from min_tfs_client_tpu.models import t5
 
         config = t5.T5Config(**config_kwargs)
-        pipe = meta.get("pipeline")
-        if pipe and mesh is None:
-            from min_tfs_client_tpu.parallel.mesh import make_mesh
-            from min_tfs_client_tpu.parallel.pipeline import STAGE_AXIS
-
-            try:
-                pipeline_mesh = make_mesh({STAGE_AXIS: int(pipe["stages"])})
-            except ValueError:
-                pipeline_mesh = None  # too few devices: serve sequential
-            if pipeline_mesh is not None:
-                sig_kwargs["pipeline_mesh"] = pipeline_mesh
-                if pipe.get("n_micro"):
-                    sig_kwargs["pipeline_n_micro"] = int(pipe["n_micro"])
-                mesh = pipeline_mesh
+        mesh = _stage_mesh(meta, mesh, sig_kwargs)
         # Speculative decoding: a draft model exported alongside
         # (draft_params.npz + "draft" config block) adds a
         # decode_speculative signature — same outputs as decode,
@@ -362,41 +362,16 @@ def build_family_signatures(family, config_kwargs, sig_kwargs, params, *,
             sig_kwargs["draft_params"] = load_params(
                 vdir / "draft_params.npz")
             sig_kwargs["draft_config"] = t5.T5Config(**meta["draft"])
-        return _attach_mesh(
+        return attach_mesh(
             t5.build_signatures(params, config, **sig_kwargs), mesh)
-    if family == "resnet":
-        from min_tfs_client_tpu.models import resnet
-
-        if "stage_sizes" in config_kwargs:
-            config_kwargs["stage_sizes"] = tuple(config_kwargs["stage_sizes"])
-        config = resnet.ResNetConfig(**config_kwargs)
-        return _attach_mesh(
-            resnet.build_signatures(params, config, **sig_kwargs), mesh)
-    if family == "use":
-        from min_tfs_client_tpu.models import use
-
-        if "seq_buckets" in config_kwargs:
-            config_kwargs["seq_buckets"] = tuple(config_kwargs["seq_buckets"])
-        if "batch_buckets" in sig_kwargs:
-            sig_kwargs["batch_buckets"] = tuple(sig_kwargs["batch_buckets"])
-        config = use.USEConfig(**config_kwargs)
-        return _attach_mesh(
-            use.build_signatures(params, config, **sig_kwargs), mesh)
-    if family == "mimo":
-        from min_tfs_client_tpu.models import mimo
-
-        if "batch_buckets" in sig_kwargs:
-            sig_kwargs["batch_buckets"] = tuple(sig_kwargs["batch_buckets"])
-        config = mimo.MimoConfig(**config_kwargs)
-        return _attach_mesh(
-            mimo.build_signatures(params, config, **sig_kwargs), mesh)
-    if family == "granite_hybrid":
-        from min_tfs_client_tpu.models import granite_hybrid
-
-        if "batch_buckets" in sig_kwargs:
-            sig_kwargs["batch_buckets"] = tuple(sig_kwargs["batch_buckets"])
-        config = granite_hybrid.GraniteHybridConfig(**config_kwargs)
-        return _attach_mesh(
-            granite_hybrid.build_signatures(params, config, **sig_kwargs),
-            mesh)
-    raise ValueError(f"unknown family {family!r}")  # pragma: no cover
+    if family not in _PLAIN_FAMILIES:  # pragma: no cover
+        raise ValueError(f"unknown family {family!r}")
+    config_class, config_tuples, sig_tuples = _PLAIN_FAMILIES[family]
+    module = importlib.import_module(f"min_tfs_client_tpu.models.{family}")
+    for kwargs, names in ((config_kwargs, config_tuples),
+                          (sig_kwargs, sig_tuples)):
+        kwargs.update((name, tuple(kwargs[name]))
+                      for name in names if name in kwargs)
+    config = getattr(module, config_class)(**config_kwargs)
+    return attach_mesh(
+        module.build_signatures(params, config, **sig_kwargs), mesh)

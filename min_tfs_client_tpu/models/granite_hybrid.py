@@ -10,12 +10,12 @@ head; the published multipliers scale the embedding, every residual
 branch, the attention scores and the logits.
 
 Served as whole generations on `serving_default` through the
-whole-generation front (servables/decode_signatures.whole_generation)
-over the decode contract, as models/mimo.py: `prefill(params, ids) ->
-state`, `step(params, state) -> (state', token)`. The state carries two
-kinds of memory through one loop: for a state-space layer the last
-`d_conv - 1` rows before the convolution and the recurrent state (N x
-channels float32 a sequence, the same at any context), for an attention
+whole-generation front (servables/decode_signatures.generation_signature)
+over the decode contract, `prefill(params, ids) -> state` and `step(params,
+state) -> (state', token)`, both written over models/packed.py. The state
+carries two kinds of memory through one loop: for a state-space layer the
+last `d_conv - 1` rows before the convolution and the recurrent state (N
+x channels float32 a sequence, the same at any context), for an attention
 layer a full-length K/V cache; with each example's own length.
 
 Numerics: matrices and their operands in the parameters' dtype (bfloat16
@@ -27,33 +27,24 @@ softmax, the router and the logits in float32.
 from __future__ import annotations
 
 import dataclasses
-import time
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from min_tfs_client_tpu.models import layers as nn
-from min_tfs_client_tpu.models.mimo import (
-    PREFILL_ROW_BLOCK,
-    _attend_cache,
-    _mm,
-    _norm,
-    note_route,
-    route_counts,
-)
+from min_tfs_client_tpu.models import packed
 from min_tfs_client_tpu.ops import ssm
 from min_tfs_client_tpu.ops.attention import attention
 from min_tfs_client_tpu.parallel.moe import HeldExperts, held_experts_ffn
 
-# What `generate/state` carries, one row an example: its prompt tokens,
+# The columns of `state_counts`, one row an example: its prompt tokens,
 # the rows the chunked scan ran for it in one state-space layer, the
 # bytes of state it holds through the loop, its decode steps; and of its
 # BATCH, on every row: the (row, state-space layer, step) recurrent states
 # the decode steps held and those they moved (the rows the batch owns).
-STATE_COUNTS = ("prompt_tokens", "scan_rows", "state_bytes", "steps",
-                "state_rows_held", "state_rows_moved")
-STATE_BATCH_COUNTS = ("state_rows_held", "state_rows_moved")
+STATE_COLUMNS = ("prompt_tokens", "scan_rows", "state_bytes", "steps",
+                 "state_rows_held", "state_rows_moved")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -114,11 +105,6 @@ class GraniteHybridConfig:
     @property
     def head_dim(self) -> int:
         return self.hidden_size // self.num_heads
-
-    @property
-    def moe_pattern(self) -> tuple:
-        """Every layer has an expert layer (what mimo.route_counts reads)."""
-        return (1,) * self.num_layers
 
     @property
     def state_bytes(self) -> int:
@@ -221,11 +207,15 @@ def init_params(rng: jax.Array, config: GraniteHybridConfig) -> dict:
 # -- pieces -------------------------------------------------------------------
 
 
+def _norm(params: dict, x: jax.Array, config: GraniteHybridConfig):
+    return nn.rms_norm(params, x, eps=config.eps)
+
+
 def _swiglu(p: dict, x: jax.Array) -> jax.Array:
     """x (T, D) float32 (normed) -> the shared expert's rows, float32."""
     f = p["w_out"].shape[0]
-    hidden = _mm(x, p["w_in"], p["w_in"].dtype).astype(jnp.float32)
-    return _mm(jax.nn.silu(hidden[:, :f]) * hidden[:, f:], p["w_out"])
+    hidden = nn.mm(x, p["w_in"], p["w_in"].dtype).astype(jnp.float32)
+    return nn.mm(jax.nn.silu(hidden[:, :f]) * hidden[:, f:], p["w_out"])
 
 
 def _experts(config: GraniteHybridConfig, layer: dict, x: jax.Array,
@@ -257,7 +247,7 @@ def _in_projection(config: GraniteHybridConfig, p: dict, x: jax.Array):
     (T, heads) float32, softplus'd."""
     di, cd = config.d_inner, config.conv_dim
     dtype = p["in"]["kernel"].dtype
-    proj = _mm(x, p["in"]["kernel"])
+    proj = nn.mm(x, p["in"]["kernel"])
     dt = jax.nn.softplus(proj[:, di + cd:] + p["dt_bias"])
     return proj[:, :di].astype(dtype), proj[:, di:di + cd].astype(dtype), dt
 
@@ -267,14 +257,14 @@ def _gated_out(config: GraniteHybridConfig, p: dict, y: jax.Array,
     """RMSNorm(y * silu(z)) over all of d_inner, then the out-projection,
     times the residual multiplier. -> (T, D) float32."""
     gated = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
-    return config.residual_multiplier * _mm(
+    return config.residual_multiplier * nn.mm(
         _norm(p["norm"], gated, config), p["out"]["kernel"])
 
 
 def _qkv(config: GraniteHybridConfig, attn: dict, x: jax.Array):
     """x (..., D) -> q (..., H, d), k and v (..., kv, d), no positions."""
     h, kv, hd = config.num_heads, config.num_kv_heads, config.head_dim
-    fused = _mm(x, attn["qkv"]["kernel"], attn["qkv"]["kernel"].dtype)
+    fused = nn.mm(x, attn["qkv"]["kernel"], attn["qkv"]["kernel"].dtype)
     lead = fused.shape[:-1]
     return (fused[..., :h * hd].reshape(*lead, h, hd),
             fused[..., h * hd:(h + kv) * hd].reshape(*lead, kv, hd),
@@ -286,56 +276,21 @@ def _qkv(config: GraniteHybridConfig, attn: dict, x: jax.Array):
 
 def _prefill_chunk(params: dict, config: GraniteHybridConfig, ids: jax.Array,
                    max_decode_len: int, row_block: int):
-    """Some examples (b, S) through the whole stack -> (caches, logits at
-    each example's last position (b, V), held pairs (b,), load (layers,
-    held experts), rows of per-token work run, rows the scan ran (b,)).
-
-    The residual stream is PACKED as in models/mimo.py: the chunk's real
-    tokens first, in (example, position) order, and everything that
-    treats rows one by one (norms, projections, the convolution, the
-    gate, the shared expert, the router, residual sums) runs in blocks
-    of `row_block` rows, as many as the real tokens fill. The scan and
-    attention see the (example, position) grid: their operands are cut
-    out of the packed rows an example at a time (the rows behind an
-    example's last are whatever lies there: the scan gives them dt = 0,
-    attention masks them), and their output is read back by row index."""
-    b, s = ids.shape
-    block = min(row_block, b * s)
-    t = -(-b * s // block) * block
-    lengths = jnp.sum((ids != config.pad_id).astype(jnp.int32), axis=-1)
-    ends = jnp.cumsum(lengths)
-    starts, total = ends - lengths, ends[-1]
-    blocks = (total + block - 1) // block
-    row = jnp.arange(t)
-    example = jnp.minimum(jnp.searchsorted(ends, row, side="right"), b - 1)
-    position = row - starts[example]
-    on_grid = jnp.clip(example * s + position, 0, b * s - 1)
-    h = _embed(params, config, jnp.where(
-        row < total, ids.reshape(-1)[on_grid], config.pad_id))
+    """Some examples (b, S) through the whole stack, as
+    `packed.prefill_by_chunks` takes them, with the rows the scan ran as
+    the model's own count. The residual stream is PACKED
+    (`packed.Packing`): what treats rows one by one (norms, projections,
+    the convolution, the gate, the shared expert, the router, residual
+    sums) runs over the blocks the real tokens fill; the scan and
+    attention see the (example, position) grid (of the rows behind an
+    example's last the scan makes dt = 0, attention masks them) and
+    their output is read back by row index."""
+    pk = packed.pack(ids, config.pad_id, row_block)
+    b, s, t, block, cut, put = pk.b, pk.s, pk.t, pk.block, pk.cut, pk.put
+    lengths, ends = pk.lengths, pk.ends
+    h = _embed(params, config, pk.tokens)
     dtype = params["embed"]["embedding"].dtype
     taps = config.mamba_d_conv
-
-    def over_blocks(body, carry):
-        return jax.lax.fori_loop(
-            0, blocks, lambda i, c: body(i * block, c), carry)
-
-    def cut(x, lo):
-        return jax.lax.dynamic_slice_in_dim(x, lo, block)
-
-    def put(x, part, lo):
-        return jax.lax.dynamic_update_slice_in_dim(x, part, lo, 0)
-
-    def grid(packed):
-        """(t, width) packed -> (b, S, width): example e's S rows from
-        its first (starts[e] + S <= (e + 1) S: inside the buffer)."""
-        return jnp.stack([jax.lax.dynamic_slice_in_dim(packed, starts[e], s)
-                          for e in range(b)])
-
-    def back(on_the_grid, lo):
-        """The block's rows of what the scan or attention gave (b * S,
-        width); rows past the last real one read zeros."""
-        real = (lo + jnp.arange(block) < total)[:, None]
-        return jnp.where(real, on_the_grid[cut(on_grid, lo)], 0)
 
     caches, held, loads, scanned = [], jnp.zeros((b,), jnp.int32), [], None
     for kind, layer in zip(config.layer_types, params["layers"]):
@@ -349,7 +304,7 @@ def _prefill_chunk(params: dict, config: GraniteHybridConfig, ids: jax.Array,
                 # the causal convolution over the packed rows: a tap that
                 # reaches before its example's first row reads nothing
                 seen = jnp.concatenate([tail, pre_]).astype(jnp.float32)
-                at = cut(position, lo)[:, None]
+                at = cut(pk.position, lo)[:, None]
                 conv = p["conv_bias"] + sum(
                     jnp.where(at >= taps - 1 - k, seen[k:k + block], 0.0)
                     * p["conv"][k] for k in range(taps))
@@ -357,16 +312,16 @@ def _prefill_chunk(params: dict, config: GraniteHybridConfig, ids: jax.Array,
                         put(mixed, jax.nn.silu(conv).astype(dtype), lo),
                         put(dt, dt_, lo), pre_[block - (taps - 1):])
 
-            z, pre, mixed, dt, _ = over_blocks(project, (
+            z, pre, mixed, dt, _ = pk.over_blocks(project, (
                 jnp.zeros((t, config.d_inner), dtype),
                 jnp.zeros((t, config.conv_dim), dtype),
                 jnp.zeros((t, config.conv_dim), dtype),
                 jnp.zeros((t, config.mamba_n_heads), jnp.float32),
                 jnp.zeros((taps - 1, config.conv_dim), dtype)))
-            mixed = grid(mixed)
+            mixed = pk.grid(mixed)
             di, n = config.d_inner, config.mamba_d_state
             y, state, scanned = ssm.ssd(
-                mixed[..., :di], grid(dt), -jnp.exp(p["a_log"]),
+                mixed[..., :di], pk.grid(dt), -jnp.exp(p["a_log"]),
                 mixed[..., di:di + n], mixed[..., di + n:], p["d"], lengths,
                 chunk=config.mamba_chunk_size)
             # the window decoding goes on from: the last rows before the
@@ -379,7 +334,8 @@ def _prefill_chunk(params: dict, config: GraniteHybridConfig, ids: jax.Array,
             mixer_rows = y.reshape(b * s, -1)
 
             def mixer_out(lo, p=p, z=z, mixer_rows=mixer_rows):
-                return _gated_out(config, p, back(mixer_rows, lo), cut(z, lo))
+                return _gated_out(config, p, pk.back(mixer_rows, lo),
+                                  cut(z, lo))
         else:
             attn = layer["attn"]
             widths = (config.num_heads * config.head_dim,
@@ -392,12 +348,13 @@ def _prefill_chunk(params: dict, config: GraniteHybridConfig, ids: jax.Array,
                 return tuple(put(all_, part.reshape(block, -1), lo)
                              for all_, part in zip(qkv, parts))
 
-            qkv = over_blocks(project, tuple(jnp.zeros((t, w), dtype)
-                                             for w in widths))
-            q, k, v = (grid(x).reshape(b, s, heads, -1).transpose(0, 2, 1, 3)
-                       for x, heads in zip(qkv, (config.num_heads,
-                                                 config.num_kv_heads,
-                                                 config.num_kv_heads)))
+            qkv = pk.over_blocks(project, tuple(jnp.zeros((t, w), dtype)
+                                                for w in widths))
+            q, k, v = (
+                pk.grid(x).reshape(b, s, heads, -1).transpose(0, 2, 1, 3)
+                for x, heads in zip(qkv, (config.num_heads,
+                                          config.num_kv_heads,
+                                          config.num_kv_heads)))
             out = attention(q, k, v, causal=True, lengths=lengths,
                             causal_offset=0,
                             scale=config.attention_multiplier,
@@ -407,8 +364,8 @@ def _prefill_chunk(params: dict, config: GraniteHybridConfig, ids: jax.Array,
             mixer_rows = out.transpose(0, 2, 1, 3).reshape(b * s, -1)
 
             def mixer_out(lo, attn=attn, mixer_rows=mixer_rows):
-                return config.residual_multiplier * _mm(
-                    back(mixer_rows, lo), attn["out"]["kernel"])
+                return config.residual_multiplier * nn.mm(
+                    pk.back(mixer_rows, lo), attn["out"]["kernel"])
 
         def mix(lo, carry, layer=layer, mixer_out=mixer_out):
             h, normed = carry
@@ -418,85 +375,52 @@ def _prefill_chunk(params: dict, config: GraniteHybridConfig, ids: jax.Array,
                 layer["shared"], x)
             return put(h, rows, lo), put(normed, x, lo)
 
-        h, normed = over_blocks(mix, (h, jnp.zeros(
+        h, normed = pk.over_blocks(mix, (h, jnp.zeros(
             (t, config.hidden_size), jnp.float32)))
-        h, routed = _experts(config, layer, normed, rows=total, onto=h)
-        counted = jnp.concatenate([jnp.zeros((1,), jnp.int32),
-                                   jnp.cumsum(routed.held)])
-        held = held + counted[ends] - counted[starts]
+        h, routed = _experts(config, layer, normed, rows=pk.total, onto=h)
+        held = pk.held_by_example(routed.held, onto=held)
         loads.append(routed.load)
-    last = jnp.where(lengths[:, None] > 0, h[jnp.maximum(ends - 1, 0)], 0.0)
+    last = pk.last_rows(h)
     if scanned is None:
         scanned = jnp.zeros((b,), jnp.int32)
     return (caches, _logits(params, config, last), held, jnp.stack(loads),
-            blocks * block, scanned)
+            pk.blocks * pk.block, {"scan_rows": scanned})
 
 
 def prefill(params: dict, config: GraniteHybridConfig, input_ids: jax.Array,
             *, max_decode_len: int,
-            row_block: int = PREFILL_ROW_BLOCK) -> dict:
-    """The prompts (B, seq_len), right-padded with pad_id, through the
-    stack (`config.prefill_rows` examples at a time) -> the state a
-    generation carries: per state-space layer the convolution's window
-    and the recurrent state AFTER EACH EXAMPLE'S LAST REAL TOKEN, per
-    attention layer K/V of seq_len + max_decode_len positions, each
-    example's length, the logits its next token is chosen from, `token`,
-    `finished`, and what the prefill, the scan and the expert layers
-    counted. A row of length 0 (one that pads the batch) touches nothing:
-    zero state, zero window."""
-    ids = jnp.asarray(input_ids, jnp.int32)
-    b, s = ids.shape
-    rows = min(config.prefill_rows, b)
-    if b % rows:
-        rows = b
-    caches, logits, held, load, ran, scanned = jax.lax.map(
+            row_block: int = packed.PREFILL_ROW_BLOCK) -> dict:
+    """The prompts (B, seq_len), right-padded with pad_id -> the state a
+    generation carries (models/packed.py), `config.prefill_rows` examples
+    at a time. Its caches: per state-space layer the convolution's
+    window and the recurrent state AFTER EACH EXAMPLE'S LAST REAL TOKEN,
+    per attention layer K/V of seq_len + max_decode_len positions. A row
+    of length 0 (one that pads the batch) touches nothing: zero state,
+    zero window."""
+    return packed.prefill_by_chunks(
         lambda chunk: _prefill_chunk(params, config, chunk, max_decode_len,
                                      row_block),
-        ids.reshape(b // rows, rows, s))
-    merge = lambda x: x.reshape(b, *x.shape[2:])  # noqa: E731
-    load = jnp.sum(load, axis=0)
-    lengths = jnp.sum((ids != config.pad_id).astype(jnp.int32), axis=-1)
-    return {
-        "caches": jax.tree_util.tree_map(merge, caches),
-        "length": lengths,
-        "logits": merge(logits),
-        "token": jnp.full((b, 1), config.pad_id, jnp.int32),
-        "finished": jnp.zeros((b,), jnp.bool_),
-        "counts": {"prompt_tokens": lengths, "held_prefill": merge(held),
-                   "held_decode": jnp.zeros((b,), jnp.int32),
-                   "steps": jnp.zeros((b,), jnp.int32),
-                   "max_load": jnp.max(load, initial=0),
-                   "load_total": jnp.sum(load),
-                   "prefill_rows": jnp.sum(ran),
-                   "hit_decode": jnp.zeros((), jnp.int32),
-                   "scan_rows": merge(scanned).astype(jnp.int32),
-                   "state_rows_held": jnp.zeros((), jnp.int32),
-                   "state_rows_moved": jnp.zeros((), jnp.int32)},
-    }
+        input_ids, rows=config.prefill_rows, pad_id=config.pad_id,
+        extra_counts=("state_rows_held", "state_rows_moved"))
 
 
 # -- one decode step ----------------------------------------------------------
 
 
 def step(params: dict, config: GraniteHybridConfig, state: dict):
-    """(state) -> (state', token (B,)): choose each example's next token
-    from the state's logits (greedy; a finished example gives pad_id),
-    feed it through the stack: a state-space layer shifts its window by
-    the token's row and moves its recurrent state one step, where it
-    lies; the attention layer writes its cache at the example's own
-    position. A prompt of length 0 (a row that pads the batch) is routed
-    to no expert, and its recurrent states are neither read nor
-    written."""
-    token = jnp.argmax(state["logits"], axis=-1).astype(jnp.int32)
-    token = jnp.where(state["finished"], config.pad_id, token)
-    finished = jnp.logical_or(state["finished"], token == config.eos_id)
-    position = state["length"]
+    """(state) -> (state', token (B,)): each example's next token
+    (`packed.choose`) through the stack: a state-space layer shifts its
+    window by the token's row and moves its recurrent state one step,
+    where it lies; the attention layer writes its cache at the example's
+    own position. A row that pads the batch is routed to no expert, and
+    its recurrent states are neither read nor written."""
+    token, finished, position, owned = packed.choose(
+        state, config.pad_id, config.eos_id)
     b = token.shape[0]
     each = jnp.arange(b)
     h = _embed(params, config, token)
     caches, held = [], jnp.zeros((b,), jnp.int32)
     hit = jnp.zeros((), jnp.int32)
-    owned = state["counts"]["prompt_tokens"] > 0
     states_held = states_moved = jnp.zeros((), jnp.int32)
     for kind, layer, cache in zip(config.layer_types, params["layers"],
                                   state["caches"]):
@@ -523,113 +447,52 @@ def step(params: dict, config: GraniteHybridConfig, state: dict):
             cache = {"k": cache["k"].at[each, :, position].set(k),
                      "v": cache["v"].at[each, :, position].set(v)}
             caches.append(cache)
-            h = h + config.residual_multiplier * _mm(
-                _attend_cache(q, cache, rows <= position[:, None], None,
-                              scale=config.attention_multiplier),
+            h = h + config.residual_multiplier * nn.mm(
+                nn.attend_cache(q, cache, rows <= position[:, None], None,
+                                scale=config.attention_multiplier),
                 attn["out"]["kernel"])
         x = _norm(layer["ffn_norm"], h, config)
         y, routed = _experts(config, layer, x, valid=owned)
         h = h + y + config.residual_multiplier * _swiglu(layer["shared"], x)
         held, hit = held + routed.held, hit + routed.hit
-    counts = dict(state["counts"])
-    counts["held_decode"] = counts["held_decode"] + held
-    counts["hit_decode"] = counts["hit_decode"] + hit
-    counts["steps"] = counts["steps"] + 1
-    counts["state_rows_held"] = counts["state_rows_held"] + states_held
-    counts["state_rows_moved"] = counts["state_rows_moved"] + states_moved
-    return {"caches": caches, "length": position + 1,
-            "logits": _logits(params, config, h), "token": token[:, None],
-            "finished": finished, "counts": counts}, token
-
-
-def state_counts(config: GraniteHybridConfig, state: dict) -> jax.Array:
-    """(B, len(STATE_COUNTS)) int32, one row an example: what
-    `generate/state` carries (the batch's figures on every row)."""
-    counts = state["counts"]
-    columns = {"prompt_tokens": counts["prompt_tokens"],
-               "scan_rows": counts["scan_rows"],
-               "state_bytes": jnp.full_like(counts["steps"],
-                                            config.state_bytes),
-               "steps": counts["steps"],
-               **{name: jnp.broadcast_to(counts[name],
-                                         counts["steps"].shape)
-                  for name in STATE_BATCH_COUNTS}}
-    return jnp.stack([columns[name].astype(jnp.int32)
-                      for name in STATE_COUNTS], axis=-1)
+    return packed.advance(
+        state, caches, _logits(params, config, h), token, finished,
+        held_decode=held, hit_decode=hit, state_rows_held=states_held,
+        state_rows_moved=states_moved), token
 
 
 # -- serving ------------------------------------------------------------------
 
 
-def note_answer(signature, outputs) -> None:
-    """The `on_answer` of the generation signature: `generate/route` as
-    models/mimo.py notes it, and a request's own rows of `state_counts`
-    as the span `generate/state` on its trace and into the process's
-    counters (`/monitoring/runtime`, `state`, under the signature's
-    label). The batch's figures go as they are, on every rider's span
-    and into every rider's counters: summed over the riders both grow
-    alike, and `state_rows_moved` over `state_rows_held` is what is
-    read."""
-    from min_tfs_client_tpu.observability import runtime, tracing
+def count_tables(config: GraniteHybridConfig) -> tuple:
+    """The expert layers' table (every layer has one) and the state's.
+    The batch's figures go as they are onto every rider's span and into
+    every rider's counters: summed over the riders both grow alike, and
+    `state_rows_moved` over `state_rows_held` is what is read."""
+    from min_tfs_client_tpu.servables.decode_signatures import CountTable
 
-    note_route(signature, outputs)
-    rows = outputs.get("state_counts")
-    if rows is None:
-        return
-    rows = np.asarray(rows).reshape(-1, len(STATE_COUNTS))
-    args = {name: int(rows[:, i].max() if name in STATE_BATCH_COUNTS
-                      else rows[:, i].sum())
-            for i, name in enumerate(STATE_COUNTS)}
-    now = time.perf_counter()
-    tracing.add_span("generate/state", now, now, **args)
-    runtime.count_state(signature.telemetry_label or "unlabeled", args)
+    return (packed.route_table(config.top_k * config.num_layers),
+            CountTable(
+                output="state_counts", span="generate/state",
+                section="state", columns=STATE_COLUMNS,
+                derived={"state_bytes": (None, config.state_bytes)},
+                batch=("state_rows_held", "state_rows_moved")))
 
 
 def build_signatures(params: dict, config: GraniteHybridConfig, *,
                      seq_len: int, max_decode_len: int,
                      batch_buckets: tuple = (1, 4, 16, 32)) -> dict:
-    """`serving_default`: input_ids (B, seq_len) -> output_ids (B,
-    max_decode_len), output_lengths, and of the timed path itself the
-    float32 logits the first and the last generated token were chosen
-    from, with the expert layers' counts (`route_counts`, columns
-    mimo.ROUTE_COUNTS) and the state's (`state_counts`, columns
-    STATE_COUNTS). Of the three states the front hands back only logits
-    and counts are kept: no recurrent state outlives the loop."""
-    from min_tfs_client_tpu.models.mimo import ROUTE_COUNTS
+    """`serving_default` alone (generation_signature), with the expert
+    layers' counts (`route_counts`) and the state's (`state_counts`,
+    columns STATE_COLUMNS): no recurrent state outlives the loop."""
     from min_tfs_client_tpu.servables.decode_signatures import (
-        whole_generation,
+        generation_signature,
     )
-    from min_tfs_client_tpu.servables.servable import Signature, TensorSpec
 
-    def generate_fn(tree, inputs):
-        found = whole_generation(
-            lambda p, ids: prefill(p, config, ids,
-                                   max_decode_len=max_decode_len),
-            lambda p, state: step(p, config, state),
-            tree, inputs["input_ids"], max_decode_len=max_decode_len,
-            pad_id=config.pad_id)
-        return {"output_ids": found["output_ids"],
-                "output_lengths": found["output_lengths"],
-                "first_logits": found["first"]["logits"],
-                "last_logits": found["before_last"]["logits"],
-                "route_counts": route_counts(config, found["final"]),
-                "state_counts": state_counts(config, found["final"])}
-
-    generate = Signature(
-        fn=generate_fn, params=params,
-        inputs={"input_ids": TensorSpec(np.int32, (None, seq_len))},
-        outputs={
-            "output_ids": TensorSpec(np.int32, (None, max_decode_len)),
-            "output_lengths": TensorSpec(np.int32, (None,)),
-            "first_logits": TensorSpec(np.float32,
-                                       (None, config.vocab_size)),
-            "last_logits": TensorSpec(np.float32, (None, config.vocab_size)),
-            "route_counts": TensorSpec(np.int32,
-                                       (None, len(ROUTE_COUNTS))),
-            "state_counts": TensorSpec(np.int32,
-                                       (None, len(STATE_COUNTS)))},
-        batch_buckets=tuple(batch_buckets),
-        # a padding row is a prompt of length 0: no state, no expert
-        batch_pad_values={"input_ids": config.pad_id},
-        on_answer=note_answer)
-    return {"serving_default": generate}
+    return {"serving_default": generation_signature(
+        lambda p, ids: prefill(p, config, ids,
+                               max_decode_len=max_decode_len),
+        lambda p, state: step(p, config, state), params,
+        seq_len=seq_len, max_decode_len=max_decode_len,
+        vocab_size=config.vocab_size, pad_id=config.pad_id,
+        batch_buckets=batch_buckets, tables=count_tables(config))}

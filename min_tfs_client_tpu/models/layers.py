@@ -19,7 +19,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from min_tfs_client_tpu.ops.attention import attention, attention_rows
+from min_tfs_client_tpu.ops.attention import NEG_INF, attention, attention_rows
 
 COMPUTE_DTYPE = jnp.bfloat16
 
@@ -97,12 +97,12 @@ def mha_init(rng, d_model: int, num_heads: int, *, d_kv: Optional[int] = None,
     }
 
 
-def _heads(x: jax.Array, num_heads: int) -> jax.Array:
+def heads(x: jax.Array, num_heads: int) -> jax.Array:
     b, s, d = x.shape
     return x.reshape(b, s, num_heads, d // num_heads).transpose(0, 2, 1, 3)
 
 
-def _unheads(x: jax.Array) -> jax.Array:
+def unheads(x: jax.Array) -> jax.Array:
     b, h, s, d = x.shape
     return x.transpose(0, 2, 1, 3).reshape(b, s, h * d)
 
@@ -137,10 +137,10 @@ def mha(
        the cache behind it (speculative decoding's target pass).
     Returns (output, updated_cache).
     """
-    q = _heads(dense(params["query"], x), num_heads)
+    q = heads(dense(params["query"], x), num_heads)
     src = x if kv is None else kv
-    k = _heads(dense(params["key"], src), num_heads)
-    v = _heads(dense(params["value"], src), num_heads)
+    k = heads(dense(params["key"], src), num_heads)
+    v = heads(dense(params["value"], src), num_heads)
 
     causal_offset = None
     if cache is not None:
@@ -176,10 +176,10 @@ def mha(
 
         out = ring_attention(q, k, v, mesh=seq_mesh, causal=causal,
                              lengths=lengths, scale=scale)
-        return dense(params["out"], _unheads(out)), cache
+        return dense(params["out"], unheads(out)), cache
     out = attention(q, k, v, causal=causal, lengths=lengths, bias=bias,
                     scale=scale, causal_offset=causal_offset)
-    return dense(params["out"], _unheads(out)), cache
+    return dense(params["out"], unheads(out)), cache
 
 
 def cross_rows(blocks: list[dict], kv: jax.Array) -> dict:
@@ -279,3 +279,41 @@ def lengths_from_mask(mask: jax.Array) -> jax.Array:
 
 def count_params(params) -> int:
     return sum(int(np.prod(p.shape)) for p in jax.tree_util.tree_leaves(params))
+
+
+# -- what the decoders served as whole generations share ----------------------
+
+
+def mm(x: jax.Array, kernel: jax.Array, out_dtype=jnp.float32) -> jax.Array:
+    """x @ kernel with the operands in the kernel's dtype and float32
+    accumulation."""
+    return jnp.dot(x.astype(kernel.dtype), kernel,
+                   preferred_element_type=jnp.float32).astype(out_dtype)
+
+
+def attend_cache(q: jax.Array, cache: dict, seen: jax.Array,
+                 sink: jax.Array | None,
+                 scale: float | None = None) -> jax.Array:
+    """One query row a head over a cache, in plain jnp: q (B, H, dk),
+    cache k (B, kv, S, dk) / v (B, kv, S, dv), `seen` (B, S) bool the
+    rows this example's query may read; scores times `scale` (dk ** -0.5
+    where none is given). -> (B, H * dv) in q's dtype."""
+    b, h, dk = q.shape
+    if scale is None:
+        scale = dk ** -0.5
+    kv = cache["k"].shape[1]
+    scores = jnp.einsum("bngd,bnsd->bngs", q.reshape(b, kv, h // kv, dk),
+                        cache["k"], preferred_element_type=jnp.float32)
+    scores = jnp.where(seen[:, None, None, :], scores * scale, NEG_INF)
+    top = jnp.max(scores, axis=-1, keepdims=True)
+    if sink is not None:
+        sink = sink.astype(jnp.float32).reshape(1, kv, h // kv, 1)
+        top = jnp.maximum(top, sink)
+    weights = jnp.exp(scores - top)
+    total = jnp.sum(weights, axis=-1, keepdims=True)
+    if sink is not None:
+        total = total + jnp.exp(sink - top)
+    weights = (weights / total).astype(cache["v"].dtype)
+    out = jnp.einsum("bngs,bnsd->bngd", weights, cache["v"],
+                     preferred_element_type=jnp.float32)
+    return out.reshape(b, -1).astype(q.dtype)

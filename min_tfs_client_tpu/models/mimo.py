@@ -8,12 +8,12 @@ in the leading layers and a sigmoid-routed top-k expert layer after
 (parallel/moe.py: this chip's share of the experts, dropless).
 
 Served as whole generations on `serving_default` through the
-whole-generation front (servables/decode_signatures.whole_generation)
-over the decode contract: `prefill(params, ids) -> state`, `step(params,
-state) -> (state', token)`. The state carries two kinds of cache through
-one loop: a full-length K/V cache for each full layer, a ring of `window`
-rows for each window layer (a position is written at position mod
-window), with each example's own length.
+whole-generation front (servables/decode_signatures.generation_signature)
+over the decode contract, `prefill(params, ids) -> state` and `step(params,
+state) -> (state', token)`, both written over models/packed.py. The state
+carries two kinds of cache through one loop: a full-length K/V cache for
+each full layer, a ring of `window` rows for each window layer (a position
+is written at position mod window), with each example's own length.
 
 Numerics: matrices and their operands in the parameters' dtype
 (bfloat16 as served) with float32 accumulation; the residual stream, the
@@ -23,21 +23,14 @@ norms, the softmax, the router's scores and the logits in float32.
 from __future__ import annotations
 
 import dataclasses
-import time
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from min_tfs_client_tpu.models import layers as nn
-from min_tfs_client_tpu.ops.attention import NEG_INF, attention
+from min_tfs_client_tpu.models import packed
+from min_tfs_client_tpu.ops.attention import attention
 from min_tfs_client_tpu.parallel.moe import HeldExperts, held_experts_ffn
-
-ROUTE_COUNTS = ("prompt_tokens", "pairs_prefill", "held_prefill",
-                "pairs_decode", "held_decode", "max_load", "load_total",
-                "prefill_rows", "hit_decode")
-# ... of which these are the whole batch's, the same on every row
-BATCH_COUNTS = ("max_load", "load_total", "prefill_rows", "hit_decode")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -150,13 +143,6 @@ def init_params(rng: jax.Array, config: MimoConfig) -> dict:
 # -- pieces -------------------------------------------------------------------
 
 
-def _mm(x: jax.Array, kernel: jax.Array, out_dtype=jnp.float32) -> jax.Array:
-    """x @ kernel with the operands in the kernel's dtype and float32
-    accumulation."""
-    return jnp.dot(x.astype(kernel.dtype), kernel,
-                   preferred_element_type=jnp.float32).astype(out_dtype)
-
-
 def _rope(x: jax.Array, positions: jax.Array, theta: float,
           rot: int) -> jax.Array:
     """Rotary embedding on the first `rot` dims of a head, halves paired
@@ -180,7 +166,7 @@ def _qkv(config: MimoConfig, layer: int, attn: dict, x: jax.Array,
     h, dk, dv = config.num_heads, config.head_dim, config.v_head_dim
     kv = config.kv_heads(layer)
     dtype = attn["qkv"]["kernel"].dtype
-    fused = _mm(x, attn["qkv"]["kernel"], dtype)
+    fused = nn.mm(x, attn["qkv"]["kernel"], dtype)
     lead = fused.shape[:-1]
     q = fused[..., :h * dk].reshape(*lead, h, dk)
     k = fused[..., h * dk:(h + kv) * dk].reshape(*lead, kv, dk)
@@ -198,10 +184,10 @@ def _ffn(config: MimoConfig, layer: dict, x: jax.Array, **routing):
     if "mlp" in layer:
         wi, wo = layer["mlp"]["wi"]["kernel"], layer["mlp"]["wo"]["kernel"]
         f = wo.shape[0]
-        hidden = _mm(x, wi, wi.dtype)
+        hidden = nn.mm(x, wi, wi.dtype)
         hidden = (jax.nn.silu(hidden[:, :f].astype(jnp.float32))
                   * hidden[:, f:].astype(jnp.float32))
-        return _mm(hidden, wo), None
+        return nn.mm(hidden, wo), None
     return held_experts_ffn(
         HeldExperts(**layer["moe"]), x, top_k=config.top_k,
         experts_held=config.experts_held,
@@ -213,7 +199,7 @@ def _norm(params: dict, x: jax.Array, config: MimoConfig) -> jax.Array:
 
 
 def _logits(params: dict, config: MimoConfig, h: jax.Array) -> jax.Array:
-    return _mm(_norm(params["final_norm"], h, config),
+    return nn.mm(_norm(params["final_norm"], h, config),
                params["head"]["kernel"])
 
 
@@ -231,56 +217,18 @@ def _ring_of(rows: jax.Array, lengths: jax.Array, window: int) -> jax.Array:
     return jnp.take_along_axis(rows, position[:, None, :, None], axis=2)
 
 
-PREFILL_ROW_BLOCK = 512   # packed rows the per-token work takes at a time
-
-
 def _prefill_chunk(params: dict, config: MimoConfig, ids: jax.Array,
                    max_decode_len: int, row_block: int):
-    """Some examples (b, S) through the whole stack -> (caches, logits
-    at each example's last position (b, V), held pairs (b,), load
-    (expert layers, held experts), rows of per-token work run).
-
-    The residual stream is PACKED: the chunk's real tokens first, in
-    (example, position) order, and everything that treats rows one by
-    one (norms, projections, rotation, dense layer, router, residual
-    sums) runs in blocks of `row_block` rows, as many as the real tokens
-    fill. Attention alone sees the (example, position) grid: q, k and v
-    are cut out of the packed rows an example at a time (the rows behind
-    an example's last are whatever lies there; the kernel masks them),
-    and its output is read back by row index."""
-    b, s = ids.shape
-    block = min(row_block, b * s)
-    t = -(-b * s // block) * block
-    lengths = jnp.sum((ids != config.pad_id).astype(jnp.int32), axis=-1)
-    ends = jnp.cumsum(lengths)
-    starts, total = ends - lengths, ends[-1]
-    blocks = (total + block - 1) // block
-    row = jnp.arange(t)
-    example = jnp.minimum(jnp.searchsorted(ends, row, side="right"), b - 1)
-    position = row - starts[example]
-    # where a packed row lies on the grid (rows past the last: anywhere)
-    on_grid = jnp.clip(example * s + position, 0, b * s - 1)
-    h = params["embed"]["embedding"][jnp.where(
-        row < total, ids.reshape(-1)[on_grid], config.pad_id)].astype(
-            jnp.float32)
-
-    def over_blocks(body, carry):
-        return jax.lax.fori_loop(
-            0, blocks, lambda i, c: body(i * block, c), carry)
-
-    def cut(x, lo):
-        return jax.lax.dynamic_slice_in_dim(x, lo, block)
-
-    def put(x, part, lo):
-        return jax.lax.dynamic_update_slice_in_dim(x, part, lo, 0)
-
-    def grid(packed, heads):
-        """(t, heads * d) packed -> (b, heads, S, d): example e's S rows
-        from its first (starts[e] + S <= (e + 1) S: inside the buffer)."""
-        rows = jnp.stack([jax.lax.dynamic_slice_in_dim(packed, starts[e], s)
-                          for e in range(b)])
-        return rows.reshape(b, s, heads, -1).transpose(0, 2, 1, 3)
-
+    """Some examples (b, S) through the whole stack, as
+    `packed.prefill_by_chunks` takes them. The residual stream is PACKED
+    (`packed.Packing`): what treats rows one by one (norms, projections,
+    rotation, dense layer, router, residual sums) runs over the blocks
+    the real tokens fill; attention alone sees the (example, position)
+    grid (the kernel masks the rows behind an example's last) and its
+    output is read back by row index."""
+    p = packed.pack(ids, config.pad_id, row_block)
+    b, s, t, cut, put = p.b, p.s, p.t, p.cut, p.put
+    h = params["embed"]["embedding"][p.tokens].astype(jnp.float32)
     dtype = params["layers"][0]["attn"]["qkv"]["kernel"].dtype
     caches, held, loads = [], jnp.zeros((b,), jnp.int32), []
     for index, layer in enumerate(params["layers"]):
@@ -292,17 +240,17 @@ def _prefill_chunk(params: dict, config: MimoConfig, ids: jax.Array,
         def project(lo, qkv, h=h, index=index, layer=layer, attn=attn):
             parts = _qkv(config, index, attn,
                          _norm(layer["attn_norm"], cut(h, lo), config),
-                         cut(position, lo))
-            return tuple(put(all_, part.reshape(block, -1), lo)
+                         cut(p.position, lo))
+            return tuple(put(all_, part.reshape(p.block, -1), lo)
                          for all_, part in zip(qkv, parts))
 
         # rows that no block writes stay zeros: masked positions read them
-        qkv = over_blocks(project, tuple(jnp.zeros((t, w), dtype)
-                                         for w in widths))
-        q, k, v = (grid(x, heads) for x, heads in
-                   zip(qkv, (config.num_heads, kv, kv)))
+        qkv = p.over_blocks(project, tuple(jnp.zeros((t, w), dtype)
+                                           for w in widths))
+        q, k, v = (p.grid(x).reshape(b, s, heads, -1).transpose(0, 2, 1, 3)
+                   for x, heads in zip(qkv, (config.num_heads, kv, kv)))
         out = attention(
-            q, k, v, causal=True, lengths=lengths, causal_offset=0,
+            q, k, v, causal=True, lengths=p.lengths, causal_offset=0,
             window=config.window if windowed else None,
             sink=attn.get("sink"), queries_ragged=True)
         out = out.transpose(0, 2, 1, 3).reshape(b * s, -1)
@@ -310,123 +258,60 @@ def _prefill_chunk(params: dict, config: MimoConfig, ids: jax.Array,
 
         def mix(lo, carry, layer=layer, attn=attn, out=out, dense=dense):
             h, normed = carry
-            rows = cut(h, lo) + _mm(out[cut(on_grid, lo)],
-                                    attn["out"]["kernel"])
+            rows = cut(h, lo) + nn.mm(out[cut(p.on_grid, lo)],
+                                      attn["out"]["kernel"])
             x = _norm(layer["ffn_norm"], rows, config)
             if dense:
                 return put(h, rows + _ffn(config, layer, x)[0], lo), normed
             return put(h, rows, lo), put(normed, x, lo)
 
-        h, normed = over_blocks(mix, (h, None if dense else jnp.zeros(
+        h, normed = p.over_blocks(mix, (h, None if dense else jnp.zeros(
             (t, config.hidden_size), jnp.float32)))
         if not dense:
-            h, routed = _ffn(config, layer, normed, rows=total, onto=h)
-            counted = jnp.concatenate([jnp.zeros((1,), jnp.int32),
-                                       jnp.cumsum(routed.held)])
-            held = held + counted[ends] - counted[starts]
+            h, routed = _ffn(config, layer, normed, rows=p.total, onto=h)
+            held = p.held_by_example(routed.held, onto=held)
             loads.append(routed.load)
         if windowed:
-            caches.append({"k": _ring_of(k, lengths, config.window),
-                           "v": _ring_of(v, lengths, config.window)})
+            caches.append({"k": _ring_of(k, p.lengths, config.window),
+                           "v": _ring_of(v, p.lengths, config.window)})
         else:
             room = ((0, 0), (0, 0), (0, max_decode_len), (0, 0))
             caches.append({"k": jnp.pad(k, room), "v": jnp.pad(v, room)})
-    # each example's last row (an example of no token: zeros, whatever its
-    # neighbours are)
-    last = jnp.where(lengths[:, None] > 0, h[jnp.maximum(ends - 1, 0)], 0.0)
-    logits = _logits(params, config, last)
+    logits = _logits(params, config, p.last_rows(h))
     load = (jnp.stack(loads) if loads
             else jnp.zeros((0, config.experts_held), jnp.int32))
-    return caches, logits, held, load, blocks * block
+    return caches, logits, held, load, p.blocks * p.block, {}
 
 
 def prefill(params: dict, config: MimoConfig, input_ids: jax.Array, *,
             max_decode_len: int,
-            row_block: int = PREFILL_ROW_BLOCK) -> dict:
-    """The prompts (B, seq_len), right-padded with pad_id, through the
-    stack (`config.prefill_rows` examples at a time, their real tokens
-    packed and taken `row_block` rows at a time) -> the state a
-    generation carries: per full layer K/V of seq_len + max_decode_len
-    positions, per window layer a ring of `window` rows, each example's
-    length, the logits its next token is chosen from, `token` (the last
-    one chosen), `finished`, and what the prefill and the expert layers
-    counted."""
-    ids = jnp.asarray(input_ids, jnp.int32)
-    b, s = ids.shape
-    rows = min(config.prefill_rows, b)
-    if b % rows:
-        rows = b
-    caches, logits, held, load, ran = jax.lax.map(
+            row_block: int = packed.PREFILL_ROW_BLOCK) -> dict:
+    """The prompts (B, seq_len), right-padded with pad_id -> the state a
+    generation carries (models/packed.py), `config.prefill_rows` examples
+    at a time, their real tokens taken `row_block` packed rows at a time.
+    Its caches: per full layer K/V of seq_len + max_decode_len positions,
+    per window layer a ring of `window` rows."""
+    return packed.prefill_by_chunks(
         lambda chunk: _prefill_chunk(params, config, chunk, max_decode_len,
                                      row_block),
-        ids.reshape(b // rows, rows, s))
-    merge = lambda x: x.reshape(b, *x.shape[2:])  # noqa: E731
-    load = jnp.sum(load, axis=0)
-    lengths = jnp.sum((ids != config.pad_id).astype(jnp.int32), axis=-1)
-    return {
-        "caches": jax.tree_util.tree_map(merge, caches),
-        "length": lengths,
-        "logits": merge(logits),
-        "token": jnp.full((b, 1), config.pad_id, jnp.int32),
-        "finished": jnp.zeros((b,), jnp.bool_),
-        "counts": {"prompt_tokens": lengths, "held_prefill": merge(held),
-                   "held_decode": jnp.zeros((b,), jnp.int32),
-                   "steps": jnp.zeros((b,), jnp.int32),
-                   "max_load": jnp.max(load, initial=0),
-                   "load_total": jnp.sum(load),
-                   "prefill_rows": jnp.sum(ran),
-                   "hit_decode": jnp.zeros((), jnp.int32)},
-    }
+        input_ids, rows=config.prefill_rows, pad_id=config.pad_id)
 
 
 # -- one decode step ----------------------------------------------------------
 
 
-def _attend_cache(q: jax.Array, cache: dict, seen: jax.Array,
-                  sink: jax.Array | None,
-                  scale: float | None = None) -> jax.Array:
-    """One query row a head over a cache, in plain jnp: q (B, H, dk),
-    cache k (B, kv, S, dk) / v (B, kv, S, dv), `seen` (B, S) bool the
-    rows this example's query may read; scores times `scale` (dk ** -0.5
-    where none is given). -> (B, H * dv) in q's dtype."""
-    b, h, dk = q.shape
-    if scale is None:
-        scale = dk ** -0.5
-    kv = cache["k"].shape[1]
-    scores = jnp.einsum("bngd,bnsd->bngs", q.reshape(b, kv, h // kv, dk),
-                        cache["k"], preferred_element_type=jnp.float32)
-    scores = jnp.where(seen[:, None, None, :], scores * scale, NEG_INF)
-    top = jnp.max(scores, axis=-1, keepdims=True)
-    if sink is not None:
-        sink = sink.astype(jnp.float32).reshape(1, kv, h // kv, 1)
-        top = jnp.maximum(top, sink)
-    weights = jnp.exp(scores - top)
-    total = jnp.sum(weights, axis=-1, keepdims=True)
-    if sink is not None:
-        total = total + jnp.exp(sink - top)
-    weights = (weights / total).astype(cache["v"].dtype)
-    out = jnp.einsum("bngs,bnsd->bngd", weights, cache["v"],
-                     preferred_element_type=jnp.float32)
-    return out.reshape(b, -1).astype(q.dtype)
-
-
 def step(params: dict, config: MimoConfig, state: dict):
-    """(state) -> (state', token (B,)): choose each example's next token
-    from the state's logits (greedy; a finished example gives pad_id),
-    feed it through the stack at the example's own position, through
-    both kinds of cache, and leave the logits of the token after it. A
-    prompt of length 0 (a row that pads the batch) is routed to no
-    expert."""
-    token = jnp.argmax(state["logits"], axis=-1).astype(jnp.int32)
-    token = jnp.where(state["finished"], config.pad_id, token)
-    finished = jnp.logical_or(state["finished"], token == config.eos_id)
-    position = state["length"]
+    """(state) -> (state', token (B,)): each example's next token
+    (`packed.choose`) through the stack at the example's own position,
+    through both kinds of cache, to the logits of the token after it. A
+    row that pads the batch is routed to no expert."""
+    token, finished, position, owned = packed.choose(
+        state, config.pad_id, config.eos_id)
     b = token.shape[0]
     each = jnp.arange(b)
     h = params["embed"]["embedding"][token].astype(jnp.float32)
     caches, held = [], jnp.zeros((b,), jnp.int32)
     hit = jnp.zeros((), jnp.int32)
-    owned = state["counts"]["prompt_tokens"] > 0
     for index, (layer, cache) in enumerate(zip(params["layers"],
                                                state["caches"])):
         attn = layer["attn"]
@@ -445,111 +330,35 @@ def step(params: dict, config: MimoConfig, state: dict):
         cache = {"k": cache["k"].at[each, :, slot].set(k),
                  "v": cache["v"].at[each, :, slot].set(v)}
         caches.append(cache)
-        h = h + _mm(_attend_cache(q, cache, seen, attn.get("sink")),
-                    attn["out"]["kernel"])
+        h = h + nn.mm(nn.attend_cache(q, cache, seen, attn.get("sink")),
+                      attn["out"]["kernel"])
         y, routed = _ffn(config, layer, _norm(layer["ffn_norm"], h, config),
                          valid=owned)
         h = h + y
         if routed is not None:
             held, hit = held + routed.held, hit + routed.hit
-    counts = dict(state["counts"])
-    counts["held_decode"] = counts["held_decode"] + held
-    counts["hit_decode"] = counts["hit_decode"] + hit
-    counts["steps"] = counts["steps"] + 1
-    return {"caches": caches, "length": position + 1,
-            "logits": _logits(params, config, h), "token": token[:, None],
-            "finished": finished, "counts": counts}, token
-
-
-def route_counts(config: MimoConfig, state: dict) -> jax.Array:
-    """(B, len(ROUTE_COUNTS)) int32, one row an example: what
-    `generate/route` carries (the batch's figures on every row;
-    `hit_decode`: the (step, expert layer, hit expert) products the
-    decode steps ran, 0 where the pairs were sorted)."""
-    counts = state["counts"]
-    per_token = config.top_k * sum(config.moe_pattern)
-    b = counts["steps"].shape[0]
-    columns = {
-        "prompt_tokens": counts["prompt_tokens"],
-        "pairs_prefill": counts["prompt_tokens"] * per_token,
-        "held_prefill": counts["held_prefill"],
-        "pairs_decode": counts["steps"] * per_token,
-        "held_decode": counts["held_decode"],
-        **{name: jnp.broadcast_to(counts[name], (b,))
-           for name in BATCH_COUNTS},
-    }
-    return jnp.stack([columns[name].astype(jnp.int32)
-                      for name in ROUTE_COUNTS], axis=-1)
+    return packed.advance(state, caches, _logits(params, config, h), token,
+                          finished, held_decode=held, hit_decode=hit), token
 
 
 # -- serving ------------------------------------------------------------------
 
 
-def note_route(signature, outputs) -> None:
-    """The `on_answer` of the generation signature: a request's own rows
-    of `route_counts` as the span `generate/route` on its trace, and into
-    the process's counters (`/monitoring/runtime`, `route`, under the
-    signature's label)."""
-    from min_tfs_client_tpu.observability import runtime, tracing
-
-    rows = outputs.get("route_counts")
-    if rows is None:
-        return
-    rows = np.asarray(rows).reshape(-1, len(ROUTE_COUNTS))
-    sums = rows.sum(axis=0)
-    args = {name: int(rows[:, i].max() if name in BATCH_COUNTS else sums[i])
-            for i, name in enumerate(ROUTE_COUNTS)}
-    now = time.perf_counter()
-    tracing.add_span("generate/route", now, now, **args)
-    counted = {k: v for k, v in args.items() if k not in BATCH_COUNTS}
-    # The batch's rows and trips, a request's share of them: by its share
-    # of the batch's held pairs, the one count whose batch total a row
-    # carries, so that the requests of a batch add up to the batch's figure.
-    share = args["held_prefill"] / max(args["load_total"], 1)
-    for name in ("prefill_rows", "hit_decode"):
-        counted[name] = round(args[name] * share)
-    runtime.count_route(signature.telemetry_label or "unlabeled", counted)
-
-
 def build_signatures(params: dict, config: MimoConfig, *, seq_len: int,
                      max_decode_len: int,
                      batch_buckets: tuple = (1, 4, 16, 32)) -> dict:
-    """`serving_default`: input_ids (B, seq_len) -> output_ids (B,
-    max_decode_len), output_lengths, and of the timed path itself the
-    float32 logits the first and the last generated token were chosen
-    from, with the expert layers' counts (`route_counts`, columns
-    ROUTE_COUNTS). No session signatures yet (ROADMAP, Reach)."""
+    """`serving_default` alone (generation_signature), with the expert
+    layers' counts (`route_counts`, columns packed.ROUTE_COLUMNS). No
+    session signatures yet (ROADMAP, Reach)."""
     from min_tfs_client_tpu.servables.decode_signatures import (
-        whole_generation,
+        generation_signature,
     )
-    from min_tfs_client_tpu.servables.servable import Signature, TensorSpec
 
-    def generate_fn(tree, inputs):
-        found = whole_generation(
-            lambda p, ids: prefill(p, config, ids,
-                                   max_decode_len=max_decode_len),
-            lambda p, state: step(p, config, state),
-            tree, inputs["input_ids"], max_decode_len=max_decode_len,
-            pad_id=config.pad_id)
-        return {"output_ids": found["output_ids"],
-                "output_lengths": found["output_lengths"],
-                "first_logits": found["first"]["logits"],
-                "last_logits": found["before_last"]["logits"],
-                "route_counts": route_counts(config, found["final"])}
-
-    generate = Signature(
-        fn=generate_fn, params=params,
-        inputs={"input_ids": TensorSpec(np.int32, (None, seq_len))},
-        outputs={
-            "output_ids": TensorSpec(np.int32, (None, max_decode_len)),
-            "output_lengths": TensorSpec(np.int32, (None,)),
-            "first_logits": TensorSpec(np.float32,
-                                       (None, config.vocab_size)),
-            "last_logits": TensorSpec(np.float32, (None, config.vocab_size)),
-            "route_counts": TensorSpec(np.int32,
-                                       (None, len(ROUTE_COUNTS)))},
-        batch_buckets=tuple(batch_buckets),
-        # a padding row is a prompt of length 0: no attention, no expert
-        batch_pad_values={"input_ids": config.pad_id},
-        on_answer=note_route)
-    return {"serving_default": generate}
+    return {"serving_default": generation_signature(
+        lambda p, ids: prefill(p, config, ids,
+                               max_decode_len=max_decode_len),
+        lambda p, state: step(p, config, state), params,
+        seq_len=seq_len, max_decode_len=max_decode_len,
+        vocab_size=config.vocab_size, pad_id=config.pad_id,
+        batch_buckets=batch_buckets, tables=(packed.route_table(
+            config.top_k * sum(config.moe_pattern)),))}

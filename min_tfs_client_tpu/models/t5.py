@@ -14,7 +14,6 @@ pre-RMSNorm, ReLU MLP, no biases in dense layers, tied softmax scaled by
 
 from __future__ import annotations
 
-import time
 from dataclasses import dataclass
 
 import jax
@@ -381,7 +380,7 @@ def paged_decoder_positions(params: dict, config: T5Config,
     for i, layer in enumerate(dec["layers"]):
         h = nn.rms_norm(layer["self_norm"], x)
         p = layer["self_attention"]
-        q = nn._heads(nn.dense(p["query"], h), config.num_heads)
+        q = nn.heads(nn.dense(p["query"], h), config.num_heads)
         # K and V go in as the projection leaves them, (B, L, H * D): one
         # arena row a token, its heads side by side.
         kv = kv.append(
@@ -391,7 +390,7 @@ def paged_decoder_positions(params: dict, config: T5Config,
         out = kv.attend(q, _cache_key(i, "k"), _cache_key(i, "v"),
                         bias=bias, scale=1.0, lengths=lengths_in,
                         q_start=q_start)
-        x = x + nn.dense(p["out"], nn._unheads(out))
+        x = x + nn.dense(p["out"], nn.unheads(out))
         h = nn.rms_norm(layer["cross_norm"], x)
         cross, _ = nn.mha(
             layer["cross_attention"], h, num_heads=config.num_heads,
@@ -977,8 +976,6 @@ def build_signatures(params: dict, config: T5Config, *, seq_len: int,
         the tile; `rows_held`, steps x the cache's max_decode_len rows).
         All five also go into the process's counters
         (`/monitoring/runtime`, `route`, under the signature's label)."""
-        from min_tfs_client_tpu.observability import runtime, tracing
-
         tokens = np.sum(np.asarray(inputs["input_ids"]) != config.pad_id,
                         axis=-1).reshape(-1)
         cross = {"input_tokens": int(tokens.sum()),
@@ -986,11 +983,9 @@ def build_signatures(params: dict, config: T5Config, *, seq_len: int,
                  "blocks_held": int(tokens.size * (seq_len // block))}
         cache = {"rows_read": int(tokens.size * self_rows_read),
                  "rows_held": int(tokens.size * max_decode_len ** 2)}
-        now = time.perf_counter()
-        tracing.add_span("generate/cross", now, now, **cross)
-        tracing.add_span("generate/self", now, now, **cache)
-        runtime.count_route(signature.telemetry_label or "unlabeled",
-                            {**cross, **cache})
+        decode_signatures.note_generation(
+            signature, "route", {"generate/cross": cross,
+                                 "generate/self": cache}, {**cross, **cache})
 
     decode_sig = Signature(
         fn=decode_fn,

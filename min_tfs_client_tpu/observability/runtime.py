@@ -315,45 +315,29 @@ def count_transfer(direction: str, nbytes: int) -> None:
         pass
 
 
-_route_counts: dict[str, dict[str, int]] = {}   # guarded_by: _lock
+# {section: {label: {"requests": n, count: total}}}
+_generation_counts: dict[str, dict[str, dict]] = {}   # guarded_by: _lock
 
 
-def count_route(label: str, counts: Mapping[str, int]) -> None:
-    """Accumulate what a model's expert layers counted for one answered
-    request (prompt tokens, token-choice pairs in all and on held
-    experts), under the model's label: `route` in /monitoring/runtime."""
+def count_generation(section: str, label: str,
+                     counts: Mapping[str, int]) -> None:
+    """Accumulate what a whole generation's program counted for one
+    answered request, under the signature's label, in a section of
+    /monitoring/runtime (`route`, `state`, or a model's own:
+    servables/decode_signatures.CountTable)."""
     with _lock:
-        totals = _route_counts.setdefault(label, {"requests": 0})
+        totals = _generation_counts.setdefault(section, {}).setdefault(
+            label, {"requests": 0})
         totals["requests"] += 1
         for name, value in counts.items():
             totals[name] = totals.get(name, 0) + int(value)
 
 
-def route_totals() -> dict:
+def generation_totals(section: str) -> dict:
+    """{label: totals} of one section; {} where nothing wrote it."""
     with _lock:
-        return {label: dict(totals)
-                for label, totals in _route_counts.items()}
-
-
-_state_counts: dict[str, dict[str, int]] = {}   # guarded_by: _lock
-
-
-def count_state(label: str, counts: Mapping[str, int]) -> None:
-    """Accumulate what a model with a recurrent state counted for one
-    answered request (prompt tokens, rows its chunked scan ran, bytes of
-    state held through the loop, decode steps), under the model's label:
-    `state` in /monitoring/runtime."""
-    with _lock:
-        totals = _state_counts.setdefault(label, {"requests": 0})
-        totals["requests"] += 1
-        for name, value in counts.items():
-            totals[name] = totals.get(name, 0) + int(value)
-
-
-def state_totals() -> dict:
-    with _lock:
-        return {label: dict(totals)
-                for label, totals in _state_counts.items()}
+        return {label: dict(totals) for label, totals
+                in _generation_counts.get(section, {}).items()}
 
 
 def transfer_totals() -> dict:
@@ -414,6 +398,8 @@ def gc_pause_seconds() -> dict:
 def snapshot(include_live_arrays: bool = False) -> dict:
     from min_tfs_client_tpu.server import profiler
 
+    with _lock:
+        sections = ("route", "state", *_generation_counts)
     payload = {
         "compile": compile_ledger(),
         "devices": device_memory(),
@@ -421,8 +407,8 @@ def snapshot(include_live_arrays: bool = False) -> dict:
         "profiler": profiler.status(),
         "pipeline": pipeline_stats(),
         "kv_pool": kv_pool_stats(),
-        "route": route_totals(),
-        "state": state_totals(),
+        # What whole generations counted, a section a count table.
+        **{section: generation_totals(section) for section in sections},
         # The gRPC front end: requests answered on the event loop and on
         # the worker pool, and the loop's sampled lag (utils/aio_loop.py).
         "grpc": aio_loop.stats(),

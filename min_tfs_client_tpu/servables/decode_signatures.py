@@ -32,13 +32,18 @@ it is the one seam (`_Backend`), with two implementations:
                 more: decode_step collects it (parked, or from the
                 round under way). Sessions are then single-sequence.
 
+Beside the sessions, a decoder served as WHOLE generations: the loop over
+the same `prefill` / `step` (`whole_generation`) and its one Signature,
+with what the program counted as `CountTable`s (`generation_signature`).
+
 Arrows: models/* -> this module -> decode_sessions -> ops/attention.
 """
 
 from __future__ import annotations
 
 import dataclasses
-from typing import Callable, NamedTuple, Optional
+import time
+from typing import Callable, Mapping, NamedTuple, Optional
 
 import numpy as np
 
@@ -143,6 +148,125 @@ def whole_generation(prefill: Callable, step: Callable, params,
             "output_lengths": jnp.sum(
                 (output_ids != pad_id).astype(jnp.int32), axis=-1),
             "first": first, "before_last": before_last, "final": final}
+
+
+@dataclasses.dataclass(frozen=True)
+class CountTable:
+    """What a generation's program counted, as data: an int32 output of
+    the signature (`output`, a row an example, `columns` in order), the
+    span a request's rows become on its trace, the section of
+    `/monitoring/runtime` they add up in.
+
+    derived    {column: (count, factor)}: the state's count of that name
+               times the factor (no count: the factor on every row); any
+               other column is the state's count of its own name.
+    batch      columns that are the whole batch's, the same on every row:
+               a request's figure is the max over its rows, not the sum.
+    uncounted  columns left out of the section.
+    shared     {batch column: (own, total)}: it enters the section times
+               the request's share of the batch, its rows' `own` column
+               over the batch's `total`."""
+
+    output: str
+    span: str
+    section: str
+    columns: tuple
+    derived: Mapping = dataclasses.field(default_factory=dict)
+    batch: tuple = ()
+    uncounted: tuple = ()
+    shared: Mapping = dataclasses.field(default_factory=dict)
+
+    def rows(self, counts: Mapping):
+        """A state's `counts` -> (B, len(columns)) int32, traced."""
+        import jax.numpy as jnp
+
+        def column(name):
+            count, factor = self.derived.get(name, (name, None))
+            if count is None:
+                return jnp.full_like(counts["steps"], factor)
+            if factor is not None:
+                return counts[count] * factor
+            if name in self.batch:
+                return jnp.broadcast_to(counts[name], counts["steps"].shape)
+            return counts[name]
+
+        return jnp.stack([column(name).astype(jnp.int32)
+                          for name in self.columns], axis=-1)
+
+    def note(self, signature: Signature, outputs: Mapping) -> None:
+        """A request's own rows of the output -> span and section."""
+        rows = outputs.get(self.output)
+        if rows is None:
+            return
+        rows = np.asarray(rows).reshape(-1, len(self.columns))
+        args = {name: int(rows[:, i].max() if name in self.batch
+                          else rows[:, i].sum())
+                for i, name in enumerate(self.columns)}
+        counted = {name: value for name, value in args.items()
+                   if name not in self.uncounted}
+        for name, (own, total) in self.shared.items():
+            counted[name] = round(args[name] * (args[own]
+                                                / max(args[total], 1)))
+        note_generation(signature, self.section, {self.span: args}, counted)
+
+
+def note_generation(signature: Signature, section: str, spans: Mapping,
+                    counted: Mapping) -> None:
+    """What a generation counted for ONE request: `spans` ({name:
+    arguments}) as spans of no duration on its trace, and `counted` into
+    the process's counters (`/monitoring/runtime`, `section`, under the
+    signature's label)."""
+    from min_tfs_client_tpu.observability import runtime, tracing
+
+    now = time.perf_counter()
+    for name, args in spans.items():
+        tracing.add_span(name, now, now, **args)
+    runtime.count_generation(
+        section, signature.telemetry_label or "unlabeled", counted)
+
+
+def generation_signature(prefill: Callable, step: Callable, params, *,
+                         seq_len: int, max_decode_len: int, vocab_size: int,
+                         pad_id: int, batch_buckets: tuple,
+                         tables: tuple = ()) -> Signature:
+    """The one signature of a decoder served as whole generations
+    (`whole_generation` over its `prefill` and `step`): input_ids (B,
+    seq_len) -> output_ids (B, max_decode_len), output_lengths, of the
+    timed path itself the float32 logits the first and the last token
+    were chosen from (of the loop's three states only logits and counts
+    are kept), and an int32 output a count table, which `on_answer` turns
+    into the table's span and section."""
+
+    def generate_fn(tree, inputs):
+        found = whole_generation(prefill, step, tree, inputs["input_ids"],
+                                 max_decode_len=max_decode_len,
+                                 pad_id=pad_id)
+        return {"output_ids": found["output_ids"],
+                "output_lengths": found["output_lengths"],
+                "first_logits": found["first"]["logits"],
+                "last_logits": found["before_last"]["logits"],
+                **{table.output: table.rows(found["final"]["counts"])
+                   for table in tables}}
+
+    def note_answer(signature, outputs):
+        for table in tables:
+            table.note(signature, outputs)
+
+    return Signature(
+        fn=generate_fn, params=params,
+        inputs={"input_ids": TensorSpec(np.int32, (None, seq_len))},
+        outputs={
+            "output_ids": TensorSpec(np.int32, (None, max_decode_len)),
+            "output_lengths": TensorSpec(np.int32, (None,)),
+            "first_logits": TensorSpec(np.float32, (None, vocab_size)),
+            "last_logits": TensorSpec(np.float32, (None, vocab_size)),
+            **{table.output: TensorSpec(np.int32,
+                                        (None, len(table.columns)))
+               for table in tables}},
+        batch_buckets=tuple(batch_buckets),
+        # a padding row is a prompt of length 0: `owned` by no request
+        batch_pad_values={"input_ids": pad_id},
+        on_answer=note_answer)
 
 
 class _Step:

@@ -141,9 +141,9 @@ def cross(out: dict, name: str, config, rows, lengths) -> None:
 
     def by_reference(q, rows, i, lengths):
         found = attention.attention_reference(
-            nn._heads(q, h), nn._heads(rows["key"][i], h),
-            nn._heads(rows["value"][i], h), lengths=lengths, scale=1.0)
-        return nn._unheads(found)
+            nn.heads(q, h), nn.heads(rows["key"][i], h),
+            nn.heads(rows["value"][i], h), lengths=lengths, scale=1.0)
+        return nn.unheads(found)
 
     forms = {"reference": (by_reference, rows)}
     if hasattr(attention, "rows_flash_attention"):

@@ -668,15 +668,33 @@ class TestTickBatcher:
     def test_astep_awaits_its_round_and_one_call_wakes_all_its_riders(self):
         """The coroutine form of `step` (what the gRPC event loop runs):
         a parked token is collected at once; riders that await a round
-        are all resolved by ONE call onto their loop, and ride the next
-        round like riders that block."""
+        under way are all resolved by ONE call onto their loop, and ride
+        the next round like riders that block.
+
+        Which slots a round takes is decided when the loop thread takes
+        its snapshot, so the three slots are made due only while a round
+        of slot 0's is held open (an event, not a clock): the round after
+        it then takes all three."""
         import asyncio
 
-        ticks = _Ticks(hold=2)
+        held = {n: (threading.Event(), threading.Event()) for n in (1, 3, 4)}
+
+        def rows(slots, of_round):
+            if of_round.ordinal in held:
+                holding, let_go = held[of_round.ordinal]
+                holding.set()
+                assert let_go.wait(10)
+            return {s: (s, of_round.ordinal) for s in slots}
+
+        ticks = _Ticks(rows=rows)
         batcher = TickBatcher(ticks)
+        batcher.admit(0, room=8)
+        assert held[1][0].wait(5)                # round 1: slot 0 alone
         for slot in (1, 2, 3):
             batcher.admit(slot, room=8)
-        until(lambda: len(ticks.rounds) == 1 and not tick_loop_threads())
+        held[1][1].set()
+        until(lambda: len(ticks.rounds) == 2 and not tick_loop_threads())
+        assert ticks.slots_of(2) == [1, 2, 3]
 
         async def main():
             loop = asyncio.get_running_loop()
@@ -686,25 +704,35 @@ class TestTickBatcher:
                 calls.append(getattr(fn, "__name__", ""))
                 return threadsafe(fn, *args)
 
+            async def reached(event):
+                assert await loop.run_in_executor(None, event.wait, 5)
+
             loop.call_soon_threadsafe = counted
+            assert await batcher.astep(0) == (0, 1)
+            await reached(held[3][0])            # round 3: slot 0 alone
             first = [await batcher.astep(slot) for slot in (1, 2, 3)]
-            assert first == [(1, 1), (2, 1), (3, 1)]     # parked: no wait
-            await loop.run_in_executor(None, ticks.holding.wait, 5)
+            assert first == [(1, 2), (2, 2), (3, 2)]     # parked: no wait
+            held[3][1].set()
+            await reached(held[4][0])            # round 4: all three, held
+            assert ticks.slots_of(4) == [1, 2, 3]
             riders = [loop.create_task(batcher.astep(slot))
                       for slot in (1, 2, 3)]
-            await asyncio.sleep(0.05)
-            assert not any(r.done() for r in riders)     # round 2 is held
-            ticks.let_go.set()
+            await asyncio.sleep(0)               # each runs up to its wait
+            with batcher._lock:
+                assert all(batcher._slots[slot].waiter is not None
+                           for slot in (1, 2, 3))
+            assert not any(r.done() for r in riders)     # round 4 is held
+            held[4][1].set()
             second = await asyncio.wait_for(asyncio.gather(*riders),
                                             timeout=10)
-            assert second == [(1, 2), (2, 2), (3, 2)]
+            assert second == [(1, 4), (2, 4), (3, 4)]
             assert calls.count("_resolve") == 1
             return calls
 
         asyncio.run(main())
-        until(lambda: len(ticks.rounds) == 3)    # handed out: due at once
-        assert ticks.slots_of(3) == [1, 2, 3]
-        for slot in (1, 2, 3):
+        until(lambda: len(ticks.rounds) == 5)    # handed out: due at once
+        assert ticks.slots_of(5) == [1, 2, 3]
+        for slot in (0, 1, 2, 3):
             batcher.release(slot)
 
     def test_astep_raises_a_tick_wide_exception_and_returns_a_slots_error(

@@ -297,7 +297,7 @@ def test_the_four_shares_add_up_to_the_uncut_layer(tiny):
 
 
 def test_an_answer_carries_its_route_and_its_state(tiny):
-    from min_tfs_client_tpu.models.mimo import ROUTE_COUNTS
+    from min_tfs_client_tpu.models.packed import ROUTE_COLUMNS
     from min_tfs_client_tpu.observability import runtime, tracing
 
     signature = gh.build_signatures(
@@ -310,7 +310,7 @@ def test_an_answer_carries_its_route_and_its_state(tiny):
         signature.on_answer(signature, out)      # what the handlers do
     assert out["output_ids"].shape == (12, 8)
     assert out["first_logits"].shape == out["last_logits"].shape == (12, 96)
-    assert out["route_counts"].shape == (12, len(ROUTE_COUNTS))
+    assert out["route_counts"].shape == (12, len(ROUTE_COLUMNS))
     rows = out["state_counts"]
     assert rows[:, 0].tolist() == list(LENGTHS)
     per_sequence = tiny["program_config"].state_bytes

@@ -15,7 +15,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from min_tfs_client_tpu.models import export, mimo
+from min_tfs_client_tpu.models import export, mimo, packed
 from min_tfs_client_tpu.ops.attention import (
     attention_reference,
     flash_attention,
@@ -97,8 +97,8 @@ def test_every_decode_step_through_ring_and_full_caches_agrees(tiny):
         [np.arange(LENGTHS[r] - 1, LENGTHS[r] - 1 + STEPS) for r in rows])
     for r, logits in zip(rows, want):
         np.testing.assert_allclose(chosen_from[r], logits, atol=5e-4)
-    counts = np.asarray(mimo.route_counts(config, state))
     per_token = config.top_k * sum(config.moe_pattern)
+    counts = np.asarray(packed.route_table(per_token).rows(state["counts"]))
     assert counts[:, 0].tolist() == list(LENGTHS)
     assert (counts[:, 1] == np.asarray(LENGTHS) * per_token).all()
     assert (counts[:, 3] == STEPS * per_token).all()
@@ -156,8 +156,9 @@ def test_the_packed_prefill_agrees_with_the_references_full_forward(
 
     # what the expert layers counted: the pairs of the real tokens alone
     held, load = _held_pairs_by_the_reference(tiny, ids, lengths)
-    counts = dict(zip(mimo.ROUTE_COUNTS,
-                      np.asarray(mimo.route_counts(config, prefilled)).T))
+    table = packed.route_table(config.top_k * sum(config.moe_pattern))
+    counts = dict(zip(packed.ROUTE_COLUMNS,
+                      np.asarray(table.rows(prefilled["counts"])).T))
     assert counts["prompt_tokens"].tolist() == list(lengths)
     assert counts["held_prefill"].tolist() == held.tolist()
     assert (counts["max_load"] == load.max()).all()
@@ -220,7 +221,7 @@ def test_whole_generation_equals_prefill_then_steps(tiny):
     np.testing.assert_array_equal(out["output_ids"], np.stack(tokens, 1))
     np.testing.assert_allclose(out["last_logits"], last, atol=1e-4)
     assert out["route_counts"].shape == (len(LENGTHS),
-                                         len(mimo.ROUTE_COUNTS))
+                                         len(packed.ROUTE_COLUMNS))
 
 
 def test_the_rows_that_pad_a_batch_are_prompts_of_length_zero(tiny):
@@ -237,7 +238,7 @@ def test_the_rows_that_pad_a_batch_are_prompts_of_length_zero(tiny):
     whole = signature.run({"input_ids": tiny["ids"]})
     np.testing.assert_array_equal(three["output_ids"],
                                   whole["output_ids"][:3])
-    counts = dict(zip(mimo.ROUTE_COUNTS, three["route_counts"].T))
+    counts = dict(zip(packed.ROUTE_COLUMNS, three["route_counts"].T))
     assert (counts["load_total"] == np.sum(counts["held_prefill"])).all()
 
 
@@ -419,7 +420,7 @@ def test_serving_default_through_a_real_server_with_batching(tiny, tmp_path):
                         "batch_timeout_micros { value: 300000 }\n"
                         "allowed_batch_sizes: 8\n")
     before = {"prompt_tokens": 0, "prefill_rows": 0,
-              **runtime.route_totals().get(LABEL, {})}
+              **runtime.generation_totals("route").get(LABEL, {})}
     server = Server(ServerOptions(
         grpc_port=0, model_name="mimo", model_base_path=str(tmp_path / "mimo"),
         model_platform="jax", enable_batching=True,
@@ -456,7 +457,7 @@ def test_serving_default_through_a_real_server_with_batching(tiny, tmp_path):
               if name == "generate/route"][-len(LENGTHS):]
     assert sorted(r["prompt_tokens"] for r in routes) == sorted(LENGTHS)
     assert all(r["pairs_decode"] == STEPS * 2 * 6 for r in routes)
-    totals = runtime.route_totals()[LABEL]
+    totals = runtime.generation_totals("route")[LABEL]
     assert totals["prompt_tokens"] - before["prompt_tokens"] == sum(LENGTHS)
     # the rows the prefill ran are a batch's figure, the same on each of
     # its riders; the counters give each request its share of them

@@ -247,7 +247,7 @@ def test_t5_whole_generation_notes_what_its_cross_attention_reads():
     for row, n in enumerate((129, 0, 512)):
         ids[row, :n] = 7
     label = sig.telemetry_label or "unlabeled"
-    before = dict(runtime.route_totals().get(label, {}))
+    before = dict(runtime.generation_totals("route").get(label, {}))
     with tracing.request_trace("predict") as trace:
         Handlers._noted("on_request", sig, {"input_ids": ids})
     (args,) = [a for name, _, _, a in trace.spans
@@ -255,7 +255,7 @@ def test_t5_whole_generation_notes_what_its_cross_attention_reads():
     assert args == {"input_tokens": 641,
                     "blocks_read": -(-129 // block) + seq_len // block,
                     "blocks_held": 3 * (seq_len // block)}
-    after = runtime.route_totals()[label]
+    after = runtime.generation_totals("route")[label]
     assert after["requests"] - before.get("requests", 0) == 1
     assert after["blocks_read"] - before.get("blocks_read", 0) == \
         args["blocks_read"]
@@ -281,7 +281,7 @@ def test_t5_whole_generation_notes_what_its_self_attention_copies(
         sig = t5.build_signatures(params, config, seq_len=128,
                                   max_decode_len=steps)[signature]
         label = sig.telemetry_label or "unlabeled"
-        before = dict(runtime.route_totals().get(label, {}))
+        before = dict(runtime.generation_totals("route").get(label, {}))
         with tracing.request_trace("predict") as trace:
             Handlers._noted("on_request", sig, {
                 "input_ids": np.full((examples, 128), 7, np.int32)})
@@ -289,7 +289,7 @@ def test_t5_whole_generation_notes_what_its_self_attention_copies(
             "generate/cross", "generate/self"]
         assert trace.spans[1][3] == {"rows_read": examples * read,
                                      "rows_held": examples * steps * steps}
-        after = runtime.route_totals()[label]
+        after = runtime.generation_totals("route")[label]
         assert after["requests"] - before.get("requests", 0) == 1
         for name, value in trace.spans[1][3].items():
             assert after[name] - before.get(name, 0) == value
