@@ -30,6 +30,7 @@ _PLAIN_FAMILIES = {
     "use": ("USEConfig", ("seq_buckets",), ("batch_buckets",)),
     "mimo": ("MimoConfig", (), ("batch_buckets",)),
     "granite_hybrid": ("GraniteHybridConfig", (), ("batch_buckets",)),
+    "ling_hybrid": ("LingHybridConfig", (), ("batch_buckets",)),
 }
 FAMILIES = ("bert", "t5", *_PLAIN_FAMILIES)
 

@@ -203,6 +203,11 @@ STAGES = (
     # arguments are the numbers (prompt_tokens, scan_rows, state_bytes,
     # steps).
     "generate/state",
+    # What a whole generation's decode steps read of the latent cache it
+    # holds (models/ling_hybrid.py), beside `generate/route` and
+    # `generate/state`: no duration, its arguments are the numbers
+    # (prompt_tokens, steps, latent_rows_read, latent_rows_held).
+    "generate/latent",
     "serving/serialize",
 )
 

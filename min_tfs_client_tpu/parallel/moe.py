@@ -151,10 +151,13 @@ def moe_ffn_reference(params: MoeParams, x: jax.Array) -> jax.Array:
 
 # -- a chip's share of a dropless top-k expert layer -------------------------
 #
-# The served form of expert parallelism (models/mimo.py): the layer is told
-# WHICH experts this chip holds, routes every token over ALL the experts
-# (the router keeps its published width), and computes the part of the
-# result that its own experts give. What the absent experts would add is
+# The served form of expert parallelism (models/mimo.py, granite_hybrid.py,
+# ling_hybrid.py): the layer is told WHICH experts this chip holds, routes
+# every token over ALL the experts (the router keeps its published width)
+# by the model's own rule (`ROUTINGS`: sigmoid top-k, softmax top-k, or
+# group-limited sigmoid top-k, whose group choice too runs over all the
+# experts on every share), and computes the part of the result that its
+# own experts give. What the absent experts would add is
 # left out; the exchange that a deployment runs between its chips is not
 # here, and nothing stands in for it. Nothing is dropped: the static bound
 # is every (token, choice) pair, walked in row blocks by a loop whose trip
@@ -186,7 +189,14 @@ ROW_BLOCK = 2048   # rows a grouped product takes at a time
 # scatter-add that keep a row off the experts it did not choose cost more
 # than they save. On the v5e the walk takes 0.65 / 1.03 / 1.23 / 1.54 ms
 # at 32 / 64 / 128 / 256 rows where the sorted pairs take 0.91 / 2.25 /
-# 2.65 / 3.06 (PERF.md section 5); above 256 nothing was read.
+# 2.65 / 3.06 (16 held, all hit: PERF.md section 5); above 256 nothing
+# was read. The same holds where most held experts are hit by NO row: at
+# 128 held of 512 under the group-limited rule (tests/tpu/ling_pieces.py,
+# PR 51) 12 / 20 / 32 valid rows of 32 hit 27 / 37 / 51 experts and the
+# walk takes 0.62 / 0.82 / 1.11 ms, 22 us a hit expert of 11.8 MB (two
+# thirds of the chip's bandwidth), where the sorted pairs' `ragged_dot`
+# over 128 groups takes 0.80 / 1.06 / 1.42: the walk's list of hit
+# experts (comparisons over held x held) costs nothing to speak of.
 DECODE_ROWS = 256
 
 
@@ -218,7 +228,35 @@ def softmax_top_k(x: jax.Array, router: jax.Array,
     return experts.astype(jnp.int32), jax.nn.softmax(chosen, axis=-1)
 
 
-ROUTINGS = ("sigmoid", "softmax_top_k")
+def sigmoid_grouped_top_k(x: jax.Array, router: jax.Array, bias: jax.Array,
+                          top_k: int, n_group: int, topk_group: int
+                          ) -> tuple[jax.Array, jax.Array]:
+    """Group-limited sigmoid routing (DeepSeek-V3's `noaux_tc`): scores
+    sigmoid(x W) over ALL experts as `sigmoid_top_k`'s; the experts lie
+    in `n_group` groups of equal size, a group's score is the sum of its
+    two largest scores + bias, only the `topk_group` best groups stay in
+    the choice, and the top_k of scores + bias among their experts are
+    chosen; the weights are the chosen scores (no bias) over their sum.
+    Every chip of a layer runs the group choice over all the experts,
+    whichever of them it holds. -> (experts (T, k) int32, weights (T, k)
+    float32)."""
+    scores = jax.nn.sigmoid(jnp.dot(
+        x.astype(jnp.float32), router.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST))
+    biased = scores + bias.astype(jnp.float32)
+    t, e = biased.shape
+    by_group = biased.reshape(t, n_group, e // n_group)
+    best_two, _ = jax.lax.top_k(by_group, 2)
+    _, groups = jax.lax.top_k(jnp.sum(best_two, axis=-1), topk_group)
+    stays = jnp.any(groups[:, :, None] == jnp.arange(n_group), axis=1)
+    _, experts = jax.lax.top_k(jnp.where(
+        stays[:, :, None], by_group, -jnp.inf).reshape(t, e), top_k)
+    chosen = jnp.take_along_axis(scores, experts, axis=-1)
+    return (experts.astype(jnp.int32),
+            chosen / jnp.sum(chosen, axis=-1, keepdims=True))
+
+
+ROUTINGS = ("sigmoid", "softmax_top_k", "sigmoid_grouped")
 
 
 def held_experts_ffn(params: HeldExperts, x: jax.Array, *, top_k: int,
@@ -228,14 +266,18 @@ def held_experts_ffn(params: HeldExperts, x: jax.Array, *, top_k: int,
                      onto: jax.Array | None = None,
                      row_block: int = ROW_BLOCK,
                      routing: str = "sigmoid",
-                     scale: float | None = None
+                     scale: float | None = None,
+                     n_group: int | None = None,
+                     topk_group: int | None = None
                      ) -> tuple[jax.Array, Routed]:
     """x (T, D) -> (y (T, D) float32, Routed): y = sum over a token's
     chosen experts e in [expert_offset, expert_offset + experts_held) of
     w_e * SwiGLU_e(x). A token none of whose choices is held gets 0.
     `routing` (static, the model's to say from its published config) is
     the rule that gives the choices and the w_e: `sigmoid`
-    (`sigmoid_top_k`, with the selection bias) or `softmax_top_k`.
+    (`sigmoid_top_k`, with the selection bias), `softmax_top_k`, or
+    `sigmoid_grouped` (`sigmoid_grouped_top_k`, with the bias, `n_group`
+    and `topk_group`).
     `scale` multiplies every w_e (a model's residual multiplier, so that
     `onto` can be its residual stream).
     `valid` (T,) bool leaves padding rows out of the routing altogether.
@@ -262,6 +304,9 @@ def held_experts_ffn(params: HeldExperts, x: jax.Array, *, top_k: int,
     def choose(some):
         if routing == "sigmoid":
             return sigmoid_top_k(some, params.router, params.bias, top_k)
+        if routing == "sigmoid_grouped":
+            return sigmoid_grouped_top_k(some, params.router, params.bias,
+                                         top_k, n_group, topk_group)
         return softmax_top_k(some, params.router, top_k)
 
     if rows is None:

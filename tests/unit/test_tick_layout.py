@@ -165,3 +165,36 @@ def test_the_step_compiles_for_the_v5e_and_writes_the_state_in_place(
     assert "may-alias" in text[:text.index("\n")] \
         or "must-alias" in text[:text.index("\n")]
     assert not re.search(r"= f32\[32,128,8192\]\S* copy\(", text)
+
+
+# -- the delta-rule step at the published widths ------------------------------
+
+
+@pytest.mark.parametrize("masked", [False, True],
+                         ids=["every_row", "owned_rows"])
+def test_the_kda_step_compiles_for_the_v5e_and_writes_the_state_in_place(
+        v5e, masked):
+    """ling-3.0-flash's KDA layer: 32 heads, a state of 128 x 128 a head.
+    The decay, k and q come in as lane-dense rows (turned into columns in
+    the kernel): no operand one lane wide, so nothing but the state and
+    one small array of rows is moved."""
+    import jax.numpy as jnp
+
+    from min_tfs_client_tpu.ops import kda
+
+    def struct(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    row = struct((32, 32, 128))
+    owned = [struct((32,), np.bool_)] if masked else []
+    compiled = jax.jit(kda.kda_step_kernel, donate_argnums=(0,)).lower(
+        struct((32, 32, 128, 128)), row, row, row, row, struct((32, 32)),
+        *owned).compile()
+    text = compiled.as_text()
+    assert "_kda_step_kernel" in text
+    # the donated state is the output's buffer, and no copy of it is made
+    assert "may-alias" in text[:text.index("\n")] \
+        or "must-alias" in text[:text.index("\n")]
+    assert not re.search(r"= f32\[32,32,128,128\]\S* copy\(", text)
+    # beside the state: the rows and o, not three lane-padded columns
+    assert compiled.memory_analysis().temp_size_in_bytes < 8 << 20
