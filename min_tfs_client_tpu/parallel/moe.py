@@ -21,11 +21,14 @@ expert, scaled by E) is returned for training use.
 
 from __future__ import annotations
 
+import functools
 from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from min_tfs_client_tpu.parallel.mesh import EXPERT_AXIS
@@ -192,11 +195,16 @@ ROW_BLOCK = 2048   # rows a grouped product takes at a time
 # 2.65 / 3.06 (16 held, all hit: PERF.md section 5); above 256 nothing
 # was read. The same holds where most held experts are hit by NO row: at
 # 128 held of 512 under the group-limited rule (tests/tpu/ling_pieces.py,
-# PR 51) 12 / 20 / 32 valid rows of 32 hit 27 / 37 / 51 experts and the
-# walk takes 0.62 / 0.82 / 1.11 ms, 22 us a hit expert of 11.8 MB (two
-# thirds of the chip's bandwidth), where the sorted pairs' `ragged_dot`
-# over 128 groups takes 0.80 / 1.06 / 1.42: the walk's list of hit
-# experts (comparisons over held x held) costs nothing to speak of.
+# PRs 51 and 52) 12 / 20 / 32 valid rows of 32 hit 27 / 37 / 51 experts
+# and the walk as a loop of XLA products takes 0.62 / 0.82 / 1.11 ms, 22
+# us a hit expert of 11.8 MB (two thirds of the chip's bandwidth), where
+# the sorted pairs' `ragged_dot` over 128 groups takes 0.79 / 1.04 /
+# 1.40: the walk's list of hit experts (comparisons over held x held)
+# costs nothing to speak of. As one kernel (`_expert_walk_kernel`, below)
+# the same walk takes 0.43 / 0.59 / 0.81 ms, 16 us a hit expert, where
+# the loop takes 0.58 / 0.78 / 1.07 (16 applications a call: timed call
+# by call from the host as the figures before, 0.57 / 0.64 / 0.86, the
+# first of them the host's dispatch and not the device).
 DECODE_ROWS = 256
 
 
@@ -289,11 +297,15 @@ def held_experts_ffn(params: HeldExperts, x: jax.Array, *, top_k: int,
 
     One algorithm in two forms, chosen by the static row count: up to
     DECODE_ROWS rows (and no `rows=`) the layer walks the experts that
-    were hit (`_by_hit_expert`); above, the (token, choice) pairs are
+    were hit (`_by_hit_expert`: on a TPU as ONE kernel that streams them
+    back to back wherever `_walk_kernel_applies` reads from the shapes
+    that two experts' matrices fit its VMEM, as a loop of XLA products
+    otherwise); above, the (token, choice) pairs are
     sorted by held expert (`_by_sorted_pair`). Per (token, expert) every
-    product, rounding and accumulation is the same in both: rows in the
+    product, rounding and accumulation is the same in all: rows in the
     weights' dtype, float32 accumulation, SwiGLU and the combine in
-    float32; only the order in which a token's terms are added differs.
+    float32; only the order in which a token's terms are added differs
+    (the kernel's is the loop's).
     The router reads x as it is given (float32 from the models)."""
     t, _ = x.shape
     if params.w_in.shape[0] != experts_held:
@@ -405,6 +417,11 @@ def _by_hit_expert(params: HeldExperts, x: jax.Array, local: jax.Array,
     listed = jnp.sum(jnp.where(
         jnp.logical_and(hit, place == each[:, None]), each, 0), axis=1)
     rows = x.astype(params.w_in.dtype)
+    trips = jnp.sum(hit, dtype=jnp.int32)
+    if _on_tpu() and _walk_kernel_applies(params, rows):
+        return (expert_walk_kernel(rows, listed, trips, combine,
+                                   params.w_in, params.w_out, y),
+                load, trips)
 
     def one(i, y):
         e = listed[i]
@@ -414,5 +431,173 @@ def _by_hit_expert(params: HeldExperts, x: jax.Array, local: jax.Array,
                       preferred_element_type=jnp.float32)
         return y + combine[e][:, None] * out
 
-    trips = jnp.sum(hit, dtype=jnp.int32)
     return jax.lax.fori_loop(0, trips, one, y), load, trips
+
+
+# -- the walk as one kernel --------------------------------------------------
+#
+# The loop above is two dependent XLA products a trip, their weight
+# operands sliced by `listed[i]`: trip i + 1 starts nothing before trip i
+# has ended, so every hit expert pays the start and the drain of its own
+# stream of weights. Where an expert is small that is a third of its time
+# (22 us a hit expert of 11.8 MB at 128 held of 512, 14.4 at the v5e's
+# bandwidth; PERF.md section 5). The kernel keeps ONE stream going: the
+# matrices stay where they lie in HBM, expert i + 1's are on their way
+# into one pair of VMEM buffers while expert i's products run out of the
+# other, and an expert that no row chose costs nothing at all.
+
+_WALK_SLOTS = 2       # experts in VMEM: the one computed, the one fetched
+
+
+def _expert_walk_kernel(listed_ref, trips_ref, rows_ref, combine_ref,
+                        onto_ref, w_in_hbm, w_out_hbm, y_ref, in_buf,
+                        out_buf, sem):
+    """y = onto + sum over i < trips of combine[:, e] * (SwiGLU(rows @
+    w_in[e]) @ w_out[e]), e = listed[i], in list order. listed_ref
+    (held,) and trips_ref (1,) in SMEM; rows (T, D) in the weights' dtype,
+    combine (T, held) float32 and onto (T, D) float32 whole in VMEM; w_in
+    (held, D, 2 F) and w_out (held, F, D) in HBM; in_buf and out_buf two
+    slots of one expert's matrices, sem (2, slots) their copies'."""
+    trips = trips_ref[0]
+    d_ff = out_buf.shape[1]
+
+    def copies(i, slot):
+        e = listed_ref[i]
+        return (pltpu.make_async_copy(w_in_hbm.at[e], in_buf.at[slot],
+                                      sem.at[0, slot]),
+                pltpu.make_async_copy(w_out_hbm.at[e], out_buf.at[slot],
+                                      sem.at[1, slot]))
+
+    def start(i, slot):
+        for copy in copies(i, slot):
+            copy.start()
+
+    pl.when(trips > 0)(lambda: start(0, 0))
+    y_ref[...] = onto_ref[...]
+    # which lane of `combine` is expert e's: its column is picked by a
+    # masked sum over the lanes (one term, so exact)
+    expert = jax.lax.broadcasted_iota(jnp.int32, combine_ref.shape, 1)
+
+    def one(i, _):
+        slot = i % _WALK_SLOTS
+        pl.when(i + 1 < trips)(
+            lambda: start(i + 1, (i + 1) % _WALK_SLOTS))
+        fetched_in, fetched_out = copies(i, slot)
+        fetched_in.wait()  # servelint: blocks a DMA's semaphore on the device
+        h = jnp.dot(rows_ref[...], in_buf[slot],
+                    preferred_element_type=jnp.float32)
+        h = jax.nn.silu(h[:, :d_ff]) * h[:, d_ff:]
+        fetched_out.wait()  # servelint: blocks a DMA's semaphore on the device
+        out = jnp.dot(h.astype(out_buf.dtype), out_buf[slot],
+                      preferred_element_type=jnp.float32)
+        weight = jnp.sum(jnp.where(expert == listed_ref[i],
+                                   combine_ref[...], 0.0),
+                         axis=1, keepdims=True)                  # (T, 1)
+        y_ref[...] += weight * out
+        return None
+
+    jax.lax.fori_loop(0, trips, one, None)
+
+
+def _walk_rows(t: int, dtype) -> int:
+    """Rows as the kernel sees them: whole sublane tiles of `dtype`."""
+    tile = 8 * 4 // jnp.dtype(dtype).itemsize
+    return -(-t // tile) * tile
+
+
+def _walk_vmem_bytes(t: int, held: int, d: int, d_ff: int,
+                     itemsize: int) -> int:
+    """VMEM the kernel takes: `_WALK_SLOTS` experts' matrices; the rows,
+    combine, `onto` and y, each in the two buffers a call's operands
+    get; the products' float32 results (T, 2 F), (T, F) and (T, D), the
+    middle one again in the weights' dtype."""
+    lanes = -(-held // 128) * 128
+    return (_WALK_SLOTS * 3 * d * d_ff * itemsize
+            + t * (2 * (d * itemsize + 4 * lanes + 2 * 4 * d)
+                   + 4 * (3 * d_ff + d) + d_ff * itemsize))
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def expert_walk_kernel(rows: jax.Array, listed: jax.Array, trips: jax.Array,
+                       combine: jax.Array, w_in: jax.Array, w_out: jax.Array,
+                       onto: jax.Array, *, interpret: bool = False
+                       ) -> jax.Array:
+    """`_by_hit_expert`'s loop as ONE Pallas call: rows (T, D) in the
+    weights' dtype, `listed` (held,) the hit experts first, `trips` ()
+    how many they are, `combine` (held, T) float32, `onto` (T, D)
+    float32 -> onto + the listed experts' weighted rows, float32. Each
+    product, rounding and accumulation is the loop's own, the experts
+    added in list order. `onto`'s buffer is the result's."""
+    t, d = rows.shape
+    held, d_ff, _ = w_out.shape
+    padded = _walk_rows(t, rows.dtype)
+    # XLA plans what IT keeps in VMEM around a call by the limit the call
+    # names: at 64 MiB it moved a whole recurrent state and a latent
+    # cache in and out of VMEM every decode step, at the account and
+    # 4 MiB for what Mosaic keeps it moved nothing (AOT for the v5e,
+    # PERF.md section 6, PR 52)
+    vmem_limit = _walk_vmem_bytes(
+        padded, held, d, d_ff, rows.dtype.itemsize) + (4 << 20)
+    pad = ((0, padded - t), (0, 0))
+    rows, onto, by_row = (jnp.pad(a, pad) if padded > t else a
+                          for a in (rows, onto, combine.T))
+    whole = lambda shape: pl.BlockSpec(  # noqa: E731
+        shape, lambda i, listed, trips: (0, 0))
+    y = pl.pallas_call(
+        _expert_walk_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,  # listed, trips
+            grid=(1,),
+            in_specs=[whole((padded, d)), whole((padded, held)),
+                      whole((padded, d)),
+                      pl.BlockSpec(memory_space=pl.ANY),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=whole((padded, d)),
+            scratch_shapes=[
+                pltpu.VMEM((_WALK_SLOTS, d, 2 * d_ff), w_in.dtype),
+                pltpu.VMEM((_WALK_SLOTS, d_ff, d), w_out.dtype),
+                pltpu.SemaphoreType.DMA((2, _WALK_SLOTS)),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((padded, d), jnp.float32),
+        # operand 4: the two prefetched scalars, rows and combine first
+        input_output_aliases={4: 0},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=vmem_limit),
+        interpret=interpret,
+        name="_expert_walk_kernel",  # the device-trace reduction finds it
+    )(listed.astype(jnp.int32), jnp.reshape(trips, (1,)).astype(jnp.int32),
+      rows, by_row, onto, w_in, w_out)
+    return y[:t]
+
+
+# What the kernel may hold in VMEM by its own account (the v5e has 128
+# MiB). On the v5e, 32 rows, the walk alone (tests/tpu/ling_pieces.py
+# and PERF.md section 6, PR 52): experts of 11.8 MB (2 x 11.8 in flight)
+# 15.7-16.1 us a hit expert where the loop takes 22.2-22.5; of 18.9 MB
+# (2 x 18.9) 25.5-26.0 where the loop takes 33.1-33.4: both inside.
+# Experts of 50.3 MB would want 100.6 MB in flight and stay with the
+# loop (74.4 us a hit expert, 61.4 by their bytes): cut along F they
+# would fit, and were not tried.
+_WALK_VMEM_BYTES = 48 << 20
+
+
+def _walk_kernel_applies(params: HeldExperts, rows: jax.Array) -> bool:
+    """The shapes `_expert_walk_kernel` is written for, read from the
+    shapes alone: rows and gated halves of whole 128-lane tiles, one
+    dtype for both matrices, and two experts' matrices beside the rows
+    inside VMEM (`_walk_vmem_bytes`). One device only: XLA cannot split
+    a Mosaic kernel over a mesh by itself."""
+    held, d_ff, d = params.w_out.shape
+    mesh = jax.sharding.get_abstract_mesh()
+    return (d % 128 == 0 and d_ff % 128 == 0
+            and params.w_in.dtype == params.w_out.dtype == rows.dtype
+            and _walk_vmem_bytes(_walk_rows(rows.shape[0], rows.dtype),
+                                 held, d, d_ff, rows.dtype.itemsize)
+            <= _WALK_VMEM_BYTES
+            and not set(mesh.axis_names) - set(mesh.manual_axes))
+
+
+def _on_tpu() -> bool:
+    return jax.default_backend() == "tpu"

@@ -4,7 +4,8 @@ the widths of perfbench/configs/ling-3.0-flash.json (PERF.md section 5
 and parallel/moe.py's header quote them). Not a test and not part of the
 benchmark: run it on a machine with the chip,
 
-    python tests/tpu/ling_pieces.py [--pieces decode,experts,step] [--out FILE]
+    python tests/tpu/ling_pieces.py [--pieces decode,experts,step,prefill]
+                                    [--out FILE]
 
 and read chiprun_out/ling_pieces.json (or FILE). `decode`: the decode
 step as the loop of a whole generation runs it, a `lax.scan` of 16 steps
@@ -13,8 +14,20 @@ captured once for its device time by operation (a `while` spans its
 body's operations), on 12, 20 and 32 real rows of the traffic's own
 lengths, the rest rows that pad the batch (length 0). `experts`:
 `held_experts_ffn` alone under the group-limited rule, 128 held of 512,
-top 8, on 12, 20 and 32 valid rows of 32 in both forms (the walk over
-hit experts and the sorted pairs): ms a call and the experts hit.
+top 8, on 12, 20 and 32 valid rows of 32: the walk over the hit experts
+in both its forms side by side (`walk`, as the tree runs it: the kernel
+`_expert_walk_kernel` where the gate admits the shapes; `walk_loop`, the
+`fori_loop` of XLA products with the gate held shut by this script) and
+the sorted pairs: ms a call (50 calls from the host, as PR 51 read it:
+under about 0.56 ms it is the host's dispatch that is timed; and
+`_in_a_scan`, 16 applications a call, each added onto the one before),
+the experts hit, us a hit expert and the share of 819 GB/s the hit
+experts' 11.8 MB each come to; and the kernel's result against the
+loop's there. `prefill` (run by no other
+piece's default): the prefill on 12, 20 and 32 real rows, ms a call and
+one call captured by operation; and `kda.kda_chunked` alone on each of
+the prefill's groups of 4 examples, x 6 layers: what the chunked delta
+rule takes of a prefill.
 `step`: `kda.kda_step` alone, a step's 6 calls over 6 states of (32, 32,
 128, 128) float32 donated and handed on, 16 steps a call, with 32, 20, 12
 and 1 of the rows owned: ms a call, and the share of 819 GB/s the owned
@@ -23,9 +36,11 @@ against `kda_step_reference` there.
 """
 
 import argparse
+import functools
 import json
 import pathlib
 import sys
+import tempfile
 import time
 
 ROOT = pathlib.Path(__file__).resolve().parents[2]
@@ -38,7 +53,7 @@ import numpy as np  # noqa: E402
 from min_tfs_client_tpu.models import ling_hybrid as lh  # noqa: E402
 from min_tfs_client_tpu.ops import kda  # noqa: E402
 from min_tfs_client_tpu.parallel import moe  # noqa: E402
-from mimo_pieces import SCAN  # noqa: E402  (beside this file)
+from mimo_pieces import SCAN, ops_a_step  # noqa: E402  (beside this file)
 from perfbench import children  # noqa: E402
 from t5_pieces import timed_and_captured  # noqa: E402  (beside this file)
 
@@ -71,8 +86,9 @@ def decode(out: dict, name: str, params, pc, state) -> None:
 
 
 def experts(out: dict, pc) -> None:
-    """One expert layer alone on a step's rows: the two forms of
-    `held_experts_ffn` under the group-limited rule."""
+    """One expert layer alone on a step's rows: `held_experts_ffn`
+    under the group-limited rule, the walk as kernel and as loop and the
+    sorted pairs."""
     k = jax.random.split(jax.random.PRNGKey(5), 5)
     d, f, held = pc.hidden_size, pc.moe_intermediate_size, pc.experts_held
     params = moe.HeldExperts(
@@ -86,25 +102,83 @@ def experts(out: dict, pc) -> None:
     common = dict(top_k=pc.top_k, experts_held=held, expert_offset=0,
                   routing="sigmoid_grouped", n_group=pc.n_group,
                   topk_group=pc.topk_group, scale=pc.routed_scaling_factor)
-    forms = {
-        "walk": jax.jit(lambda p, x, valid: moe.held_experts_ffn(
-            p, x, valid=valid, **common)),
-        "sorted": jax.jit(lambda p, x, valid: moe.held_experts_ffn(
-            p, x, valid=valid, rows=jnp.asarray(BATCH), **common))}
+
+    def ffn(p, x, valid, **more):
+        return moe.held_experts_ffn(p, x, valid=valid, **common, **more)
+
+    def gate_shut(p, x, valid, **more):
+        """The walk as the loop: traced with the kernel's gate answering
+        no (a tree without the kernel has no gate, and is the loop)."""
+        gate = getattr(moe, "_walk_kernel_applies", None)
+        moe._walk_kernel_applies = lambda *_: False
+        try:
+            return ffn(p, x, valid, **more)
+        finally:
+            if gate is None:
+                del moe._walk_kernel_applies
+            else:
+                moe._walk_kernel_applies = gate
+
+    plain = {"walk": ffn, "walk_loop": gate_shut,
+             "sorted": functools.partial(ffn, rows=jnp.asarray(BATCH))}
+    forms = {name: jax.jit(form) for name, form in plain.items()}
+    # SCAN applications a call, each added onto the one before (so that
+    # none can be left out), the device never waiting for the host; what
+    # does not follow from the carried rows (the router) may be computed
+    # once a call
+    scans = {name: jax.jit(
+        lambda y, p, x, valid, form=form: jax.lax.scan(
+            lambda y, _: (form(p, x, valid, onto=y)[0], None), y, None,
+            length=SCAN)[0], donate_argnums=(0,))
+        for name, form in plain.items()}
+    out["experts_walk_is_the_kernel"] = "_expert_walk_kernel" in forms[
+        "walk"].lower(params, x, jnp.arange(BATCH) < BATCH).as_text()
+    expert_bytes = 3 * d * f * params.w_in.dtype.itemsize
     for real in REAL:
         valid = jnp.arange(BATCH) < real
+        found = {}
         for name, form in forms.items():
             y, routed = jax.block_until_ready(form(params, x, valid))
+            found[name] = y
             clock = time.perf_counter()
             for _ in range(50):
                 y, routed = form(params, x, valid)
             jax.block_until_ready(y)
-            out[f"experts_{name}_{real}_rows_ms"] = (
-                time.perf_counter() - clock) / 50 * 1e3
-            out[f"experts_{real}_rows_hit"] = int(
-                jnp.sum(routed.load > 0))
+            from_the_host = (time.perf_counter() - clock) / 50 * 1e3
+            hit = int(jnp.sum(routed.load > 0))
+            carried = jax.block_until_ready(
+                scans[name](jnp.zeros_like(y), params, x, valid))
+            scan_clock = time.perf_counter()
+            for _ in range(5):
+                carried = scans[name](carried, params, x, valid)
+            jax.block_until_ready(carried)
+            for how, ms in (
+                    ("", from_the_host),
+                    ("_in_a_scan", (time.perf_counter() - scan_clock)
+                     / 5 / SCAN * 1e3)):
+                out[f"experts_{name}_{real}_rows{how}_ms"] = ms
+                out[f"experts_{name}_{real}_rows{how}_us_a_hit_expert"] = (
+                    ms * 1e3 / hit)
+                out[f"experts_{name}_{real}_rows{how}_share_of_hbm_peak"] = (
+                    hit * expert_bytes / HBM_BYTES_PER_S / (ms / 1e3))
+            out[f"experts_{real}_rows_hit"] = hit
             out[f"experts_{real}_rows_held_pairs"] = int(
                 jnp.sum(routed.held))
+        out[f"experts_{real}_rows_walk_vs_loop_max_abs_diff"] = float(
+            jnp.max(jnp.abs(found["walk"] - found["walk_loop"])))
+        out[f"experts_{real}_rows_max_abs"] = float(
+            jnp.max(jnp.abs(found["walk_loop"])))
+
+
+def delta_rule_inputs(k, shape) -> tuple:
+    """(q, k, v, g, beta) of `shape` (..., heads, d): k of unit length,
+    q of unit length times d ** -0.5, g under 0, beta under 1."""
+    unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)  # noqa: E731
+    return (unit(jax.random.normal(k[0], shape)) * shape[-1] ** -0.5,
+            unit(jax.random.normal(k[1], shape)),
+            jax.random.normal(k[2], shape),
+            -5.0 * jax.nn.sigmoid(jax.random.normal(k[3], shape) - 3),
+            jax.nn.sigmoid(jax.random.normal(k[4], shape[:-1])))
 
 
 def step(out: dict, pc) -> None:
@@ -114,12 +188,7 @@ def step(out: dict, pc) -> None:
     layers = pc.layer_types.count("kda")
     h, d = pc.num_heads, pc.head_dim
     k = jax.random.split(jax.random.PRNGKey(3), 5)
-    unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)  # noqa: E731
-    token = (unit(jax.random.normal(k[0], (BATCH, h, d))) * d ** -0.5,
-             unit(jax.random.normal(k[1], (BATCH, h, d))),
-             jax.random.normal(k[2], (BATCH, h, d)),
-             -5.0 * jax.nn.sigmoid(jax.random.normal(k[3], (BATCH, h, d)) - 3),
-             jax.nn.sigmoid(jax.random.normal(k[4], (BATCH, h))))
+    token = delta_rule_inputs(k, (BATCH, h, d))
     scattered = np.zeros((BATCH,), bool)
     scattered[np.random.default_rng(1).permutation(BATCH)[:20]] = True
     masks = {f"{rows}_owned": np.arange(BATCH) < rows
@@ -162,6 +231,33 @@ def step(out: dict, pc) -> None:
             moved / HBM_BYTES_PER_S / (call_ms / 1e3))
 
 
+def kda_alone(out: dict, pc, ids) -> None:
+    """`kda.kda_chunked` alone on each group of `prefill_rows` examples as
+    the prefill meets them (the 32 prompts' own lengths; inputs as
+    `step`'s), ms a group; a prefill of `real` rows runs its first
+    groups, once a KDA layer."""
+    rows, h, d = pc.prefill_rows, pc.num_heads, pc.head_dim
+    token = delta_rule_inputs(jax.random.split(jax.random.PRNGKey(7), 5),
+                              (rows, SEQ_LEN, h, d))
+    run = jax.jit(functools.partial(kda.kda_chunked, chunk=pc.kda_chunk))
+    lengths = np.sum(ids > 0, axis=1)
+    groups = []
+    for lo in range(0, BATCH, rows):
+        group = jnp.asarray(lengths[lo:lo + rows], jnp.int32)
+        found = jax.block_until_ready(run(*token, group))
+        clock = time.perf_counter()
+        for _ in range(3):
+            found = run(*token, group)
+        jax.block_until_ready(found)
+        groups.append((time.perf_counter() - clock) / 3 * 1e3)
+    out["prefill_kda_chunked_alone_ms_a_group"] = groups
+    out["prefill_group_longest_example"] = [
+        int(lengths[lo:lo + rows].max()) for lo in range(0, BATCH, rows)]
+    for real in REAL:
+        out[f"prefill_{real}_real_rows_kda_chunked_alone_ms"] = (
+            pc.layer_types.count("kda") * sum(groups[:-(-real // rows)]))
+
+
 def main() -> None:
     parser = argparse.ArgumentParser()
     parser.add_argument("--pieces", default="decode,experts,step")
@@ -177,21 +273,32 @@ def main() -> None:
         step(out, pc)
     if "experts" in pieces:
         experts(out, pc)
-    if "decode" in pieces:
+    if pieces & {"decode", "prefill"}:
         params = jax.jit(lambda k: lh.init_params(k, pc))(
             jax.random.PRNGKey(1))
         grid = json.loads((ROOT / "perfbench/traffic/long-answers.json")
                           .read_text())["input_length_grid"]
         prefill = jax.jit(lambda p, ids: lh.prefill(
             p, pc, ids, max_decode_len=MAX_DECODE_LEN))
-        for name, ids in prompts(grid, pc.vocab_size).items():
+        given = prompts(grid, pc.vocab_size)
+        if "prefill" in pieces:
+            kda_alone(out, pc, given[f"{BATCH}_real_rows"])
+        for name, ids in given.items():
             clock = time.perf_counter()
             state = jax.block_until_ready(prefill(params, ids))
             out[f"prefill_{name}_first_call_s"] = time.perf_counter() - clock
             clock = time.perf_counter()
             state = jax.block_until_ready(prefill(params, ids))
             out[f"prefill_{name}_ms"] = (time.perf_counter() - clock) * 1e3
-            decode(out, name, params, pc, state)
+            if "prefill" in pieces:
+                with tempfile.TemporaryDirectory() as capture:
+                    jax.profiler.start_trace(capture)
+                    state = jax.block_until_ready(prefill(params, ids))
+                    jax.profiler.stop_trace()
+                    out[f"prefill_{name}_ops"] = ops_a_step(
+                        capture, most=30, steps=1)
+            if "decode" in pieces:
+                decode(out, name, params, pc, state)
     print(json.dumps(out, indent=1))
     pathlib.Path(args.out).parent.mkdir(parents=True, exist_ok=True)
     pathlib.Path(args.out).write_text(json.dumps(out, indent=1))

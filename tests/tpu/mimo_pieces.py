@@ -191,11 +191,12 @@ def decode(out: dict, name: str, params, pc, state) -> None:
                                      * sum(pc.moe_pattern))
 
 
-def ops_a_step(capture: str, most: int = 40) -> dict:
+def ops_a_step(capture: str, most: int = 40, steps: int = SCAN) -> dict:
     """The capture's device operations by name (XLA's instances of one
     operation summed; a `copy*` or `slice*` by the shape it moves too,
-    as the event's HLO text gives it): ms and calls a decode step. A
-    `while` spans its body's operations: it is listed and not summed."""
+    as the event's HLO text gives it): ms and calls a decode step, of
+    the `steps` the captured call ran. A `while` spans its body's
+    operations: it is listed and not summed."""
     (path,) = pathlib.Path(capture).rglob("*.xplane.pb")
     found: dict = {}
     for plane in jax.profiler.ProfileData.from_file(str(path)).planes:
@@ -212,8 +213,8 @@ def ops_a_step(capture: str, most: int = 40) -> dict:
                     name += " " + (moved.group(0) if moved else "?")
                 entry = found.setdefault(name, {"ms_a_step": 0.0,
                                                 "calls_a_step": 0.0})
-                entry["ms_a_step"] += event.duration_ns / 1e6 / SCAN
-                entry["calls_a_step"] += 1 / SCAN
+                entry["ms_a_step"] += event.duration_ns / 1e6 / steps
+                entry["calls_a_step"] += 1 / steps
     listed = sorted(found.items(), key=lambda kv: -kv[1]["ms_a_step"])
     return {"sum_without_while_ms": sum(
                 e["ms_a_step"] for n, e in listed if not n.startswith("while")),

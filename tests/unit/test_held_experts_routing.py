@@ -176,3 +176,84 @@ def test_the_other_rules_lower_as_they_did_without_the_group_arguments():
         assert plain.lower(params, c["x"]).as_text() \
             == named.lower(params, c["x"]).as_text()
     assert "sigmoid_grouped" in moe.ROUTINGS
+
+
+# -- the walk's kernel and its gate (PR 52) ------------------------------------
+
+# (held, D, F) of the three decoders' expert layers as the cells run them
+WIDTHS = {"ling": (128, 2560, 768), "granite": (18, 4096, 768),
+          "mimo": (16, 4096, 2048)}
+INSIDE = {"ling": True, "granite": True, "mimo": False}
+
+
+def shapes_of(held, d, f, t=32, dtype=jnp.bfloat16, experts=None):
+    """A layer as shapes alone: nothing here has a value to read."""
+    s = jax.ShapeDtypeStruct
+    return (moe.HeldExperts(s((d, experts or held), jnp.float32),
+                            s((experts or held,), jnp.float32),
+                            s((held, d, 2 * f), dtype),
+                            s((held, f, d), dtype)),
+            s((t, d), jnp.float32))
+
+
+def test_the_gate_of_the_walks_kernel_reads_shapes_alone():
+    """`_walk_kernel_applies` answers from shapes and dtypes (here there
+    is nothing else to read): lane multiples, one dtype, and two experts'
+    matrices beside the rows inside its VMEM budget."""
+    def answer(held, d, f, t=32, dtype=jnp.bfloat16, rows_dtype=None):
+        params, x = shapes_of(held, d, f, t, dtype)
+        return moe._walk_kernel_applies(
+            params, jax.ShapeDtypeStruct(x.shape, rows_dtype or dtype))
+
+    for name, widths in WIDTHS.items():
+        for t in (1, 4, 16, 32):                    # a decode step's rows
+            assert answer(*widths, t=t) is INSIDE[name], (name, t)
+    # the rows stand in VMEM beside the two experts in flight
+    assert answer(*WIDTHS["ling"], t=moe.DECODE_ROWS)
+    assert not answer(*WIDTHS["granite"], t=moe.DECODE_ROWS)
+    assert not answer(128, 2560 + 64, 768)          # rows of split lane tiles
+    assert not answer(128, 2560, 768 + 64)          # a gated half of them
+    assert not answer(128, 2560, 768, rows_dtype=jnp.float32)
+    assert answer(128, 2560, 768, dtype=jnp.float32)
+    assert not answer(18, 4096, 768, dtype=jnp.float32)   # twice the bytes
+    # how many are held says nothing about what is in flight
+    assert answer(8, 2560, 768) and answer(512, 2560, 768)
+    # arrays of the same shapes, whatever they hold
+    c = case(tokens=8, experts=16, seed=4)
+    for scale in (0.0, 1.0, 1e30):
+        params = moe.HeldExperts(c["router"], c["bias"], c["w_in"] * scale,
+                                 c["w_out"] * scale)
+        assert not moe._walk_kernel_applies(params, c["x"] * scale)
+
+
+@pytest.mark.parametrize("name", list(WIDTHS))
+def test_a_decoder_the_gate_leaves_out_lowers_as_it_did(name, monkeypatch):
+    """On a TPU the call forms of the decoders whose shapes fall outside
+    the gate lower, for the TPU, to the very text of the loop (what the
+    parent's `_by_hit_expert` is, and what runs off the TPU); the one
+    inside lowers to `_expert_walk_kernel` and no loop of products."""
+    held, d, f = WIDTHS[name]
+    forms = {"mimo": dict(top_k=8, experts=256),
+             "granite": dict(top_k=10, experts=72, routing="softmax_top_k",
+                             scale=0.22),
+             "ling": dict(top_k=8, experts=512, routing="sigmoid_grouped",
+                          n_group=8, topk_group=4, scale=2.5)}[name]
+    params, x = shapes_of(held, d, f, experts=forms.pop("experts"))
+    valid = jax.ShapeDtypeStruct((32,), jnp.bool_)
+
+    def lowered():
+        return jax.jit(lambda p, x, valid, onto: moe.held_experts_ffn(
+            p, x, experts_held=held, expert_offset=0, valid=valid,
+            onto=onto, **forms)).trace(params, x, valid, x).lower(
+                lowering_platforms=("tpu",)).as_text()
+
+    off_the_tpu = lowered()
+    assert "tpu_custom_call" not in off_the_tpu
+    monkeypatch.setattr(moe, "_on_tpu", lambda: True)
+    on_the_tpu = lowered()
+    if INSIDE[name]:
+        assert "_expert_walk_kernel" in on_the_tpu
+        assert on_the_tpu.count("stablehlo.dot_general") \
+            < off_the_tpu.count("stablehlo.dot_general")
+    else:
+        assert on_the_tpu == off_the_tpu

@@ -198,3 +198,35 @@ def test_the_kda_step_compiles_for_the_v5e_and_writes_the_state_in_place(
     assert not re.search(r"= f32\[32,32,128,128\]\S* copy\(", text)
     # beside the state: the rows and o, not three lane-padded columns
     assert compiled.memory_analysis().temp_size_in_bytes < 8 << 20
+
+
+# -- the walk over the hit experts at the published widths ---------------------
+
+
+def test_the_expert_walk_compiles_for_the_v5e_and_adds_onto_its_input(v5e):
+    """ling-3.0-flash's expert layer as a decode step meets it: 128 held
+    experts of (2560, 2 x 768) and (768, 2560) bfloat16, 32 rows. Two
+    experts' matrices (23.6 MB) stand in VMEM under the limit the call
+    names; the matrices themselves stay where they lie (no temporary of
+    their size), and `onto`, donated, is the result's buffer."""
+    import jax.numpy as jnp
+
+    from min_tfs_client_tpu.parallel import moe
+
+    def struct(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    held, d, f, rows = 128, 2560, 768, 32
+    params = moe.HeldExperts(None, None,
+                             struct((held, d, 2 * f), jnp.bfloat16),
+                             struct((held, f, d), jnp.bfloat16))
+    assert moe._walk_kernel_applies(params, struct((rows, d), jnp.bfloat16))
+    compiled = jax.jit(moe.expert_walk_kernel, donate_argnums=(6,)).lower(
+        struct((rows, d), jnp.bfloat16), struct((held,), jnp.int32),
+        struct((), jnp.int32), struct((held, rows)), params.w_in,
+        params.w_out, struct((rows, d))).compile()
+    text = compiled.as_text()
+    assert "_expert_walk_kernel" in text
+    assert "may-alias" in text[:text.index("\n")] \
+        or "must-alias" in text[:text.index("\n")]
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
