@@ -480,7 +480,7 @@ def _prefill_chunk(params: dict, config: LingHybridConfig, ids: jax.Array,
                 jnp.zeros((t, heads), jnp.float32),
                 jnp.zeros((taps - 1, 3 * hk), dtype)))
             q, k, v = _kda_heads(config, pk.grid(mixed))
-            o, state, scanned = kda.kda_chunked(
+            o, state, scanned = kda.kda_prefill(
                 q, k, v, pk.grid(g).reshape(b, s, heads, config.head_dim),
                 pk.grid(beta), lengths, chunk=config.kda_chunk)
             # the window decoding goes on from: the last rows before the

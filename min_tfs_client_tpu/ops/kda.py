@@ -1,6 +1,6 @@
 """Delta-rule linear attention with a per-channel decay (KDA, Kimi Delta
 Attention, arXiv:2510.26692): the chunked form a prefill runs and the
-one-token step a decode loop runs.
+one-token step a decode loop runs, each in plain jnp and as a kernel.
 
 The recurrence, a head of d_k key channels and d_v value channels, its
 state S (d_k, d_v) float32:
@@ -17,7 +17,7 @@ are columns, v and o are rows). Positions at or past an example's length
 take g = 0 and beta = 0: the state passes them unchanged, so the state
 handed on is the state after the last real token.
 
-Two forms of the prefill, one arithmetic:
+Three forms of the prefill, one arithmetic:
  * `kda_reference`  the recurrence token by token (a `lax.scan` over
    time): the definition, and the tests' yardstick;
  * `kda_chunked`    plain jnp over chunks of `chunk` rows with the state
@@ -35,11 +35,19 @@ Two forms of the prefill, one arithmetic:
    token: -320 a chunk of 64): A is computed in sub-blocks of 16 rows,
    each against its OWN reference, G at the sub-block's first row, so
    that every exponent is at most 15 x 5 = 75 (float32 holds exp(88)).
+   What the CPU and other platforms run, and the tests' second yardstick;
+ * `kda_chunk_kernel`  the same as the Pallas kernel `_kda_chunk_kernel`:
+   a grid of (example, chunk), the chunks of an example in order with its
+   heads' states resident, the chunks past ITS length neither fetched nor
+   run; every operand read where it lies (q, k, v (B, S, H, d): a head's
+   rows a strided load; g and o rows of every head's channels: a head a
+   lane slice). The system is solved exactly: forward substitution over
+   the sub-blocks, each diagonal sub-block inverted as the finite series
+   of a nilpotent matrix (`_inverse_of_one_plus`). What the chip runs:
+   `kda_prefill` dispatches behind a gate that reads shapes.
 and two of the step: `kda_step_reference` (jnp) and `kda_step_kernel`
 (Pallas, `_kda_step_kernel`, the state updated where it lies).
-`kda_step` dispatches behind the same gate as ops/attention.py. The
-chunked form has no kernel yet: `kda_chunked` is what the chip runs too
-(PERF.md section 7).
+`kda_step` dispatches behind the same gate as ops/attention.py.
 
 Precision: everything float32; the products of the chunked form at
 "highest" matmul precision (the sub-blocks' operands span 30 orders of
@@ -156,6 +164,211 @@ def kda_chunked(q, k, v, g, beta, lengths=None, *, chunk: int = 64):
         0, run, one, (jnp.zeros((b, s + pad, h, dv), jnp.float32),
                       jnp.zeros((b, h, dk, dv), jnp.float32)))
     return o[:, :s], state, jnp.full((b,), run * c, jnp.int32)
+
+
+# -- chunked, the Pallas kernel ----------------------------------------------
+
+
+def _inverse_of_one_plus(n: jax.Array, eye: jax.Array, dot) -> jax.Array:
+    """(I + n)^-1 for n strictly lower triangular in diagonal blocks of
+    `_SUB` rows, so n^16 = 0: the finite series I - n + n^2 - ... as the
+    product (I - n)(I + n^2)(I + n^4)(I + n^8), which is exact. The next
+    power and the next factor come out of ONE product, their left sides
+    stacked: [n^2p; x n^p] = [n^p; x] n^p."""
+    rows = n.shape[0]
+    inverse, power = eye - n, dot(n, n)
+    for _ in range(_SUB.bit_length() - 3):
+        both = dot(jnp.concatenate([power, inverse]), power)
+        power, inverse = both[:rows], inverse + both[rows:]
+    return inverse + dot(inverse, power)
+
+
+def _kda_chunk_kernel(len_ref, q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref,
+                      s_ref, *, chunk: int):
+    """One (example, chunk) grid cell; the chunks of an example run in
+    order and `s_ref`, its states (H, d_k, d_v), stays resident across
+    them: zeroed at the first, written out after the last. A chunk at or
+    past the example's length is not run (its blocks name the example's
+    last chunk, so nothing was fetched for it) and its rows of o are
+    zeros.
+
+    Refs: len_ref (B,) SMEM; q and k (C * H, d_k) and v (C * H, d_v), row
+    t * H + h the position t of head h, as (B, S, H, d) lies: a head's
+    rows are a load with a stride of H sublanes; g (C, H * d_k) and o (C,
+    H * d_v), a head a slice of whole lane tiles, as rows of every head's
+    channels lie; beta (C, H) every head's on the lanes. A head's
+    arithmetic is `kda_chunked`'s with the rows' beta on the LEFT of the
+    system,
+
+        (I + Diag(beta) A) W = Diag(beta) (V - (K exp(G)) S_0),  W = beta U,
+
+    solved exactly by forward substitution over the sub-blocks of 16
+    rows, each diagonal sub-block inverted as the finite series of a
+    nilpotent matrix (`_inverse_of_one_plus`)."""
+    example, index = pl.program_id(0), pl.program_id(1)
+    length = len_ref[example]
+    heads, dk, dv = s_ref.shape
+    f32 = jnp.float32
+    dot = functools.partial(jnp.dot, precision=_HIGHEST,
+                            preferred_element_type=f32)
+    sub_blocks = [slice(lo, lo + _SUB) for lo in range(0, chunk, _SUB)]
+
+    def lanes(j, width):
+        return pl.ds(pl.multiple_of(j * width, width), width)
+
+    @pl.when(index == 0)
+    def _():
+        s_ref[...] = jnp.zeros_like(s_ref)
+
+    @pl.when(index * chunk >= length)
+    def _():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(index * chunk < length)
+    def _():
+        row = jax.lax.broadcasted_iota(jnp.int32, (chunk, 1), 0)
+        col = jax.lax.broadcasted_iota(jnp.int32, (1, chunk), 1)
+        real = index * chunk + row < length
+        eye = (row == col).astype(f32)
+        same_block = row // _SUB == col // _SUB
+        betas = jnp.where(real, beta_ref[...].astype(f32), 0.0)   # (C, H)
+        head_lane = jax.lax.broadcasted_iota(jnp.int32, betas.shape, 1)
+
+        def head(j, _):
+            mine = pl.ds(j, chunk, stride=heads)
+            q, k, v = q_ref[mine, :], k_ref[mine, :], v_ref[mine, :]
+            beta = jnp.sum(jnp.where(head_lane == j, betas, 0.0), axis=1,
+                           keepdims=True)
+            cum = jnp.where(real, g_ref[:, lanes(j, dk)], 0.0)     # -> G
+            for shift in (1 << i for i in range((chunk - 1).bit_length())):
+                cum = cum + jnp.where(row >= shift,
+                                      pltpu.roll(cum, shift, 0), 0.0)
+            refs = [cum[at][:1] for at in sub_blocks]
+            from_ref = jnp.exp(cum - jnp.concatenate(
+                [jnp.broadcast_to(ref, (_SUB, dk)) for ref in refs]))  # <= 1
+            k_own, q_own = k * from_ref, q * from_ref
+            k_pairs, q_pairs = [], []
+            for at, ref in zip(sub_blocks, refs):
+                # the rows of k in this sub-block or before it, seen from
+                # its reference: exponents at most 15 x 5
+                k_neg = k[:at.stop] * jnp.exp(ref - cum[:at.stop])
+                pairs = jnp.pad(jax.lax.dot_general(
+                    jnp.concatenate([k_own[at], q_own[at]]), k_neg,
+                    (((1,), (1,)), ((), ())), precision=_HIGHEST,
+                    preferred_element_type=f32),
+                    ((0, 0), (0, chunk - at.stop)))                # (2 SUB, C)
+                k_pairs.append(pairs[:_SUB])
+                q_pairs.append(pairs[_SUB:])
+            a = jnp.where(row > col, jnp.concatenate(k_pairs), 0.0) * beta
+            aq = jnp.where(row >= col, jnp.concatenate(q_pairs), 0.0)
+            within = _inverse_of_one_plus(jnp.where(same_block, a, 0.0), eye,
+                                          dot)
+            grown = jnp.exp(cum)
+            last = cum[chunk - 1:chunk]
+            state = s_ref[j]
+            carried = dot(jnp.concatenate([k * grown, q * grown]), state)
+            rhs = beta * (v - carried[:chunk])
+            solved = []                                            # beta U
+            for at in sub_blocks:
+                mine_rhs = rhs[at]
+                if solved:
+                    mine_rhs = mine_rhs - dot(a[at, :at.start],
+                                              jnp.concatenate(solved))
+                solved.append(dot(within[at, at], mine_rhs))
+            w = jnp.concatenate(solved)
+            o_ref[:, lanes(j, dv)] = carried[chunk:] + dot(aq, w)
+            # exp(last) a KEY channel: a column of the state. The row
+            # (1, d_k) is spread over the lanes and turned
+            kept = jnp.broadcast_to(jnp.exp(last), (dv, dk)).T
+            s_ref[j] = kept * state + jax.lax.dot_general(
+                k * jnp.exp(last - cum), w, (((0,), (0,)), ((), ())),
+                precision=_HIGHEST, preferred_element_type=f32)
+            return 0
+
+        jax.lax.fori_loop(0, heads, head, 0)
+
+
+def _chunk_vmem_bytes(chunk: int, heads: int, dk: int, dv: int) -> int:
+    """What a call of `_kda_chunk_kernel` keeps in VMEM: two buffers of
+    each block (q, k, g, v, o float32; beta a lane tile wide) and of the
+    states."""
+    return 2 * 4 * (chunk * heads * (3 * dk + 2 * dv) + chunk * 128
+                    + heads * dk * dv)
+
+
+def _chunk_kernel_applies(q, k, v, g, chunk: int) -> bool:
+    """The shapes `_kda_chunk_kernel` is written for: a head's key and
+    value channels whole lane tiles, a chunk whole sub-blocks of 16 rows,
+    the heads whole sublane tiles (a head's rows are a strided load of
+    the rows as they lie), every operand float32 (a row of a narrower
+    type shares its sublane), and its account of VMEM with the 4 MiB the
+    call adds inside 48 MiB."""
+    _, _, h, dk = q.shape
+    dv = v.shape[-1]
+    return (dk % 128 == 0 and dv % 128 == 0 and chunk % _SUB == 0
+            and h % 8 == 0
+            and all(x.dtype == jnp.float32 for x in (q, k, v, g))
+            and _chunk_vmem_bytes(chunk, h, dk, dv) <= 44 << 20)
+
+
+@functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
+def kda_chunk_kernel(q, k, v, g, beta, lengths=None, *, chunk: int = 64,
+                     interpret: bool = False):
+    """`kda_chunked` as the Pallas kernel: every example runs its own
+    chunks and no more. Same results, same return (the rows the chunks
+    ran are each example's own)."""
+    b, s, h, dk = q.shape
+    dv = v.shape[-1]
+    pad = (-s) % chunk
+    if lengths is None:
+        lengths = jnp.full((b,), s, jnp.int32)
+    lengths = lengths.astype(jnp.int32)
+    if pad:
+        q, k, v, g, beta = (
+            jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
+            for x in (q, k, v, g, beta))
+    sp = s + pad
+    # q, k, v as the heads' own arithmetic leaves them: (B, S x H, d), the
+    # same bytes, a head every H-th row; g and o as the projections' rows
+    # have them: (B, S, H x d), a head a lane slice
+    q, k, v = (x.reshape(b, sp * h, -1) for x in (q, k, v))
+    g = g.reshape(b, sp, h * dk)
+
+    def chunk_of(e, i, len_ref):
+        # a chunk past the example's last names the last: nothing moves
+        return (e, jnp.minimum(i, jnp.maximum(
+            (len_ref[e] + chunk - 1) // chunk - 1, 0)), 0)
+
+    o, state = pl.pallas_call(
+        functools.partial(_kda_chunk_kernel, chunk=chunk),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b, sp // chunk),
+            in_specs=[
+                pl.BlockSpec((None, chunk * h, dk), chunk_of),
+                pl.BlockSpec((None, chunk * h, dk), chunk_of),
+                pl.BlockSpec((None, chunk * h, dv), chunk_of),
+                pl.BlockSpec((None, chunk, h * dk), chunk_of),
+                pl.BlockSpec((None, chunk, h), chunk_of),
+            ],
+            out_specs=[
+                # every chunk's rows of o are written: zeros where not run
+                pl.BlockSpec((None, chunk, h * dv),
+                             lambda e, i, len_ref: (e, i, 0)),
+                pl.BlockSpec((None, h, dk, dv),
+                             lambda e, i, len_ref: (e, 0, 0, 0)),
+            ],
+        ),
+        out_shape=[jax.ShapeDtypeStruct((b, sp, h * dv), jnp.float32),
+                   jax.ShapeDtypeStruct((b, h, dk, dv), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=_chunk_vmem_bytes(chunk, h, dk, dv) + (4 << 20)),
+        interpret=interpret,
+        name="_kda_chunk_kernel",  # the device-trace reduction finds it
+    )(lengths, q, k, v, g, beta)
+    return (o[:, :s].reshape(b, s, h, dv), state,
+            (lengths + chunk - 1) // chunk * chunk)
 
 
 # -- one token ---------------------------------------------------------------
@@ -295,3 +508,12 @@ def kda_step(state, q, k, v, g, beta, owned=None):
     if _on_tpu() and _step_kernel_applies(state):
         return kda_step_kernel(state, q, k, v, g, beta, owned)
     return kda_step_reference(state, q, k, v, g, beta, owned)
+
+
+def kda_prefill(q, k, v, g, beta, lengths=None, *, chunk: int = 64):
+    """The chunked delta rule over a prefill's examples: -> (o (B, S, H,
+    d_v) float32, state (B, H, d_k, d_v) float32, rows the chunks ran for
+    each example (B,))."""
+    if _on_tpu() and _chunk_kernel_applies(q, k, v, g, chunk):
+        return kda_chunk_kernel(q, k, v, g, beta, lengths, chunk=chunk)
+    return kda_chunked(q, k, v, g, beta, lengths, chunk=chunk)

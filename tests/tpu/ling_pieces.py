@@ -25,9 +25,13 @@ the experts hit, us a hit expert and the share of 819 GB/s the hit
 experts' 11.8 MB each come to; and the kernel's result against the
 loop's there. `prefill` (run by no other
 piece's default): the prefill on 12, 20 and 32 real rows, ms a call and
-one call captured by operation; and `kda.kda_chunked` alone on each of
-the prefill's groups of 4 examples, x 6 layers: what the chunked delta
-rule takes of a prefill.
+one call captured by operation; and the chunked delta rule alone on each
+of the prefill's groups of 4 examples, x 6 layers, in both its forms
+side by side (`kda.kda_chunked`, plain jnp run to a group's longest
+example, and `kda.kda_prefill`, as the tree's prefill runs it: the kernel
+`_kda_chunk_kernel` over each example's own chunks): ms a group, us a
+(chunk, head), what the delta rule takes of a prefill, and each form's
+largest difference from `kda_reference` on the chip.
 `step`: `kda.kda_step` alone, a step's 6 calls over 6 states of (32, 32,
 128, 128) float32 donated and handed on, 16 steps a call, with 32, 20, 12
 and 1 of the rows owned: ms a call, and the share of 819 GB/s the owned
@@ -232,30 +236,77 @@ def step(out: dict, pc) -> None:
 
 
 def kda_alone(out: dict, pc, ids) -> None:
-    """`kda.kda_chunked` alone on each group of `prefill_rows` examples as
-    the prefill meets them (the 32 prompts' own lengths; inputs as
-    `step`'s), ms a group; a prefill of `real` rows runs its first
-    groups, once a KDA layer."""
-    rows, h, d = pc.prefill_rows, pc.num_heads, pc.head_dim
+    """The chunked delta rule alone on each group of `prefill_rows`
+    examples as the prefill meets them (the 32 prompts' own lengths;
+    inputs as `step`'s; g handed in and o handed back as rows of every
+    head's channels, as `ling_hybrid` has them): `kda.kda_chunked`, and
+    beside it on the same groups `kda.kda_prefill` (what the prefill
+    calls: `_kda_chunk_kernel` where its gate admits the shapes; left out
+    on a tree without it), ms a group and us a (chunk, head) of the
+    chunks each form runs; a
+    prefill of `real` rows runs its first groups, once a KDA layer. And
+    on the first group the largest difference of each form's output (the
+    real rows) and final state from `kda_reference`'s, and of the two
+    forms from each other."""
+    rows, h, d, c = pc.prefill_rows, pc.num_heads, pc.head_dim, pc.kda_chunk
     token = delta_rule_inputs(jax.random.split(jax.random.PRNGKey(7), 5),
                               (rows, SEQ_LEN, h, d))
-    run = jax.jit(functools.partial(kda.kda_chunked, chunk=pc.kda_chunk))
-    lengths = np.sum(ids > 0, axis=1)
-    groups = []
-    for lo in range(0, BATCH, rows):
-        group = jnp.asarray(lengths[lo:lo + rows], jnp.int32)
-        found = jax.block_until_ready(run(*token, group))
-        clock = time.perf_counter()
-        for _ in range(3):
-            found = run(*token, group)
-        jax.block_until_ready(found)
-        groups.append((time.perf_counter() - clock) / 3 * 1e3)
-    out["prefill_kda_chunked_alone_ms_a_group"] = groups
-    out["prefill_group_longest_example"] = [
-        int(lengths[lo:lo + rows].max()) for lo in range(0, BATCH, rows)]
-    for real in REAL:
-        out[f"prefill_{real}_real_rows_kda_chunked_alone_ms"] = (
-            pc.layer_types.count("kda") * sum(groups[:-(-real // rows)]))
+
+    def as_the_prefill_calls_it(form):
+        # g comes from, and o goes to, rows of every head's channels
+        def call(q, k, v, g, beta, lengths):
+            o, state, ran = form(q, k, v, g.reshape(q.shape), beta, lengths,
+                                 chunk=c)
+            return o.reshape(g.shape), state, ran
+        return jax.jit(call)
+
+    forms = {name: as_the_prefill_calls_it(getattr(kda, name))
+             for name in ("kda_chunked", "kda_prefill") if hasattr(kda, name)}
+    token = (*token[:3], token[3].reshape(rows, SEQ_LEN, h * d), token[4])
+    lengths = np.sum(ids > 0, axis=1).reshape(-1, rows)
+    chunks = -(-lengths // c)
+    ran = {"kda_chunked": rows * chunks.max(1), "kda_prefill": chunks.sum(1)}
+    found = {}
+    for name, run in forms.items():
+        groups = []
+        for group in lengths:
+            group = jnp.asarray(group, jnp.int32)
+            got = jax.block_until_ready(run(*token, group))
+            found.setdefault(name, got)
+            clock = time.perf_counter()
+            for _ in range(3):
+                got = run(*token, group)
+            jax.block_until_ready(got)
+            groups.append((time.perf_counter() - clock) / 3 * 1e3)
+        out[f"prefill_{name}_alone_ms_a_group"] = groups
+        out[f"prefill_{name}_alone_us_a_chunk_head"] = (
+            sum(groups) * 1e3 / (int(ran[name].sum()) * h))
+        for real in REAL:
+            out[f"prefill_{real}_real_rows_{name}_alone_ms"] = (
+                pc.layer_types.count("kda") * sum(groups[:-(-real // rows)]))
+    out["prefill_group_longest_example"] = lengths.max(1).tolist()
+    out["prefill_group_own_chunks_of_run_to_the_longest"] = (
+        chunks.sum(1) / (rows * chunks.max(1))).tolist()
+    first = jnp.asarray(lengths[0], jnp.int32)
+    real = (jnp.arange(SEQ_LEN)[None, :] < first[:, None])[..., None]
+    found["kda_reference"] = as_the_prefill_calls_it(
+        lambda *a, chunk: (*kda.kda_reference(*a), None))(*token, first)
+    for a, b in (("kda_chunked", "kda_reference"),
+                 ("kda_prefill", "kda_reference"),
+                 ("kda_prefill", "kda_chunked")):
+        if a in found:
+            out[f"prefill_{a}_vs_{b}_max_abs_diff"] = {
+                "o": float(jnp.max(jnp.abs(
+                    jnp.where(real, found[a][0] - found[b][0], 0.0)))),
+                "state": float(jnp.max(jnp.abs(found[a][1] - found[b][1])))}
+    out["prefill_kda_reference_max_abs"] = {
+        "o": float(jnp.max(jnp.abs(jnp.where(
+            real, found["kda_reference"][0], 0.0)))),
+        "state": float(jnp.max(jnp.abs(found["kda_reference"][1])))}
+    if "kda_prefill" in forms:
+        out["prefill_kda_prefill_is_the_kernel"] = (
+            "_kda_chunk_kernel" in forms["kda_prefill"].lower(
+                *token, first).as_text())
 
 
 def main() -> None:

@@ -160,9 +160,9 @@ def test_the_absorbed_decode_is_the_decompressed_form(tiny):
 def state_after_the_padding(tiny, monkeypatch):
     """The delta rule runs on through the padding: no lengths, so no g =
     0 and beta = 0 behind an example's last token."""
-    sound = lh.kda.kda_chunked
+    sound = lh.kda.kda_prefill
     monkeypatch.setattr(
-        lh.kda, "kda_chunked", lambda q, k, v, g, beta, lengths=None, **kw:
+        lh.kda, "kda_prefill", lambda q, k, v, g, beta, lengths=None, **kw:
         sound(q, k, v, g, beta, None, **kw))
 
 
@@ -203,6 +203,38 @@ def test_a_fault_of_the_hand_over_fails_in_decoding(tiny, generated,
     row = LENGTHS.index(33)                 # real tokens AND padding
     want = reference_logits(tiny, broken, row)
     assert np.max(np.abs(broken["logits"][row, 1:] - want[1:])) > 100 * ATOL
+
+
+@pytest.mark.parametrize("form", ["jnp", "pallas"])
+def test_the_prefill_through_the_dispatcher_is_the_prefill(tiny, form,
+                                                           monkeypatch):
+    """`kda.kda_prefill` in `kda_chunked`'s place. Off the TPU it IS
+    `kda_chunked` (`jnp`: logits, states, windows and the rows the chunks
+    ran, to the bit); `pallas`: the prefill through `_kda_chunk_kernel`
+    (interpret mode) as on the chip: the same logits and states, and each
+    example's OWN chunks run, not the group's longest."""
+    pc, params = tiny["program_config"], tiny["params"]
+
+    def prefill(through):
+        monkeypatch.setattr(lh.kda, "kda_prefill", through)
+        return jax.jit(lambda p, ids: lh.prefill(
+            p, pc, ids, max_decode_len=4, row_block=32))(params, tiny["ids"])
+
+    got = prefill(lh.kda.kda_prefill if form == "jnp" else (
+        lambda *a, **kw: lh.kda.kda_chunk_kernel(*a, **kw, interpret=True)))
+    want = prefill(lh.kda.kda_chunked)
+    same = np.array_equal if form == "jnp" else (
+        lambda a, b: np.allclose(a, b, atol=ATOL))
+    assert same(got["logits"], want["logits"])
+    assert np.std(np.asarray(want["logits"])) > 0.05
+    for a, b in zip(got["caches"], want["caches"]):
+        assert all(same(a[name], b[name]) for name in a)
+    lengths = np.asarray(LENGTHS)
+    longest = lengths.reshape(-1, pc.prefill_rows).max(1).repeat(
+        pc.prefill_rows)
+    ran = {"jnp": longest, "pallas": lengths}[form]
+    assert np.asarray(got["counts"]["scan_rows"]).tolist() \
+        == (-(-ran // CHUNK) * CHUNK).tolist()
 
 
 @pytest.mark.parametrize("form", ["jnp", "pallas"])

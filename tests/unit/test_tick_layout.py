@@ -200,6 +200,42 @@ def test_the_kda_step_compiles_for_the_v5e_and_writes_the_state_in_place(
     assert compiled.memory_analysis().temp_size_in_bytes < 8 << 20
 
 
+def test_the_chunked_delta_rule_compiles_for_the_v5e_where_its_operands_lie(
+        v5e):
+    """ling-3.0-flash's prefill group: 4 examples x 2,048 positions x 32
+    heads x 128, float32. q, k and v come as the heads' own arithmetic
+    leaves them, (B, S, H, d), and the kernel reads a head's rows with a
+    stride of H sublanes; g comes from, and o goes to, rows of every
+    head's channels, (B, S, H x d), as `ling_hybrid` has them, and a head
+    is a lane slice: no operand of 134 MB is laid out again around the
+    call (with every operand a lane slice of a `(B, S, H x d)` view q, k
+    and v were: 537 MB of temporaries with o's)."""
+    import jax.numpy as jnp
+
+    from min_tfs_client_tpu.ops import kda
+
+    def struct(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    heads, rows = struct((4, 2048, 32, 128)), struct((4, 2048, 4096))
+    assert kda._chunk_kernel_applies(heads, heads, heads, heads, 64)
+
+    def as_the_prefill_calls_it(q, k, v, g, beta, lengths):
+        o, state, ran = kda.kda_chunk_kernel(
+            q, k, v, g.reshape(q.shape), beta, lengths, chunk=64)
+        return o.reshape(g.shape), state, ran
+
+    compiled = jax.jit(as_the_prefill_calls_it).lower(
+        heads, heads, heads, rows, struct((4, 2048, 32)),
+        struct((4,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "_kda_chunk_kernel" in text
+    assert not re.search(
+        r"= f32\[4,(2048,32,128|2048,4096|65536,128)\]\S* "
+        r"(copy|reshape|transpose)\(", text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
 # -- the walk over the hit experts at the published widths ---------------------
 
 
