@@ -31,6 +31,7 @@ _PLAIN_FAMILIES = {
     "mimo": ("MimoConfig", (), ("batch_buckets",)),
     "granite_hybrid": ("GraniteHybridConfig", (), ("batch_buckets",)),
     "ling_hybrid": ("LingHybridConfig", (), ("batch_buckets",)),
+    "xing": ("XingConfig", (), ("batch_buckets",)),
 }
 FAMILIES = ("bert", "t5", *_PLAIN_FAMILIES)
 

@@ -39,10 +39,10 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 
+from min_tfs_client_tpu.models import latent
 from min_tfs_client_tpu.models import layers as nn
 from min_tfs_client_tpu.models import packed
 from min_tfs_client_tpu.ops import kda
-from min_tfs_client_tpu.ops.attention import attention
 from min_tfs_client_tpu.parallel.moe import HeldExperts, held_experts_ffn
 
 # The columns of `state_counts` (models/granite_hybrid.py's, so that one
@@ -342,37 +342,21 @@ def _kda_out(config: LingHybridConfig, p: dict, o: jax.Array,
     return nn.mm(normed.reshape(o.shape[0], -1), p["out"]["kernel"])
 
 
-def _rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """Rotary embedding over ALL of x's last dim, INTERLEAVED pairs (dim
-    2i with dim 2i + 1). x (T, ..., R) float32; positions (T,)."""
-    half = x.shape[-1] // 2
-    inv = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
-    angle = positions.astype(jnp.float32).reshape(
-        -1, *(1,) * (x.ndim - 1)) * inv
-    cos, sin = jnp.cos(angle), jnp.sin(angle)
-    pair = x.reshape(*x.shape[:-1], half, 2)
-    a, b = pair[..., 0], pair[..., 1]
-    return jnp.stack([a * cos - b * sin, b * cos + a * sin],
-                     axis=-1).reshape(x.shape)
-
-
 def _mla_inputs(config: LingHybridConfig, p: dict, x: jax.Array,
                 positions: jax.Array):
     """x (T, D) float32 (normed) at `positions` (T,) -> q (T, heads, nope
     + rope) rotated on its rope lanes, the row the cache holds (T, rank +
-    rope): the latent after its norm and the ONE rotated key all heads
-    share, both in the parameters' dtype; and the output gate (T, heads)
-    float32."""
-    h, nope, rank = (config.num_heads, config.qk_nope_head_dim,
-                     config.kv_lora_rank)
+    rope; `latent.latent_row`), both in the parameters' dtype; and the
+    output gate (T, heads) float32."""
     dtype = p["q"]["kernel"].dtype
-    q = nn.mm(x, p["q"]["kernel"]).reshape(-1, h, config.qk_head_dim)
-    q = jnp.concatenate([q[..., :nope], _rope(q[..., nope:], positions,
-                                              config.rope_theta)], axis=-1)
-    kva = nn.mm(x, p["kva"]["kernel"])
-    row = jnp.concatenate([
-        _norm(p["kv_norm"], kva[:, :rank], config),
-        _rope(kva[:, rank:], positions, config.rope_theta)], axis=-1)
+    inv_freq = latent.plain_frequencies(config.rope_theta)
+    q = latent.rotate_query(
+        nn.mm(x, p["q"]["kernel"]).reshape(-1, config.num_heads,
+                                           config.qk_head_dim),
+        positions, inv_freq, config.qk_nope_head_dim)
+    row = latent.latent_row(nn.mm(x, p["kva"]["kernel"]), p["kv_norm"],
+                            positions, inv_freq, rank=config.kv_lora_rank,
+                            eps=config.eps)
     return (q.astype(dtype), row.astype(dtype),
             jax.nn.sigmoid(nn.mm(x, p["g"]["kernel"])))
 
@@ -385,48 +369,11 @@ def _mla_out(config: LingHybridConfig, p: dict, o: jax.Array,
     return nn.mm(gated.reshape(o.shape[0], -1), p["out"]["kernel"])
 
 
-def decompressed_attention(config: LingHybridConfig, p: dict, q: jax.Array,
-                           rows: jax.Array, lengths: jax.Array) -> jax.Array:
-    """Latent attention as the prefill runs it: q (b, S, heads, nope +
-    rope) and the latent rows (b, S, rank + rope) of `lengths` real
-    positions; K and V decompressed by `kvb`, causal attention over them
-    (ops/attention.py). -> (b, S, heads x d_v)."""
-    b, s, h, _ = q.shape
-    nope, rank = config.qk_nope_head_dim, config.kv_lora_rank
-    kv = nn.mm(rows[..., :rank], p["kvb"]["kernel"], rows.dtype).reshape(
-        b, s, h, nope + config.v_head_dim)
-    k = jnp.concatenate([kv[..., :nope], jnp.broadcast_to(
-        rows[:, :, None, rank:], (b, s, h, config.qk_rope_head_dim))],
-        axis=-1)
-    heads_first = lambda x: x.transpose(0, 2, 1, 3)  # noqa: E731
-    out = attention(heads_first(q), heads_first(k),
-                    heads_first(kv[..., nope:]), causal=True,
-                    lengths=lengths, causal_offset=0,
-                    scale=config.qk_head_dim ** -0.5, queries_ragged=True)
-    return heads_first(out).reshape(b, s, -1)
-
-
-def absorbed_attention(config: LingHybridConfig, p: dict, q: jax.Array,
-                       cache: jax.Array, seen: jax.Array) -> jax.Array:
-    """Latent attention as a decode step runs it: q (B, heads, nope +
-    rope) over the latent cache (B, 1, S, rank + rope), `seen` (B, S)
-    the rows each query may read. The up-projection's key half is
-    absorbed into the query, (B, heads, rank + rope), which attends the
-    cache as ONE K/V head whose values are the keys' first `rank` lanes
-    (`layers.attend_cache`); its value half takes the result to the
-    heads' value channels. -> (B, heads x d_v) float32."""
-    h, nope, rank = (config.num_heads, config.qk_nope_head_dim,
-                     config.kv_lora_rank)
-    up = p["kvb"]["kernel"].reshape(rank, h, nope + config.v_head_dim)
-    absorbed = jnp.einsum("bhd,rhd->bhr", q[..., :nope], up[..., :nope],
-                          preferred_element_type=jnp.float32)
-    query = jnp.concatenate([absorbed.astype(q.dtype), q[..., nope:]], axis=-1)
-    mixed = nn.attend_cache(
-        query, {"k": cache, "v": cache[..., :rank]}, seen, None,
-        scale=config.qk_head_dim ** -0.5)
-    out = jnp.einsum("bhr,rhd->bhd", mixed.reshape(-1, h, rank),
-                     up[..., nope:], preferred_element_type=jnp.float32)
-    return out.reshape(out.shape[0], -1)
+def _latent_sizes(config: LingHybridConfig) -> dict:
+    """What the two forms of latent attention (models/latent.py) take of
+    this model: no stretch of the rotary, so the plain scale."""
+    return dict(nope=config.qk_nope_head_dim, v_head_dim=config.v_head_dim,
+                scale=config.qk_head_dim ** -0.5)
 
 
 # -- prefill ------------------------------------------------------------------
@@ -513,8 +460,9 @@ def _prefill_chunk(params: dict, config: LingHybridConfig, ids: jax.Array,
                 jnp.zeros((t, config.latent_width), dtype),
                 jnp.zeros((t, heads), jnp.float32)))
             rows = pk.grid(rows)
-            out = decompressed_attention(
-                config, p, pk.grid(q).reshape(b, s, heads, -1), rows, lengths)
+            out = latent.decompressed_attention(
+                p["kvb"]["kernel"], pk.grid(q).reshape(b, s, heads, -1), rows,
+                lengths, **_latent_sizes(config))
             caches.append({"latent": jnp.pad(
                 rows[:, None], ((0, 0), (0, 0), (0, max_decode_len), (0, 0)))})
             mixer_rows = out.reshape(b * s, -1)
@@ -611,13 +559,14 @@ def step(params: dict, config: LingHybridConfig, state: dict):
         else:
             p = layer["mla"]
             q, row, gate = _mla_inputs(config, p, x, position)
-            latent = cache["latent"].at[each, 0, position].set(row)
-            caches.append({"latent": latent})
-            rows = jnp.arange(latent.shape[2])[None, :]
-            h = h + _mla_out(config, p, absorbed_attention(
-                config, p, q, latent, rows <= position[:, None]), gate)
+            cached = cache["latent"].at[each, 0, position].set(row)
+            caches.append({"latent": cached})
+            rows = jnp.arange(cached.shape[2])[None, :]
+            h = h + _mla_out(config, p, latent.absorbed_attention(
+                p["kvb"]["kernel"], q, cached, rows <= position[:, None],
+                **_latent_sizes(config)), gate)
             latent_read = latent_read + jnp.where(owned, position + 1, 0)
-            latent_held = latent_held + jnp.where(owned, latent.shape[2], 0)
+            latent_held = latent_held + jnp.where(owned, cached.shape[2], 0)
         x = _norm(layer["ffn_norm"], h, config)
         if ffn == "dense":
             h = h + _swiglu(layer["mlp"]["wi"]["kernel"],

@@ -208,6 +208,11 @@ STAGES = (
     # `generate/state`: no duration, its arguments are the numbers
     # (prompt_tokens, steps, latent_rows_read, latent_rows_held).
     "generate/latent",
+    # What a whole generation's hyper-connected residual path mixed
+    # (models/xing.py), beside `generate/route` and `generate/latent`: no
+    # duration, its arguments are the numbers (prompt_tokens, steps,
+    # stream_rows, sinkhorn_rounds).
+    "generate/streams",
     "serving/serialize",
 )
 

@@ -10,9 +10,12 @@ mechanism:
    (models/granite_hybrid.py);
  * `ops.kda`        delta-rule linear attention with a per-channel decay
    (KDA): the chunked form and the one-token step over a float32 state
-   (models/ling_hybrid.py).
+   (models/ling_hybrid.py);
+ * `ops.mhc`        the hyper-connected residual path (mHC): a token's n
+   streams, the three per-token maps and Sinkhorn's rounds, in plain
+   jax.numpy (models/xing.py).
 
-`ssm` and `kda` are imported by the model that runs them
+`ssm`, `kda` and `mhc` are imported by the model that runs them
 (`from min_tfs_client_tpu.ops import ssm`), not here: a family's boot
 loads its own mechanism's module and no other's.
 """

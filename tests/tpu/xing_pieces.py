@@ -1,0 +1,130 @@
+"""Device times of models/xing.py's prefill and decode step on the chip,
+at the widths of perfbench/configs/xing4.0-29b-a4b.json, with the
+hyper-connected residual path as it is and with its maps HELD CONSTANT
+(PERF.md section 5 quotes them). Not a test and not part of the
+benchmark: run it on a machine with the chip,
+
+    python tests/tpu/xing_pieces.py [--pieces decode,prefill] [--out FILE]
+
+and read chiprun_out/xing_pieces.json (or FILE). `prefill`: a batch's
+prefill on 12, 20 and 32 real rows of the traffic's own lengths, the
+rest rows that pad the batch (length 0): ms a call, and on 32 rows one
+call captured by operation. `decode`: the decode step as the loop of a
+whole generation runs it, a `lax.scan` of 16 steps with the state a real
+prefill left, donated, timed over 5 calls and captured once for its
+device time by operation (a `while` spans its body's operations), on the
+same three batches. `_maps_constant`: the same two with H_pre = 1/n,
+H_post = 1, H_res = I in every sub-layer (this script swaps
+`xing._maps`: no product with phi, no Sinkhorn round; the pre-mix and
+the post-mix still pass over the streams), on 32 rows: what the maps
+cost is the difference, what the streams' traffic costs is in the
+captured operations.
+"""
+
+import argparse
+import json
+import pathlib
+import sys
+import tempfile
+import time
+
+ROOT = pathlib.Path(__file__).resolve().parents[2]
+sys.path.insert(0, str(ROOT))
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+import numpy as np  # noqa: E402
+
+from min_tfs_client_tpu.models import xing  # noqa: E402
+from mimo_pieces import SCAN, ops_a_step  # noqa: E402  (beside this file)
+from perfbench import children  # noqa: E402
+from t5_pieces import timed_and_captured  # noqa: E402  (beside this file)
+
+BATCH, SEQ_LEN, MAX_DECODE_LEN = 32, 2048, 128
+REAL = (12, 20, 32)
+FULL = f"{BATCH}_real_rows"     # the batch whose prefill is captured
+
+
+def prompts(grid, vocab_size: int) -> dict:
+    """name -> ids (32, 2048): `real` rows of the traffic's own lengths,
+    the rest rows that pad the batch."""
+    rng = np.random.default_rng(0)
+    mixed = np.zeros((BATCH, SEQ_LEN), np.int32)
+    for row, n in enumerate(rng.permutation(grid)[:BATCH]):
+        mixed[row, :n] = rng.integers(2, vocab_size, n)
+    out = {}
+    for real in REAL:
+        ids = mixed.copy()
+        ids[real:] = 0
+        out[f"{real}_real_rows"] = ids
+    return out
+
+
+def constant_maps(config, hc, x):
+    """H_pre = 1/n, H_post = 1, H_res = I for the streams x (n, T, C)."""
+    n, t = config.hc_mult, x.shape[1]
+    return (jnp.full((n, t), 1.0 / n), jnp.ones((n, t)),
+            jnp.broadcast_to(jnp.eye(n)[:, :, None], (n, n, t)))
+
+
+def measure(out: dict, tag: str, pieces: set, params, pc, given: dict) -> None:
+    """Traces `xing.prefill` and `xing.step` as the module stands NOW (the
+    maps' swap is made before the call)."""
+    prefill = jax.jit(lambda p, ids: xing.prefill(
+        p, pc, ids, max_decode_len=MAX_DECODE_LEN))
+    steps = jax.jit(
+        lambda s, p: jax.lax.scan(lambda s, _: (xing.step(p, pc, s)[0], None),
+                                  s, None, length=SCAN)[0],
+        donate_argnums=(0,))
+    for name, ids in given.items():
+        clock = time.perf_counter()
+        state = jax.block_until_ready(prefill(params, ids))
+        out[f"prefill_{name}{tag}_first_call_s"] = time.perf_counter() - clock
+        clock = time.perf_counter()
+        for _ in range(2):
+            state = jax.block_until_ready(prefill(params, ids))
+        out[f"prefill_{name}{tag}_ms"] = (time.perf_counter() - clock) / 2 * 1e3
+        if "prefill" in pieces and name == FULL:
+            with tempfile.TemporaryDirectory() as capture:
+                jax.profiler.start_trace(capture)
+                state = jax.block_until_ready(prefill(params, ids))
+                jax.profiler.stop_trace()
+                out[f"prefill_{name}{tag}_ops"] = ops_a_step(
+                    capture, most=40, steps=1)
+        if "decode" in pieces:
+            timed_and_captured(out, f"decode_{name}{tag}", steps, state,
+                               params)
+
+
+def main() -> None:
+    parser = argparse.ArgumentParser()
+    parser.add_argument("--pieces", default="decode,prefill")
+    parser.add_argument("--out", default=str(
+        ROOT / "chiprun_out/xing_pieces.json"))
+    args = parser.parse_args()
+    pieces = set(args.pieces.split(","))
+    out = {"device": str(jax.devices()[0].device_kind)}
+    config = json.loads(
+        (ROOT / "perfbench/configs/xing4.0-29b-a4b.json").read_text())
+    pc = xing.XingConfig(**children.program_config_kwargs(config))
+    params = jax.jit(lambda k: xing.init_params(k, pc))(jax.random.PRNGKey(1))
+    grid = json.loads((ROOT / "perfbench/traffic/document-answers.json")
+                      .read_text())["input_length_grid"]
+    given = prompts(grid, pc.vocab_size)
+    out["prompt_tokens"] = {name: int(np.sum(ids > 0))
+                            for name, ids in given.items()}
+    measure(out, "", pieces, params, pc, given)
+    sound = xing._maps
+    xing._maps = constant_maps
+    try:
+        measure(out, "_maps_constant", pieces, params, pc,
+                {FULL: given[FULL]})
+    finally:
+        xing._maps = sound
+    print(json.dumps(out, indent=1))
+    pathlib.Path(args.out).parent.mkdir(parents=True, exist_ok=True)
+    pathlib.Path(args.out).write_text(json.dumps(out, indent=1))
+
+
+if __name__ == "__main__":
+    main()

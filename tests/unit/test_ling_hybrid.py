@@ -1,8 +1,9 @@
 """models/ling_hybrid.py at a small size on the CPU, seeded weights:
 prefill then decoding through the delta-rule state, the convolution's
 window and the latent cache against the plain reference's ONE forward
-pass, at logits; the absorbed decode form against the decompressed one;
-the two faults of the hand-over, made in the program, each caught; the
+pass, at logits (the absorbed decode form against the decompressed one:
+tests/unit/test_latent.py, with the latent forms' one home); the two
+faults of the hand-over, made in the program, each caught; the
 four shares of an expert layer adding up to the uncut layer; the spans
 and counters of an answer; the export round trip; what a config
 refuses."""
@@ -131,27 +132,6 @@ def test_a_row_of_length_0_touches_nothing(tiny, generated):
         == (15 * real + 15 * 16 // 2).tolist()
     assert set(np.asarray(counts["latent_rows_held"])[~empty].tolist()) \
         == {15 * (SEQ + STEPS)}
-
-
-def test_the_absorbed_decode_is_the_decompressed_form(tiny):
-    """Latent attention's two forms on the same rows: a query at each
-    example's last position over the latent rows, in the latent space
-    (the step's) and over decompressed K and V (the prefill's)."""
-    pc = tiny["program_config"]
-    p = tiny["params"]["layers"][2]["mla"]
-    keys = jax.random.split(jax.random.PRNGKey(3), 2)
-    b, s, h = 3, 40, pc.num_heads
-    q = jax.random.normal(keys[0], (b, s, h, pc.qk_head_dim))
-    rows = jax.random.normal(keys[1], (b, s, pc.latent_width))
-    lengths = jnp.asarray([40, 17, 1], jnp.int32)
-    with jax.default_matmul_precision("highest"):
-        whole = lh.decompressed_attention(pc, p, q, rows, lengths)
-        last = lengths - 1
-        seen = jnp.arange(s)[None, :] <= last[:, None]
-        one = lh.absorbed_attention(
-            pc, p, q[jnp.arange(b), last], rows[:, None], seen)
-    np.testing.assert_allclose(one, whole[jnp.arange(b), last], atol=1e-5)
-    assert float(jnp.std(one)) > 0.05
 
 
 # -- the hand-over to decoding, broken in the program -------------------------

@@ -448,4 +448,4 @@ def test_granite_hybrid_imports_nothing_from_mimo():
 
 def test_the_loaders_table_keeps_every_familys_name():
     assert export.FAMILIES == ("bert", "t5", "resnet", "use", "mimo",
-                               "granite_hybrid", "ling_hybrid")
+                               "granite_hybrid", "ling_hybrid", "xing")

@@ -266,3 +266,49 @@ def test_the_expert_walk_compiles_for_the_v5e_and_adds_onto_its_input(v5e):
     assert "may-alias" in text[:text.index("\n")] \
         or "must-alias" in text[:text.index("\n")]
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+# -- the hyper-connected residual path at the published widths -------------------
+
+
+def test_a_sub_layer_s_maps_compile_for_the_v5e_as_a_few_fusions(v5e):
+    """xing4.0-29b-a4b's residual path as a decode step meets it: 4
+    streams of (32, 3584) float32 through phi (14336, 24), the three maps
+    with 20 Sinkhorn rounds, the pre-mix and the post-mix. A round is
+    written over planes of one shape, so the TPU compiler fuses it: five
+    rounds (a trip of the loop) are 9 fusions and the rest of the
+    sub-layer 23, where the rounds alone, as slices, broadcasts and sums
+    of a (4, 4, 32) array, compiled to 78 (PERF.md section 6, PR 54). And
+    the walk's gate admits this model's experts (two of 22.0 MB inside 48
+    MiB)."""
+    import jax.numpy as jnp
+
+    from min_tfs_client_tpu.ops import mhc
+    from min_tfs_client_tpu.parallel import moe
+
+    def struct(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    n, rows, d = 4, 32, 3584
+
+    def sub_layer(x, phi, alpha, bias, y):
+        pre, post, res = mhc.mhc_maps(
+            mhc.mhc_project(x, phi, 1e-6), alpha, bias, n=n, iters=20,
+            eps=1e-6, clamp=(-30.0, 30.0))
+        return mhc.mhc_post(x, y + mhc.mhc_pre(x, pre), post, res)
+
+    compiled = jax.jit(sub_layer).lower(
+        struct((n, rows, d)), struct((n * d, n * (n + 2))), struct((3,)),
+        struct((n * (n + 2),)), struct((rows, d))).compile()
+    text = compiled.as_text()
+    fusions = len(re.findall(r" fusion\(", text[text.index("ENTRY"):]))
+    in_a_trip = max(len(re.findall(r" fusion\(", body))
+                    for body in text.split("\n\n") if "ENTRY" not in body
+                    and not body.lstrip().startswith("%fused"))
+    assert 0 < fusions <= 30 and 0 < in_a_trip <= 12
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 << 20
+    held, f = 8, 1024
+    params = moe.HeldExperts(None, None,
+                             struct((held, d, 2 * f), jnp.bfloat16),
+                             struct((held, f, d), jnp.bfloat16))
+    assert moe._walk_kernel_applies(params, struct((rows, d), jnp.bfloat16))
