@@ -26,7 +26,13 @@ import jax.numpy as jnp
 import numpy as np
 
 from min_tfs_client_tpu.models import layers as nn
-from min_tfs_client_tpu.ops.attention import attention
+from min_tfs_client_tpu.ops.attention import (
+    _latent_step_applies,
+    _on_tpu,
+    attention,
+    latent_rows_copied,
+    latent_step_attention,
+)
 
 
 # -- rotary -------------------------------------------------------------------
@@ -91,14 +97,28 @@ def rotate_query(q: jax.Array, positions: jax.Array, inv_freq: Callable,
         [q[..., :nope], rope(q[..., nope:], positions, inv_freq)], axis=-1)
 
 
+def cache_width(width: int) -> int:
+    """Lanes a cached position takes for its `width` = rank + rope values:
+    whole 128-lane tiles, zeros past the values (576 -> 640). The chip
+    holds an array's rows in whole tiles whatever their width, so the
+    padding is what a cache of 576 lanes already took of its memory and
+    of a read; said in the shape, a block of rows is a copy a kernel can
+    make (Mosaic slices a row at tile borders only)."""
+    return -(-width // 128) * 128
+
+
 def latent_row(kva: jax.Array, kv_norm: dict, positions: jax.Array,
                inv_freq: Callable, *, rank: int, eps: float) -> jax.Array:
     """What the cache holds of a position: kva (T, rank + rope), the
     down-projection's output -> the latent after its norm and the ONE
-    rotated key all heads share, side by side (T, rank + rope)."""
+    rotated key all heads share, side by side, then zeros to whole lane
+    tiles: (T, cache_width(rank + rope))."""
+    width = kva.shape[-1]
     return jnp.concatenate([
         nn.rms_norm(kv_norm, kva[:, :rank], eps=eps),
-        rope(kva[:, rank:], positions, inv_freq)], axis=-1)
+        rope(kva[:, rank:], positions, inv_freq),
+        jnp.zeros((kva.shape[0], cache_width(width) - width), jnp.float32)],
+        axis=-1)
 
 
 # -- the two forms ------------------------------------------------------------
@@ -108,16 +128,16 @@ def decompressed_attention(kvb: jax.Array, q: jax.Array, rows: jax.Array,
                            lengths: jax.Array, *, nope: int, v_head_dim: int,
                            scale: float) -> jax.Array:
     """Latent attention as the prefill runs it: q (b, S, heads, nope +
-    rope) and the latent rows (b, S, rank + rope) of `lengths` real
-    positions; K and V decompressed by `kvb` (rank, heads x (nope + v)),
-    causal attention over them (ops/attention.py). -> (b, S, heads x
-    d_v)."""
-    b, s, h, _ = q.shape
+    rope) and the latent rows (b, S, rank + rope, or wider: `latent_row`)
+    of `lengths` real positions; K and V decompressed by `kvb` (rank,
+    heads x (nope + v)), causal attention over them (ops/attention.py).
+    -> (b, S, heads x d_v)."""
+    b, s, h, dk = q.shape
     rank = kvb.shape[0]
     kv = nn.mm(rows[..., :rank], kvb, rows.dtype).reshape(
         b, s, h, nope + v_head_dim)
     k = jnp.concatenate([kv[..., :nope], jnp.broadcast_to(
-        rows[:, :, None, rank:], (b, s, h, rows.shape[-1] - rank))],
+        rows[:, :, None, rank:rank + dk - nope], (b, s, h, dk - nope))],
         axis=-1)
     heads_first = lambda x: x.transpose(0, 2, 1, 3)  # noqa: E731
     out = attention(heads_first(q), heads_first(k),
@@ -128,23 +148,46 @@ def decompressed_attention(kvb: jax.Array, q: jax.Array, rows: jax.Array,
 
 
 def absorbed_attention(kvb: jax.Array, q: jax.Array, cache: jax.Array,
-                       seen: jax.Array, *, nope: int, v_head_dim: int,
-                       scale: float) -> jax.Array:
+                       row: jax.Array, position: jax.Array,
+                       owned: jax.Array, *, nope: int, v_head_dim: int,
+                       scale: float):
     """Latent attention as a decode step runs it: q (B, heads, nope +
-    rope) over the latent cache (B, 1, S, rank + rope), `seen` (B, S)
-    the rows each query may read. The up-projection's key half is
-    absorbed into the query, (B, heads, rank + rope), which attends the
-    cache as ONE K/V head whose values are the keys' first `rank` lanes
-    (`layers.attend_cache`); its value half takes the result to the
-    heads' value channels. -> (B, heads x d_v) float32."""
-    h, rank = q.shape[1], kvb.shape[0]
+    rope) over the latent cache (B, 1, S, width) once the step's own
+    `row` (B, width; `latent_row`) lies at each example's `position`
+    (B,); `owned` (B,) bool, the rows a request owns. The up-projection's
+    key half is absorbed into the query, (B, heads, rank + rope, then
+    zeros to the cache's width), which attends the cache as ONE K/V head
+    whose values are the keys' first `rank` lanes over the positions up
+    to its own; the value half takes the result to the heads' value
+    channels. Two bodies of that arithmetic: on a TPU, where
+    `_latent_step_applies` admits the shapes, ONE kernel that owns the
+    cache (ops/attention.py:latent_step_attention: the row written where
+    it lies, an example's own blocks read and no others, nothing for a
+    row nobody owns, which gives zeros); elsewhere the row's scatter and
+    `layers.attend_cache` over every position. -> (out (B, heads x d_v)
+    float32, the cache as it now is, the cache rows brought in for each
+    example (B,): whole blocks, or all S)."""
+    b, h, rank = q.shape[0], q.shape[1], kvb.shape[0]
+    s, width = cache.shape[2:]
     up = kvb.reshape(rank, h, nope + v_head_dim)
     absorbed = jnp.einsum("bhd,rhd->bhr", q[..., :nope], up[..., :nope],
                           preferred_element_type=jnp.float32)
-    query = jnp.concatenate([absorbed.astype(q.dtype), q[..., nope:]], axis=-1)
-    mixed = nn.attend_cache(
-        query, {"k": cache, "v": cache[..., :rank]}, seen, None,
-        scale=scale)
+    rope_lanes = q.shape[-1] - nope
+    query = jnp.concatenate([
+        absorbed.astype(q.dtype), q[..., nope:],
+        jnp.zeros((b, h, width - rank - rope_lanes), q.dtype)], axis=-1)
+    if _on_tpu() and _latent_step_applies(query, cache, rank):
+        lengths = jnp.where(owned, position + 1, 0)
+        mixed, cache = latent_step_attention(
+            query, row, cache, lengths, rank=rank, scale=scale)
+        copied = latent_rows_copied(lengths, s)
+    else:
+        cache = cache.at[jnp.arange(b), 0, position].set(row)
+        seen = jnp.arange(s)[None, :] <= position[:, None]
+        mixed = nn.attend_cache(
+            query, {"k": cache, "v": cache[..., :rank]}, seen, None,
+            scale=scale)
+        copied = jnp.full((b,), s, jnp.int32)
     out = jnp.einsum("bhr,rhd->bhd", mixed.reshape(-1, h, rank),
                      up[..., nope:], preferred_element_type=jnp.float32)
-    return out.reshape(out.shape[0], -1)
+    return out.reshape(out.shape[0], -1), cache, copied

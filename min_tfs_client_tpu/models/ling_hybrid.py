@@ -54,9 +54,11 @@ STATE_COLUMNS = ("prompt_tokens", "scan_rows", "state_bytes", "steps",
                  "state_rows_held", "state_rows_moved")
 # Of `latent_counts`, one row an example: the cached positions its decode
 # steps' attention read (a step reads the positions up to its own) and
-# those the latent cache held for it meanwhile (its whole length a step).
+# those the latent cache held for it meanwhile (its whole length a step),
+# and the rows the steps brought in (whole blocks where the step's kernel
+# ran, `models/latent.py:absorbed_attention`; all it held elsewhere).
 LATENT_COLUMNS = ("prompt_tokens", "steps", "latent_rows_read",
-                  "latent_rows_held")
+                  "latent_rows_held", "latent_rows_copied")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -457,7 +459,8 @@ def _prefill_chunk(params: dict, config: LingHybridConfig, ids: jax.Array,
             # rows that no block writes stay zeros: masked positions
             q, rows, gate = pk.over_blocks(project, (
                 jnp.zeros((t, heads * config.qk_head_dim), dtype),
-                jnp.zeros((t, config.latent_width), dtype),
+                jnp.zeros((t, latent.cache_width(config.latent_width)),
+                          dtype),
                 jnp.zeros((t, heads), jnp.float32)))
             rows = pk.grid(rows)
             out = latent.decompressed_attention(
@@ -498,7 +501,8 @@ def _prefill_chunk(params: dict, config: LingHybridConfig, ids: jax.Array,
     return (caches, _logits(params, config, pk.last_rows(h)), held, load,
             pk.blocks * pk.block,
             {"scan_rows": none if scanned is None else scanned,
-             "latent_rows_read": none, "latent_rows_held": none})
+             "latent_rows_read": none, "latent_rows_held": none,
+             "latent_rows_copied": none})
 
 
 def prefill(params: dict, config: LingHybridConfig, input_ids: jax.Array,
@@ -532,12 +536,11 @@ def step(params: dict, config: LingHybridConfig, state: dict):
     token, finished, position, owned = packed.choose(
         state, config.pad_id, config.eos_id)
     b = token.shape[0]
-    each = jnp.arange(b)
     h = params["embed"]["embedding"][token].astype(jnp.float32)
     caches, held = [], jnp.zeros((b,), jnp.int32)
     hit = jnp.zeros((), jnp.int32)
     states_held = states_moved = jnp.zeros((), jnp.int32)
-    latent_read = latent_held = jnp.zeros((b,), jnp.int32)
+    latent_read = latent_held = latent_copied = jnp.zeros((b,), jnp.int32)
     for kind, ffn, layer, cache in zip(config.layer_types, config.ffn_types,
                                        params["layers"], state["caches"]):
         x = _norm(layer["norm"], h, config)
@@ -559,14 +562,14 @@ def step(params: dict, config: LingHybridConfig, state: dict):
         else:
             p = layer["mla"]
             q, row, gate = _mla_inputs(config, p, x, position)
-            cached = cache["latent"].at[each, 0, position].set(row)
+            mixed, cached, copied = latent.absorbed_attention(
+                p["kvb"]["kernel"], q, cache["latent"], row, position, owned,
+                **_latent_sizes(config))
             caches.append({"latent": cached})
-            rows = jnp.arange(cached.shape[2])[None, :]
-            h = h + _mla_out(config, p, latent.absorbed_attention(
-                p["kvb"]["kernel"], q, cached, rows <= position[:, None],
-                **_latent_sizes(config)), gate)
+            h = h + _mla_out(config, p, mixed, gate)
             latent_read = latent_read + jnp.where(owned, position + 1, 0)
             latent_held = latent_held + jnp.where(owned, cached.shape[2], 0)
+            latent_copied = latent_copied + jnp.where(owned, copied, 0)
         x = _norm(layer["ffn_norm"], h, config)
         if ffn == "dense":
             h = h + _swiglu(layer["mlp"]["wi"]["kernel"],
@@ -580,7 +583,7 @@ def step(params: dict, config: LingHybridConfig, state: dict):
         state, caches, _logits(params, config, h), token, finished,
         held_decode=held, hit_decode=hit, state_rows_held=states_held,
         state_rows_moved=states_moved, latent_rows_read=latent_read,
-        latent_rows_held=latent_held), token
+        latent_rows_held=latent_held, latent_rows_copied=latent_copied), token
 
 
 # -- serving ------------------------------------------------------------------

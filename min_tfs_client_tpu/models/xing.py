@@ -40,10 +40,11 @@ from min_tfs_client_tpu.parallel.moe import HeldExperts, held_experts_ffn
 
 # Of `latent_counts` (models/ling_hybrid.py's columns, so that one reader
 # reads both), one row an example, summed over the layers: the cached
-# positions its decode steps' attention read and those the latent caches
-# held for it meanwhile.
+# positions its decode steps' attention read, those the latent caches
+# held for it meanwhile, and the rows the steps brought in (whole blocks
+# where the step's kernel ran, all it held elsewhere).
 LATENT_COLUMNS = ("prompt_tokens", "steps", "latent_rows_read",
-                  "latent_rows_held")
+                  "latent_rows_held", "latent_rows_copied")
 # Of `stream_counts`, one row an example: its rows (prompt tokens and
 # decode steps) times the sub-layers whose streams were mixed, and
 # Sinkhorn's rounds for them. The streams' bytes are `stream_rows` x 3 x
@@ -399,7 +400,7 @@ def _prefill_chunk(params: dict, config: XingConfig, ids: jax.Array,
         # rows that no block writes stay zeros: masked positions
         x, q, rows, post, res = pk.over_blocks(project, (
             x, jnp.zeros((t, heads * config.qk_head_dim), dtype),
-            jnp.zeros((t, config.latent_width), dtype),
+            jnp.zeros((t, latent.cache_width(config.latent_width)), dtype),
             jnp.zeros((n, t), jnp.float32), jnp.zeros((n, n, t), jnp.float32)))
         rows = pk.grid(rows)
         mixer_rows = latent.decompressed_attention(
@@ -444,6 +445,7 @@ def _prefill_chunk(params: dict, config: XingConfig, ids: jax.Array,
     return (caches, _logits(params, config, h), held, load,
             pk.blocks * pk.block,
             {"latent_rows_read": none, "latent_rows_held": none,
+             "latent_rows_copied": none,
              "stream_rows": pk.lengths * 2 * config.num_layers})
 
 
@@ -474,27 +476,26 @@ def step(params: dict, config: XingConfig, state: dict):
     token, finished, position, owned = packed.choose(
         state, config.pad_id, config.eos_id)
     b = token.shape[0]
-    each = jnp.arange(b)
     x = mhc.mhc_enter(
         params["embed"]["embedding"][token].astype(jnp.float32),
         config.hc_mult)
     caches, held = [], jnp.zeros((b,), jnp.int32)
     hit = jnp.zeros((), jnp.int32)
-    latent_read = latent_held = jnp.zeros((b,), jnp.int32)
+    latent_read = latent_held = latent_copied = jnp.zeros((b,), jnp.int32)
     for layer, cache in zip(params["layers"], state["caches"]):
         p = layer["mla"]
         pre, post, res = _maps(config, layer["attn_hc"], x)
         q, row = _mla_inputs(
             config, p, _norm(layer["norm"], mhc.mhc_pre(x, pre), config),
             position)
-        cached = cache["latent"].at[each, 0, position].set(row)
+        mixed, cached, copied = latent.absorbed_attention(
+            p["kvb"]["kernel"], q, cache["latent"], row, position, owned,
+            **_latent_sizes(config))
         caches.append({"latent": cached})
-        rows = jnp.arange(cached.shape[2])[None, :]
-        x = mhc.mhc_post(x, nn.mm(latent.absorbed_attention(
-            p["kvb"]["kernel"], q, cached, rows <= position[:, None],
-            **_latent_sizes(config)), p["out"]["kernel"]), post, res)
+        x = mhc.mhc_post(x, nn.mm(mixed, p["out"]["kernel"]), post, res)
         latent_read = latent_read + jnp.where(owned, position + 1, 0)
         latent_held = latent_held + jnp.where(owned, cached.shape[2], 0)
+        latent_copied = latent_copied + jnp.where(owned, copied, 0)
         pre, post, res = _maps(config, layer["ffn_hc"], x)
         u = _norm(layer["ffn_norm"], mhc.mhc_pre(x, pre), config)
         y = _feed_forward(layer, u)
@@ -507,6 +508,7 @@ def step(params: dict, config: XingConfig, state: dict):
         state, caches, _logits(params, config, mhc.mhc_exit(x)), token,
         finished, held_decode=held, hit_decode=hit,
         latent_rows_read=latent_read, latent_rows_held=latent_held,
+        latent_rows_copied=latent_copied,
         stream_rows=jnp.where(owned, 2 * config.num_layers, 0)), token
 
 

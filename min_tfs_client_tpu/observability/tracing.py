@@ -206,7 +206,8 @@ STAGES = (
     # What a whole generation's decode steps read of the latent cache it
     # holds (models/ling_hybrid.py), beside `generate/route` and
     # `generate/state`: no duration, its arguments are the numbers
-    # (prompt_tokens, steps, latent_rows_read, latent_rows_held).
+    # (prompt_tokens, steps, latent_rows_read, latent_rows_held,
+    # latent_rows_copied).
     "generate/latent",
     # What a whole generation's hyper-connected residual path mixed
     # (models/xing.py), beside `generate/route` and `generate/latent`: no

@@ -3,8 +3,10 @@ mechanism:
 
  * `ops.attention`  softmax attention: the flash kernel a prefill runs,
    the paged kernel of the decode pool, the rows kernel of T5's whole
-   generations, and their jnp references (re-exported below: every
-   family attends);
+   generations, the latent step's kernel (a decode step of latent
+   attention over a cache it writes in place and reads by length:
+   models/latent.py calls it), and their jnp references (re-exported
+   below: every family attends);
  * `ops.ssm`        state-space (Mamba-2) mixing: the chunked scan and
    the one-token step over a float32 recurrent state
    (models/granite_hybrid.py);

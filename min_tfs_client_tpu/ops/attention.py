@@ -1227,6 +1227,254 @@ def attention_rows(
     return out.transpose(0, 2, 1, 3).reshape(q.shape)
 
 
+# -- one query row a head over a latent cache, written where it lies ----------
+#
+# A decode step of latent attention (models/latent.py:absorbed_attention)
+# attends ONE K/V head whose keys are the cache's rows (rank + rope lanes)
+# and whose values are those rows' first `rank` lanes, every query head
+# over the same rows: the heads are the M side of one product a block.
+# The step also writes the token's own row, at the example's own position.
+# Left to XLA, the row's scatter and a read of all S rows whatever the
+# lengths were half of a decode step with a cache at every layer, 3.4 ms
+# of read and in some programs 2.7 of whole-cache copies where the bytes
+# need 2.2 (PERF.md section 6, PR 55).
+# `latent_step_attention` — Pallas kernel — owns the cache for the call:
+#
+#  * the cache stays in HBM (`pl.ANY`) and IS the output
+#    (`input_output_aliases`): XLA neither copies nor stages it;
+#  * a grid step is one example: its ceil(length / block) blocks of
+#    `_LATENT_BLOCK` rows and no others come into VMEM by hand, a copy a
+#    block, in groups of `_LATENT_GROUP` blocks that lie side by side in
+#    one of two slots: the next group (or the next example's first) on its
+#    way while this one's two products run; an example of length 0 (a row
+#    that pads the batch) reads nothing, writes nothing and gives zeros;
+#  * the step's row (B, 1, width) comes in through VMEM. It belongs at
+#    position length - 1, in the example's LAST block: once that block is
+#    in VMEM the row is put into the 16-row tile that holds the position
+#    (bfloat16 packs two rows a sublane, so one row is no aligned copy),
+#    the attention reads it from there, and that one tile goes back to
+#    HBM: the cache changes in that row and no other;
+#  * a group is read once for both products: scores = q (heads, width)
+#    against its rows, values = the weights against their first `rank`
+#    lanes. Scores, the online softmax and the accumulation in float32,
+#    the weights cast to the cache's dtype for the value product. One
+#    softmax step a GROUP, not a block: a step is a chain (product, max,
+#    exp, sum, product) that the next one waits for, 0.6 us whatever the
+#    rows under it (PERF.md section 6, PR 55), so the rows of several
+#    blocks go through it together; the last group's products take the
+#    blocks that were copied and no others (a static size a case).
+
+_LATENT_BLOCK = 128  # positions a copy moves: what a read by length is cut to
+_LATENT_GROUP = 4    # the most blocks one step of the softmax takes
+
+
+def latent_rows_copied(length, seq_len: int, block: int = _LATENT_BLOCK):
+    """Cache rows `latent_step_attention` brings in for a step over
+    `length` rows of an example's `seq_len` (the step's own row among
+    them): whole blocks (what a model counts its latent reads in).
+    `length` an int or an array of them, traced or not."""
+    return jnp.minimum(-(-length // block) * block, seq_len)
+
+
+def _latent_step_kernel(len_ref, q_ref, row_ref, cache_hbm, o_ref, cache_out,
+                        buf, sem, back_sem, first_ref, *, scale: float,
+                        rank: int, block: int, group: int, batch: int):
+    """One example a grid step. Refs: lengths (B,) in SMEM (the rows the
+    query sees, its own among them; 0: a row nobody owns); q (heads,
+    width); the step's row (1, width); the cache (B, 1, S, width) in HBM,
+    in and out the same buffer; o (heads, rank); two slots of a group's
+    blocks, a semaphore a block and the tile's on its way back; the slot
+    this example's first group is in (SMEM, carried from step to step:
+    an example's groups alternate from there)."""
+    b = pl.program_id(0)
+    n = len_ref[b]
+    blocks = (n + block - 1) // block
+    ahead = jnp.minimum(b + 1, batch - 1)
+    blocks_ahead = jnp.where(b + 1 < batch,
+                             (len_ref[ahead] + block - 1) // block, 0)
+
+    def copies(example, of_blocks, grp, slot):
+        """[(whether the example has that block, its copy)] of group
+        `grp` of an example of `of_blocks` blocks, into `slot`."""
+        return [(grp * group + j < of_blocks, pltpu.make_async_copy(
+                    cache_hbm.at[example, 0, pl.ds(pl.multiple_of(
+                        (grp * group + j) * block, block), block)],
+                    buf.at[slot, pl.ds(j * block, block)], sem.at[slot, j]))
+                for j in range(group)]
+
+    def each(pairs, do):
+        for there, copy in pairs:
+            pl.when(there)(functools.partial(do, copy))
+
+    def begin(copy):
+        copy.start()
+
+    def wait(copy):
+        copy.wait()  # servelint: blocks a DMA's semaphore on the device
+
+    @pl.when(b == 0)
+    def _():
+        first_ref[0] = 0
+        each(copies(0, blocks, 0, 0), begin)
+
+    first = first_ref[0]
+    q = q_ref[...]
+
+    def attend(slot, carry, count, limit=None):
+        """One step of the online softmax over the first `count` blocks
+        in `slot`, their first `limit` rows where one is given."""
+        m_prev, l_prev, acc = carry
+        rows = buf[slot, :count * block, :]
+        scores = jax.lax.dot_general(
+            q, rows, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale     # (heads, rows)
+        if limit is not None:
+            at = jax.lax.broadcasted_iota(jnp.int32, (1, count * block), 1)
+            scores = jnp.where(at < limit, scores, NEG_INF)
+        m = jnp.maximum(m_prev, jnp.max(scores, axis=1, keepdims=True))
+        p = jnp.exp(scores - m)
+        correction = jnp.exp(m_prev - m)
+        return (m, correction * l_prev + jnp.sum(p, axis=1, keepdims=True),
+                acc * correction + jnp.dot(
+                    p.astype(rows.dtype), rows[:, :rank],
+                    preferred_element_type=jnp.float32))
+
+    def whole_group(grp, carry):
+        slot = (first + grp) % 2
+        each(copies(b, blocks, grp + 1, 1 - slot), begin)
+        for _, copy in copies(b, blocks, grp, slot):
+            wait(copy)
+        return attend(slot, carry, group)
+
+    @pl.when(n == 0)
+    def _():
+        each(copies(ahead, blocks_ahead, 0, first), begin)
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(n > 0)
+    def _():
+        heads = q.shape[0]
+        last = (blocks - 1) // group       # the group of the last block
+        carry = jax.lax.fori_loop(0, last, whole_group, (
+            jnp.full((heads, 1), NEG_INF, jnp.float32),
+            jnp.zeros((heads, 1), jnp.float32),
+            jnp.zeros((heads, rank), jnp.float32)))
+        slot = (first + last) % 2
+        each(copies(ahead, blocks_ahead, 0, 1 - slot), begin)
+        each(copies(b, blocks, last, slot), wait)
+        # The step's row into the tile that holds its position, in VMEM,
+        # and that tile back to the cache.
+        at = n - 1 - last * group * block
+        tile = pl.multiple_of(at // _ROWS_TILE * _ROWS_TILE, _ROWS_TILE)
+        held = buf[slot, pl.ds(tile, _ROWS_TILE), :]
+        mine = jax.lax.broadcasted_iota(
+            jnp.int32, (_ROWS_TILE, 1), 0) == at - tile
+        buf[slot, pl.ds(tile, _ROWS_TILE), :] = jnp.where(
+            mine, row_ref[...].astype(jnp.float32),
+            held.astype(jnp.float32)).astype(held.dtype)
+        back = pltpu.make_async_copy(
+            buf.at[slot, pl.ds(tile, _ROWS_TILE)],
+            cache_out.at[b, 0, pl.ds(pl.multiple_of(
+                last * group * block + tile, _ROWS_TILE), _ROWS_TILE)],
+            back_sem)
+        back.start()
+        for count in range(1, group + 1):
+            @pl.when(blocks - last * group == count)
+            def _(count=count):
+                m, l, acc = attend(slot, carry, count, limit=at + 1)
+                o_ref[...] = (acc / l).astype(o_ref.dtype)
+
+        wait(back)
+        first_ref[0] = 1 - slot
+
+
+def _latent_step_vmem_bytes(heads: int, width: int, rank: int, rows: int,
+                            itemsize: int) -> int:
+    """VMEM `_latent_step_kernel` takes with `rows` positions a group:
+    two slots of them; q, the step's row and o in the two buffers a
+    call's operands get; the float32 scores, weights and weighted sum
+    and what the body keeps beside them."""
+    return (2 * rows * width * itemsize
+            + 2 * (heads * width + 16 * width + heads * rank) * itemsize
+            + 4 * heads * (3 * rank + 4 * max(rows, 128))
+            + 4 * rows * width)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "rank", "scale", "block", "interpret"))
+def latent_step_attention(q: jax.Array, row: jax.Array, cache: jax.Array,
+                          lengths: jax.Array, *, rank: int, scale: float,
+                          block: int = _LATENT_BLOCK,
+                          interpret: bool = False):
+    """A decode step's latent attention as ONE Pallas call: q (B, heads,
+    width) the absorbed queries, row (B, width) the step's own latent
+    rows, cache (B, 1, S, width) with S whole blocks, lengths (B,) the
+    rows each query sees, its own (written at lengths - 1) the last of
+    them; 0 for a row nobody owns; `block` the positions a copy takes
+    (whole tiles of 16). -> (o (B, heads, rank) in q's dtype:
+    softmax(scale q . rows) over the rows' first `rank` lanes, zeros at
+    length 0; the cache with each row written, in the input's buffer)."""
+    b, heads, width = q.shape
+    s = cache.shape[2]
+    group = _LATENT_GROUP
+    by_example = lambda i, lens: (i, 0, 0)  # noqa: E731
+    o, cache = pl.pallas_call(
+        functools.partial(_latent_step_kernel, scale=scale, rank=rank,
+                          block=block, group=group, batch=b),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,  # lengths
+            grid=(b,),
+            in_specs=[pl.BlockSpec((None, heads, width), by_example),
+                      pl.BlockSpec((None, 1, width), by_example),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=[pl.BlockSpec((None, heads, rank), by_example),
+                       pl.BlockSpec(memory_space=pl.ANY)],
+            scratch_shapes=[
+                pltpu.VMEM((2, group * block, width), cache.dtype),
+                pltpu.SemaphoreType.DMA((2, group)),
+                pltpu.SemaphoreType.DMA(()),
+                pltpu.SMEM((1,), jnp.int32),
+            ]),
+        out_shape=[jax.ShapeDtypeStruct((b, heads, rank), q.dtype),
+                   jax.ShapeDtypeStruct(cache.shape, cache.dtype)],
+        # operand 3: the prefetched lengths, q and the row come first
+        input_output_aliases={3: 1},
+        # in order: a step starts the copies the next one waits for. The
+        # limit is the kernel's own account and 4 MiB for what Mosaic
+        # keeps: XLA plans what IT moves into VMEM around a call by the
+        # limit the call names (parallel/moe.py:expert_walk_kernel)
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_latent_step_vmem_bytes(
+                heads, width, rank, group * block, cache.dtype.itemsize)
+            + (4 << 20)),
+        interpret=interpret,
+        name="_latent_step_kernel",  # the device-trace reduction finds it
+    )(jnp.minimum(lengths.astype(jnp.int32), s), q,
+      row.astype(cache.dtype)[:, None], cache)
+    return o, cache
+
+
+def _latent_step_applies(q: jax.Array, cache: jax.Array, rank: int) -> bool:
+    """The shapes `_latent_step_kernel` compiles for, read from the
+    shapes alone: one K/V head, rows and their first `rank` lanes (the
+    values) both whole 128-lane tiles (a copy takes a row at tile
+    borders only), the cache's positions whole blocks, whole sublane
+    tiles of heads, one dtype, and a step inside VMEM. One device only,
+    as the other reads."""
+    _, heads, width = q.shape
+    block = _LATENT_BLOCK
+    return (cache.ndim == 4 and cache.shape[1] == 1
+            and cache.shape[3] == width and cache.dtype == q.dtype
+            and width % 128 == 0 and rank % 128 == 0 and rank <= width
+            and heads % 8 == 0
+            and cache.shape[2] % block == 0
+            and _latent_step_vmem_bytes(
+                heads, width, rank, _LATENT_GROUP * block,
+                cache.dtype.itemsize) <= _PAGED_STEP_VMEM_BYTES
+            and not _auto_mesh_axes())
+
+
 def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 

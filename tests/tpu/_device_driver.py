@@ -114,6 +114,51 @@ def main() -> int:
              "tpu_custom_call" in lowered and err < 0.06, max_err=err,
              dispatched="tpu_custom_call" in lowered)
 
+    # -- 1c. a decode step's latent attention: the kernel against the jnp
+    # form at both cells' shapes (Xing's caches of 2,176 positions and
+    # YaRN's scale, Ling's of 2,304 and the plain one): ragged positions,
+    # a row nobody owns, the row written where it lies and no other moved.
+    from min_tfs_client_tpu.models import latent
+
+    heads, nope, rope_lanes, dv, rank = 32, 128, 64, 128, 512
+    for cell, (positions, scale) in {
+            "xing": (2176, 192 ** -0.5 * latent.yarn_mscale(64.0, 1.0) ** 2),
+            "ling": (2304, 192 ** -0.5)}.items():
+        lb = 8
+        kvb = jnp.asarray(rng.standard_normal((rank, heads * (nope + dv)))
+                          * rank ** -0.5, jnp.bfloat16)
+        lq = jnp.asarray(rng.standard_normal((lb, heads, nope + rope_lanes)),
+                         jnp.bfloat16)
+        cache = jnp.asarray(rng.standard_normal((lb, 1, positions, 640)),
+                            jnp.bfloat16).at[..., 576:].set(0)
+        row = jnp.asarray(rng.standard_normal((lb, 640)),
+                          jnp.bfloat16).at[..., 576:].set(0)
+        at = jnp.asarray([0, 15, 16, 127, 128, 1030, positions - 1, 700],
+                         jnp.int32)
+        owned = jnp.asarray([True] * 7 + [False])
+        sizes = dict(nope=nope, v_head_dim=dv, scale=scale)
+        step = jax.jit(lambda *a: latent.absorbed_attention(*a, **sizes))
+        lowered = step.lower(kvb, lq, cache, row, at, owned).as_text()
+        got, after, copied = step(kvb, lq, cache, row, at, owned)
+        shut = latent._on_tpu
+        latent._on_tpu = lambda: False      # the jnp form, on the chip
+        try:
+            want, want_after, held = jax.jit(
+                lambda *a: latent.absorbed_attention(*a, **sizes))(
+                    kvb, lq, cache, row, at, owned)
+        finally:
+            latent._on_tpu = shut
+        err = float(jnp.max(jnp.abs(got[:7] - want[:7])))
+        emit(f"latent_step/{cell}",
+             "_latent_step_kernel" in lowered and err < 0.05
+             and not bool(jnp.any(got[7]))
+             and bool(jnp.all(after[:7] == want_after[:7]))
+             and bool(jnp.all(after[7] == cache[7]))
+             and copied.tolist() == [128, 128, 128, 128, 256, 1152,
+                                     positions, 0]
+             and held.tolist() == [positions] * lb,
+             max_err=err, scale_of_values=float(jnp.std(want[:7])))
+
     # -- 2. bucketed Predict through the serving stack on device -----------
     import pathlib
     import tempfile

@@ -12,7 +12,10 @@ step as the loop of a whole generation runs it, a `lax.scan` of 16 steps
 with the state a real prefill left, donated, timed over 5 calls and
 captured once for its device time by operation (a `while` spans its
 body's operations), on 12, 20 and 32 real rows of the traffic's own
-lengths, the rest rows that pad the batch (length 0). `experts`:
+lengths, the rest rows that pad the batch (length 0); the table names
+`_latent_step_kernel`, and the script FAILS where a step still copies,
+slices or updates an array shaped like the latent cache
+(`mimo_pieces.latent_step_faults`). `experts`:
 `held_experts_ffn` alone under the group-limited rule, 128 held of 512,
 top 8, on 12, 20 and 32 valid rows of 32: the walk over the hit experts
 in both its forms side by side (`walk`, as the tree runs it: the kernel
@@ -57,7 +60,12 @@ import numpy as np  # noqa: E402
 from min_tfs_client_tpu.models import ling_hybrid as lh  # noqa: E402
 from min_tfs_client_tpu.ops import kda  # noqa: E402
 from min_tfs_client_tpu.parallel import moe  # noqa: E402
-from mimo_pieces import SCAN, ops_a_step  # noqa: E402  (beside this file)
+from mimo_pieces import (  # noqa: E402  (beside this file)
+    SCAN,
+    fail_on_latent_step_faults,
+    latent_step_faults,
+    ops_a_step,
+)
 from perfbench import children  # noqa: E402
 from t5_pieces import timed_and_captured  # noqa: E402  (beside this file)
 
@@ -350,9 +358,13 @@ def main() -> None:
                         capture, most=30, steps=1)
             if "decode" in pieces:
                 decode(out, name, params, pc, state)
+                out[f"decode_{name}_faults"] = latent_step_faults(
+                    out[f"decode_{name}_ops"], BATCH,
+                    SEQ_LEN + MAX_DECODE_LEN)
     print(json.dumps(out, indent=1))
     pathlib.Path(args.out).parent.mkdir(parents=True, exist_ok=True)
     pathlib.Path(args.out).write_text(json.dumps(out, indent=1))
+    fail_on_latent_step_faults(out)
 
 
 if __name__ == "__main__":

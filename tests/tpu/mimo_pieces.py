@@ -221,6 +221,29 @@ def ops_a_step(capture: str, most: int = 40, steps: int = SCAN) -> dict:
             "ops": dict(listed[:most])}
 
 
+def latent_step_faults(ops: dict, batch: int, positions: int) -> list:
+    """What a step's by-operation table (`ops_a_step`) may not hold once
+    the latent caches are `_latent_step_kernel`'s: a copy, slice or
+    update of an array shaped like a cache, (batch, [1,] positions, .);
+    and the kernel itself must be among the operations."""
+    cache = re.compile(rf"\[{batch},(1,)?{positions},\d+\]")
+    faults = [name for name in ops["ops"]
+              if name.startswith(("copy", "slice", "dynamic-update-slice"))
+              and cache.search(name)]
+    if "_latent_step_kernel" not in ops["ops"]:
+        faults.append("no _latent_step_kernel among the step's operations")
+    return faults
+
+
+def fail_on_latent_step_faults(out: dict) -> None:
+    """Ends a pieces script with an error where any `*_faults` entry of
+    what it measured (`latent_step_faults`) names one."""
+    faults = {name: found for name, found in out.items()
+              if name.endswith("_faults") and found}
+    if faults:
+        sys.exit(f"a step still moves a latent cache: {faults}")
+
+
 def main() -> None:
     parser = argparse.ArgumentParser()
     parser.add_argument("--pieces", default="experts,flash,prefill,decode")

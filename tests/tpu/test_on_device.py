@@ -9,6 +9,8 @@ Checks driven on hardware:
   * Pallas flash attention (compiled) vs the jnp oracle — plain, causal,
     and ragged-lengths variants — and that the dispatcher picks it;
   * ragged paged attention with bias over a multi-page table (T5's path);
+  * a decode step's latent attention, the kernel against the jnp form at
+    Xing's and Ling's shapes (the row written in place, blocks by length);
   * a bucketed Predict through the full tpu:// serving stack;
   * mesh attach + predict on a 1-device device mesh;
   * int8 weight-only quantized Predict vs full precision;
@@ -87,6 +89,13 @@ def test_attention_dispatcher_picks_flash_on_device(device_results):
 @pytest.mark.parametrize("sq", [1, 5])
 def test_paged_attention_with_bias_past_one_page(device_results, sq):
     rec = device_results.get(f"paged_bias_multipage/sq{sq}")
+    assert rec is not None and rec["ok"], rec
+
+
+@pytest.mark.integration
+@pytest.mark.parametrize("cell", ["xing", "ling"])
+def test_latent_step_kernel_is_the_jnp_step_on_device(device_results, cell):
+    rec = device_results.get(f"latent_step/{cell}")
     assert rec is not None and rec["ok"], rec
 
 

@@ -5,8 +5,9 @@ pass, at logits (the absorbed decode form against the decompressed one:
 tests/unit/test_latent.py, with the latent forms' one home); the two
 faults of the hand-over, made in the program, each caught; the
 four shares of an expert layer adding up to the uncut layer; the spans
-and counters of an answer; the export round trip; what a config
-refuses."""
+and counters of an answer, through the jnp step and through the step's
+kernel (interpreted), whose generation is the jnp one's; the export
+round trip; what a config refuses."""
 
 import json
 import pathlib
@@ -19,6 +20,11 @@ import pytest
 from min_tfs_client_tpu.models import ling_hybrid as lh
 from min_tfs_client_tpu.parallel import moe
 from perfbench import children
+from tests.unit.test_latent import (
+    answers_through_both_bodies,
+    check_the_kernel_s_generation_is_the_jnp_one,
+    check_the_rows_an_answer_brought_in,
+)
 
 ROOT = pathlib.Path(__file__).resolve().parents[2]
 SEQ, STEPS, CHUNK = 80, 16, 32
@@ -116,7 +122,9 @@ def test_a_row_of_length_0_touches_nothing(tiny, generated):
             assert not np.any(np.asarray(cache["kda"])[empty])
             assert not np.any(np.asarray(cache["conv"])[empty])
         else:
-            assert cache["latent"].shape == (12, 1, SEQ + 4, 32 + 8)
+            # 32 + 8 values, then zeros to whole lane tiles
+            assert cache["latent"].shape == (12, 1, SEQ + 4, 128)
+            assert not np.any(np.asarray(cache["latent"])[..., 32 + 8:])
     assert not np.any(np.asarray(state["logits"])[empty])
     # ... and a window shorter than 3 rows is zeros in front
     short = LENGTHS.index(1)
@@ -375,7 +383,9 @@ def test_an_answer_carries_its_route_its_state_and_its_latent_rows(tiny):
     assert spans["generate/latent"] == {
         "prompt_tokens": sum(LENGTHS), "steps": 96,
         "latent_rows_read": int(latent[:, 2].sum()),
-        "latent_rows_held": 9 * 8 * (SEQ + 8)}
+        "latent_rows_held": 9 * 8 * (SEQ + 8),
+        # the jnp step brings in every row it holds
+        "latent_rows_copied": 9 * 8 * (SEQ + 8)}
     assert spans["generate/state"]["state_rows_moved"] == 9 * 3 * 8
     assert spans["generate/state"]["scan_rows"] == int(rows[:, 1].sum())
     assert spans["generate/route"]["prompt_tokens"] == sum(LENGTHS)
@@ -385,9 +395,34 @@ def test_an_answer_carries_its_route_its_state_and_its_latent_rows(tiny):
     snapshot = runtime.snapshot()
     counted = snapshot["latent"]["ling:1:serving_default"]
     assert counted["requests"] >= 1
-    assert counted["latent_rows_read"] < counted["latent_rows_held"]
+    assert counted["latent_rows_read"] < counted["latent_rows_copied"] \
+        == counted["latent_rows_held"]
     assert snapshot["state"]["ling:1:serving_default"]["steps"] >= 96
     assert snapshot["route"]["ling:1:serving_default"]["requests"] >= 1
+
+
+# -- whole generations through the step's kernel -----------------------------
+
+
+@pytest.fixture(scope="module")
+def answers(tiny):
+    """A whole generation of 16 steps as an answer, through the jnp step
+    and through the step's kernel (the caches hold 96 positions)."""
+    return answers_through_both_bodies(
+        lh, tiny["params"], tiny["program_config"], tiny["ids"],
+        seq_len=SEQ, steps=STEPS, model="ling")
+
+
+@pytest.mark.parametrize("row", [r for r, n in enumerate(LENGTHS) if n])
+def test_a_generation_through_the_kernel_is_the_jnp_generation(answers, row):
+    check_the_kernel_s_generation_is_the_jnp_one(answers, row, ATOL)
+
+
+@pytest.mark.parametrize("form", ["jnp", "kernel"])
+def test_an_answer_counts_the_cache_rows_its_steps_brought_in(answers, form):
+    check_the_rows_an_answer_brought_in(
+        answers, form, lh.LATENT_COLUMNS, LENGTHS,
+        layers=1, seq_len=SEQ, steps=STEPS)
 
 
 def test_the_family_exports_and_loads(tiny, tmp_path):

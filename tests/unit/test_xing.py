@@ -4,8 +4,10 @@ latent caches of every layer against the plain reference's ONE forward
 pass, at logits; the three faults of the hand-over, made in the program,
 each caught; a row of length 0 touches nothing; the eight shares of an
 expert layer adding up to the uncut layer; the program's parameter count
-at the published widths; the spans and counters of an answer; the export
-round trip; what a config refuses."""
+at the published widths; the spans and counters of an answer, through
+the jnp step and through the step's kernel (interpreted), whose
+generation is the jnp one's; the export round trip; what a config
+refuses."""
 
 import dataclasses
 import json
@@ -19,6 +21,11 @@ import pytest
 from min_tfs_client_tpu.models import xing
 from min_tfs_client_tpu.parallel import moe
 from perfbench import children
+from tests.unit.test_latent import (
+    answers_through_both_bodies,
+    check_the_kernel_s_generation_is_the_jnp_one,
+    check_the_rows_an_answer_brought_in,
+)
 
 ROOT = pathlib.Path(__file__).resolve().parents[2]
 SEQ, STEPS = 80, 16
@@ -143,7 +150,9 @@ def test_a_row_of_length_0_touches_nothing(tiny, generated):
     empty = np.asarray(LENGTHS) == 0
     assert len(state["caches"]) == LAYERS
     for cache in state["caches"]:
-        assert cache["latent"].shape == (12, 1, SEQ + 4, 32 + 8)
+        # 32 + 8 values, then zeros to whole lane tiles
+        assert cache["latent"].shape == (12, 1, SEQ + 4, 128)
+        assert not np.any(np.asarray(cache["latent"])[..., 32 + 8:])
     assert not np.any(np.asarray(state["logits"])[empty])
     assert np.asarray(state["counts"]["stream_rows"]).tolist() \
         == [2 * LAYERS * n for n in LENGTHS]
@@ -151,7 +160,7 @@ def test_a_row_of_length_0_touches_nothing(tiny, generated):
     # no stream
     counts = generated["state"]["counts"]
     for name in ("held_prefill", "held_decode", "latent_rows_read",
-                 "latent_rows_held", "stream_rows"):
+                 "latent_rows_held", "latent_rows_copied", "stream_rows"):
         assert np.asarray(counts[name])[empty].tolist() == [0, 0, 0], name
     # a real row's steps read the positions up to their own in every
     # layer: a prompt of n tokens and 15 steps read n + 1 .. n + 15
@@ -160,6 +169,9 @@ def test_a_row_of_length_0_touches_nothing(tiny, generated):
         == (LAYERS * (15 * real + 15 * 16 // 2)).tolist()
     assert set(np.asarray(counts["latent_rows_held"])[~empty].tolist()) \
         == {LAYERS * 15 * (SEQ + STEPS)}
+    # the jnp step brings in every row it holds
+    assert np.array_equal(counts["latent_rows_copied"],
+                          counts["latent_rows_held"])
     assert np.asarray(counts["stream_rows"])[~empty].tolist() \
         == (2 * LAYERS * (real + 15)).tolist()
 
@@ -430,6 +442,7 @@ def test_an_answer_carries_its_route_its_latent_rows_and_its_streams(tiny):
         "stream_rows": int(streams[:, 2].sum()),
         "sinkhorn_rounds": 3 * int(streams[:, 2].sum())}
     assert spans["generate/latent"]["latent_rows_held"] \
+        == spans["generate/latent"]["latent_rows_copied"] \
         == 9 * LAYERS * 8 * (SEQ + 8)
     assert spans["generate/route"]["prompt_tokens"] == sum(LENGTHS)
     # three expert layers of the four, top 2
@@ -441,8 +454,33 @@ def test_an_answer_carries_its_route_its_latent_rows_and_its_streams(tiny):
     assert counted["sinkhorn_rounds"] == 3 * counted["stream_rows"] > 0
     latent_counted = snapshot["latent"]["xing:1:serving_default"]
     assert latent_counted["latent_rows_read"] \
-        < latent_counted["latent_rows_held"]
+        < latent_counted["latent_rows_copied"] \
+        == latent_counted["latent_rows_held"]
     assert snapshot["route"]["xing:1:serving_default"]["requests"] >= 1
+
+
+# -- whole generations through the step's kernel -----------------------------
+
+
+@pytest.fixture(scope="module")
+def answers(tiny):
+    """A whole generation of 16 steps as an answer, through the jnp step
+    and through the step's kernel (the caches hold 96 positions)."""
+    return answers_through_both_bodies(
+        xing, tiny["params"], quick(tiny["program_config"]), tiny["ids"],
+        seq_len=SEQ, steps=STEPS, model="xing")
+
+
+@pytest.mark.parametrize("row", [r for r, n in enumerate(LENGTHS) if n])
+def test_a_generation_through_the_kernel_is_the_jnp_generation(answers, row):
+    check_the_kernel_s_generation_is_the_jnp_one(answers, row, ATOL)
+
+
+@pytest.mark.parametrize("form", ["jnp", "kernel"])
+def test_an_answer_counts_the_cache_rows_its_steps_brought_in(answers, form):
+    check_the_rows_an_answer_brought_in(
+        answers, form, xing.LATENT_COLUMNS, LENGTHS,
+        layers=LAYERS, seq_len=SEQ, steps=STEPS)
 
 
 def test_the_family_exports_and_loads(tiny, tmp_path):
